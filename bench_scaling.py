@@ -3,8 +3,8 @@
 Measures data-parallel ResNet train-step throughput at 1..N chips and the
 raw gradient-allreduce bandwidth, reporting scaling efficiency
 (throughput_n / (n × throughput_1)).  On a real pod the mesh covers
-physical chips and the collective rides ICI; on this 1-chip dev box run
-with ``--simulate-devices 8 --platform cpu`` for the methodology curve
+physical chips and the collective rides ICI; without one, run with
+``--simulate-devices 8 --platform cpu`` for the methodology curve
 (framework-overhead scaling only — SURVEY §7 step 7 notes v4-32 numbers
 are for the real-pod stage).
 
@@ -22,11 +22,6 @@ import numpy as np
 def measure_step_throughput(n_devices, per_chip_bs, image_size, steps,
                             model_kind="resnet18"):
     import jax
-    try:  # persistent compile cache (shared with bench.py)
-        jax.config.update("jax_compilation_cache_dir",
-                          "/tmp/chainermn_tpu_jax_cache")
-    except Exception:
-        pass
     import jax.numpy as jnp
 
     import chainermn_tpu as ct
@@ -1316,6 +1311,8 @@ def main():
         use_platform(args.platform)
 
     import jax
+    from chainermn_tpu.utils.compat import configure_persistent_cache
+    configure_persistent_cache()
     max_devices = len(jax.devices())
     counts = [n for n in (1, 2, 4, 8, 16, 32) if n <= max_devices]
 

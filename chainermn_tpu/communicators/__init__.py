@@ -3,7 +3,7 @@
 Reference: ``chainermn/communicators/__init__.py · create_communicator``
 (SURVEY.md §2.1) — maps a name string to a communicator.  All reference
 names are accepted; on TPU they are flavors of one mesh-backed
-implementation (SURVEY §2.7: the taxonomy collapses to mesh-axis +
+implementation (SURVEY §2.7: the classification collapses to mesh-axis +
 dtype + bucketing choices):
 
 ===================  ========================================================

@@ -38,8 +38,8 @@ always win, which is what makes the golden-trajectory gate exact — an
 ``autotune=`` run whose derived plan matches the hand knobs compiles
 the identical program.  The agreed plan is recorded as an artifact
 (``CHAINERMN_TPU_AUTOTUNE_DIR``) mirroring ``tools/autotune_plan.json``,
-whose committed numeric fields stay null until the recovery queue's
-FIRST-CHIP-CONTACT item 11 stamps them on real hardware.
+whose committed numeric fields stay null until a run on real hardware
+stamps them.
 """
 
 from __future__ import annotations
@@ -103,7 +103,7 @@ def measure_fabric(comm, probe_mb=1.0, iters=4):
     (the probes are real collectives over the shared mesh).
     """
     from .. import observability
-    from chainermn_tpu.utils.compat import shard_map
+    from jax import shard_map
     measurement = {"source": "startup", "probe_mb": _round6(probe_mb),
                    "iters": int(iters), "hops": {}}
     with observability.span("autotune/measure",

@@ -3,7 +3,7 @@
 Reference: the whole of ``chainermn/communicators/`` (SURVEY.md §2.1).
 The reference's eight communicator classes solve GPU-cluster problems
 (CUDA-aware MPI, host staging, node hierarchy, NCCL rings).  On TPU the
-transport is one thing — XLA collectives over ICI/DCN — so the taxonomy
+transport is one thing — XLA collectives over ICI/DCN — so the classification
 collapses into *mesh-axis choice + gradient dtype choice* (SURVEY §2.7),
 and the named variants (``naive``/``flat``/``hierarchical``/
 ``two_dimensional``/``single_node``/``non_cuda_aware``/``pure_nccl``)
@@ -1553,7 +1553,7 @@ class MeshCommunicator(CommunicatorBase):
         leading axis (one slice per rank); pass ``P()`` in ``in_specs``/
         ``out_specs`` for replicated values.
         """
-        from chainermn_tpu.utils.compat import shard_map
+        from jax import shard_map
         axis = self.axis_name
         if self._axis_in_scope():
             # already inside a shard_map binding this axis (e.g. the

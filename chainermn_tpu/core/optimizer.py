@@ -520,6 +520,17 @@ class Optimizer:
             return None
         return aot_memory_analysis(*spec)
 
+    def traced_step(self):
+        """The most recently dispatched step re-traced from its shape
+        specs (None before any update): ``.jaxpr`` for structure
+        censuses, ``.lower()`` for the program text.  No buffers are
+        touched."""
+        spec = getattr(self, "_last_step_spec", None)
+        if spec is None:
+            return None
+        step, operands = spec
+        return step.trace(*operands)
+
     def _cache_key(self, lossfun, args, kwargs):
         shapes = tuple(
             (np.shape(a), str(getattr(a, "dtype", type(a).__name__)))

@@ -189,7 +189,7 @@ class DCGANUpdater(StandardUpdater):
         if comm is None:
             # donate optimizer states (replaced by returned values)
             return jax.jit(step, donate_argnums=(2, 3))
-        from chainermn_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
         mapped = shard_map(
             step, mesh=comm.mesh,

@@ -7,7 +7,7 @@ Freshly designed for TPU rather than transcribed:
 
 * Activations run in a selectable layout: ``layout="NHWC"`` (the TPU
   native channels-last layout — channels map onto the MXU lane dimension,
-  so XLA inserts no relayout transposes between conv/BN/relu) or
+  so XLA inserts no layout-change transposes between conv/BN/relu) or
   ``"NCHW"`` (the reference layout, kept as the compatibility default).
   Kernels are stored OIHW either way, so checkpoints are layout-portable.
 * ``compute_dtype=bfloat16`` runs conv/matmul compute in bf16 (MXU-native)

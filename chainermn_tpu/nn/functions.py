@@ -202,7 +202,7 @@ def embed_id(x, W, ignore_label=None):
 # Kernel storage is always OIHW (the reference layout — checkpoints stay
 # portable); the ACTIVATION layout is a per-call choice.  "NCHW" is the
 # reference's layout; "NHWC" is the TPU-native layout (channels-last maps
-# directly onto the MXU's lane dimension, so XLA inserts no relayout
+# directly onto the MXU's lane dimension, so XLA inserts no layout-change
 # transposes between conv, BN, and elementwise ops).
 
 def _pair(v):

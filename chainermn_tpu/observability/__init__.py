@@ -11,7 +11,7 @@ Two host-side pieces every subsystem shares:
   the object collectives and rendered in Prometheus text format
   (``PROBE=obs`` / ``make probe-obs``).
 
-Span taxonomy, knob ladder, and the merge workflow:
+Span classification, knob ladder, and the merge workflow:
 ``docs/observability.md``.
 """
 

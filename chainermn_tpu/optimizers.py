@@ -94,6 +94,16 @@ def _rehome_replicated(tree, communicator):
     return jax.tree.map(move, tree)
 
 
+def _pmean_state(pstate, axis):
+    """Mean the floating persistent state (BN running statistics) over
+    the ranks.  Integer leaves (BN's batch counter) are equal on every
+    rank and pass through: a ``pmean`` would return them as floats, and
+    that dtype change compiles the step a second time."""
+    return jax.tree.map(
+        lambda s: lax.pmean(s, axis)
+        if jnp.issubdtype(jnp.result_type(s), jnp.inexact) else s, pstate)
+
+
 def create_multi_node_optimizer(actual_optimizer, communicator,
                                 double_buffering=False, zero_fill=True,
                                 zero_sharding=False, exchange=None,
@@ -614,6 +624,12 @@ class _MultiNodeOptimizer:
                     if self._sharded_update
                     else self._make_step(lossfun, args, kwargs))
             self._mn_step_cache[key] = step
+            # first dispatch of this program: hand it its state placed
+            # as it will return it, or the second dispatch recompiles
+            pstate = self._replicate_uncommitted(pstate)
+            if not self._sharded_update:
+                opt_state = actual._opt_state = \
+                    self._replicate_uncommitted(opt_state)
 
         if self._double_buffering and self._stale_grads is None:
             if self._db_dcn:
@@ -675,6 +691,19 @@ class _MultiNodeOptimizer:
         reporter_module.report(obs)
         self._maybe_online_retune()
         return loss
+
+    def _replicate_uncommitted(self, tree):
+        """Place what is not yet committed to a device (optax's scalar
+        step count, BN's Python-int batch counter) replicated over the
+        mesh, which is how the step returns it.  Otherwise the first
+        dispatch sees an uncommitted single-device scalar and the second
+        a committed mesh array: two jit cache keys, and the whole step
+        compiles twice."""
+        from jax.sharding import NamedSharding
+        replicated = NamedSharding(self.communicator.mesh, P())
+        return jax.tree.map(
+            lambda a: a if isinstance(a, jax.Array) and a.committed
+            else jax.device_put(a, replicated), tree)
 
     # -- ZeRO-1 sharded optimizer state (beyond reference) -----------------
     def _zero_transform(self):
@@ -1002,7 +1031,7 @@ class _MultiNodeOptimizer:
         return zero_update
 
     def _make_zero_step(self, lossfun, ex_args, ex_kwargs):
-        from chainermn_tpu.utils.compat import shard_map
+        from jax import shard_map
         from .core.optimizer import make_loss_and_grad
         comm = self.communicator
         actual = self.actual_optimizer
@@ -1025,8 +1054,7 @@ class _MultiNodeOptimizer:
                             residual[0] if needs_residual else None)
             loss = lax.pmean(loss, axis)
             obs = jax.tree.map(lambda o: lax.pmean(o, axis), obs)
-            new_pstate = jax.tree.map(lambda s: lax.pmean(s, axis),
-                                      new_pstate)
+            new_pstate = _pmean_state(new_pstate, axis)
             # grads out: the fresh mean-gradient CHUNK under double
             # buffering (it becomes the next stale buffer); otherwise
             # None — the full mean gradient never exists on this path
@@ -1096,7 +1124,7 @@ class _MultiNodeOptimizer:
             f"as 0-d arrays)")
 
     def _make_step(self, lossfun, ex_args, ex_kwargs):
-        from chainermn_tpu.utils.compat import shard_map
+        from jax import shard_map
         from .core.optimizer import (apply_transform_update,
                                      make_loss_and_grad)
         comm = self.communicator
@@ -1152,7 +1180,7 @@ class _MultiNodeOptimizer:
             # per-rank scalars → global means for reporting / BN state
             loss = lax.pmean(loss, axis)
             obs = jax.tree.map(lambda o: lax.pmean(o, axis), obs)
-            new_pstate = jax.tree.map(lambda s: lax.pmean(s, axis), new_pstate)
+            new_pstate = _pmean_state(new_pstate, axis)
             out_grads = (grads, fresh_dcn) if db_dcn else grads
             return new_params, new_pstate, new_opt_state, loss, out_grads, \
                 res_out, obs
@@ -1280,7 +1308,7 @@ class _MultiNodeOptimizer:
         return losses
 
     def _make_scan_step(self, lossfun, ex_args, ex_kwargs, n_steps):
-        from chainermn_tpu.utils.compat import shard_map
+        from jax import shard_map
         from .core.optimizer import (apply_transform_update,
                                      make_loss_and_grad)
         comm = self.communicator
@@ -1327,7 +1355,7 @@ class _MultiNodeOptimizer:
                                     init_res, jnp.int32(0)),
                          (args, kwargs))
             losses = lax.pmean(losses, axis)
-            pstate = jax.tree.map(lambda s: lax.pmean(s, axis), pstate)
+            pstate = _pmean_state(pstate, axis)
             # observations: mean over the K fused steps (matches what a
             # LogReport consumer would average from K plain updates), then
             # over ranks
@@ -1358,7 +1386,7 @@ class _MultiNodeOptimizer:
         params (ONE buffer, exactly as per-step ZeRO keeps one gathered
         copy live) plus the sharded flat opt state; each scan iteration
         is the full reduce-scatter → chunk update → all-gather step."""
-        from chainermn_tpu.utils.compat import shard_map
+        from jax import shard_map
         from .core.optimizer import make_loss_and_grad
         comm = self.communicator
         actual = self.actual_optimizer
@@ -1392,7 +1420,7 @@ class _MultiNodeOptimizer:
                          (params, pstate, opt_state, init_res,
                           jnp.int32(0)), (args, kwargs))
             losses = lax.pmean(losses, axis)
-            pstate = jax.tree.map(lambda s: lax.pmean(s, axis), pstate)
+            pstate = _pmean_state(pstate, axis)
             obs = jax.tree.map(
                 lambda o: lax.pmean(jnp.mean(o, axis=0), axis), all_obs)
             res_out = (last_res,) if needs_residual else ()

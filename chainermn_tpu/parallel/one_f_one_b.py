@@ -162,7 +162,7 @@ def make_pipeline_train_step(comm, stage_fn, loss_fn, tx, n_microbatches):
     internally.  The whole schedule + update compiles to one program —
     the pipeline counterpart of ``create_multi_node_optimizer``'s DP step.
     """
-    from chainermn_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from .pipeline import split_microbatches
     axis = comm.axis_name

@@ -1,4 +1,4 @@
-"""Typed serving errors — the backpressure half of the PR 1 taxonomy.
+"""Typed serving errors — the backpressure half of the PR 1 classification.
 
 The resilience subsystem's rule (``communicators._host_channel``): a
 failure crossing a subsystem boundary is a TYPED exception carrying the

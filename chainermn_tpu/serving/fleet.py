@@ -452,7 +452,7 @@ class FleetWorker:
 
     def sync_weights(self, view, joiners, root=None):
         """Walk the view's multicast tree plan from this worker's seat:
-        receive the weight payload when this rank is a ``dst``, relay
+        receive the weight payload when this rank is a ``dst``, forward
         it when a later round names this rank a ``src``.  Pure-plan
         symmetric counterpart of :meth:`ReplicaFleet._sync_weights`."""
         me = self.membership.rank

@@ -16,7 +16,7 @@ discipline one level up:
   :class:`~chainermn_tpu.serving.errors.QueueSaturatedError` from its
   own scheduler; the router SHEDS the request sideways to the next
   replica in rotation and only re-raises (the same typed error — the
-  ingress taxonomy is unchanged) when EVERY live replica refused.
+  ingress classification is unchanged) when EVERY live replica refused.
   :class:`~chainermn_tpu.serving.errors.PagePoolExhaustedError` (the
   could-never-fit submit check) sheds the same way — identical pools
   will all refuse, heterogeneous fleets may not.
@@ -135,7 +135,7 @@ class FleetRouter:
                                   "spills": i, "reroute": reroute})
                     return replica.rid
                 # every live replica refused: surface the typed
-                # taxonomy unchanged (the caller's retry-after
+                # classification unchanged (the caller's retry-after
                 # contract)
                 raise last_exc
         finally:

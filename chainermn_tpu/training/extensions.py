@@ -339,7 +339,7 @@ class Evaluator(Extension):
                 return obs
 
             if shardable:
-                from chainermn_tpu.utils.compat import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as P
                 args_specs = jax.tree.map(lambda _: P(axis), args)
                 fn = jax.jit(shard_map(

@@ -1,8 +1,11 @@
 """ctypes binding + build for the native data-loader core.
 
-Compiled on first use with g++ (cached beside the source); degrades
-gracefully to None when no toolchain is available — consumers fall back
-to the pure-Python iterators.
+Compiled on first use with g++ (cached beside the source, for the
+generic target so the file is valid on any machine of the same
+architecture).  ``load_library()`` answers None when it cannot build —
+callers that merely probe fall back to the pure-Python iterators;
+``NativeLoader``, which a caller asks for by name, raises with the
+build error.
 """
 
 from __future__ import annotations
@@ -19,18 +22,19 @@ __all__ = ["load_library", "bind_signatures", "NativeLoader"]
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_error = None
 
 
 def _build(src, out):
     subprocess.run(
-        ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
+        ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
          "-pthread", src, "-o", out],
         check=True, capture_output=True)
 
 
 def load_library():
     """Build (if needed) and load the shared library; None on failure."""
-    global _lib, _tried
+    global _lib, _tried, _error
     with _lock:
         if _lib is not None or _tried:
             return _lib
@@ -43,7 +47,10 @@ def load_library():
                     os.path.getmtime(out) < os.path.getmtime(src):
                 _build(src, out)
             lib = ctypes.CDLL(out)
-        except Exception:
+        except (OSError, subprocess.CalledProcessError) as e:
+            stderr = getattr(e, "stderr", None)
+            _error = f"{e}" + (f"\n{stderr.decode(errors='replace')}"
+                               if stderr else "")
             return None
         bind_signatures(lib)
         _lib = lib
@@ -85,7 +92,9 @@ class NativeLoader:
                  n_threads=4):
         lib = load_library()
         if lib is None:
-            raise RuntimeError("native loader unavailable (no g++?)")
+            raise RuntimeError(
+                f"native loader unavailable: building "
+                f"dataloader.cpp failed: {_error}")
         self._lib = lib
         self._array = np.ascontiguousarray(array)  # keep alive
         self.row_shape = self._array.shape[1:]

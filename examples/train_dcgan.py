@@ -34,6 +34,8 @@ def main():
     if args.platform:
         from chainermn_tpu.utils import use_platform
         use_platform(args.platform)
+    from chainermn_tpu.utils.compat import configure_persistent_cache
+    configure_persistent_cache()
 
     comm = ct.create_communicator(args.communicator)
     gen = Generator(n_hidden=args.n_hidden, ch=args.ch)

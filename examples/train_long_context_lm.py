@@ -42,15 +42,14 @@ def main():
     if args.platform:
         from chainermn_tpu.utils import use_platform
         use_platform(args.platform)
+    from chainermn_tpu.utils.compat import configure_persistent_cache
+    configure_persistent_cache()
 
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    # version-compat shim (jax.shard_map vs jax.experimental.shard_map
-    # with the check_vma/check_rep rename) — the same absorption point
-    # the framework and __graft_entry__ use
-    from chainermn_tpu.utils.compat import shard_map
+    from jax import shard_map
 
     import chainermn_tpu as ct
     from chainermn_tpu.core.link import bind_state, extract_state

@@ -59,6 +59,8 @@ def main():
     if args.platform:
         from chainermn_tpu.utils import use_platform
         use_platform(args.platform)
+    from chainermn_tpu.utils.compat import configure_persistent_cache
+    configure_persistent_cache()
 
     model = Classifier(MLP(args.unit, 10))
     optimizer = Adam().setup(model)

@@ -77,6 +77,8 @@ def main():
     if args.platform:
         from chainermn_tpu.utils import use_platform
         use_platform(args.platform)
+    from chainermn_tpu.utils.compat import configure_persistent_cache
+    configure_persistent_cache()
 
     comm = ct.create_communicator(
         args.communicator, allreduce_grad_dtype=args.grad_dtype,

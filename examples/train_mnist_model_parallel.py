@@ -65,6 +65,8 @@ def main():
     if args.platform:
         from chainermn_tpu.utils import use_platform
         use_platform(args.platform)
+    from chainermn_tpu.utils.compat import configure_persistent_cache
+    configure_persistent_cache()
 
     comm = ct.create_communicator("jax_ici", axis_name="stage")
     model = SplitMLP(comm, args.unit, 10)

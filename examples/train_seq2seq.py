@@ -37,6 +37,8 @@ def main():
     if args.platform:
         from chainermn_tpu.utils import use_platform
         use_platform(args.platform)
+    from chainermn_tpu.utils.compat import configure_persistent_cache
+    configure_persistent_cache()
 
     xs, ys_in, ys_out = make_synthetic_translation_data(n=512)
     dataset = TupleDataset(xs, ys_in, ys_out)

@@ -13,7 +13,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from chainermn_tpu.communicators import create_communicator
-from chainermn_tpu.utils.compat import axis_env_contains, shard_map
+from jax import shard_map
+from chainermn_tpu.utils.compat import axis_env_contains
 
 
 def test_out_of_scope_is_false():

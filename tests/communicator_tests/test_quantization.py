@@ -192,7 +192,7 @@ def test_quantized_hop_bytes_pinned():
 def _trace_one_arg_transform(comm):
     """Trace comm.grad_transform's legacy 1-arg form inside a bound
     mesh axis (the warning fires at trace time, before any execution)."""
-    from chainermn_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(g):
@@ -240,7 +240,7 @@ def test_quantized_exchange_matches_hand_mean():
     travels with its codewords) — checked against a hand-computed
     reference on the 8-device mesh."""
     import chainermn_tpu as ct
-    from chainermn_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     comm = ct.create_communicator("jax_ici", allreduce_grad_dtype="int8")

@@ -39,7 +39,7 @@ def test_mnbn_matches_global_batch_bn():
         out, new = apply_state(mnbn, {"params": params, "state": pstate}, x)
         return out, new["state"]
 
-    from chainermn_tpu.utils.compat import shard_map
+    from jax import shard_map
     mapped = shard_map(body, mesh=COMM.mesh,
                        in_specs=(P(), P(), P(COMM.axis_name)),
                        out_specs=(P(COMM.axis_name), P()),
@@ -79,7 +79,7 @@ def test_mnbn_gradients_match_global_bn():
         grads = jax.grad(loss)(params)
         return jax.tree.map(lambda g: jax.lax.psum(g, COMM.axis_name), grads)
 
-    from chainermn_tpu.utils.compat import shard_map
+    from jax import shard_map
     mapped = shard_map(body, mesh=COMM.mesh,
                        in_specs=(P(), P(), P(COMM.axis_name)),
                        out_specs=P(),

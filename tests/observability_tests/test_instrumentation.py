@@ -1,6 +1,6 @@
 """End-to-end observability acceptance (ISSUE 14): seeded runs of the
 three subsystems each produce a schema-valid Chrome-trace shard whose
-span names cover the committed taxonomy, the rank shards merge
+span names cover the committed classification, the rank shards merge
 losslessly, the metrics registry carries the committed scheduler/
 trainer/supervisor metrics — and with the DEFAULT off mode the same
 runs emit nothing.
@@ -73,7 +73,7 @@ def test_trainer_run_produces_schema_valid_trace(events_mode, tmp_path):
     events = obs.read_jsonl(str(shard))
     obs.validate_events(events)
     names = _span_names(events)
-    # the committed trainer-phase taxonomy (docs/observability.md)
+    # the committed trainer-phase classification (docs/observability.md)
     assert {"train/input_stall", "train/optimizer_update",
             "train/grad_exchange/bucket0",
             "train/checkpoint_serialize"} <= names, names
@@ -326,7 +326,7 @@ def test_elastic_shrink_regrow_timeline(events_mode, tmp_path):
     assert reg.get("chainermn_tpu_recovery_recoveries").value() >= 1
 
 
-# -- PROBE=obs + bench fingerprint fences ------------------------------------
+# -- PROBE=obs ------------------------------------
 
 def test_probe_obs_renders_merged_registry(events_mode, capsys):
     sys.path.insert(0, os.path.join(os.path.dirname(__file__),
@@ -345,26 +345,3 @@ def test_probe_obs_renders_merged_registry(events_mode, capsys):
                for l in prom)
     assert any("chainermn_tpu_serving_queue_wait_ms_count" in l
                for l in prom)
-
-
-def test_bench_fingerprint_fences_traced_runs(monkeypatch):
-    """CHAINERMN_TPU_TRACE=off (the default) leaves the flagship
-    fingerprint unchanged; a traced run can never be flagship-cacheable
-    (its numbers stamp the overhead delta, recovery-queue item 8)."""
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__),
-                                    "..", ".."))
-    import bench
-    monkeypatch.delenv("CHAINERMN_TPU_TRACE", raising=False)
-    for model in ("resnet50", "transformer"):
-        assert bench._config_fingerprint(model) \
-            == bench._DEFAULT_FINGERPRINTS[model]
-    monkeypatch.setenv("CHAINERMN_TPU_TRACE", "events")
-    for model in ("resnet50", "transformer"):
-        fp = bench._config_fingerprint(model)
-        assert fp["trace"] == "events"
-        assert fp != bench._DEFAULT_FINGERPRINTS[model]
-        # legacy cached entries (no trace key) backfill to the default
-        legacy = {k: v for k, v in
-                  bench._DEFAULT_FINGERPRINTS[model].items()
-                  if k != "trace"}
-        assert bench._backfill_fp(model, legacy)["trace"] == "off"

@@ -2,7 +2,7 @@
 
 Mirrors tests/test_comm_budget.py's sweep pattern:
 tools/autotune_plan.json commits HOW exchange plans are derived and —
-once the recovery queue's FIRST-CHIP-CONTACT item 11 stamps it — WHAT
+once a run on the real fabric stamps it — WHAT
 plan the first real fabric measurements implied.  Two layers:
 
 * DERIVATION (backend-neutral, always on): the artifact's recorded
@@ -93,7 +93,7 @@ def test_measured_plan_rederives_bit_identically():
     rederived = derive_exchange_plan(measurements, plan["topology"])
     assert rederived["fingerprint"] == plan["fingerprint"], (
         "committed plan no longer re-derives from its own measurements "
-        "(planner rules changed?): bump PLAN_VERSION and re-stamp via "
-        "the recovery queue before re-committing")
+        "(planner rules changed?): bump PLAN_VERSION and re-stamp from "
+        "a chip run before re-committing")
     assert plan_fingerprint(plan) == plan["fingerprint"], \
         "committed plan body was edited without updating its fingerprint"

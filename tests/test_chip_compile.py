@@ -1,0 +1,183 @@
+"""Ask the TPU's compiler, without a TPU: the main path's kernels and
+serving programs compile for a described v5e at real widths.
+
+The topology is described inside a fixture (never at import, never in
+conftest): only one process may load the TPU library, and under xdist
+every worker imports this file.  Code that asks ``jax.default_backend()``
+sees the CPU here, so the kernels and program functions are compiled
+directly, with ``interpret=False`` steered from the test.  A compile that
+passes is not a chip run; what it catches is what the chip's compiler
+would refuse (tiling, VMEM, HBM, donation that does not alias).
+"""
+
+import functools
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# the package re-exports a function of the same name over the module
+fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
+
+# the d768 x 12 transformer of bench.py / chip_smoke.py
+D_MODEL, N_HEADS, N_LAYERS, VOCAB, D_HEAD = 768, 12, 12, 32768, 64
+PAGES, PAGE, MAX_BATCH, MAX_CONTEXT = 256, 16, 8, 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _qkv(shape, sharding):
+    return [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+            for _ in range(3)]
+
+
+def _fwd_bwd():
+    # a fresh function per test: jit's trace cache is keyed on the
+    # function, not on CHAINERMN_TPU_FLASH_BWD
+    def loss(q, k, v):
+        return jnp.sum(fa._flash_diff(q, k, v, True, None, False)
+                       .astype(jnp.float32))
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+
+def _lse_fwd_bwd():
+    def loss(q, k, v):
+        out, lse = fa._flash_lse_diff(q, k, v, True, 0.125, False)
+        return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+
+def _compile(fn, *specs, **jit_kw):
+    compiled = jax.jit(fn, **jit_kw).lower(*specs).compile()
+    return compiled, compiled.as_text()
+
+
+@pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (2, 12, 8192, 64)],
+                         ids=["bs8_seq1024", "bs2_seq8192"])
+def test_flash_fwd_and_fused_bwd_compile(one_chip, no_persistent_cache,
+                                         shape):
+    _, text = _compile(_fwd_bwd(), *_qkv(shape, one_chip))
+    assert text.count("tpu_custom_call") >= 2
+    for name in ("_flash_kernel_lse", "_flash_bwd_fused_kernel"):
+        assert name in text
+
+
+def test_flash_split_bwd_compiles(one_chip, no_persistent_cache,
+                                  monkeypatch):
+    monkeypatch.setattr(fa, "_FLASH_BWD", "split")
+    _, text = _compile(_fwd_bwd(), *_qkv((2, 12, 8192, 64), one_chip))
+    for name in ("_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"):
+        assert name in text
+
+
+@pytest.mark.parametrize("shape", [(2, 12, 2048, 64), (2, 3, 8192, 64)],
+                         ids=["ring_seq8192_over4", "ulysses_seq8192_over4"])
+def test_lse_kernel_pair_compiles_at_sequence_parallel_shapes(
+        one_chip, no_persistent_cache, shape):
+    """``attention_with_lse``'s kernels at what each of 4 chips holds of
+    a seq-8192 batch: a 2048-token block (ring) / 3 of 12 heads
+    (Ulysses)."""
+    _, text = _compile(_lse_fwd_bwd(), *_qkv(shape, one_chip))
+    for name in ("_flash_kernel_lse", "_flash_bwd_fused_kernel"):
+        assert name in text
+
+
+# -- the serving programs ----------------------------------------------------
+
+@pytest.fixture(scope="module")
+def serving(one_chip):
+    """The d768 x 12 model, its state as shapes on the described chip,
+    and the pool pair ``[L, P, S, H, D]`` in bf16."""
+    from chainermn_tpu.core.link import extract_state
+    from chainermn_tpu.models import TransformerLM
+    model = TransformerLM(n_vocab=VOCAB, d_model=D_MODEL, n_heads=N_HEADS,
+                          n_layers=N_LAYERS, max_len=MAX_CONTEXT, seed=0,
+                          compute_dtype=jnp.bfloat16)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    state = jax.tree.map(lambda a: spec(a.shape, a.dtype),
+                         extract_state(model))
+    pool = spec((N_LAYERS, PAGES, PAGE, N_HEADS, D_HEAD), jnp.bfloat16)
+    pool_bytes = N_LAYERS * PAGES * PAGE * N_HEADS * D_HEAD * 2
+    return model, state, pool, pool_bytes, spec
+
+
+def _assert_pools_aliased(compiled, pool_bytes):
+    # the programs write pages with a whole-pool .at[li].set — cheap
+    # only when XLA updates the donated pools in place
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= 2 * pool_bytes, ma
+
+
+@pytest.mark.parametrize("bucket", [16, MAX_CONTEXT])
+def test_prefill_program_compiles_with_pools_donated(
+        serving, no_persistent_cache, monkeypatch, bucket):
+    from chainermn_tpu.serving import prefill_program
+    model, state, pool, pool_bytes, spec = serving
+    # the dispatcher would take its CPU branch here: steer it onto the
+    # compiled kernel, as it goes on the chip
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    compiled, text = _compile(
+        functools.partial(prefill_program, model), state, pool, pool,
+        spec((1, bucket), jnp.int32), spec((), jnp.int32),
+        spec((MAX_CONTEXT // PAGE,), jnp.int32), donate_argnums=(1, 2))
+    assert "_flash_kernel" in text and "tpu_custom_call" in text
+    _assert_pools_aliased(compiled, pool_bytes)
+
+
+def test_decode_program_compiles_with_pools_donated(serving,
+                                                    no_persistent_cache):
+    from chainermn_tpu.serving import decode_program
+    model, state, pool, pool_bytes, spec = serving
+    compiled, _ = _compile(
+        functools.partial(decode_program, model, mode="paged"),
+        state, pool, pool, spec((MAX_BATCH,), jnp.int32),
+        spec((MAX_BATCH,), jnp.int32),
+        spec((MAX_BATCH, MAX_CONTEXT // PAGE), jnp.int32),
+        donate_argnums=(1, 2))
+    _assert_pools_aliased(compiled, pool_bytes)
+
+
+def test_spec_verify_program_compiles_with_pools_donated(
+        serving, no_persistent_cache):
+    from chainermn_tpu.serving import spec_verify_program
+    model, state, pool, pool_bytes, spec = serving
+    spec_k = 4
+    compiled, _ = _compile(
+        functools.partial(spec_verify_program, model),
+        state, pool, pool, spec((MAX_BATCH, spec_k + 1), jnp.int32),
+        spec((MAX_BATCH,), jnp.int32), spec((MAX_BATCH,), jnp.int32),
+        spec((MAX_BATCH, MAX_CONTEXT // PAGE), jnp.int32),
+        donate_argnums=(1, 2))
+    _assert_pools_aliased(compiled, pool_bytes)

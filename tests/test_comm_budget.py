@@ -14,7 +14,7 @@ it.  Two layers:
   names, the DCN gradient payload pinned at exactly 1/intra_size, the
   slow-hop-first emission order, and per-hop dtype compression.
   Verified against the traced program, not against documentation.
-* NUMBERS (measured on chip by the recovery queue's bucket sweep /
+* NUMBERS (measured on chip by the bucket sweep /
   exposed-comm A/B): dormant while ``sweep.status`` is
   ``pending_on_chip``; arms when rows are stamped ``measured``.
 

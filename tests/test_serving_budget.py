@@ -11,7 +11,7 @@ every future PR to it.  Two layers:
   this exists to catch), zero backward kernels; prefill reuses the
   fused flash FORWARD (one Pallas kernel per layer, zero bwd kernels).
   Verified against the traced programs, not documentation.
-* TARGETS (measured on chip by the recovery queue's BENCH_MODEL=serving
+* TARGETS (measured on chip by BENCH_MODEL=serving
   rows): dormant while ``status`` is ``pending_on_chip``; once measured,
   the committed tokens/sec + p99 latency arm.
 """
@@ -228,8 +228,8 @@ def test_targets_armed_when_measured():
     b = _budgets()
     t = b["targets"]
     if t["status"] != "measured":
-        # dormant: the numeric half waits for the recovery queue's
-        # serving rows; the schema relation is still enforced
+        # dormant: the numeric half waits for serving rows from a
+        # chip run; the schema relation is still enforced
         assert t["tokens_per_sec"] is None
         return
     assert t["tokens_per_sec"] > 0

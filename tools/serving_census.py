@@ -147,8 +147,7 @@ def _census_facts(jaxpr, pool_layer_shape, t_full):
     for eqn, inside in _walk_eqns(jaxpr, into_pallas=False):
         name = eqn.primitive.name
         if name == "pallas_call":
-            info = eqn.params.get("name_and_src_info")
-            kname = getattr(info, "name", str(info))
+            kname = eqn.params["name"]
             if "bwd" in kname:
                 facts["bwd_kernels"] += 1
             elif "_flash_kernel" in kname:
