@@ -63,23 +63,6 @@ def _is_traced(*xs):
                for x in xs for leaf in jax.tree.leaves(x))
 
 
-def _bucket_scope(name, bucket):
-    """Trace-time name for one bucket's collective emission.
-
-    Default: the stable census-era scope name alone.  Under
-    ``CHAINERMN_TPU_TRACE=full`` (ISSUE 14) the scope is prefixed with
-    the span-tracer vocabulary (``train.grad_exchange.bucketK``) so an
-    XProf/jax.profiler capture attributes real device time to the SAME
-    names the host-side Chrome trace carries — the two timelines join
-    on the span name.  Pure trace-time metadata: no primitive is added
-    and the compiled program's census is unchanged."""
-    from .. import observability
-    if observability.named_scopes_enabled():
-        return jax.named_scope(
-            f"train.grad_exchange.bucket{bucket}.{name}")
-    return jax.named_scope(name)
-
-
 _warned_inert_ef = False
 
 
@@ -303,7 +286,7 @@ class MeshCommunicator(CommunicatorBase):
         # every subsequent event is rank/epoch-tagged — the merge tool
         # keys rank lanes off this.  No-op when tracing is off.
         from .. import observability
-        if observability.enabled():
+        if observability.ring_enabled():
             observability.tracer().configure(
                 rank=self.rank, epoch=getattr(self, "epoch", None))
 
@@ -1116,10 +1099,10 @@ class MeshCommunicator(CommunicatorBase):
                 if len(idx) == 1:
                     # single-leaf bucket: skip the pack/unpack reshape
                     # noise (identical math, cleaner program)
-                    with _bucket_scope("mn_leaf_pmean", k):
+                    with jax.named_scope("mn_leaf_pmean"):
                         out[idx[0]] = lax.pmean(leaves[idx[0]], axis)
                     continue
-                with _bucket_scope("mn_bucket_pmean", k):
+                with jax.named_scope("mn_bucket_pmean"):
                     flat, spec = tree_pack([leaves[i] for i in idx])
                     flat = lax.pmean(flat, axis)
                     for i, g in zip(idx, tree_unpack(flat, spec)):
@@ -1170,7 +1153,7 @@ class MeshCommunicator(CommunicatorBase):
             new_res = []
             offset = 0
             for k, idx in enumerate(buckets):
-                with _bucket_scope("mn_q_bucket_exchange", k):
+                with jax.named_scope("mn_q_bucket_exchange"):
                     flat, spec = tree_pack([leaves[i] for i in idx])
                     n = flat.shape[0]
                     r = None
@@ -1261,14 +1244,14 @@ class MeshCommunicator(CommunicatorBase):
             for op, b in hop_schedule(len(buckets)):
                 idx = buckets[b]
                 if op == "ici_reduce_scatter":
-                    with _bucket_scope("mn_hier_rs_ici", b):
+                    with jax.named_scope("mn_hier_rs_ici"):
                         flat, spec = tree_pack([leaves[i] for i in idx])
                         flat, n_true = pad_to_multiple(flat, intra)
                         specs[b] = (spec, n_true)
                         chunks[b] = lax.psum_scatter(
                             flat, ici, scatter_dimension=0, tiled=True)
                 elif op == "dcn_exchange" and q_dcn:
-                    with _bucket_scope("mn_hier_quantized_dcn", b):
+                    with jax.named_scope("mn_hier_quantized_dcn"):
                         c = chunks[b]
                         wire = c.dtype
                         n = c.shape[0]
@@ -1285,7 +1268,7 @@ class MeshCommunicator(CommunicatorBase):
                         chunks[b] = (dequantize_sum(qg, sg)
                                      / size).astype(wire)
                 elif op == "dcn_exchange":
-                    with _bucket_scope("mn_hier_allreduce_dcn", b):
+                    with jax.named_scope("mn_hier_allreduce_dcn"):
                         c = chunks[b]
                         wire = c.dtype
                         if dcn_dtype is not None:
@@ -1293,7 +1276,7 @@ class MeshCommunicator(CommunicatorBase):
                         c = lax.psum(c, dcn)
                         chunks[b] = c.astype(wire) / size
                 else:  # ici_all_gather
-                    with _bucket_scope("mn_hier_ag_ici", b):
+                    with jax.named_scope("mn_hier_ag_ici"):
                         full = lax.all_gather(chunks[b], ici, tiled=True)
                     spec, n_true = specs[b]
                     for i, g in zip(idx, tree_unpack(full[:n_true], spec)):
@@ -1407,7 +1390,7 @@ class MeshCommunicator(CommunicatorBase):
             for op, b in hop_schedule(len(buckets), mode="striped"):
                 idx = buckets[b]
                 if op == "dcn_path_scatter":
-                    with _bucket_scope("mn_stripe_pack_scatter_dcn", b):
+                    with jax.named_scope("mn_stripe_pack_scatter_dcn"):
                         flat, spec = tree_pack([leaves[i] for i in idx])
                         specs[b] = (spec, flat.dtype)
                         a_flat = flat[:n_i[b]]
@@ -1445,7 +1428,7 @@ class MeshCommunicator(CommunicatorBase):
                 elif op == "ici_path_scatter":
                     if not n_i[b]:
                         continue
-                    with _bucket_scope("mn_stripe_rs_ici", b):
+                    with jax.named_scope("mn_stripe_rs_ici"):
                         a_pad, _ = pad_to_multiple(a_chunk[b], intra)
                         a_chunk[b] = lax.psum_scatter(
                             a_pad, ici, scatter_dimension=0, tiled=True)
@@ -1453,7 +1436,7 @@ class MeshCommunicator(CommunicatorBase):
                     if not n_d[b]:
                         continue
                     if q_dcn:
-                        with _bucket_scope("mn_stripe_dequant_psum_ici", b):
+                        with jax.named_scope("mn_stripe_dequant_psum_ici"):
                             # decode every DCN group's (q, scale) pair,
                             # then finish the reduction across ICI in
                             # f32 — the lossless fast hop, same
@@ -1462,7 +1445,7 @@ class MeshCommunicator(CommunicatorBase):
                             s = dequantize_sum(qg, sg)
                             b_full[b] = lax.psum(s, ici) / size
                     else:
-                        with _bucket_scope("mn_stripe_allreduce_ici", b):
+                        with jax.named_scope("mn_stripe_allreduce_ici"):
                             # the DCN-path chunk's cross-fabric
                             # allreduce rides the LOSSLESS fast hop:
                             # upcast to f32 before accumulating
@@ -1475,7 +1458,7 @@ class MeshCommunicator(CommunicatorBase):
                     c = a_chunk[b]
                     wire = c.dtype
                     if q_dcn:
-                        with _bucket_scope("mn_stripe_quantized_chunk", b):
+                        with jax.named_scope("mn_stripe_quantized_chunk"):
                             n = c.shape[0]
                             r = None
                             if residual is not None:
@@ -1489,7 +1472,7 @@ class MeshCommunicator(CommunicatorBase):
                             a_chunk[b] = (dequantize_sum(qg, sg)
                                           / size).astype(wire)
                     else:
-                        with _bucket_scope("mn_stripe_allreduce_dcn", b):
+                        with jax.named_scope("mn_stripe_allreduce_dcn"):
                             if dcn_dtype is not None:
                                 c = c.astype(dcn_dtype)
                             c = lax.psum(c, dcn)
@@ -1497,7 +1480,7 @@ class MeshCommunicator(CommunicatorBase):
                 elif op == "dcn_path_gather":
                     if not n_d[b] or q_dcn:
                         continue  # quantized path is already full
-                    with _bucket_scope("mn_stripe_ag_dcn", b):
+                    with jax.named_scope("mn_stripe_ag_dcn"):
                         c = b_chunk[b]
                         if dcn_dtype is not None:
                             c = c.astype(dcn_dtype)
@@ -1507,7 +1490,7 @@ class MeshCommunicator(CommunicatorBase):
                     spec, wire = specs[b]
                     parts = []
                     if n_i[b]:
-                        with _bucket_scope("mn_stripe_ag_ici", b):
+                        with jax.named_scope("mn_stripe_ag_ici"):
                             full = lax.all_gather(a_chunk[b], ici,
                                                   tiled=True)
                         parts.append(full[:n_i[b]].astype(wire))

@@ -206,7 +206,7 @@ class FailureRecovery(Extension):
         become gauges a ``PROBE=obs`` render (or a real scraper) reads
         next to the subsystem counters.  No-op when observability is
         off."""
-        if not observability.enabled():
+        if not observability.ring_enabled():
             return
         reg = observability.registry()
         for key in ("recoveries", "generation_bumps", "resizes",

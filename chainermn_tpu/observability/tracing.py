@@ -21,33 +21,39 @@ Design:
 * ``pid`` is the RANK (so a merged multi-rank trace shows one process
   lane per rank), ``tid`` is the host thread — or a synthetic
   per-request track for serving lifecycles;
-* the knob ladder is ``CHAINERMN_TPU_TRACE=off|events|full``: ``off``
+* the knob ladder is ``CHAINERMN_TPU_TRACE=off|events``: ``off``
   (default) makes every call site a no-op returning a module-level
   singleton (zero allocations — pinned by test), ``events`` records
-  host spans, ``full`` additionally opens ``jax.named_scope`` around
-  each span so XProf/jax.profiler timelines carry the SAME vocabulary
-  (the two tools join on span names).
+  host spans on the ring;
+* whenever a ``jax.profiler`` session is recording — whoever started
+  it — :func:`span` ALSO opens a ``jax.profiler.TraceAnnotation`` of
+  the same name with the tags as the event's stats, so the program's
+  spans land in the profiler's own ``.xplane.pb`` on plane
+  ``/host:CPU``: the same file, reader and clock as the device's
+  ``XLA Ops`` line.  No knob: the profiler being on is the switch.
 
-The mode is resolved ONCE at import (the documented near-zero-cost
-contract: the hot path is one module-global truthiness check);
+The mode is resolved ONCE at import; the hot path with nothing
+listening is one module-global truthiness check plus the profiler's
+own flag read (``TraceAnnotation.is_enabled()``, about 0.1 us).
 :func:`set_mode` exists for tests and tools that flip it in-process.
 """
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import threading
 import time
 
-__all__ = ["Span", "SpanTracer", "tracer", "span", "instant", "mode",
-           "enabled", "named_scopes_enabled", "set_mode", "reset_tracer",
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Span", "SpanTracer", "tracer", "span", "instant", "complete",
+           "mode", "enabled", "ring_enabled", "set_mode", "reset_tracer",
            "validate_events", "repair_balance", "read_jsonl",
            "TRACE_ENV", "MODES"]
 
 TRACE_ENV = "CHAINERMN_TPU_TRACE"
-MODES = ("off", "events", "full")
+MODES = ("off", "events")
 
 _REQUIRED_KEYS = ("name", "ph", "ts", "pid", "tid")
 
@@ -60,37 +66,42 @@ def _resolve_mode(value=None):
     return v
 
 
-# Resolved at import: the disabled hot path is `if not _ENABLED` on a
-# module global — no env read, no object construction, per call site.
+# Resolved at import: the ring's hot path is `if _RING` on a module
+# global — no env read, no object construction, per call site.
 _MODE = _resolve_mode()
-_ENABLED = _MODE != "off"
-_FULL = _MODE == "full"
+_RING = _MODE == "events"
+
+_profiling = TraceAnnotation.is_enabled
 
 
 def mode():
-    """The resolved ``CHAINERMN_TPU_TRACE`` mode (off|events|full)."""
+    """The resolved ``CHAINERMN_TPU_TRACE`` mode (off|events)."""
     return _MODE
 
 
+def ring_enabled():
+    """True when spans are recorded on the ring (``events``).  What a
+    call site asks before work whose output only the ring or the
+    metrics registry takes: an ``instant``, a retroactive ``complete``,
+    a request's synthetic lane, a registry counter; and what the JSONL
+    export and ``autotune="online"`` read."""
+    return _RING
+
+
 def enabled():
-    """True when spans are recorded (``events`` or ``full``)."""
-    return _ENABLED
-
-
-def named_scopes_enabled():
-    """True only under ``full``: span names also open
-    ``jax.named_scope`` so XProf timelines share the vocabulary."""
-    return _FULL
+    """Is anyone listening to spans: the ring is on, or a
+    ``jax.profiler`` session is recording.  What a call site asks
+    before it builds a span's tag dict or sets a span's stats."""
+    return _RING or _profiling()
 
 
 def set_mode(value):
     """Re-resolve the trace mode in-process (tests / tools; production
     runs set the env var before import).  Returns the previous mode."""
-    global _MODE, _ENABLED, _FULL
+    global _MODE, _RING
     prev = _MODE
     _MODE = _resolve_mode(value)
-    _ENABLED = _MODE != "off"
-    _FULL = _MODE == "full"
+    _RING = _MODE == "events"
     return prev
 
 
@@ -107,6 +118,9 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **stats):
+        pass
+
 
 _NOOP = _NoopSpan()
 
@@ -116,12 +130,13 @@ class Span:
     ``__exit__``/``close()``.  Context-manager use guarantees balance;
     an unclosed span is repaired at export (synthetic ``E``)."""
 
-    __slots__ = ("_tracer", "name", "tid")
+    __slots__ = ("_tracer", "name", "tid", "_end_tags")
 
     def __init__(self, tracer, name, tags=None, tid=None):
         self._tracer = tracer
         self.name = name
         self.tid = tid if tid is not None else threading.get_ident()
+        self._end_tags = None
         tracer._emit("B", name, tracer._now_us(), self.tid, tags)
 
     def __enter__(self):
@@ -131,10 +146,15 @@ class Span:
         self.close()
         return False
 
+    def set(self, **stats):
+        """Counts known only once the work is done: they ride the
+        ``E`` event's args."""
+        self._end_tags = stats
+
     def close(self):
         if self._tracer is not None:
             self._tracer._emit("E", self.name, self._tracer._now_us(),
-                               self.tid, None)
+                               self.tid, self._end_tags)
             self._tracer = None
 
 
@@ -374,29 +394,63 @@ def reset_tracer():
     _TRACER = None
 
 
-@contextlib.contextmanager
-def _full_span(name, tags, tid):
-    import jax
-    with jax.named_scope(name.replace("/", ".")):
-        with tracer().span(name, tags=tags, tid=tid):
-            yield
+class _ProfilerSpan:
+    """A span on the profiler's clock: a ``TraceAnnotation`` on the
+    calling thread (an annotation has no synthetic track, so ``tid``
+    reaches the ring alone) with the tags as its stats (numbers and
+    strings come back as such, anything else by its ``str``), and the
+    ring's span beside it when the ring is on."""
+
+    __slots__ = ("_annotation", "_ring")
+
+    def __init__(self, name, tags, tid):
+        self._annotation = TraceAnnotation(name, **(tags or {}))
+        self._ring = tracer().span(name, tags=tags, tid=tid) \
+            if _RING else None
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
+        if self._ring is not None:
+            self._ring.close()
+        return False
+
+    def set(self, **stats):
+        self._annotation.set_metadata(**stats)
+        if self._ring is not None:
+            self._ring.set(**stats)
 
 
 def span(name, tags=None, tid=None):
-    """Open a span on the global tracer — THE instrumentation call site.
+    """Open a span — THE instrumentation call site.
 
-    Off (default): returns the no-op singleton — no allocation, no
-    clock read.  ``events``: records B/E on the ring.  ``full``:
-    additionally opens ``jax.named_scope`` so any surrounding
-    jax.profiler trace carries the same name."""
-    if not _ENABLED:
-        return _NOOP
-    if _FULL:
-        return _full_span(name, tags, tid)
-    return tracer().span(name, tags=tags, tid=tid)
+    While a ``jax.profiler`` session records, whoever started it, the
+    span is a ``TraceAnnotation`` with ``tags`` as its stats; with
+    ``events`` it is written to the ring (both, when both listen).
+    With neither it is the no-op singleton: no allocation, no clock
+    read.  ``set(**stats)`` on the span adds counts known only at its
+    end."""
+    if _profiling():
+        return _ProfilerSpan(name, tags, tid)
+    if _RING:
+        return tracer().span(name, tags=tags, tid=tid)
+    return _NOOP
 
 
 def instant(name, tags=None, tid=None):
-    """Record a point event on the global tracer (no-op when off)."""
-    if _ENABLED:
+    """Record a point event on the ring (no-op when it is off).  Never
+    on the profiler: an annotation has a start and an end on the real
+    clock."""
+    if _RING:
         tracer().instant(name, tags=tags, tid=tid)
+
+
+def complete(name, duration_s, tags=None, tid=None):
+    """Record a retroactive span on the ring
+    (:meth:`SpanTracer.complete`; no-op when it is off, and never on
+    the profiler, whose clock the duration was not measured on)."""
+    if _RING:
+        tracer().complete(name, duration_s, tags=tags, tid=tid)

@@ -173,7 +173,7 @@ def create_multi_node_optimizer(actual_optimizer, communicator,
                 communicator = retune_communicator(communicator,
                                                    mode="startup")
         else:
-            if observability.enabled():
+            if observability.ring_enabled():
                 online_after = autotune if isinstance(autotune, int) \
                     and not isinstance(autotune, bool) else 3
                 communicator._autotune_mode = "online"
@@ -346,11 +346,7 @@ class _MultiNodeOptimizer:
         time individual buckets: the host trace instead carries one
         instant event per bucket stamped with the PLANNED wire payload
         (the same ``grad_buckets_for`` plan the census gates check),
-        and the registry accumulates the per-bucket byte counters.
-        Under ``CHAINERMN_TPU_TRACE=full`` the in-graph bucket emission
-        is additionally wrapped in ``jax.named_scope`` (see
-        ``communicators.mesh_communicator._bucket_scope``) so an XProf
-        capture attributes real device time to the SAME names."""
+        and the registry accumulates the per-bucket byte counters."""
         plan = self._exchange_plan_rows()
         comm = self.communicator
         exchange = getattr(comm, "exchange", None) or self.exchange
@@ -656,11 +652,12 @@ class _MultiNodeOptimizer:
         operands = (params, pstate, opt_state, actual._hyper_values(),
                     actual._next_rng_key(), stale, residual, args, kwargs)
         actual._stash_step_spec(step, operands)
-        if observability.enabled():
+        if observability.ring_enabled():
             self._emit_exchange_telemetry()
         try:
-            new_params, new_pstate, new_opt_state, loss, grads, \
-                res_out, obs = step(*operands)
+            with observability.span("train/step_dispatch"):
+                new_params, new_pstate, new_opt_state, loss, grads, \
+                    res_out, obs = step(*operands)
         except Exception as e:
             from .core.optimizer import raise_if_donated_state_lost
             raise_if_donated_state_lost(e, actual)
@@ -1288,7 +1285,7 @@ class _MultiNodeOptimizer:
         operands = (params, pstate, opt_state, actual._hyper_values(),
                     actual._next_rng_key(), residual, args, kwargs)
         actual._stash_step_spec(step, operands)
-        if observability.enabled():
+        if observability.ring_enabled():
             self._emit_exchange_telemetry()
         try:
             new_params, new_pstate, new_opt_state, losses, grads, \

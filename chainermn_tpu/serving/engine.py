@@ -730,9 +730,8 @@ class ServingEngine:
                 "prompt": int(req.prompt.size)}
         if readmit:
             tags["readmit"] = True
-        observability.tracer().complete("serve/queue_wait", wait_s,
-                                        tags=tags,
-                                        tid=self._req_tid(req))
+        observability.complete("serve/queue_wait", wait_s, tags=tags,
+                               tid=self._req_tid(req))
         observability.registry().histogram(
             "chainermn_tpu_serving_queue_wait_ms",
             help="admission queue wait per request (ms)").observe(
@@ -765,7 +764,7 @@ class ServingEngine:
         self.running.remove(req)
         req.finish_time = now
         self.completed.append(req)
-        if observability.enabled():
+        if observability.ring_enabled():
             observability.instant("serve/finish",
                                   tags={"tenant": req.tenant,
                                         "request": req.request_id,
@@ -791,7 +790,7 @@ class ServingEngine:
         req.requeue_time = now
         self.scheduler.requeue_front(req)
         self.evictions += 1
-        if observability.enabled():
+        if observability.ring_enabled():
             observability.instant("serve/evict",
                                   tags={"tenant": req.tenant,
                                         "request": req.request_id},
@@ -808,7 +807,7 @@ class ServingEngine:
             self.kv.k_pool, self.kv.v_pool, jnp.int32(src),
             jnp.int32(dst))
         self.forks += 1
-        if observability.enabled():
+        if observability.ring_enabled():
             observability.instant("serve/fork",
                                   tags={"src": int(src), "dst": int(dst)})
             observability.registry().counter(
@@ -868,7 +867,7 @@ class ServingEngine:
         shipped = nb * self.kv.n_layers * self.kv.page_bytes
         self.transferred_page_bytes += shipped
         self.transfers += 1
-        if observability.enabled():
+        if observability.ring_enabled():
             observability.instant("serve/page_transfer",
                                   tags={"request": req.request_id,
                                         "pages": int(nb),
@@ -945,10 +944,13 @@ class ServingEngine:
         req.queue_wait_s += wait_s
         # lazy tag construction: the conditional expressions below keep
         # the trace-off path free of per-admission dict/lane-id work
-        # (the module's near-zero-cost-off contract)
+        # (the module's near-zero-cost-off contract).  The request's
+        # lane, the retroactive queue-wait span and the registry are the
+        # ring's; a profiler session alone takes the span tags only
         obs_on = observability.enabled()
-        rtid = self._req_tid(req) if obs_on else None
-        if obs_on:
+        ring_on = observability.ring_enabled()
+        rtid = self._req_tid(req) if ring_on else None
+        if ring_on:
             self._obs_admitted(req, wait_s, readmit)
         if chunked:
             # chunk-admitted: the prompt enters the chunk state machine
@@ -966,34 +968,34 @@ class ServingEngine:
             req.requeue_time = None   # consumed: next eviction re-stamps
             self.chunked_admissions += 1
             self.prefilling.append(req)
-            if obs_on:
+            if ring_on:
                 observability.instant(
                     "serve/chunk_admit",
                     tags={"request": sid, "prompt": L,
                           "matched": matched}, tid=rtid)
             return
+        # one request's spans share ``request``; ``wait_ms`` is this
+        # admission's queue wait.  Each span runs to the first token on
+        # the host, so the wait for the prefill's result lies inside it
+        tags = {"request": sid, "prompt": L, "matched": matched,
+                "wait_ms": wait_s * 1e3} if obs_on else None
+        req.admit_time = t_admit
+        req.requeue_time = None   # consumed: next eviction re-stamps
         if matched:
-            with observability.span(
-                    "serve/suffix_prefill",
-                    tags={"request": sid, "matched": matched,
-                          "suffix": L - matched} if obs_on else None,
-                    tid=rtid):
+            with observability.span("serve/suffix_prefill", tags=tags,
+                                    tid=rtid):
                 logits = self._run_prefix_prefill(req, L, matched)
-            self.prefix_hits += 1
-            self.prefix_tokens_matched += matched
+                self.prefix_hits += 1
+                self.prefix_tokens_matched += matched
+                self._complete_admission(req, logits, clock, prompt_t)
         elif self.disagg:
-            with observability.span(
-                    "serve/prefill",
-                    tags={"request": sid, "prompt": L,
-                          "disagg": True} if obs_on else None,
-                    tid=rtid):
+            if obs_on:
+                tags["disagg"] = True
+            with observability.span("serve/prefill", tags=tags, tid=rtid):
                 logits = self._run_disagg_prefill(req, L)
+                self._complete_admission(req, logits, clock, prompt_t)
         else:
-            with observability.span(
-                    "serve/prefill",
-                    tags={"request": sid,
-                          "prompt": L} if obs_on else None,
-                    tid=rtid):
+            with observability.span("serve/prefill", tags=tags, tid=rtid):
                 Tb = _bucket(L, self.prefill_buckets, "prompt length")
                 tokens = np.zeros((1, Tb), dtype=np.int32)
                 tokens[0, :L] = req.prompt
@@ -1002,9 +1004,7 @@ class ServingEngine:
                     jnp.asarray(tokens), np.int32(L),
                     jnp.asarray(self._bt_row(sid)))
                 self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
-        req.admit_time = t_admit
-        req.requeue_time = None   # consumed: next eviction re-stamps
-        self._complete_admission(req, logits, clock, prompt_t)
+                self._complete_admission(req, logits, clock, prompt_t)
 
     def _complete_admission(self, req, logits, clock, prompt_t):
         """The bookkeeping shared by one-shot and LAST-chunk admission:
@@ -1112,7 +1112,8 @@ class ServingEngine:
                         tags={"request": req.request_id,
                               "start": startp, "chunk": size,
                               "final": final} if obs_on else None,
-                        tid=self._req_tid(req) if obs_on else None):
+                        tid=self._req_tid(req)
+                        if observability.ring_enabled() else None):
                     self._run_chunk(req, startp, size, final, clock)
                 budget -= size
                 progressed += size
@@ -1331,7 +1332,18 @@ class ServingEngine:
         pinned ``now`` (deterministic tests / simulated clocks) stamps
         everything in this step with that value."""
         clock = time.monotonic if now is None else (lambda: now)
-        stats = {"admitted": 0, "evicted_before": self.evictions}
+        with observability.span("serve/step") as sp:
+            stats = self._step(clock)
+            if observability.enabled():
+                sp.set(running=stats["running"],
+                       used_pages=self.allocator.used_pages,
+                       num_pages=self.allocator.num_pages)
+        return stats
+
+    def _step(self, clock):
+        stats = {"admitted": 0}
+        obs_on = observability.enabled()
+        evicted_before = self.evictions
         # capacity FIRST: secure this step's token page(s) for every
         # running sequence (evicting youngest-first when the pool runs
         # dry) BEFORE admitting anyone — admission into pages the
@@ -1341,83 +1353,95 @@ class ServingEngine:
         # positions); mid-chunk prompts are eviction candidates too —
         # preferred victims, in fact: they hold pages and have produced
         # zero tokens
-        i = 0
-        while i < len(self.running):
-            req = self.running[i]
-            need = self._spec_nv(req) if self.spec_k else 1
-            try:
-                self.allocator.ensure(req.request_id, req._ctx + need)
-                i += 1
-            except PagePoolExhaustedError:
-                # refcount-aware victim choice: a victim must FREE
-                # something (EvictionStalledError otherwise — the
-                # prefix-sharing livelock guard)
-                victim = self.scheduler.pick_victim(
-                    self.running, self.allocator,
-                    prefilling=self.prefilling)
-                self._evict(victim, clock())
-                # victim may be req: the slot under scrutiny vanished —
-                # re-check the same index (now the next request)
+        with observability.span("serve/capacity"):
+            i = 0
+            while i < len(self.running):
+                req = self.running[i]
+                need = self._spec_nv(req) if self.spec_k else 1
+                try:
+                    self.allocator.ensure(req.request_id,
+                                          req._ctx + need)
+                    i += 1
+                except PagePoolExhaustedError:
+                    # refcount-aware victim choice: a victim must FREE
+                    # something (EvictionStalledError otherwise — the
+                    # prefix-sharing livelock guard)
+                    victim = self.scheduler.pick_victim(
+                        self.running, self.allocator,
+                        prefilling=self.prefilling)
+                    self._evict(victim, clock())
+                    # victim may be req: the slot under scrutiny
+                    # vanished — re-check the same index (now the next
+                    # request)
         # admission at decode-step granularity, into the pages left
         # over (its growth page is secured by _admit's ensure; a
         # chunk-admitted prompt counts against max_batch from its
         # FIRST chunk — the engine's concurrency bound covers work in
         # flight, not just work decoding)
-        while len(self.running) + len(self.prefilling) < self.max_batch:
-            req = self.scheduler.next_admission(arrived_by=clock())
-            if req is None:
-                break
-            try:
-                self._admit(req, clock)
-                stats["admitted"] += 1
-            except (PagePoolExhaustedError, _AdmitDeferred):
-                # pool full (or the scratch slice is busy): wait
-                # (admission never preempts running work — only decode
-                # growth does)
-                self.scheduler.requeue_front(req, preempted=False)
-                break
+        with observability.span("serve/admission"):
+            while len(self.running) + len(self.prefilling) \
+                    < self.max_batch:
+                req = self.scheduler.next_admission(arrived_by=clock())
+                if req is None:
+                    break
+                try:
+                    self._admit(req, clock)
+                    stats["admitted"] += 1
+                except (PagePoolExhaustedError, _AdmitDeferred):
+                    # pool full (or the scratch slice is busy): wait
+                    # (admission never preempts running work — only
+                    # decode growth does)
+                    self.scheduler.requeue_front(req, preempted=False)
+                    break
         # the chunk pass: long prompts stream in, budgeted, BETWEEN
         # the admission pass and the decode dispatch — decode keeps
         # running every step, which is the whole p99 story
         if self.prefilling:
             stats["chunk_tokens"] = self._advance_chunks(clock)
         n = len(self.running)
-        stats["evicted"] = self.evictions - stats.pop("evicted_before")
+        stats["evicted"] = self.evictions - evicted_before
         stats["running"] = n
         stats["occupancy"] = (self.allocator.used_pages
                               / self.allocator.num_pages)
         stats["capacity_x"] = self.capacity_multiplier()
-        if observability.enabled():
+        if observability.ring_enabled():
             self._obs_queue_depths()
         if n == 0:
             stats["decoded"] = 0
             return stats
         if self.spec_k:
             return self._spec_step(n, clock, stats)
+        Bb = _bucket(n, self.batch_buckets, "batch")
         with observability.span(
                 "serve/decode_window",
-                tags={"batch": n, "step": self.decode_steps}
-                if observability.enabled() else None):
-            Bb = _bucket(n, self.batch_buckets, "batch")
-            toks = np.zeros(Bb, dtype=np.int32)
-            pos = np.full(Bb, -1, dtype=np.int32)
-            bts = np.zeros((Bb, self.n_block_entries), dtype=np.int32)
-            for j, req in enumerate(self.running):
-                toks[j] = req.tokens[-1]
-                pos[j] = req._ctx
-                bts[j] = self._bt_row(req.request_id)
-            k_pool, v_pool, _logits, nxt = self._decode_fn(
-                self.state, self.kv.k_pool, self.kv.v_pool,
-                jnp.asarray(toks), jnp.asarray(pos), jnp.asarray(bts))
+                tags={"batch": n, "bucket": Bb, "step": self.decode_steps}
+                if obs_on else None):
+            with observability.span("serve/decode_build"):
+                toks = np.zeros(Bb, dtype=np.int32)
+                pos = np.full(Bb, -1, dtype=np.int32)
+                bts = np.zeros((Bb, self.n_block_entries),
+                               dtype=np.int32)
+                for j, req in enumerate(self.running):
+                    toks[j] = req.tokens[-1]
+                    pos[j] = req._ctx
+                    bts[j] = self._bt_row(req.request_id)
+                operands = (jnp.asarray(toks), jnp.asarray(pos),
+                            jnp.asarray(bts))
+            with observability.span("serve/decode_dispatch"):
+                k_pool, v_pool, _logits, nxt = self._decode_fn(
+                    self.state, self.kv.k_pool, self.kv.v_pool,
+                    *operands)
             self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
-            nxt = np.asarray(nxt)   # device->host sync: the decode
-            self.decode_steps += 1  # window span times the real step
-        t_tok = clock()
-        for j, req in enumerate(list(self.running)):
-            req._ctx += 1
-            self._record_token(req, nxt[j], t_tok)
-            if self._finished(req):
-                self._retire(req, t_tok)
+            with observability.span("serve/decode_fetch"):
+                nxt = np.asarray(nxt)   # device->host sync: the decode
+            self.decode_steps += 1      # window span times the real step
+        with observability.span("serve/record"):
+            t_tok = clock()
+            for j, req in enumerate(list(self.running)):
+                req._ctx += 1
+                self._record_token(req, nxt[j], t_tok)
+                if self._finished(req):
+                    self._retire(req, t_tok)
         stats["decoded"] = n
         return stats
 
@@ -1470,7 +1494,8 @@ class ServingEngine:
                 jnp.asarray(toks), jnp.asarray(start), jnp.asarray(nvb),
                 jnp.asarray(bts))
             self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
-            g = np.asarray(g)       # device->host sync
+            with observability.span("serve/decode_fetch"):
+                g = np.asarray(g)       # device->host sync
             self.decode_steps += 1  # ONE dispatch for up to K+1 tokens
             self.spec_steps += 1
             self.spec_lane_steps += n

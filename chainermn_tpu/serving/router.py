@@ -127,7 +127,7 @@ class FleetRouter:
                     self.routed += 1
                     if reroute:
                         self.rerouted += 1
-                    if obs_on:
+                    if observability.ring_enabled():
                         observability.instant(
                             "fleet/route",
                             tags={"replica": replica.rid,

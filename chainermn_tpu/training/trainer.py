@@ -229,7 +229,7 @@ class Trainer:
             # (merge shards with tools/trace_merge.py).  Off = the
             # default: no file, no cost.
             from .. import observability
-            if observability.enabled():
+            if observability.ring_enabled():
                 try:
                     tr = observability.tracer()
                     tr.export(os.path.join(

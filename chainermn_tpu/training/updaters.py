@@ -83,7 +83,8 @@ class StandardUpdater(Updater):
         iterator = self._iterators["main"]
         optimizer = self._optimizers["main"]
         batch = self._next_reporting_stall(iterator)
-        in_arrays = self.converter(batch, self.device)
+        with observability.span("train/convert"):
+            in_arrays = self.converter(batch, self.device)
         loss_func = self.loss_func or optimizer.target
         with observability.span("train/optimizer_update"):
             if isinstance(in_arrays, tuple):
@@ -138,13 +139,17 @@ class StandardUpdater(Updater):
             batch = iterator.next()
             cls._report_stall_delta(iterator, stall_before)
             return batch
-        t0 = time.monotonic()
+        # the stall counter lives in the registry, which is on with
+        # the ring; a profiler session alone takes the span
+        ring_on = observability.ring_enabled()
+        t0 = time.monotonic() if ring_on else 0.0
         with observability.span(
                 "train/input_stall",
                 tags={"iterator": type(iterator).__name__}):
             batch = iterator.next()
         cls._report_stall_delta(iterator, stall_before)
-        cls._record_stall_metric(iterator, stall_before, t0)
+        if ring_on:
+            cls._record_stall_metric(iterator, stall_before, t0)
         return batch
 
     def finalize(self):
@@ -207,7 +212,8 @@ class FusedUpdater(StandardUpdater):
         # lazy tags (the near-zero-cost-off contract — same pattern as
         # _next_reporting_stall and the serving engine)
         obs_on = observability.enabled()
-        t0 = time.monotonic() if obs_on else 0.0
+        ring_on = observability.ring_enabled()
+        t0 = time.monotonic() if ring_on else 0.0
         with observability.span(
                 "train/input_stall",
                 tags={"iterator": type(iterator).__name__,
@@ -215,7 +221,7 @@ class FusedUpdater(StandardUpdater):
             batches = [self.converter(iterator.next(), self.device)
                        for _ in range(self.n_fused)]
         self._report_stall_delta(iterator, stall_before)
-        if obs_on:
+        if ring_on:
             # the shared counter semantics (converter included here —
             # this path stacks K batches host-side, and that cost is
             # exposed feed latency)

@@ -200,29 +200,6 @@ def test_set_mode_rejects_unknown():
         obs.set_mode("loud")
 
 
-def test_full_mode_opens_named_scope(events_mode, monkeypatch):
-    opened = []
-    import jax
-
-    class _Scope:
-        def __init__(self, name):
-            opened.append(name)
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *a):
-            return False
-
-    monkeypatch.setattr(jax, "named_scope", _Scope)
-    obs.set_mode("full")
-    with obs.span("train/optimizer_update"):
-        pass
-    assert opened == ["train.optimizer_update"]
-    evs = obs.tracer().events()
-    assert [e["ph"] for e in evs] == ["B", "E"]
-
-
 # -- trace_merge --------------------------------------------------------------
 
 def test_trace_merge_lossless_and_sorted(events_mode, tmp_path):

@@ -40,6 +40,32 @@ def test_summarize_parses_real_trace(tmp_path, capsys):
     assert rows, f"no op rows parsed from trace:\n{out}"
 
 
+def test_empty_device_planes_fall_back_to_the_host_plane(tmp_path, capsys):
+    """A process that has loaded libtpu (a compile-only client is
+    enough) writes TPU planes with no events into a CPU capture: the
+    summary must still read the host plane."""
+    import glob
+
+    @jax.jit
+    def step(x):
+        return jnp.tanh(x @ x).sum()
+
+    x = jnp.ones((256, 256), jnp.float32)
+    float(step(x))
+    out_dir = str(tmp_path / "trace")
+    with jax.profiler.trace(out_dir):
+        float(step(x))
+    path, = glob.glob(os.path.join(out_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    name = b"/device:TPU:0"
+    plane = b"\x12" + bytes([len(name)]) + name    # XPlane.name(2)
+    with open(path, "ab") as f:                     # XSpace.planes(1)
+        f.write(b"\x0a" + bytes([len(plane)]) + plane)
+
+    profile_tpu_step.summarize(out_dir)
+    assert "plane: /host:CPU" in capsys.readouterr().out
+
+
 def test_summarize_empty_dir_reports_cleanly(tmp_path, capsys):
     profile_tpu_step.summarize(str(tmp_path))
     out = capsys.readouterr().out

@@ -902,8 +902,7 @@ def probe_obs():
     import trace_merge
     from chainermn_tpu import observability as obs
 
-    requested = os.environ.get(obs.TRACE_ENV, "").strip().lower()
-    prev = obs.set_mode("full" if requested == "full" else "events")
+    prev = obs.set_mode("events")
     obs.reset_tracer()
     obs.reset_registry()
     try:

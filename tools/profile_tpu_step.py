@@ -219,10 +219,17 @@ def _collect(out_dir, by_category=False):
                 return v.decode(errors="replace")
         return ""
 
-    chosen = [p for p in planes
+    device = [p for p in planes
               if "TPU" in plane_name(p) or "/device" in plane_name(p).lower()]
-    if not chosen:
-        chosen = [p for p in planes if plane_name(p) == "/host:CPU"]
+    host = [p for p in planes if plane_name(p) == "/host:CPU"]
+    # a process that has loaded libtpu (a compile-only client is enough)
+    # writes TPU planes with no events into a CPU capture: the host
+    # plane is the fallback for device planes that are empty, too
+    return _plane_totals(device, by_category) \
+        or _plane_totals(host, by_category)
+
+
+def _plane_totals(chosen, by_category):
     result = {}
     for plane in chosen:
         name = ""
