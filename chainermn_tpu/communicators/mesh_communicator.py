@@ -1038,6 +1038,19 @@ class MeshCommunicator(CommunicatorBase):
             dtypes = [self.allreduce_grad_dtype] * len(dtypes)
         return self.grad_buckets(shapes, dtypes)
 
+    @property
+    def grad_exchange_on_wire(self):
+        """False when :meth:`grad_transform` exchanges nothing: the
+        plain (one-axis, non-quantized) transform over an axis of ONE
+        device, where the mean over ranks is the value itself.  The
+        transform then emits no pack, collective or unpack, and the
+        exchange telemetry announces no bucket.  Quantized wires stay
+        on (quantization is lossy, so it is part of the result even at
+        size 1), as do the hierarchical and striped transforms."""
+        from ._memory_utility import is_quantized_dtype
+        return not (self.size == 1 and self.hierarchy is None
+                    and not is_quantized_dtype(self.allreduce_grad_dtype))
+
     def grad_transform(self):
         """Return ``grads -> grads`` for use inside a compiled train step.
 
@@ -1064,6 +1077,12 @@ class MeshCommunicator(CommunicatorBase):
         Packing goes through ``_memory_utility.tree_pack``/``tree_unpack``
         — the one pack/unpack implementation (shared with ZeRO and the
         reduce-scatter update).
+
+        Over an axis of ONE device (:attr:`grad_exchange_on_wire` is
+        False) the mean is the value itself: none of the three packs or
+        exchanges anything, only the dtype cast (part of the result)
+        remains — bitwise the packed round trip
+        (tests/communicator_tests/test_one_device_exchange.py).
 
         QUANTIZED wires (ISSUE 8): with an int8/fp8
         ``allreduce_grad_dtype`` the returned transform accepts an
@@ -1092,6 +1111,13 @@ class MeshCommunicator(CommunicatorBase):
             orig_dtypes = [g.dtype for g in leaves]
             if dtype is not None:
                 leaves = [g.astype(dtype) for g in leaves]
+            if not comm.grad_exchange_on_wire:
+                # one device on the axis: the mean over ranks is the
+                # value itself, so nothing is packed, exchanged or
+                # unpacked, whatever batch_collectives says.  The cast
+                # above and back below is part of the result and stays
+                leaves = [g.astype(d) for g, d in zip(leaves, orig_dtypes)]
+                return jax.tree.unflatten(treedef, leaves)
             buckets = comm.grad_buckets([g.shape for g in leaves],
                                         [g.dtype for g in leaves])
             out = [None] * len(leaves)

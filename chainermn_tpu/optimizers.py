@@ -348,6 +348,8 @@ class _MultiNodeOptimizer:
         (the same ``grad_buckets_for`` plan the census gates check),
         and the registry accumulates the per-bucket byte counters."""
         plan = self._exchange_plan_rows()
+        if not plan:
+            return
         comm = self.communicator
         exchange = getattr(comm, "exchange", None) or self.exchange
         counter = observability.registry().counter(
@@ -367,16 +369,23 @@ class _MultiNodeOptimizer:
         the telemetry instants, the timed eager span's payload tags
         (the ISSUE 19 small fix: bandwidth readable off a trace), and
         nothing else; invalidated wherever ``_obs_exchange_plan``
-        resets (setup, change_communicator)."""
+        resets (setup, change_communicator).  Empty where the
+        communicator's ``grad_transform`` exchanges nothing (a
+        one-device axis): no instant, no counter, no payload tag."""
         plan = self.__dict__.get("_obs_exchange_plan")
         if plan is None:
             comm = self.communicator
             target = self.actual_optimizer.target
-            try:
-                shapes, dtypes = comm.grad_leaf_specs(target)
-                buckets = comm.grad_buckets_for(target)
-            except Exception:
-                buckets, shapes, dtypes = [], [], []
+            buckets = []
+            # over a one-device axis grad_transform exchanges nothing:
+            # no bucket is announced on a wire
+            if self._sharded_update \
+                    or getattr(comm, "grad_exchange_on_wire", True):
+                try:
+                    shapes, dtypes = comm.grad_leaf_specs(target)
+                    buckets = comm.grad_buckets_for(target)
+                except Exception:
+                    buckets = []
             plan = []
             for i, idx in enumerate(buckets):
                 elems = sum(int(np.prod(shapes[j])) for j in idx)

@@ -57,7 +57,7 @@ def _model():
 
 
 def _run(exchange, double_buffering=False, donate=True, grad_dtype=None,
-         steps=STEPS, opt_cls=MomentumSGD, **opt_kw):
+         steps=STEPS, opt_cls=MomentumSGD, devices=None, **opt_kw):
     """Trajectory (losses, params) of one exchange variant.
 
     ``exchange``: per_leaf | flat | bucketed (communicator flavors of
@@ -68,6 +68,7 @@ def _run(exchange, double_buffering=False, donate=True, grad_dtype=None,
     opt_kw = opt_kw or dict(lr=0.1, momentum=0.9)
     comm = ct.create_communicator(
         "hierarchical" if exchange in _HIER else "jax_ici",
+        devices=devices,
         inter_size=2 if exchange in _HIER else None,
         batch_collectives=_BC.get(exchange, True),
         bucket_mb=TINY_BUCKET_MB if "bucketed" in exchange else None,
@@ -121,12 +122,15 @@ def test_exchange_matches_single_device_golden(exchange, golden):
                                    err_msg=f"{exchange} params diverged")
 
 
-def test_allreduce_packings_bitwise_equal():
+@pytest.mark.parametrize("n_devices", [8, 1])
+def test_allreduce_packings_bitwise_equal(n_devices):
     """per-leaf == flat == bucketed BITWISE: packing changes the
-    schedule, not the math (pmean is elementwise)."""
-    ref = _run("per_leaf")
+    schedule, not the math (pmean is elementwise).  Over ONE device
+    all three are the same program: nothing is packed or exchanged."""
+    devices = jax.devices()[:n_devices]
+    ref = _run("per_leaf", devices=devices)
     for exchange in ("flat", "bucketed"):
-        losses, params, _ = _run(exchange)
+        losses, params, _ = _run(exchange, devices=devices)
         assert losses == ref[0], f"{exchange} losses differ bitwise"
         for a, b in zip(params, ref[1]):
             np.testing.assert_array_equal(a, b)

@@ -48,8 +48,8 @@ def _data(n=32, d=12, k=3, seed=0):
 
 
 def _trainer(tmp_path, iterator, n_iter=3, with_checkpoint=True,
-             updater_cls=StandardUpdater, **updater_kw):
-    comm = ct.create_communicator("flat")
+             updater_cls=StandardUpdater, devices=None, **updater_kw):
+    comm = ct.create_communicator("flat", devices=devices)
     model = Classifier(MLP(n_units=16, n_out=3, seed=0))
     opt = ct.create_multi_node_optimizer(
         MomentumSGD(lr=0.05), comm).setup(model)
@@ -83,6 +83,27 @@ def test_trainer_run_produces_schema_valid_trace(events_mode, tmp_path):
     c = obs.registry().get(
         "chainermn_tpu_grad_exchange_payload_bytes_total")
     assert c is not None and c.value(bucket="0", exchange="flat") > 0
+
+
+@pytest.mark.parametrize("updater_cls,updater_kw",
+                         [(StandardUpdater, {}),
+                          (FusedUpdater, {"n_fused": 2})])
+def test_one_device_run_reports_no_gradient_bytes_on_a_wire(
+        events_mode, tmp_path, updater_cls, updater_kw):
+    """Over a one-device axis the exchange packs and exchanges nothing
+    (ISSUE 25), and the telemetry says so: no
+    ``train/grad_exchange/bucketK`` instant, no payload counter."""
+    x, t = _data()
+    it = SerialIterator(TupleDataset(x, t), 8, shuffle=False)
+    _trainer(tmp_path / "out", it, with_checkpoint=False,
+             updater_cls=updater_cls, devices=jax.devices()[:1],
+             **updater_kw).run()
+    events = obs.read_jsonl(str(tmp_path / "out" / "trace-rank0.jsonl"))
+    names = _span_names(events)
+    assert "train/optimizer_update" in names
+    assert not [n for n in names if n.startswith("train/grad_exchange")]
+    assert obs.registry().get(
+        "chainermn_tpu_grad_exchange_payload_bytes_total") is None
 
 
 def test_trainer_run_off_emits_nothing(tmp_path):
