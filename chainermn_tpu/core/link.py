@@ -36,6 +36,8 @@ __all__ = [
     "grad_tree",
     "set_grads",
     "load_param_tree",
+    "abstract_init",
+    "cast_params",
 ]
 
 
@@ -54,6 +56,20 @@ class Parameter:
         self.grad = None
         self.name = name
         self._initializer = None
+
+    def draw(self, shape, dtype, fn):
+        """Set the initial value: ``fn()`` (a host draw of ``shape`` and
+        ``dtype``) placed as a ``jax.Array``, or, inside
+        :func:`abstract_init`, the shape and dtype alone
+        (``jax.ShapeDtypeStruct``): nothing drawn, nothing allocated."""
+        if getattr(_thread_local, "abstract", False):
+            self.array = jax.ShapeDtypeStruct(tuple(shape), jnp.dtype(dtype))
+        else:
+            self.array = jnp.asarray(fn())
+
+    @property
+    def is_abstract(self):
+        return isinstance(self.array, jax.ShapeDtypeStruct)
 
     # -- chainer-parity conveniences -------------------------------------
     @property
@@ -90,6 +106,25 @@ class Parameter:
 
 
 _thread_local = threading.local()
+
+
+@contextlib.contextmanager
+def abstract_init():
+    """Links constructed inside keep their parameters as shapes only.
+
+    A model whose float32 weights do not fit twice (zero-filled by the
+    constructor, then made again from a seed) is built here, read for
+    its ``namedparams()`` shapes, and filled by :func:`load_param_tree`.
+    Links take part by setting their parameters through
+    :meth:`Parameter.draw`.  An abstract leaf reaching a compiled
+    program is an error of the caller: :func:`extract_state` refuses it.
+    """
+    prev = getattr(_thread_local, "abstract", False)
+    _thread_local.abstract = True
+    try:
+        yield
+    finally:
+        _thread_local.abstract = prev
 
 
 class Link:
@@ -331,6 +366,12 @@ def extract_state(link: Link) -> dict:
     one full extra XLA compilation per step function).
     """
     params = {path: p.array for path, p in link.namedparams() if p.array is not None}
+    abstract = [path for path, a in params.items()
+                if isinstance(a, jax.ShapeDtypeStruct)]
+    if abstract:
+        raise ValueError(
+            f"{len(abstract)} parameter(s) are still shapes only "
+            f"(abstract_init), e.g. {abstract[0]}: load them first")
     state = {}
     for sublink, name, full in _persistent_slots(link):
         value = getattr(sublink, name)
@@ -363,6 +404,21 @@ def load_param_tree(link: Link, params: dict):
     for path, p in link.namedparams():
         if path in params:
             p.array = params[path]
+
+
+def cast_params(link: Link, dtype):
+    """Hold every floating parameter of ``link`` in ``dtype``, one leaf
+    at a time: each leaf's old array loses its last reference here as
+    its cast replaces it, so the peak is the tree plus one leaf, not two
+    trees (the caller must hold no other reference to the old arrays).
+    """
+    dtype = jnp.dtype(dtype)
+    for p in link.params():
+        a = p.array
+        if a is not None and not p.is_abstract and a.dtype != dtype \
+                and jnp.issubdtype(a.dtype, jnp.floating):
+            p.array = a.astype(dtype)
+            del a
 
 
 def _persistent_slots(link: Link):

@@ -10,6 +10,7 @@ from .dcgan import Generator, Discriminator, DCGANUpdater
 from .transformer import TransformerLM, TransformerBlock, MultiHeadAttention
 from .moe_transformer import (MoETransformerLM, MoETransformerBlock,
                               MoEFeedForward)
+from .latent_moe import LatentMoELM, LatentMoEBlock, LatentAttention
 from .convnets import AlexNet, NIN, VGG16, GoogLeNet
 
 __all__ = ["MLP", "Classifier", "ResNet", "ResNet18", "ResNet50",
@@ -19,4 +20,5 @@ __all__ = ["MLP", "Classifier", "ResNet", "ResNet18", "ResNet50",
            "make_synthetic_translation_data", "Generator", "Discriminator",
            "DCGANUpdater", "TransformerLM", "TransformerBlock",
            "MultiHeadAttention", "MoETransformerLM", "MoETransformerBlock",
-           "MoEFeedForward", "AlexNet", "NIN", "VGG16", "GoogLeNet"]
+           "MoEFeedForward", "LatentMoELM", "LatentMoEBlock",
+           "LatentAttention", "AlexNet", "NIN", "VGG16", "GoogLeNet"]

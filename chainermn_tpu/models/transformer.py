@@ -21,6 +21,11 @@ from ..core import reporter
 from ..nn import functions as F
 from ..nn import links as L
 from ..ops import attention as fused_attention
+from ..ops.paged_attention import (head_sharding, paged_decode_attention,
+                                   paged_prefill_attention,
+                                   paged_verify_attention)
+from ..serving.kv_cache import (write_prompt_kv, write_prompt_kv_at,
+                                write_span_kv, write_token_kv)
 
 __all__ = ["MultiHeadAttention", "TransformerBlock", "TransformerLM"]
 
@@ -119,7 +124,199 @@ def _remat_policy(remat):
     return policy
 
 
-class TransformerLM(Chain):
+# -- the serving interface (ServingEngine; docs/serving.md) -------------------
+
+class _ServingMixin:
+    """What :class:`~chainermn_tpu.serving.ServingEngine` asks of a model:
+    the cache entry a token leaves in a layer, the context limit, and
+    the block's prefill / suffix prefill / decode (and, here, the
+    speculative verify) over per-layer views of the page pools.  Each
+    runs with the parameters bound (``bind_state``) inside one of the
+    engine's compiled programs and returns ``(pools, logits, extras)``,
+    ``extras`` a tuple of further device values for the engine's spans
+    (none here)."""
+
+    #: the dtype the engine holds the parameters in (``None``: as loaded)
+    serve_param_dtype = None
+
+    @property
+    def serve_max_context(self):
+        """Learned positions: the table's rows."""
+        return self.pos_embed.W.shape[0]
+
+    @property
+    def serve_cache_layers(self):
+        return len(self.blocks)
+
+    @property
+    def serve_page_dtype(self):
+        return self.compute_dtype or jnp.float32
+
+    def serve_cache_entry(self):
+        """K and V of ``[H, D]`` a token a layer: two pools."""
+        attn = self.blocks[0].attn
+        return ((attn.n_heads, attn.d_head),) * 2
+
+    def serve_pool_sharding(self, mesh):
+        """Tensor-parallel decode: the pools shard over their HEAD axis
+        (the ulysses layout), which the mesh must divide."""
+        attn = self.blocks[0].attn
+        if attn.n_heads % mesh.size:
+            raise ValueError(f"tp={mesh.size} must divide n_heads="
+                             f"{attn.n_heads}")
+        return head_sharding(mesh, 5, 3)
+
+    def _serve_embed(self, toks, positions):
+        """Token + position embeddings cast to the model's compute dtype
+        (the ``hidden`` discipline: params fp32, block compute in
+        ``compute_dtype``)."""
+        h = self.embed(toks) + self.pos_embed(positions)
+        if self.compute_dtype is not None:
+            h = h.astype(self.compute_dtype)
+        return h
+
+    def serve_prefill(self, pools, tokens, true_len, bt_row):
+        """Full causal forward over the (padded) prompt ``tokens [1,
+        Tb]`` (positions ``>= true_len`` are padding — their K/V writes
+        drop, and causality keeps them out of every valid position's
+        attention).  ``logits``: the fp32 ``[V]`` row at position
+        ``true_len - 1``."""
+        k_pool, v_pool = pools
+        B, T = tokens.shape
+        pos = jax.lax.broadcasted_iota(jnp.int32, (B, T), 1)
+        h = self._serve_embed(tokens, pos)
+        for li, block in enumerate(self.blocks):
+            x = block.ln1(h)
+            qkv = block.attn.qkv(x.reshape(B * T, -1)).reshape(
+                B, T, 3, block.attn.n_heads, block.attn.d_head)
+            q, k, v = [jnp.moveaxis(qkv[:, :, j], 1, 2) for j in range(3)]
+            # the flash dispatcher: Pallas forward on TPU (no backward is
+            # ever traced — inference), XLA/interpret elsewhere
+            att = fused_attention(q, k, v, causal=True)
+            att = jnp.moveaxis(att, 2, 1).reshape(B * T, -1)
+            h = h + block.attn.proj(att).reshape(B, T, -1)
+            m = block.fc2(F.gelu(block.fc1(block.ln2(h).reshape(B * T,
+                                                                -1))))
+            h = h + m.reshape(B, T, -1)
+            k_pool = k_pool.at[li].set(write_prompt_kv(
+                k_pool[li], jnp.moveaxis(k[0], 0, 1), bt_row, true_len))
+            v_pool = v_pool.at[li].set(write_prompt_kv(
+                v_pool[li], jnp.moveaxis(v[0], 0, 1), bt_row, true_len))
+        h_last = jax.lax.dynamic_slice_in_dim(
+            h[0], jnp.maximum(true_len - 1, 0), 1, axis=0)
+        logits = self.head(self.ln_f(h_last))[0]
+        return (k_pool, v_pool), logits.astype(jnp.float32), ()
+
+    def serve_suffix_prefill(self, pools, tokens, true_len, start, bt_row):
+        """SUFFIX prefill for a prefix-shared request (round 14), and
+        one chunk of a chunked one.  Suffix index ``t`` sits at absolute
+        position ``start + t``; ``bt_row`` covers the WHOLE context
+        (shared prefix pages + the request's fresh suffix pages).  Per
+        layer the suffix's K/V scatter through the offset writer FIRST,
+        then one gather per pool reads the whole context back and the
+        suffix queries run one masked softmax against it
+        (:func:`~chainermn_tpu.ops.paged_attention.paged_prefill_attention`)
+        — ZERO flash kernels touch the shared pages, and the score
+        matrix is suffix-by-context, never context-by-context: skipping
+        the matched prefix's O(L²) attention and O(L·d²) projections is
+        the FLOP saving the prefix hit buys."""
+        k_pool, v_pool = pools
+        B, T = tokens.shape
+        pos = start + jax.lax.broadcasted_iota(jnp.int32, (B, T), 1)
+        h = self._serve_embed(tokens, pos)
+        scale = 1.0 / (self.blocks[0].attn.d_head ** 0.5)
+        for li, block in enumerate(self.blocks):
+            x = block.ln1(h)
+            qkv = block.attn.qkv(x.reshape(B * T, -1)).reshape(
+                B, T, 3, block.attn.n_heads, block.attn.d_head)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            k_pool = k_pool.at[li].set(write_prompt_kv_at(
+                k_pool[li], k[0], bt_row, start, true_len))
+            v_pool = v_pool.at[li].set(write_prompt_kv_at(
+                v_pool[li], v[0], bt_row, start, true_len))
+            att = paged_prefill_attention(q[0], k_pool[li], v_pool[li],
+                                          bt_row, start, true_len,
+                                          scale=scale)
+            h = h + block.attn.proj(att.reshape(B * T, -1)) \
+                .reshape(B, T, -1)
+            m = block.fc2(F.gelu(block.fc1(block.ln2(h).reshape(B * T,
+                                                                -1))))
+            h = h + m.reshape(B, T, -1)
+        h_last = jax.lax.dynamic_slice_in_dim(
+            h[0], jnp.maximum(true_len - 1, 0), 1, axis=0)
+        logits = self.head(self.ln_f(h_last))[0]
+        return (k_pool, v_pool), logits.astype(jnp.float32), ()
+
+    def serve_decode(self, pools, toks, pos, bts, mode=None, tp_mesh=None):
+        """One token per batch lane (``pos < 0`` marks an idle padding
+        lane: its K/V write drops and its attention context is empty).
+        Writes each lane's K/V at ``pos`` then attends over ``[0, pos]``
+        through the block table.  ``tp_mesh``: the tensor-parallel mesh
+        — pools arrive head-sharded and the attention op constrains its
+        gathers to stay that way.  ``logits``: ``[Bb, V]`` fp32."""
+        k_pool, v_pool = pools
+        Bb = toks.shape[0]
+        safe_pos = jnp.maximum(pos, 0)
+        h = self._serve_embed(toks, safe_pos)
+        ctx_len = jnp.where(pos >= 0, pos + 1, 0)
+        scale = 1.0 / (self.blocks[0].attn.d_head ** 0.5)
+        for li, block in enumerate(self.blocks):
+            x = block.ln1(h)
+            qkv = block.attn.qkv(x).reshape(
+                Bb, 3, block.attn.n_heads, block.attn.d_head)
+            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
+            k_pool = k_pool.at[li].set(
+                write_token_kv(k_pool[li], k, bts, pos))
+            v_pool = v_pool.at[li].set(
+                write_token_kv(v_pool[li], v, bts, pos))
+            att = paged_decode_attention(q, k_pool[li], v_pool[li], bts,
+                                         ctx_len, scale=scale, mode=mode,
+                                         tp_mesh=tp_mesh)
+            h = h + block.attn.proj(att.reshape(Bb, -1))
+            h = h + block.fc2(F.gelu(block.fc1(block.ln2(h))))
+        logits = self.head(self.ln_f(h)).astype(jnp.float32)
+        return (k_pool, v_pool), logits, ()
+
+    def serve_verify(self, pools, toks, start, n_valid, bts, tp_mesh=None):
+        """Speculative VERIFY: score K+1 tokens per lane in one dispatch
+        (round 20).  ``toks``: ``[Bb, K1]`` — lane ``b``'s pending token
+        followed by its K draft proposals; token ``j`` sits at absolute
+        position ``start[b] + j`` (``start < 0`` = idle lane); only the
+        first ``n_valid[b]`` span slots write K/V.  Per layer: ONE
+        drop-fenced span scatter per pool (``write_span_kv``), then ONE
+        gather per pool and a multi-query masked softmax over the block
+        tables (``paged_verify_attention``) — query ``j`` sees exactly
+        positions ``<= start + j``, the context a vanilla decode step at
+        that position would see.  ``logits``: ``[Bb, K1, V]`` fp32."""
+        k_pool, v_pool = pools
+        Bb, K1 = toks.shape
+        safe_start = jnp.maximum(start, 0)
+        pos = safe_start[:, None] + jnp.arange(K1, dtype=jnp.int32)[None]
+        h = self._serve_embed(toks, pos)
+        scale = 1.0 / (self.blocks[0].attn.d_head ** 0.5)
+        for li, block in enumerate(self.blocks):
+            x = block.ln1(h)
+            qkv = block.attn.qkv(x.reshape(Bb * K1, -1)).reshape(
+                Bb, K1, 3, block.attn.n_heads, block.attn.d_head)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            k_pool = k_pool.at[li].set(write_span_kv(
+                k_pool[li], k, bts, start, n_valid))
+            v_pool = v_pool.at[li].set(write_span_kv(
+                v_pool[li], v, bts, start, n_valid))
+            att = paged_verify_attention(q, k_pool[li], v_pool[li], bts,
+                                         start, scale=scale,
+                                         tp_mesh=tp_mesh)
+            h = h + block.attn.proj(att.reshape(Bb * K1, -1)) \
+                .reshape(Bb, K1, -1)
+            m = block.fc2(F.gelu(block.fc1(block.ln2(h)
+                                           .reshape(Bb * K1, -1))))
+            h = h + m.reshape(Bb, K1, -1)
+        logits = self.head(self.ln_f(h.reshape(Bb * K1, -1))) \
+            .reshape(Bb, K1, -1).astype(jnp.float32)
+        return (k_pool, v_pool), logits, ()
+
+
+class TransformerLM(Chain, _ServingMixin):
     """Causal LM.  ``sequence_parallel``: pass ``sp_comm`` and call inside
     a program sharding the T dimension over its axis.  Position ids are
     supplied automatically when the axis is bound: contiguous offsets for

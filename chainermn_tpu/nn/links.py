@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ..core.link import Chain, Link, Parameter
@@ -26,8 +27,8 @@ from . import initializers as I
 
 __all__ = ["Linear", "Convolution2D", "Deconvolution2D",
            "DepthwiseConvolution2D", "BatchNormalization",
-           "LayerNormalization", "EmbedID", "LSTM", "StatelessLSTM",
-           "GroupNormalization", "StatelessGRU", "GRU", "NStepLSTM",
+           "LayerNormalization", "RMSNorm", "EmbedID", "LSTM",
+           "StatelessLSTM", "GroupNormalization", "StatelessGRU", "GRU", "NStepLSTM",
            "NStepGRU", "Highway", "Maxout", "Scale", "Classifier"]
 
 _default_rng = np.random.RandomState(817)
@@ -61,9 +62,12 @@ class Linear(Link):
     def _init_params(self, in_size):
         rng = _rng(self._seed)
         self.in_size = in_size
-        self.W.array = jnp.asarray(self._initW((self.out_size, in_size), np.float32, rng))
+        shape = (self.out_size, in_size)
+        self.W.draw(shape, np.float32,
+                    lambda: self._initW(shape, np.float32, rng))
         if not self.nobias:
-            self.b.array = jnp.asarray(self._initb((self.out_size,), np.float32, rng))
+            self.b.draw((self.out_size,), np.float32, lambda: self._initb(
+                (self.out_size,), np.float32, rng))
 
     def forward(self, x, n_batch_axes=1):
         if self.W.array is None:
@@ -309,6 +313,28 @@ class LayerNormalization(Link):
         return F.layer_normalization(x, self.gamma.array, self.beta.array, self.eps)
 
 
+class RMSNorm(Link):
+    """Root-mean-square normalization with a learned gain and no bias
+    (Zhang & Sennrich 2019): ``x / sqrt(mean(x²) + eps) · gamma``, the
+    statistics in float32 whatever the activation dtype, the result in
+    the activation's dtype."""
+
+    def __init__(self, size, eps=1e-5):
+        super().__init__()
+        self.eps = eps
+        with self.init_scope():
+            self.gamma = Parameter()
+        self.gamma.draw((size,), np.float32,
+                        lambda: np.ones((size,), np.float32))
+
+    def forward(self, x):
+        x32 = x.astype(jnp.float32)
+        inv = jax.lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1,
+                                     keepdims=True) + self.eps)
+        return (x32 * inv * self.gamma.array.astype(jnp.float32)) \
+            .astype(x.dtype)
+
+
 class EmbedID(Link):
     """Embedding lookup (reference: ``L.EmbedID``)."""
 
@@ -321,7 +347,9 @@ class EmbedID(Link):
         rng = _rng(seed)
         initW = I._get_initializer(initialW, I.Normal(1.0))
         with self.init_scope():
-            self.W = Parameter(initW((in_size, out_size), np.float32, rng))
+            self.W = Parameter()
+        self.W.draw((in_size, out_size), np.float32,
+                    lambda: initW((in_size, out_size), np.float32, rng))
 
     def forward(self, x):
         return F.embed_id(x, self.W.array, self.ignore_label)

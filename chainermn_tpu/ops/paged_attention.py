@@ -43,7 +43,8 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["paged_decode_attention", "paged_prefill_attention",
-           "paged_verify_attention", "paged_attn_mode", "head_sharding"]
+           "paged_verify_attention", "paged_latent_attention",
+           "paged_attn_mode", "head_sharding"]
 
 
 def paged_attn_mode(mode=None):
@@ -266,3 +267,41 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, ctx_len,
                               (ks, vs, jnp.arange(N)))
     out = acc / jnp.maximum(l, 1e-30)
     return _constrain_heads(out.astype(q.dtype), 1, tp_mesh, tp_axis)
+
+
+def paged_latent_attention(q, pool, block_table, qpos, rank, scale,
+                           layer=None):
+    """Attention over a pool of LATENTS, in the absorbed form of
+    multi-head latent attention: one cached vector a token serves every
+    head as key (all of it) and as value (its first ``rank`` entries).
+
+    ``q``: ``[B, Tq, H, C]`` absorbed queries (the key half of the
+    expansion already applied, then the rotary part); ``pool``: ``[P, S,
+    C]`` one layer's pages, or with a (static) ``layer`` the whole ``[L,
+    P, S, C]`` pool, gathered from at that layer with no slice taken
+    out first; ``block_table``: ``[B, N]``; ``qpos``:
+    ``[B, Tq]`` the queries' absolute positions, ``< 0`` for a query
+    that sees nothing (an idle lane: its output is zeros).  The queries'
+    own latents must already be WRITTEN, so ONE gather through the block
+    table covers the whole context, and query ``(b, j)`` sees positions
+    ``<= qpos[b, j]``.  The one-token decode is ``Tq = 1``; the suffix
+    of a prefix hit is ``B = 1``.  Scores are ``[B, Tq, H, N·S]``
+    float32, one full-width masked softmax.  Returns ``o_lat [B, Tq, H,
+    rank]`` in ``q.dtype``, to go through the value half of the
+    expansion."""
+    B, Tq, H, C = q.shape
+    S = pool.shape[-2]
+    N = block_table.shape[1]
+    lat = (pool[block_table] if layer is None
+           else pool[layer, block_table]).reshape(B, N * S, C)
+    # queries and heads as ONE row axis: both products are then plain
+    # batched matrix products over the lanes
+    s = jnp.einsum("bmc,bkc->bmk", q.reshape(B, Tq * H, C), lat,
+                   preferred_element_type=jnp.float32) * scale
+    kpos = lax.broadcasted_iota(jnp.int32, (1, 1, 1, N * S), 3)
+    p, l = _masked_softmax_stats(s.reshape(B, Tq, H, N * S),
+                                 kpos <= qpos[:, :, None, None])
+    p = (p / jnp.maximum(l, 1e-30)).reshape(B, Tq * H, N * S)
+    out = jnp.einsum("bmk,bkc->bmc", p.astype(lat.dtype),
+                     lat[..., :rank], preferred_element_type=jnp.float32)
+    return out.reshape(B, Tq, H, rank).astype(q.dtype)

@@ -58,10 +58,14 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
+from ..core.link import Link, Parameter
+
 __all__ = ["switch_moe", "moe_dispatch_combine", "moe_dispatch_combine_topk",
-           "moe_capacity"]
+           "moe_capacity", "sigmoid_topk_route", "held_experts_ffn",
+           "HeldExperts"]
 
 
 def moe_capacity(n_tokens, n_experts, capacity_factor, k=1):
@@ -367,3 +371,113 @@ def moe_dispatch_combine_topk(comm, x, gate_logits, expert_fn, k=2,
                       "dropped_frac":
                           1.0 - jnp.mean(keep.astype(jnp.float32)),
                       "capacity": capacity}
+
+
+# -- a share of an expert layer: route over all, compute the held -------------
+#
+# What one chip of an expert-parallel group runs once the group is larger
+# than one expert a rank: the router keeps every expert's output and its
+# k a token, the chip holds experts ``[first, first + count)`` and adds
+# only their terms of the sum.  Nothing is dropped: there is no capacity
+# buffer, every copy routed to a held expert is computed.  No exchange is
+# emitted here; what the other chips' experts add is not this layer's.
+
+def sigmoid_topk_route(x, router_w, bias, k, scale):
+    """Sigmoid scoring with a selection bias (DeepSeek-V3's ``noaux_tc``
+    with one group): ``s = sigmoid(x W_g)`` in float32 over ALL experts,
+    the ``k`` chosen are the top ``k`` of ``s + bias``, and their weights
+    are ``s`` (without the bias) over the chosen, divided by their sum,
+    times ``scale``.  ``x``: ``[T, D]``; ``router_w``: ``[E, D]``;
+    ``bias``: ``[E]``.  Returns ``(ids [T, k] int32, weights [T, k]
+    float32)``."""
+    s = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32).T,
+        precision=lax.Precision.HIGHEST))
+    _, ids = lax.top_k(s + bias.astype(jnp.float32), k)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
+    return ids.astype(jnp.int32), w
+
+
+def held_experts_ffn(x, ids, weights, w_gate, w_up, w_down, first,
+                     valid=None):
+    """The held experts' part of a routed SwiGLU layer, nothing dropped.
+
+    ``x``: ``[T, D]``.  ``ids``/``weights``: ``[T, k]`` from the router,
+    over all experts.  ``w_gate``/``w_up``: ``[H, F, D]`` (out, in) and
+    ``w_down``: ``[H, F, D]`` (in, out) for the ``H`` experts held,
+    which are experts ``first .. first + H - 1``.  Returns ``(y [T, D],
+    counts [H] int32)``: ``y = sum over held e of w_e · down_e(silu(
+    gate_e x) * up_e x)`` with ``w_e`` zero where the token was not
+    routed to ``e``, and the token-copies that landed on each held
+    expert (those of ``valid`` tokens, a ``[T]`` mask, where given).
+
+    Grouped by masking, not by sorting: the held experts' matrices are
+    read as ONE SwiGLU of width ``H · F`` (two plain products over the
+    stacked leaves, no copy and no gather of weights), and the routing
+    weight scales each expert's slice of the hidden activation between
+    them.  Every held expert is computed for every token, so the cost
+    does not depend on the routing, a skew onto one expert overflows
+    nothing, and a decode step, which reads every held weight once
+    whatever the routing, pays nothing for it; a long prefill computes
+    ``H · E / (k · H)`` times the products a sort would (PERF.md)."""
+    T, D = x.shape
+    H, Fw = w_gate.shape[0], w_gate.shape[1]
+    local = ids - first
+    onehot = local[..., None] == jnp.arange(H, dtype=ids.dtype)  # [T,k,H]
+    gate_w = jnp.sum(jnp.where(onehot, weights[..., None], 0.0), axis=1)
+    live = onehot if valid is None else onehot & valid[:, None, None]
+    counts = jnp.sum(live, axis=(0, 1), dtype=jnp.int32)
+    g = x @ w_gate.reshape(H * Fw, D).T
+    u = x @ w_up.reshape(H * Fw, D).T
+    h = (jax.nn.silu(g) * u).reshape(T, H, Fw) \
+        * gate_w[..., None].astype(x.dtype)
+    return h.reshape(T, H * Fw) @ w_down.reshape(H * Fw, D), counts
+
+
+class HeldExperts(Link):
+    """A chip's share of a routed expert layer: the router over all
+    ``n_experts`` and the SwiGLU experts ``held = (first, count)``.
+
+    ``forward(x, valid=None)`` → ``(y, counts)`` as
+    :func:`held_experts_ffn`.  Under an expert-parallel axis of
+    ``n_experts // count`` chips this is each chip's layer (the sum
+    over chips of ``y`` is the whole layer's routed part); on one chip
+    it runs as it stands, with no exchange."""
+
+    def __init__(self, d_model, d_expert, n_experts, held, k,
+                 routed_scale=1.0):
+        super().__init__()
+        first, count = held
+        if not (0 <= first and first + count <= n_experts and count > 0):
+            raise ValueError(f"held={held} is not inside the layer's "
+                             f"{n_experts} experts")
+        self.n_experts, self.k = int(n_experts), int(k)
+        self.first, self.count = int(first), int(count)
+        self.routed_scale = float(routed_scale)
+        with self.init_scope():
+            self.router = Parameter()
+            self.router_bias = Parameter()
+            self.w_gate = Parameter()
+            self.w_up = Parameter()
+            self.w_down = Parameter()
+        rng = np.random.RandomState(0)
+        shapes = {"router": ((n_experts, d_model), d_model),
+                  "w_gate": ((count, d_expert, d_model), d_model),
+                  "w_up": ((count, d_expert, d_model), d_model),
+                  "w_down": ((count, d_expert, d_model), d_expert)}
+        for name, (shape, fan_in) in shapes.items():
+            getattr(self, name).draw(
+                shape, np.float32,
+                lambda shape=shape, fan_in=fan_in: rng.normal(
+                    0.0, fan_in ** -0.5, shape).astype(np.float32))
+        self.router_bias.draw((n_experts,), np.float32,
+                              lambda: np.zeros(n_experts, np.float32))
+
+    def forward(self, x, valid=None):
+        ids, w = sigmoid_topk_route(x, self.router.array,
+                                    self.router_bias.array, self.k,
+                                    self.routed_scale)
+        return held_experts_ffn(x, ids, w, self.w_gate.array,
+                                self.w_up.array, self.w_down.array,
+                                self.first, valid=valid)

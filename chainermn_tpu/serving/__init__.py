@@ -48,7 +48,8 @@ from .engine import (ServingEngine, decode_program, ngram_propose,
                      prefill_program, prefix_prefill_program,
                      serve_disagg_mode, serve_spec_k, spec_verify_program)
 from .errors import (EvictionStalledError, PagePoolExhaustedError,
-                     QueueSaturatedError, ServingError)
+                     QueueSaturatedError, ServingError,
+                     UnsupportedProgramError)
 from .fleet import (FleetWorker, LocalReplica, QueueDepthScalePolicy,
                     RemoteReplica, ReplicaFleet, fleet_mode)
 from .kv_cache import (PagedKVCache, copy_page, insert_pages,
@@ -68,7 +69,7 @@ __all__ = [
     "write_token_kv", "copy_page", "insert_pages",
     "BlockAllocator", "Request", "RequestScheduler",
     "ServingError", "PagePoolExhaustedError", "QueueSaturatedError",
-    "EvictionStalledError",
+    "EvictionStalledError", "UnsupportedProgramError",
     # round 16 (ISSUE 15): the elastic serving fleet
     "ReplicaFleet", "FleetRouter", "LocalReplica", "RemoteReplica",
     "FleetWorker", "QueueDepthScalePolicy", "fleet_mode",

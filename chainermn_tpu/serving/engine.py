@@ -1,20 +1,27 @@
-"""Continuous-batching serving engine: prefill/decode split over paged KV.
+"""Continuous-batching serving engine: prefill/decode split over a paged cache.
 
 The reference's marquee trick — keep the device busy by overlapping the
-slow path behind the hot loop — applied to inference.  Two compiled
-programs share one paged KV cache:
+slow path behind the hot loop — applied to inference.  The engine owns
+the scheduling, the pages, the buckets and the spans; the MODEL owns its
+block (PR 27): it declares what a token leaves in the cache
+(``serve_cache_entry()``: K and V of ``[H, D]`` for a GPT-2-shaped
+model, one latent vector for a latent-attention one) and provides the
+programs' bodies (``serve_prefill`` / ``serve_suffix_prefill`` /
+``serve_decode``) over per-layer views of the page pools.  Two compiled
+programs share the pools:
 
 * **prefill** (one request at a time): the prompt runs through the
-  normal flash-attention forward (``ops.attention`` — the PR 4 kernels
-  on TPU, backward never traced), each layer's K/V scattering into the
-  request's pages, and the last valid position's logits produce the
-  first generated token.  Prompt lengths are PADDED to a bucket
-  (powers of two), so ragged prompts reuse a small fixed set of
-  compiled programs.
+  model's normal attention forward (``ops.attention`` — the PR 4
+  kernels on TPU, backward never traced), each layer's cache entries
+  scattering into the request's pages, and the last valid position's
+  logits produce the first generated token.  Prompt lengths are PADDED
+  to a bucket (powers of two), so ragged prompts reuse a small fixed
+  set of compiled programs.
 * **decode** (the whole running batch, one token per sequence): a
-  single-query step per layer — write the token's K/V into its page,
-  then :func:`~chainermn_tpu.ops.paged_attention.paged_decode_attention`
-  gathers the batch's context through the block tables.  The batch
+  single-query step per layer — write the token's entry into its page,
+  then attend over the batch's context gathered through the block
+  tables (:func:`~chainermn_tpu.ops.paged_attention.paged_decode_attention`
+  for K and V, ``paged_latent_attention`` for latents).  The batch
   dimension is padded to a bucket too, so sequences joining and leaving
   the running batch NEVER retrace — the engine counts traces
   (``prefill_traces``/``decode_traces``) and the tests pin it.
@@ -108,17 +115,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import observability
-from ..core.link import bind_state, extract_state
-from ..nn import functions as F
-from ..ops import attention as flash_attention_op
-from ..ops.paged_attention import (head_sharding, paged_attn_mode,
-                                   paged_decode_attention,
-                                   paged_prefill_attention,
-                                   paged_verify_attention)
-from .errors import PagePoolExhaustedError
-from .kv_cache import (PagedKVCache, copy_page, insert_pages,
-                       write_prompt_kv, write_prompt_kv_at, write_span_kv,
-                       write_token_kv)
+from ..core.link import bind_state, cast_params, extract_state
+from ..ops.paged_attention import paged_attn_mode
+from .errors import PagePoolExhaustedError, UnsupportedProgramError
+from .kv_cache import PagedKVCache, copy_page, insert_pages
 from .page_allocator import BlockAllocator
 from .scheduler import RequestScheduler
 
@@ -187,190 +187,106 @@ def ngram_propose(history, k, n=3):
     return prop
 
 
-def _embed_tokens(model, toks, positions):
-    """Token + position embeddings cast to the model's compute dtype
-    (the TransformerLM.hidden discipline: params fp32, block compute in
-    ``compute_dtype``)."""
-    h = model.embed(toks) + model.pos_embed(positions)
-    if model.compute_dtype is not None:
-        h = h.astype(model.compute_dtype)
-    return h
+def _served(model, program):
+    """The model's own side of ``program`` (``serve_<program>``), or the
+    typed refusal: a model that lacks a program is never served by a
+    stand-in."""
+    fn = getattr(model, "serve_" + program, None)
+    if fn is None:
+        raise UnsupportedProgramError(type(model).__name__, program)
+    return fn
 
 
-def prefill_program(model, state, k_pool, v_pool, tokens, true_len,
-                    bt_row):
+def prefill_program(model, state, *operands):
     """Pure prefill: full causal forward over the (padded) prompt.
 
-    ``tokens``: ``[1, Tb]`` int32 (positions ``>= true_len`` are
-    padding — their K/V writes drop, and causality keeps them out of
-    every valid position's attention).  Returns ``(k_pool, v_pool,
-    logits)`` with ``logits`` the fp32 ``[V]`` row at position
-    ``true_len - 1``.
+    ``operands``: the cache pools the model declares, then ``tokens``
+    ``[1, Tb]`` int32 (positions ``>= true_len`` are padding — their
+    cache writes drop, and causality keeps them out of every valid
+    position's attention), ``true_len`` and ``bt_row``.  The block is
+    the model's (``serve_prefill``).  Returns ``(*pools, logits,
+    *extras)`` with ``logits`` the fp32 ``[V]`` row at position
+    ``true_len - 1`` and ``extras`` whatever the model counts for the
+    engine's spans (nothing, for most).
     """
+    *pools, tokens, true_len, bt_row = operands
     with bind_state(model, state):
-        B, T = tokens.shape
-        pos = jax.lax.broadcasted_iota(jnp.int32, (B, T), 1)
-        h = _embed_tokens(model, tokens, pos)
-        for li, block in enumerate(model.blocks):
-            x = block.ln1(h)
-            qkv = block.attn.qkv(x.reshape(B * T, -1)).reshape(
-                B, T, 3, block.attn.n_heads, block.attn.d_head)
-            q, k, v = [jnp.moveaxis(qkv[:, :, j], 1, 2) for j in range(3)]
-            # the flash dispatcher: Pallas forward on TPU (no backward is
-            # ever traced — inference), XLA/interpret elsewhere
-            att = flash_attention_op(q, k, v, causal=True)
-            att = jnp.moveaxis(att, 2, 1).reshape(B * T, -1)
-            h = h + block.attn.proj(att).reshape(B, T, -1)
-            m = block.fc2(F.gelu(block.fc1(block.ln2(h).reshape(B * T,
-                                                                -1))))
-            h = h + m.reshape(B, T, -1)
-            k_pool = k_pool.at[li].set(write_prompt_kv(
-                k_pool[li], jnp.moveaxis(k[0], 0, 1), bt_row, true_len))
-            v_pool = v_pool.at[li].set(write_prompt_kv(
-                v_pool[li], jnp.moveaxis(v[0], 0, 1), bt_row, true_len))
-        h_last = jax.lax.dynamic_slice_in_dim(
-            h[0], jnp.maximum(true_len - 1, 0), 1, axis=0)
-        logits = model.head(model.ln_f(h_last))[0]
-        return k_pool, v_pool, logits.astype(jnp.float32)
+        pools, logits, extras = _served(model, "prefill")(
+            tuple(pools), tokens, true_len, bt_row)
+        return (*pools, logits, *extras)
 
 
-def prefix_prefill_program(model, state, k_pool, v_pool, tokens, true_len,
-                           start, bt_row):
+def prefix_prefill_program(model, state, *operands):
     """Pure SUFFIX prefill for a prefix-shared request (round 14).
 
-    ``tokens``: ``[1, Tb]`` int32 suffix tokens (positions ``>=
-    true_len`` padding); suffix index ``t`` sits at absolute position
-    ``start + t``, where ``start`` is the matched prefix length.
-    ``bt_row``: ``[N]`` block table covering the WHOLE context (shared
-    prefix pages + the request's fresh suffix pages).  Per layer the
-    suffix's K/V scatter through the offset writer FIRST, then one
-    gather per pool reads the whole context back and the suffix queries
-    run one masked softmax against it
-    (:func:`~chainermn_tpu.ops.paged_attention.paged_prefill_attention`)
-    — ZERO flash kernels touch the shared pages, and the score matrix
-    is suffix-by-context, never context-by-context: skipping the
-    matched prefix's O(L²) attention and O(L·d²) projections is the
-    FLOP saving the prefix hit buys.  Returns ``(k_pool, v_pool,
-    logits)`` with ``logits`` the fp32 ``[V]`` row at suffix position
-    ``true_len - 1`` (the match is capped at ``prompt - 1`` tokens, so
-    the first-generation logits always come from a live suffix
-    position).
+    ``operands``: the pools, then ``tokens`` ``[1, Tb]`` int32 suffix
+    tokens (positions ``>= true_len`` padding), ``true_len``, ``start``
+    and ``bt_row``; suffix index ``t`` sits at absolute position
+    ``start + t``, where ``start`` is the matched prefix length, and
+    ``bt_row`` (``[N]``) covers the WHOLE context (shared prefix pages +
+    the request's fresh suffix pages).  The model
+    (``serve_suffix_prefill``) writes the suffix's entries through the
+    offset writer and attends against the context read back through the
+    block table: no attention and no projection is recomputed over the
+    shared pages, which is the FLOP saving the prefix hit buys.
+    Returns ``(*pools, logits, *extras)`` with ``logits`` the fp32
+    ``[V]`` row at suffix position ``true_len - 1`` (the match is capped
+    at ``prompt - 1`` tokens, so the first-generation logits always come
+    from a live suffix position).
     """
+    *pools, tokens, true_len, start, bt_row = operands
     with bind_state(model, state):
-        B, T = tokens.shape
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (B, T), 1)
-        h = _embed_tokens(model, tokens, pos)
-        scale = 1.0 / (model.blocks[0].attn.d_head ** 0.5)
-        for li, block in enumerate(model.blocks):
-            x = block.ln1(h)
-            qkv = block.attn.qkv(x.reshape(B * T, -1)).reshape(
-                B, T, 3, block.attn.n_heads, block.attn.d_head)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            k_pool = k_pool.at[li].set(write_prompt_kv_at(
-                k_pool[li], k[0], bt_row, start, true_len))
-            v_pool = v_pool.at[li].set(write_prompt_kv_at(
-                v_pool[li], v[0], bt_row, start, true_len))
-            att = paged_prefill_attention(q[0], k_pool[li], v_pool[li],
-                                          bt_row, start, true_len,
-                                          scale=scale)
-            h = h + block.attn.proj(att.reshape(B * T, -1)) \
-                .reshape(B, T, -1)
-            m = block.fc2(F.gelu(block.fc1(block.ln2(h).reshape(B * T,
-                                                                -1))))
-            h = h + m.reshape(B, T, -1)
-        h_last = jax.lax.dynamic_slice_in_dim(
-            h[0], jnp.maximum(true_len - 1, 0), 1, axis=0)
-        logits = model.head(model.ln_f(h_last))[0]
-        return k_pool, v_pool, logits.astype(jnp.float32)
+        pools, logits, extras = _served(model, "suffix_prefill")(
+            tuple(pools), tokens, true_len, start, bt_row)
+        return (*pools, logits, *extras)
 
 
-def decode_program(model, state, k_pool, v_pool, toks, pos, bts, *,
-                   mode, tp_mesh=None):
+def decode_program(model, state, *operands, mode, tp_mesh=None):
     """Pure decode step: one token per batch lane.
 
-    ``toks``/``pos``: ``[Bb]`` int32 (``pos < 0`` marks an idle padding
-    lane: its K/V write drops and its attention context is empty).
-    ``bts``: ``[Bb, N]`` block tables.  Writes each lane's K/V at
+    ``operands``: the pools, then ``toks``/``pos`` ``[Bb]`` int32
+    (``pos < 0`` marks an idle padding lane: its cache write drops and
+    its attention context is empty) and ``bts`` ``[Bb, N]`` block
+    tables.  The model (``serve_decode``) writes each lane's entry at
     ``pos`` then attends over ``[0, pos]`` through the block table.
-    ``tp_mesh``: the tensor-parallel mesh — pools arrive head-sharded
-    and the attention op constrains its gathers to stay that way.
-    Returns ``(k_pool, v_pool, logits [Bb, V] fp32, next_tok [Bb])``.
+    ``tp_mesh``: the tensor-parallel mesh — pools arrive sharded as the
+    model declared.  Returns ``(*pools, logits [Bb, V] fp32, next_tok
+    [Bb], *extras)``.
     """
+    *pools, toks, pos, bts = operands
     with bind_state(model, state):
-        Bb = toks.shape[0]
-        safe_pos = jnp.maximum(pos, 0)
-        h = _embed_tokens(model, toks, safe_pos)
-        ctx_len = jnp.where(pos >= 0, pos + 1, 0)
-        scale = 1.0 / (model.blocks[0].attn.d_head ** 0.5)
-        for li, block in enumerate(model.blocks):
-            x = block.ln1(h)
-            qkv = block.attn.qkv(x).reshape(
-                Bb, 3, block.attn.n_heads, block.attn.d_head)
-            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-            k_pool = k_pool.at[li].set(
-                write_token_kv(k_pool[li], k, bts, pos))
-            v_pool = v_pool.at[li].set(
-                write_token_kv(v_pool[li], v, bts, pos))
-            att = paged_decode_attention(q, k_pool[li], v_pool[li], bts,
-                                         ctx_len, scale=scale, mode=mode,
-                                         tp_mesh=tp_mesh)
-            h = h + block.attn.proj(att.reshape(Bb, -1))
-            h = h + block.fc2(F.gelu(block.fc1(block.ln2(h))))
-        logits = model.head(model.ln_f(h)).astype(jnp.float32)
-        return k_pool, v_pool, logits, jnp.argmax(logits, axis=-1) \
-            .astype(jnp.int32)
+        pools, logits, extras = _served(model, "decode")(
+            tuple(pools), toks, pos, bts, mode=mode, tp_mesh=tp_mesh)
+        return (*pools, logits, jnp.argmax(logits, axis=-1)
+                .astype(jnp.int32), *extras)
 
 
-def spec_verify_program(model, state, k_pool, v_pool, toks, start,
-                        n_valid, bts, *, tp_mesh=None):
+def spec_verify_program(model, state, *operands, tp_mesh=None):
     """Pure speculative VERIFY step: score K+1 tokens per lane in one
     dispatch (round 20).
 
-    ``toks``: ``[Bb, K1]`` int32 — lane ``b``'s pending token followed
-    by its K draft proposals; token ``j`` sits at absolute position
-    ``start[b] + j``.  ``start``: ``[Bb]`` int32 (``< 0`` = idle
-    lane).  ``n_valid``: ``[Bb]`` int32 — only the first ``n_valid[b]``
-    span slots write K/V (lanes near their emit budget speculate
-    short; surplus writes drop).  Per layer: ONE drop-fenced span
-    scatter per pool (``write_span_kv``), then ONE gather per pool and
-    a multi-query masked softmax over the block tables
-    (:func:`~chainermn_tpu.ops.paged_attention.paged_verify_attention`)
-    — query ``j`` sees exactly positions ``<= start + j``, i.e. the
-    context a vanilla decode step at that position would see, which is
-    why the returned argmax row ``g[b, j]`` equals what one-token
-    decode WOULD have produced had tokens ``0..j`` been emitted one at
-    a time.  The host then accepts the longest prefix where draft
-    ``j+1`` equals ``g[j]`` and emits ``g[0..a]`` — up to K+1 tokens
-    from one dispatch, bit-identical to vanilla greedy decode.
-    Returns ``(k_pool, v_pool, logits [Bb, K1, V] fp32, g [Bb, K1])``.
+    ``operands``: the pools, then ``toks`` ``[Bb, K1]`` int32 — lane
+    ``b``'s pending token followed by its K draft proposals; token ``j``
+    sits at absolute position ``start[b] + j`` — ``start`` ``[Bb]``
+    int32 (``< 0`` = idle lane), ``n_valid`` ``[Bb]`` int32 (only the
+    first ``n_valid[b]`` span slots write; lanes near their emit budget
+    speculate short; surplus writes drop) and ``bts``.  The model
+    (``serve_verify``) makes query ``j`` see exactly positions ``<=
+    start + j``, i.e. the context a vanilla decode step at that
+    position would see, which is why the returned argmax row ``g[b,
+    j]`` equals what one-token decode WOULD have produced had tokens
+    ``0..j`` been emitted one at a time.  The host then accepts the
+    longest prefix where draft ``j+1`` equals ``g[j]`` and emits
+    ``g[0..a]`` — up to K+1 tokens from one dispatch, bit-identical to
+    vanilla greedy decode.  Returns ``(*pools, logits [Bb, K1, V] fp32,
+    g [Bb, K1])``.
     """
+    *pools, toks, start, n_valid, bts = operands
     with bind_state(model, state):
-        Bb, K1 = toks.shape
-        safe_start = jnp.maximum(start, 0)
-        pos = safe_start[:, None] + jnp.arange(K1, dtype=jnp.int32)[None]
-        h = _embed_tokens(model, toks, pos)
-        scale = 1.0 / (model.blocks[0].attn.d_head ** 0.5)
-        for li, block in enumerate(model.blocks):
-            x = block.ln1(h)
-            qkv = block.attn.qkv(x.reshape(Bb * K1, -1)).reshape(
-                Bb, K1, 3, block.attn.n_heads, block.attn.d_head)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            k_pool = k_pool.at[li].set(write_span_kv(
-                k_pool[li], k, bts, start, n_valid))
-            v_pool = v_pool.at[li].set(write_span_kv(
-                v_pool[li], v, bts, start, n_valid))
-            att = paged_verify_attention(q, k_pool[li], v_pool[li], bts,
-                                         start, scale=scale,
-                                         tp_mesh=tp_mesh)
-            h = h + block.attn.proj(att.reshape(Bb * K1, -1)) \
-                .reshape(Bb, K1, -1)
-            m = block.fc2(F.gelu(block.fc1(block.ln2(h)
-                                           .reshape(Bb * K1, -1))))
-            h = h + m.reshape(Bb, K1, -1)
-        logits = model.head(model.ln_f(h.reshape(Bb * K1, -1))) \
-            .reshape(Bb, K1, -1).astype(jnp.float32)
-        return k_pool, v_pool, logits, jnp.argmax(logits, axis=-1) \
-            .astype(jnp.int32)
+        pools, logits, _ = _served(model, "verify")(
+            tuple(pools), toks, start, n_valid, bts, tp_mesh=tp_mesh)
+        return (*pools, logits, jnp.argmax(logits, axis=-1)
+                .astype(jnp.int32))
 
 
 class _AdmitDeferred(Exception):
@@ -398,9 +314,17 @@ def _pow2_buckets(lo, hi):
 
 
 class ServingEngine:
-    """Continuous-batching engine over a ``TransformerLM``-shaped model
-    (anything exposing ``embed``/``pos_embed``/``blocks``/``ln_f``/
-    ``head`` with the block layout of ``models.transformer``).
+    """Continuous-batching engine over any model with the serving
+    interface (docs/serving.md): the model declares what a token leaves
+    in the cache (``serve_cache_layers``, ``serve_cache_entry()``,
+    ``serve_page_dtype``), its context limit (``serve_max_context``) and
+    the dtype its parameters are held in (``serve_param_dtype``), and
+    owns its block: ``serve_prefill`` / ``serve_suffix_prefill`` /
+    ``serve_decode`` over per-layer views of the page pools.  The engine
+    keeps the scheduling, the pages, the buckets and the spans.  A
+    program a model lacks (``serve_verify`` for ``spec_k``,
+    ``serve_pool_sharding`` for ``tp``) is refused at construction with
+    :class:`~chainermn_tpu.serving.errors.UnsupportedProgramError`.
 
     Greedy sampling (the serving bench's configuration); the paged/dense
     attention lowering is resolved ONCE at construction
@@ -412,13 +336,14 @@ class ServingEngine:
     and ship finished pages into the decode pool (``None`` = the env
     knob; the default prefill device is the next device after the
     decode slice, degenerating to the same device on one-device hosts).
-    ``tp``: shard the KV pools (and both programs) over the head axis
-    of a ``tp``-way mesh.
+    ``tp``: shard the cache pools (and both programs) over a ``tp``-way
+    mesh, along the axis the model declares (``serve_pool_sharding``:
+    the head axis of K and V).
     ``spec_k``: speculative decoding — K draft tokens verified per
     sequence per decode dispatch (0 = vanilla one-token decode;
     ``CHAINERMN_TPU_SERVE_SPEC=off`` forces 0).  ``draft_model``: a
-    small TransformerLM-shaped drafter (same vocabulary; its KV pages
-    are indexed by the SAME block tables, so it must accept the
+    small drafter with the same interface (same vocabulary; its cache
+    pages are indexed by the SAME block tables, so it must accept the
     engine's page geometry); ``None`` = the n-gram self-draft.
     ``chunk_tokens``: chunked prefill — prompts whose unmatched
     remainder exceeds this admit in page-multiple chunks interleaved
@@ -434,18 +359,18 @@ class ServingEngine:
                  prefill_device=None, decode_device=None,
                  spec_k=0, draft_model=None, chunk_tokens=None,
                  chunk_budget=None):
-        blk = model.blocks[0].attn
-        n_layers = len(list(model.blocks))
-        max_len = model.pos_embed.W.shape[0]
+        max_len = model.serve_max_context
         if max_context > max_len:
             raise ValueError(f"max_context={max_context} exceeds the "
                              f"model's max_len={max_len}")
         if page_dtype is None:
-            page_dtype = model.compute_dtype or jnp.float32
+            page_dtype = model.serve_page_dtype
         self.model = model
-        self.state = extract_state(model)
-        self.kv = PagedKVCache(n_layers, num_pages, page_size,
-                               blk.n_heads, blk.d_head, dtype=page_dtype)
+        self.state = self._held_state(model)
+        self.kv = PagedKVCache(model.serve_cache_layers, num_pages,
+                               page_size, model.serve_cache_entry(),
+                               dtype=page_dtype)
+        n_pools = len(self.kv.pools)
         self.allocator = BlockAllocator(num_pages, page_size)
         self.scheduler = scheduler or RequestScheduler(max_queue=max_queue)
         self.max_batch = int(max_batch)
@@ -459,6 +384,8 @@ class ServingEngine:
         self.spec_k = serve_spec_k(spec_k)
         if self.spec_k < 0:
             raise ValueError(f"spec_k={spec_k} must be >= 0")
+        if self.spec_k:
+            _served(model, "verify")    # typed refusal, before any compile
         self.draft_model = draft_model if self.spec_k else None
         self.chunk_tokens = int(chunk_tokens) if chunk_tokens else None
         if self.chunk_tokens is not None:
@@ -509,20 +436,19 @@ class ServingEngine:
         self.chunk_prefills = 0
         self.chunked_admissions = 0
 
-        # draft KV pools: indexed by the SAME block tables as the target
-        # pools (same page geometry), so draft pages ride the same
+        # draft cache pools: indexed by the SAME block tables as the
+        # target pools (same page geometry), so draft pages ride the same
         # refcounted allocator — one accounting, one eviction story
         if self.draft_model is not None:
-            dblk = self.draft_model.blocks[0].attn
-            d_max_len = self.draft_model.pos_embed.W.shape[0]
+            d_max_len = self.draft_model.serve_max_context
             if d_max_len < self.max_context:
                 raise ValueError(
                     f"draft_model max_len={d_max_len} below "
                     f"max_context={max_context}")
-            self._draft_state = extract_state(self.draft_model)
+            self._draft_state = self._held_state(self.draft_model)
             self._kv_draft = PagedKVCache(
-                len(list(self.draft_model.blocks)), num_pages, page_size,
-                dblk.n_heads, dblk.d_head, dtype=page_dtype)
+                self.draft_model.serve_cache_layers, num_pages, page_size,
+                self.draft_model.serve_cache_entry(), dtype=page_dtype)
             # the draft's full-prompt prefill buckets are UNCAPPED by
             # chunking (the draft is small — one flash pass is cheaper
             # than teaching it the chunk machinery)
@@ -531,25 +457,23 @@ class ServingEngine:
 
         devices = jax.devices()
 
-        # -- tensor-parallel decode: pools laid out per shard (head axis
-        # of the tp mesh — the ulysses sharding), params replicated over
-        # the mesh; both programs then compile under GSPMD
+        # -- tensor-parallel decode: pools laid out per shard (for K and
+        # V the head axis of the tp mesh — the ulysses sharding), params
+        # replicated over the mesh; both programs then compile under
+        # GSPMD
         if self.tp > 1:
-            if blk.n_heads % self.tp:
-                raise ValueError(f"tp={self.tp} must divide n_heads="
-                                 f"{blk.n_heads}")
             if len(devices) < self.tp:
                 raise ValueError(f"tp={self.tp} needs {self.tp} devices, "
                                  f"have {len(devices)}")
             from jax.sharding import Mesh, NamedSharding, PartitionSpec
             self._tp_mesh = Mesh(np.array(devices[:self.tp]), ("tp",))
-            pool_sh = head_sharding(self._tp_mesh, 5, 3)
-            self.kv.k_pool = jax.device_put(self.kv.k_pool, pool_sh)
-            self.kv.v_pool = jax.device_put(self.kv.v_pool, pool_sh)
+            pool_sh = _served(model, "pool_sharding")(self._tp_mesh)
+            self.kv.pools = [jax.device_put(p, pool_sh)
+                             for p in self.kv.pools]
             self.state = jax.device_put(
                 self.state, NamedSharding(self._tp_mesh, PartitionSpec()))
-            # transferred page blocks land head-sharded too
-            self._block_placement = head_sharding(self._tp_mesh, 5, 3)
+            # transferred page blocks land sharded the same way
+            self._block_placement = pool_sh
         else:
             self._tp_mesh = None
             self._block_placement = decode_device or devices[0]
@@ -562,16 +486,15 @@ class ServingEngine:
                 devices[self.tp % len(devices)]
             if self.tp == 1:
                 dd = decode_device or devices[0]
-                self.kv.k_pool = jax.device_put(self.kv.k_pool, dd)
-                self.kv.v_pool = jax.device_put(self.kv.v_pool, dd)
+                self.kv.pools = [jax.device_put(p, dd)
+                                 for p in self.kv.pools]
                 self.state = jax.device_put(self.state, dd)
             self._kv_prefill = PagedKVCache(
-                n_layers, self.n_block_entries, page_size, blk.n_heads,
-                blk.d_head, dtype=page_dtype)
-            self._kv_prefill.k_pool = jax.device_put(
-                self._kv_prefill.k_pool, self._prefill_device)
-            self._kv_prefill.v_pool = jax.device_put(
-                self._kv_prefill.v_pool, self._prefill_device)
+                model.serve_cache_layers, self.n_block_entries, page_size,
+                model.serve_cache_entry(), dtype=page_dtype)
+            self._kv_prefill.pools = [
+                jax.device_put(p, self._prefill_device)
+                for p in self._kv_prefill.pools]
             self._state_prefill = jax.device_put(self.state,
                                                  self._prefill_device)
             # the scratch pool's identity block table: prefill always
@@ -581,71 +504,62 @@ class ServingEngine:
                 self._prefill_device)
 
         # donate the pools on real accelerators only: XLA then updates
-        # pages in place; on cpu donation is ignored and merely warns
+        # pages in place; on cpu donation is ignored and merely warns.
+        # Every program takes the state, then the pools the model
+        # declared, then its operands, and returns the pools first
         real = jax.default_backend() == "tpu"
-        donate = (1, 2) if real else ()
-        donate01 = (0, 1) if real else ()
+        donate = tuple(range(1, 1 + n_pools)) if real else ()
+        donate0 = tuple(range(n_pools)) if real else ()
 
-        def _prefill(state, k_pool, v_pool, tokens, true_len, bt_row):
+        def _prefill(state, *operands):
             self.prefill_traces += 1   # trace-time side effect only
-            return prefill_program(self.model, state, k_pool, v_pool,
-                                   tokens, true_len, bt_row)
+            return prefill_program(self.model, state, *operands)
 
-        def _prefix_prefill(state, k_pool, v_pool, tokens, true_len,
-                            start, bt_row):
+        def _prefix_prefill(state, *operands):
             self.prefix_prefill_traces += 1
-            return prefix_prefill_program(self.model, state, k_pool,
-                                          v_pool, tokens, true_len,
-                                          start, bt_row)
+            return prefix_prefill_program(self.model, state, *operands)
 
-        def _decode(state, k_pool, v_pool, toks, pos, bts):
+        def _decode(state, *operands):
             self.decode_traces += 1    # trace-time side effect only
-            return decode_program(self.model, state, k_pool, v_pool,
-                                  toks, pos, bts, mode=self.mode,
-                                  tp_mesh=self._tp_mesh)
+            return decode_program(self.model, state, *operands,
+                                  mode=self.mode, tp_mesh=self._tp_mesh)
 
-        def _spec_verify(state, k_pool, v_pool, toks, start, n_valid,
-                         bts):
+        def _spec_verify(state, *operands):
             self.spec_traces += 1   # trace-time side effect only
-            return spec_verify_program(self.model, state, k_pool, v_pool,
-                                       toks, start, n_valid, bts,
+            return spec_verify_program(self.model, state, *operands,
                                        tp_mesh=self._tp_mesh)
 
-        def _chunk(state, k_pool, v_pool, tokens, true_len, start,
-                   bt_row):
+        def _chunk(state, *operands):
             # the chunk program IS the suffix-prefill program — the
             # chunk cursor rides the same offset writer — but with its
             # own jit identity so chunk compiles are counted (and
             # warmed) separately from prefix-hit suffix prefills
             self.chunk_traces += 1
-            return prefix_prefill_program(self.model, state, k_pool,
-                                          v_pool, tokens, true_len,
-                                          start, bt_row)
+            return prefix_prefill_program(self.model, state, *operands)
 
-        def _draft_prefill(state, k_pool, v_pool, tokens, true_len,
-                           bt_row):
+        def _draft_prefill(state, *operands):
             self.spec_traces += 1
-            return prefill_program(self.draft_model, state, k_pool,
-                                   v_pool, tokens, true_len, bt_row)
+            return prefill_program(self.draft_model, state, *operands)
 
-        def _draft_decode(state, k_pool, v_pool, toks, pos, bts):
+        def _draft_decode(state, *operands):
             self.spec_traces += 1
-            return decode_program(self.draft_model, state, k_pool,
-                                  v_pool, toks, pos, bts, mode=self.mode,
-                                  tp_mesh=None)
+            return decode_program(self.draft_model, state, *operands,
+                                  mode=self.mode, tp_mesh=None)
 
-        def _fork(k_pool, v_pool, src, dst):
+        def _fork(*pools_src_dst):
             self.fork_traces += 1
-            return copy_page(k_pool, v_pool, src, dst)
+            return copy_page(*pools_src_dst)
 
-        def _extract(k_pool, v_pool, nb):
+        def _extract(*pools_nb):
             self.transfer_traces += 1
-            return k_pool[:, :nb], v_pool[:, :nb]
+            return tuple(p[:, :pools_nb[-1]] for p in pools_nb[:-1])
 
-        def _insert(k_pool, v_pool, kb, vb, rows):
+        def _insert(*pools_blocks_rows):
             self.transfer_traces += 1
-            return (insert_pages(k_pool, kb, rows),
-                    insert_pages(v_pool, vb, rows))
+            pools = pools_blocks_rows[:n_pools]
+            blocks = pools_blocks_rows[n_pools:-1]
+            return tuple(insert_pages(p, b, pools_blocks_rows[-1])
+                         for p, b in zip(pools, blocks))
 
         self._prefill_fn = jax.jit(_prefill, donate_argnums=donate)
         self._prefix_prefill_fn = jax.jit(_prefix_prefill,
@@ -658,9 +572,28 @@ class ServingEngine:
                                          donate_argnums=donate)
         self._draft_decode_fn = jax.jit(_draft_decode,
                                         donate_argnums=donate)
-        self._fork_fn = jax.jit(_fork, donate_argnums=donate01)
-        self._extract_fn = jax.jit(_extract, static_argnums=2)
-        self._insert_fn = jax.jit(_insert, donate_argnums=donate01)
+        self._fork_fn = jax.jit(_fork, donate_argnums=donate0)
+        self._extract_fn = jax.jit(_extract, static_argnums=n_pools)
+        self._insert_fn = jax.jit(_insert, donate_argnums=donate0)
+
+    @staticmethod
+    def _held_state(model):
+        """The model's state as the engine holds it: its parameters in
+        the dtype the model declares (``serve_param_dtype``; ``None``
+        keeps them as loaded), cast in the model's own links one leaf at
+        a time so that each leaf's old array is freed as it goes."""
+        if model.serve_param_dtype is not None:
+            cast_params(model, model.serve_param_dtype)
+        return extract_state(model)
+
+    @staticmethod
+    def _run(fn, kv, state, *operands):
+        """One compiled program over ``kv``'s pools: the pools it
+        returns (donated, on an accelerator) are stored back, the rest
+        of its outputs returned."""
+        out = fn(state, *kv.pools, *operands)
+        kv.pools = list(out[:len(kv.pools)])
+        return out[len(kv.pools):]
 
     # -- ingress -------------------------------------------------------------
 
@@ -803,9 +736,8 @@ class ServingEngine:
     def _run_fork(self, src, dst):
         """Copy-on-write page copy, in-graph (traced indices: every
         fork reuses the one compiled program)."""
-        self.kv.k_pool, self.kv.v_pool = self._fork_fn(
-            self.kv.k_pool, self.kv.v_pool, jnp.int32(src),
-            jnp.int32(dst))
+        self.kv.pools = list(self._fork_fn(
+            *self.kv.pools, jnp.int32(src), jnp.int32(dst)))
         self.forks += 1
         if observability.ring_enabled():
             observability.instant("serve/fork",
@@ -823,12 +755,10 @@ class ServingEngine:
         Tb = _bucket(Ts, self.prefill_buckets, "suffix length")
         tokens = np.zeros((1, Tb), dtype=np.int32)
         tokens[0, :Ts] = req.prompt[matched:]
-        k_pool, v_pool, logits = self._prefix_prefill_fn(
-            self.state, self.kv.k_pool, self.kv.v_pool,
+        return self._run(
+            self._prefix_prefill_fn, self.kv, self.state,
             jnp.asarray(tokens), np.int32(Ts), np.int32(matched),
             jnp.asarray(self._bt_row(req.request_id)))
-        self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
-        return logits
 
     def _run_disagg_prefill(self, req, L):
         """Prefix MISS on the disagg split: the full flash prefill runs
@@ -840,13 +770,11 @@ class ServingEngine:
         Tb = _bucket(L, self.prefill_buckets, "prompt length")
         tokens = np.zeros((1, Tb), dtype=np.int32)
         tokens[0, :L] = req.prompt
-        k, v, logits = self._prefill_fn(
-            self._state_prefill, self._kv_prefill.k_pool,
-            self._kv_prefill.v_pool, jnp.asarray(tokens), np.int32(L),
-            self._scratch_bt)
-        self._kv_prefill.k_pool, self._kv_prefill.v_pool = k, v
+        out = self._run(self._prefill_fn, self._kv_prefill,
+                        self._state_prefill, jnp.asarray(tokens),
+                        np.int32(L), self._scratch_bt)
         self._ship_pages(req, L)
-        return logits
+        return out
 
     def _ship_pages(self, req, L):
         """Ship the first ``pages_for(L)`` scratch-pool pages into the
@@ -855,15 +783,13 @@ class ServingEngine:
         chunked prompt ships ONCE, after its last chunk)."""
         n_pages = self.allocator.pages_for(L)
         nb = _bucket(n_pages, self.transfer_buckets, "transfer pages")
-        kb, vb = self._extract_fn(self._kv_prefill.k_pool,
-                                  self._kv_prefill.v_pool, nb)
-        kb = jax.device_put(kb, self._block_placement)
-        vb = jax.device_put(vb, self._block_placement)
+        blocks = [jax.device_put(b, self._block_placement) for b in
+                  self._extract_fn(*self._kv_prefill.pools, nb)]
         rows = np.full(nb, self.kv.num_pages, dtype=np.int32)
         rows[:n_pages] = self.allocator.block_table(
             req.request_id)[:n_pages]
-        self.kv.k_pool, self.kv.v_pool = self._insert_fn(
-            self.kv.k_pool, self.kv.v_pool, kb, vb, jnp.asarray(rows))
+        self.kv.pools = list(self._insert_fn(
+            *self.kv.pools, *blocks, jnp.asarray(rows)))
         shipped = nb * self.kv.n_layers * self.kv.page_bytes
         self.transferred_page_bytes += shipped
         self.transfers += 1
@@ -983,28 +909,42 @@ class ServingEngine:
         req.requeue_time = None   # consumed: next eviction re-stamps
         if matched:
             with observability.span("serve/suffix_prefill", tags=tags,
-                                    tid=rtid):
-                logits = self._run_prefix_prefill(req, L, matched)
+                                    tid=rtid) as sp:
+                logits, *extras = self._run_prefix_prefill(req, L, matched)
                 self.prefix_hits += 1
                 self.prefix_tokens_matched += matched
                 self._complete_admission(req, logits, clock, prompt_t)
+                self._set_model_stats(sp, extras)
         elif self.disagg:
             if obs_on:
                 tags["disagg"] = True
-            with observability.span("serve/prefill", tags=tags, tid=rtid):
-                logits = self._run_disagg_prefill(req, L)
+            with observability.span("serve/prefill", tags=tags,
+                                    tid=rtid) as sp:
+                logits, *extras = self._run_disagg_prefill(req, L)
                 self._complete_admission(req, logits, clock, prompt_t)
+                self._set_model_stats(sp, extras)
         else:
-            with observability.span("serve/prefill", tags=tags, tid=rtid):
+            with observability.span("serve/prefill", tags=tags,
+                                    tid=rtid) as sp:
                 Tb = _bucket(L, self.prefill_buckets, "prompt length")
                 tokens = np.zeros((1, Tb), dtype=np.int32)
                 tokens[0, :L] = req.prompt
-                k_pool, v_pool, logits = self._prefill_fn(
-                    self.state, self.kv.k_pool, self.kv.v_pool,
+                logits, *extras = self._run(
+                    self._prefill_fn, self.kv, self.state,
                     jnp.asarray(tokens), np.int32(L),
                     jnp.asarray(self._bt_row(sid)))
-                self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
                 self._complete_admission(req, logits, clock, prompt_t)
+                self._set_model_stats(sp, extras)
+
+    def _set_model_stats(self, sp, extras):
+        """What the model counted inside a program (``extras``: device
+        values its ``serve_*`` returned beside the logits) as stats of
+        the span around it, through the model's own reading of them
+        (``serve_span_stats``).  Read only while a span records: with
+        tracing off the counts stay on the device, unfetched."""
+        if extras and observability.enabled():
+            sp.set(**self.model.serve_span_stats(
+                *(np.asarray(e) for e in extras)))
 
     def _complete_admission(self, req, logits, clock, prompt_t):
         """The bookkeeping shared by one-shot and LAST-chunk admission:
@@ -1036,11 +976,9 @@ class ServingEngine:
         Tb = _bucket(L, self._draft_prefill_buckets, "draft prompt")
         tokens = np.zeros((1, Tb), dtype=np.int32)
         tokens[0, :L] = req.prompt
-        k, v, _ = self._draft_prefill_fn(
-            self._draft_state, self._kv_draft.k_pool,
-            self._kv_draft.v_pool, jnp.asarray(tokens), np.int32(L),
-            jnp.asarray(self._bt_row(req.request_id)))
-        self._kv_draft.k_pool, self._kv_draft.v_pool = k, v
+        self._run(self._draft_prefill_fn, self._kv_draft,
+                  self._draft_state, jnp.asarray(tokens), np.int32(L),
+                  jnp.asarray(self._bt_row(req.request_id)))
         req._draft_ctx = L
 
     def _run_chunk(self, req, startp, size, final, clock):
@@ -1057,17 +995,15 @@ class ServingEngine:
         tokens[0, :size] = req.prompt[startp:startp + size]
         scratch = getattr(req, "_chunk_scratch", False)
         if scratch:
-            k, v, logits = self._chunk_fn(
-                self._state_prefill, self._kv_prefill.k_pool,
-                self._kv_prefill.v_pool, jnp.asarray(tokens),
-                np.int32(size), np.int32(startp), self._scratch_bt)
-            self._kv_prefill.k_pool, self._kv_prefill.v_pool = k, v
+            logits, *_ = self._run(
+                self._chunk_fn, self._kv_prefill, self._state_prefill,
+                jnp.asarray(tokens), np.int32(size), np.int32(startp),
+                self._scratch_bt)
         else:
-            k, v, logits = self._chunk_fn(
-                self.state, self.kv.k_pool, self.kv.v_pool,
+            logits, *_ = self._run(
+                self._chunk_fn, self.kv, self.state,
                 jnp.asarray(tokens), np.int32(size), np.int32(startp),
                 jnp.asarray(self._bt_row(sid)))
-            self.kv.k_pool, self.kv.v_pool = k, v
         self.chunk_prefills += 1
         req._chunk_pos = startp + size
         if final:
@@ -1180,11 +1116,9 @@ class ServingEngine:
                     else int(req.prompt[-1])
                 req._draft_ctx = req._ctx
         if any_gap:
-            k, v, _, _ = self._draft_decode_fn(
-                self._draft_state, self._kv_draft.k_pool,
-                self._kv_draft.v_pool, jnp.asarray(cu_tok),
-                jnp.asarray(cu_pos), bts_j)
-            self._kv_draft.k_pool, self._kv_draft.v_pool = k, v
+            self._run(self._draft_decode_fn, self._kv_draft,
+                      self._draft_state, jnp.asarray(cu_tok),
+                      jnp.asarray(cu_pos), bts_j)
             self.draft_dispatches += 1
         drafts = np.zeros((n, K), dtype=np.int32)
         cur = np.zeros(Bb, dtype=np.int32)
@@ -1199,11 +1133,9 @@ class ServingEngine:
                     live = True
             if not live:
                 break
-            k, v, _, nxt = self._draft_decode_fn(
-                self._draft_state, self._kv_draft.k_pool,
-                self._kv_draft.v_pool, jnp.asarray(cur),
-                jnp.asarray(pos), bts_j)
-            self._kv_draft.k_pool, self._kv_draft.v_pool = k, v
+            _, nxt, *_ = self._run(
+                self._draft_decode_fn, self._kv_draft, self._draft_state,
+                jnp.asarray(cur), jnp.asarray(pos), bts_j)
             self.draft_dispatches += 1
             nxt = np.asarray(nxt)
             keep = pos >= 0
@@ -1232,92 +1164,69 @@ class ServingEngine:
         decode grids — afterwards ``spec_traces``/``chunk_traces``
         stay frozen across joins, forks, evictions and accept-length
         swings (the round-20 retrace pin)."""
+        zero_row = jnp.zeros(self.n_block_entries, jnp.int32)
+
+        def idle(Bb):
+            return (jnp.zeros(Bb, jnp.int32), jnp.full(Bb, -1, jnp.int32),
+                    jnp.zeros((Bb, self.n_block_entries), jnp.int32))
+
         for Tb in self.prefill_buckets:
+            empty = (jnp.zeros((1, Tb), jnp.int32), np.int32(0))
             if self.disagg:
-                k, v, _ = self._prefill_fn(
-                    self._state_prefill, self._kv_prefill.k_pool,
-                    self._kv_prefill.v_pool,
-                    jnp.zeros((1, Tb), jnp.int32), np.int32(0),
-                    self._scratch_bt)
-                self._kv_prefill.k_pool, self._kv_prefill.v_pool = k, v
+                self._run(self._prefill_fn, self._kv_prefill,
+                          self._state_prefill, *empty, self._scratch_bt)
             else:
-                k_pool, v_pool, _ = self._prefill_fn(
-                    self.state, self.kv.k_pool, self.kv.v_pool,
-                    jnp.zeros((1, Tb), jnp.int32), np.int32(0),
-                    jnp.zeros(self.n_block_entries, jnp.int32))
-                self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
+                self._run(self._prefill_fn, self.kv, self.state, *empty,
+                          zero_row)
         if self.disagg:
             for nb in self.transfer_buckets:
-                kb, vb = self._extract_fn(self._kv_prefill.k_pool,
-                                          self._kv_prefill.v_pool, nb)
-                kb = jax.device_put(kb, self._block_placement)
-                vb = jax.device_put(vb, self._block_placement)
+                blocks = [jax.device_put(b, self._block_placement)
+                          for b in self._extract_fn(
+                              *self._kv_prefill.pools, nb)]
                 rows = jnp.full(nb, self.kv.num_pages, jnp.int32)
-                self.kv.k_pool, self.kv.v_pool = self._insert_fn(
-                    self.kv.k_pool, self.kv.v_pool, kb, vb, rows)
+                self.kv.pools = list(self._insert_fn(
+                    *self.kv.pools, *blocks, rows))
         if self.prefix_cache:
             for Tb in self.prefill_buckets:
-                k_pool, v_pool, _ = self._prefix_prefill_fn(
-                    self.state, self.kv.k_pool, self.kv.v_pool,
-                    jnp.zeros((1, Tb), jnp.int32), np.int32(0),
-                    np.int32(0),
-                    jnp.zeros(self.n_block_entries, jnp.int32))
-                self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
+                self._run(self._prefix_prefill_fn, self.kv, self.state,
+                          jnp.zeros((1, Tb), jnp.int32), np.int32(0),
+                          np.int32(0), zero_row)
             # the fork-copy program: src == dst == 0 is a self-copy
             # (contents unchanged); indices are traced, so this one
             # compile serves every fork
-            self.kv.k_pool, self.kv.v_pool = self._fork_fn(
-                self.kv.k_pool, self.kv.v_pool, jnp.int32(0),
-                jnp.int32(0))
+            self.kv.pools = list(self._fork_fn(
+                *self.kv.pools, jnp.int32(0), jnp.int32(0)))
         if self.chunk_tokens is not None:
             for Tb in self.prefill_buckets:
-                k_pool, v_pool, _ = self._chunk_fn(
-                    self.state, self.kv.k_pool, self.kv.v_pool,
-                    jnp.zeros((1, Tb), jnp.int32), np.int32(0),
-                    np.int32(0),
-                    jnp.zeros(self.n_block_entries, jnp.int32))
-                self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
+                empty = (jnp.zeros((1, Tb), jnp.int32), np.int32(0),
+                         np.int32(0))
+                self._run(self._chunk_fn, self.kv, self.state, *empty,
+                          zero_row)
                 if self.disagg:
                     # scratch-pool chunk shape (prefix-miss chunks run
                     # on the prefill slice): distinct pool dims mean a
                     # distinct compile — warm it too
-                    k, v, _ = self._chunk_fn(
-                        self._state_prefill, self._kv_prefill.k_pool,
-                        self._kv_prefill.v_pool,
-                        jnp.zeros((1, Tb), jnp.int32), np.int32(0),
-                        np.int32(0), self._scratch_bt)
-                    self._kv_prefill.k_pool = k
-                    self._kv_prefill.v_pool = v
+                    self._run(self._chunk_fn, self._kv_prefill,
+                              self._state_prefill, *empty,
+                              self._scratch_bt)
         for Bb in self.batch_buckets:
-            k_pool, v_pool, _, nxt = self._decode_fn(
-                self.state, self.kv.k_pool, self.kv.v_pool,
-                jnp.zeros(Bb, jnp.int32),
-                jnp.full(Bb, -1, jnp.int32),
-                jnp.zeros((Bb, self.n_block_entries), jnp.int32))
-            self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
+            _, nxt, *_ = self._run(self._decode_fn, self.kv, self.state,
+                                   *idle(Bb))
             if self.spec_k:
-                k_pool, v_pool, _, nxt = self._spec_verify_fn(
-                    self.state, self.kv.k_pool, self.kv.v_pool,
+                _, nxt = self._run(
+                    self._spec_verify_fn, self.kv, self.state,
                     jnp.zeros((Bb, self.spec_k + 1), jnp.int32),
-                    jnp.full(Bb, -1, jnp.int32),
-                    jnp.zeros(Bb, jnp.int32),
+                    jnp.full(Bb, -1, jnp.int32), jnp.zeros(Bb, jnp.int32),
                     jnp.zeros((Bb, self.n_block_entries), jnp.int32))
-                self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
         if self.draft_model is not None:
             for Tb in self._draft_prefill_buckets:
-                k, v, _ = self._draft_prefill_fn(
-                    self._draft_state, self._kv_draft.k_pool,
-                    self._kv_draft.v_pool,
-                    jnp.zeros((1, Tb), jnp.int32), np.int32(0),
-                    jnp.zeros(self.n_block_entries, jnp.int32))
-                self._kv_draft.k_pool, self._kv_draft.v_pool = k, v
+                self._run(self._draft_prefill_fn, self._kv_draft,
+                          self._draft_state, jnp.zeros((1, Tb), jnp.int32),
+                          np.int32(0), zero_row)
             for Bb in self.batch_buckets:
-                k, v, _, nxt = self._draft_decode_fn(
-                    self._draft_state, self._kv_draft.k_pool,
-                    self._kv_draft.v_pool, jnp.zeros(Bb, jnp.int32),
-                    jnp.full(Bb, -1, jnp.int32),
-                    jnp.zeros((Bb, self.n_block_entries), jnp.int32))
-                self._kv_draft.k_pool, self._kv_draft.v_pool = k, v
+                _, nxt, *_ = self._run(self._draft_decode_fn,
+                                       self._kv_draft, self._draft_state,
+                                       *idle(Bb))
         np.asarray(nxt)  # sync: compiles really happened
 
     # -- the step loop -------------------------------------------------------
@@ -1415,7 +1324,7 @@ class ServingEngine:
         with observability.span(
                 "serve/decode_window",
                 tags={"batch": n, "bucket": Bb, "step": self.decode_steps}
-                if obs_on else None):
+                if obs_on else None) as window:
             with observability.span("serve/decode_build"):
                 toks = np.zeros(Bb, dtype=np.int32)
                 pos = np.full(Bb, -1, dtype=np.int32)
@@ -1428,13 +1337,14 @@ class ServingEngine:
                 operands = (jnp.asarray(toks), jnp.asarray(pos),
                             jnp.asarray(bts))
             with observability.span("serve/decode_dispatch"):
-                k_pool, v_pool, _logits, nxt = self._decode_fn(
-                    self.state, self.kv.k_pool, self.kv.v_pool,
-                    *operands)
-            self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
+                _logits, nxt, *extras = self._run(
+                    self._decode_fn, self.kv, self.state, *operands)
             with observability.span("serve/decode_fetch"):
                 nxt = np.asarray(nxt)   # device->host sync: the decode
-            self.decode_steps += 1      # window span times the real step
+                # window span times the real step; what the model
+                # counted comes in the same fetch, while a span records
+                self._set_model_stats(window, extras)
+            self.decode_steps += 1
         with observability.span("serve/record"):
             t_tok = clock()
             for j, req in enumerate(list(self.running)):
@@ -1489,11 +1399,10 @@ class ServingEngine:
                 start[j] = req._ctx
                 nvb[j] = nv[j]
                 bts[j] = self._bt_row(req.request_id)
-            k_pool, v_pool, _logits, g = self._spec_verify_fn(
-                self.state, self.kv.k_pool, self.kv.v_pool,
+            _logits, g = self._run(
+                self._spec_verify_fn, self.kv, self.state,
                 jnp.asarray(toks), jnp.asarray(start), jnp.asarray(nvb),
                 jnp.asarray(bts))
-            self.kv.k_pool, self.kv.v_pool = k_pool, v_pool
             with observability.span("serve/decode_fetch"):
                 g = np.asarray(g)       # device->host sync
             self.decode_steps += 1  # ONE dispatch for up to K+1 tokens

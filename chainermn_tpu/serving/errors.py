@@ -27,7 +27,7 @@ host-channel catch-point.
 from __future__ import annotations
 
 __all__ = ["ServingError", "PagePoolExhaustedError", "QueueSaturatedError",
-           "EvictionStalledError"]
+           "EvictionStalledError", "UnsupportedProgramError"]
 
 
 class ServingError(RuntimeError):
@@ -80,3 +80,23 @@ class EvictionStalledError(ServingError):
             f"eviction stalled: none of the {self.n_running} running "
             "sequence(s) owns a uniquely-held page — evicting any of "
             "them would free nothing (all pages shared)")
+
+
+
+class UnsupportedProgramError(ServingError):
+    """The engine was asked for a program the model does not provide.
+
+    A served model owns its block: the engine calls the model's
+    ``serve_<program>``.  Prefill, suffix prefill and decode every model
+    has; the speculative ``verify`` and the ``pool_sharding`` of
+    tensor-parallel decode are a model's to offer.  Asking for one that
+    is not there is refused here, at construction, never answered by
+    another program in its place.  Carries the model's class name and
+    the program."""
+
+    def __init__(self, model, program):
+        self.model = str(model)
+        self.program = str(program)
+        super().__init__(
+            f"{self.model} has no serve_{self.program}: the engine "
+            f"cannot run its {self.program!r} program for this model")

@@ -1,24 +1,31 @@
-"""Paged KV cache: preallocated device pools + in-graph page writes.
+"""Paged cache: preallocated device pools + in-graph page writes.
 
-The cache is two arrays per engine — ``k_pool``/``v_pool`` shaped
-``[L, P, S, H, D]`` (layers × pages × page slots × heads × head dim) —
-allocated ONCE at engine construction and only ever updated functionally
-inside the compiled prefill/decode programs (donated on real
-accelerators, so XLA writes pages in place).  Pages are bf16 by default:
-the decode step is HBM-bandwidth-bound on cache reads (PR 3's byte
-roofline applied to serving), so halving the stored byte per element is
-the single biggest lever — the dtype is pinned at construction and every
-write casts through it.
+What a token leaves in the cache, per layer, is the MODEL's to declare
+(``serve_cache_entry()``): a tuple of per-token shapes, one pool array
+``[L, P, S, *shape]`` (layers × pages × page slots × the entry) for each.
+A GPT-2-shaped model declares K and V of ``[H, D]`` — two pools, named
+``k_pool``/``v_pool`` here; a latent-attention model declares one vector
+(``[kv_rank + rope_dim]``) — one pool.  The pools are allocated ONCE at
+engine construction and only ever updated functionally inside the
+compiled prefill/decode programs (donated on real accelerators, so XLA
+writes pages in place).  Pages are bf16 by default: the decode step is
+HBM-bandwidth-bound on cache reads (PR 3's byte roofline applied to
+serving), so halving the stored byte per element is the single biggest
+lever — the dtype is pinned at construction and every write casts
+through it.
 
 Token ``t`` of a sequence lives at ``(page=block_table[t // S],
-slot=t % S)``.  Both writers below map positions to ``(page, slot)``
+slot=t % S)``.  The writers below map positions to ``(page, slot)``
 pairs in-graph and scatter with ``mode="drop"``: a lane that must not
 write (idle decode slot, prompt padding) is routed to the
 out-of-range page id ``P`` and dropped by XLA — no host-side masking,
-no host-side copies, one scatter per pool per layer.
+no host-side copies, one scatter per pool per layer.  They address the
+two leading axes of a layer's pool only, so they serve any entry shape.
 """
 
 from __future__ import annotations
+
+import math
 
 import jax.numpy as jnp
 
@@ -26,23 +33,42 @@ __all__ = ["PagedKVCache", "write_prompt_kv", "write_prompt_kv_at",
            "write_token_kv", "write_span_kv", "copy_page", "insert_pages"]
 
 
-def write_prompt_kv(pool_l, kv, block_table_row, true_len):
-    """Write a whole prompt's K or V into one layer's pool.
+def _geometry(pool, layer):
+    """``(P, S)`` of a layer's pool, or of the whole ``[L, P, S, ...]``
+    pool addressed at ``layer``."""
+    lead = 0 if layer is None else 1
+    return pool.shape[lead], pool.shape[lead + 1]
 
-    ``pool_l``: ``[P, S, H, D]``.  ``kv``: ``[T, H, D]`` (position-major,
+
+def _scatter(pool, layer, pages, slots, kv):
+    """The one drop-fenced scatter of every writer.  ``layer=None``:
+    ``pool`` is one layer's ``[P, S, ...]``.  With a (static) ``layer``,
+    ``pool`` is the whole ``[L, P, S, ...]`` array and the entries land
+    in that layer of it directly — no layer slice is taken out and put
+    back, so a donated pool is updated in place by the scatter alone."""
+    at = pool.at[pages, slots] if layer is None \
+        else pool.at[layer, pages, slots]
+    return at.set(kv.astype(pool.dtype), mode="drop")
+
+
+def write_prompt_kv(pool_l, kv, block_table_row, true_len, layer=None):
+    """Write a whole prompt's entries into one layer's pool.
+
+    ``pool_l``: ``[P, S, *entry]``.  ``kv``: ``[T, *entry]`` (position-major,
     possibly padded past ``true_len``).  ``block_table_row``: ``[N]``
     page ids covering at least ``true_len`` positions.  Positions
     ``>= true_len`` scatter to the out-of-range page and are dropped.
+    ``layer``: see :func:`_scatter` (the prompt and token writers take it).
     """
-    P, S = pool_l.shape[0], pool_l.shape[1]
+    P, S = _geometry(pool_l, layer)
     T = kv.shape[0]
     t = jnp.arange(T, dtype=jnp.int32)
     pages = jnp.where(t < true_len, block_table_row[t // S], P)
-    return pool_l.at[pages, t % S].set(kv.astype(pool_l.dtype),
-                                       mode="drop")
+    return _scatter(pool_l, layer, pages, t % S, kv)
 
 
-def write_prompt_kv_at(pool_l, kv, block_table_row, start, true_len):
+def write_prompt_kv_at(pool_l, kv, block_table_row, start, true_len,
+                       layer=None):
     """Offset prompt writer for the prefix-sharing suffix prefill.
 
     ``kv``: ``[T, H, D]`` SUFFIX K/V — position ``t`` of the suffix
@@ -51,48 +77,46 @@ def write_prompt_kv_at(pool_l, kv, block_table_row, start, true_len):
     Positions ``>= true_len`` (suffix padding) drop.  ``start = 0``
     degenerates to :func:`write_prompt_kv`.
     """
-    P, S = pool_l.shape[0], pool_l.shape[1]
+    P, S = _geometry(pool_l, layer)
     T = kv.shape[0]
     t = jnp.arange(T, dtype=jnp.int32)
     posn = start + t
     pages = jnp.where(t < true_len, block_table_row[posn // S], P)
-    return pool_l.at[pages, posn % S].set(kv.astype(pool_l.dtype),
-                                          mode="drop")
+    return _scatter(pool_l, layer, pages, posn % S, kv)
 
 
-def copy_page(k_pool, v_pool, src, dst):
-    """Fork-on-write: duplicate page ``src`` into page ``dst`` across
-    every layer of BOTH pools — the copy-on-write half of the round-14
-    prefix sharing, run in-graph through the same scatter machinery as
-    the writers (``mode="drop"`` fencing intact).  ``src``/``dst`` are
-    TRACED scalars, so one compiled program serves every fork (the
-    never-retrace contract covers forks)."""
-    k_pool = k_pool.at[:, dst].set(k_pool[:, src], mode="drop")
-    v_pool = v_pool.at[:, dst].set(v_pool[:, src], mode="drop")
-    return k_pool, v_pool
+def copy_page(*pools_src_dst):
+    """``copy_page(*pools, src, dst)`` — fork-on-write: duplicate page
+    ``src`` into page ``dst`` across every layer of EVERY pool — the
+    copy-on-write half of the round-14 prefix sharing, run in-graph
+    through the same scatter machinery as the writers (``mode="drop"``
+    fencing intact).  ``src``/``dst`` are TRACED scalars, so one
+    compiled program serves every fork (the never-retrace contract
+    covers forks).  Returns the pools, as a tuple."""
+    *pools, src, dst = pools_src_dst
+    return tuple(p.at[:, dst].set(p[:, src], mode="drop") for p in pools)
 
 
 def insert_pages(pool, block, rows):
     """Disaggregation ship receiver: scatter a transferred page block
-    ``[L, nb, S, H, D]`` (the prefill slice's finished pages) into the
+    ``[L, nb, S, *entry]`` (the prefill slice's finished pages) into the
     decode pool at page ids ``rows`` (``[nb]`` int32; padding rows carry
     the out-of-range id ``P`` and drop)."""
     return pool.at[:, rows].set(block.astype(pool.dtype), mode="drop")
 
 
-def write_token_kv(pool_l, kv, block_tables, pos):
+def write_token_kv(pool_l, kv, block_tables, pos, layer=None):
     """Write one decode token per batch lane into one layer's pool.
 
     ``kv``: ``[B, H, D]``.  ``pos``: ``[B]`` int32 position being
     written; ``pos < 0`` marks an idle lane (dropped).  ``block_tables``:
     ``[B, N]``.
     """
-    P, S = pool_l.shape[0], pool_l.shape[1]
+    P, S = _geometry(pool_l, layer)
     b = jnp.arange(pos.shape[0])
     safe = jnp.maximum(pos, 0)
     pages = jnp.where(pos >= 0, block_tables[b, safe // S], P)
-    return pool_l.at[pages, safe % S].set(kv.astype(pool_l.dtype),
-                                          mode="drop")
+    return _scatter(pool_l, layer, pages, safe % S, kv)
 
 
 def write_span_kv(pool_l, kv, block_tables, start, n_valid):
@@ -119,34 +143,51 @@ def write_span_kv(pool_l, kv, block_tables, start, n_valid):
     live = (start[:, None] >= 0) & (j < n_valid[:, None])
     safe = jnp.maximum(posn, 0)
     pages = jnp.where(live, block_tables[b, safe // S], P)
-    return pool_l.at[pages, safe % S].set(kv.astype(pool_l.dtype),
-                                          mode="drop")
+    return _scatter(pool_l, None, pages, safe % S, kv)
 
 
 class PagedKVCache:
-    """The engine-owned pool pair.  Construction allocates the full
-    ``[L, P, S, H, D]`` arrays (zeros); the engine threads them through
-    its jit programs and stores back the returned (donated) arrays."""
+    """The engine-owned pools.  ``entry`` is what the model declares a
+    token leaves in a layer (a tuple of per-token shapes); construction
+    allocates one ``[L, P, S, *shape]`` array of zeros for each, in
+    ``pools``.  The engine threads them through its jit programs and
+    stores back the returned (donated) arrays."""
 
-    def __init__(self, n_layers, num_pages, page_size, n_heads, d_head,
+    def __init__(self, n_layers, num_pages, page_size, entry,
                  dtype=jnp.bfloat16):
         self.n_layers = int(n_layers)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
-        self.n_heads = int(n_heads)
-        self.d_head = int(d_head)
+        self.entry = tuple(tuple(int(d) for d in shape) for shape in entry)
         self.dtype = jnp.dtype(dtype)
-        shape = (self.n_layers, self.num_pages, self.page_size,
-                 self.n_heads, self.d_head)
-        self.k_pool = jnp.zeros(shape, self.dtype)
-        self.v_pool = jnp.zeros(shape, self.dtype)
+        self.pools = [
+            jnp.zeros((self.n_layers, self.num_pages, self.page_size)
+                      + shape, self.dtype) for shape in self.entry]
+
+    # the names of a two-array (K, V) entry's pools
+    @property
+    def k_pool(self):
+        return self.pools[0]
+
+    @k_pool.setter
+    def k_pool(self, value):
+        self.pools[0] = value
+
+    @property
+    def v_pool(self):
+        return self.pools[1]
+
+    @v_pool.setter
+    def v_pool(self, value):
+        self.pools[1] = value
 
     @property
     def page_bytes(self):
-        """Bytes one page holds across K+V (the roofline accounting in
-        docs/serving.md prices decode reads with this)."""
-        return (2 * self.page_size * self.n_heads * self.d_head
-                * self.dtype.itemsize)
+        """Bytes one page holds in one layer, over every array of the
+        declared entry (the roofline accounting in docs/serving.md
+        prices decode reads with this)."""
+        return (sum(math.prod(shape) for shape in self.entry)
+                * self.page_size * self.dtype.itemsize)
 
     @property
     def pool_bytes(self):

@@ -301,11 +301,10 @@ def _paged_logits(model, cfg, prompt, forced):
     from chainermn_tpu.core.link import extract_state
     from chainermn_tpu.serving import (BlockAllocator, PagedKVCache,
                                        decode_program, prefill_program)
-    blk = model.blocks[0].attn
     S = cfg["page_size"]
     n_entries = cfg["max_context"] // S
-    kv = PagedKVCache(len(list(model.blocks)), 2 * n_entries, S,
-                      blk.n_heads, blk.d_head, dtype=model.compute_dtype)
+    kv = PagedKVCache(model.serve_cache_layers, 2 * n_entries, S,
+                      model.serve_cache_entry(), dtype=model.compute_dtype)
     alloc = BlockAllocator(2 * n_entries, S)
     state = extract_state(model)
     prefill = jax.jit(functools.partial(prefill_program, model))

@@ -43,7 +43,8 @@ class Harness:
         self.state = extract_state(model)
         blk = model.blocks[0].attn
         self.kv = PagedKVCache(len(list(model.blocks)), num_pages,
-                               page_size, blk.n_heads, blk.d_head,
+                               page_size,
+                               ((blk.n_heads, blk.d_head),) * 2,
                                dtype=dtype)
         self.alloc = BlockAllocator(num_pages, page_size)
         self.N = max_context // page_size

@@ -138,7 +138,7 @@ def test_suffix_prefill_logits_match_oneshot():
     pa = base                            # provider: partial tail at 20
     pb = np.concatenate([base, rng.randint(0, VOCAB, 9).astype(np.int32)])
     blk = model.blocks[0].attn
-    kv = PagedKVCache(2, 64, 8, blk.n_heads, blk.d_head,
+    kv = PagedKVCache(2, 64, 8, model.serve_cache_entry(),
                       dtype=jnp.float32)
     alloc = BlockAllocator(64, 8)
     N = 64 // 8
@@ -346,7 +346,7 @@ def test_tp_decode_matches_single_chip():
     state = extract_state(model)
     blk = model.blocks[0].attn
     rng = np.random.RandomState(9)
-    kv = PagedKVCache(2, 16, 8, blk.n_heads, blk.d_head,
+    kv = PagedKVCache(2, 16, 8, model.serve_cache_entry(),
                       dtype=jnp.float32)
     prompt = rng.randint(0, VOCAB, 11).astype(np.int32)
     toks = np.zeros((1, 16), np.int32)

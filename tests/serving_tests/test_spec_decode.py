@@ -234,8 +234,8 @@ def test_chunk_boundary_logits_match_oneshot():
     model = _model()
     state = extract_state(model)
     blk = model.blocks[0].attn
-    kv = PagedKVCache(len(list(model.blocks)), 64, 8, blk.n_heads,
-                      blk.d_head, dtype=jnp.float32)
+    kv = PagedKVCache(len(list(model.blocks)), 64, 8,
+                      model.serve_cache_entry(), dtype=jnp.float32)
     alloc = BlockAllocator(64, 8)
     L, chunk = 37, 16
     full = np.random.RandomState(8).randint(0, VOCAB, L).astype(np.int32)

@@ -181,3 +181,63 @@ def test_spec_verify_program_compiles_with_pools_donated(
         spec((MAX_BATCH, MAX_CONTEXT // PAGE), jnp.int32),
         donate_argnums=(1, 2))
     _assert_pools_aliased(compiled, pool_bytes)
+
+
+# -- the latent-attention MoE share (kimi-k2.6-share), published widths ------
+
+@pytest.fixture(scope="module")
+def latent(one_chip):
+    """The configuration's builder at the published widths, cut to the
+    dense layer and ONE expert layer (a compile of the alike layers says
+    nothing more), its bfloat16 state as shapes on the described chip,
+    and the cell's latent pool."""
+    from benchmark import harness
+    config = harness.load_json(os.path.join(
+        harness.HERE, "configs", "kimi-k2.6-share.json"))
+    config["num_hidden_layers"] = 2
+    model = harness.load_module("models", "latent_moe_lm").build(
+        config, max_len=4608)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    state = {"params": {path: spec(p.shape, jnp.bfloat16)
+                        for path, p in model.namedparams()}, "state": {}}
+    pool = spec((2, 18432, 16) + model.serve_cache_entry()[0], jnp.bfloat16)
+    return model, state, pool, spec
+
+
+def _assert_pool_in_place(compiled, text, pool):
+    """Donated, aliased, and never copied whole: the pool's STORED layout
+    (the runtime's choice for its shape) is the one the program computes
+    in.  A latent of 576 values, 4.5 lane tiles, is stored page-axis
+    minor-most and costs two whole-pool copies a call."""
+    nbytes = 2 * 18432 * 16 * 640 * 2
+    assert pool.shape[-1] == 640
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+    assert "bf16[2,18432,16,640]{3,2,1,0" in text
+    assert "bf16[2,18432,16,640]{1,3,2,0" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < nbytes
+
+
+def test_latent_decode_program_updates_its_pool_in_place(
+        latent, no_persistent_cache):
+    from chainermn_tpu.serving import decode_program
+    model, state, pool, spec = latent
+    compiled, text = _compile(
+        functools.partial(decode_program, model, mode=None), state, pool,
+        spec((64,), jnp.int32), spec((64,), jnp.int32),
+        spec((64, 288), jnp.int32), donate_argnums=(1,))
+    _assert_pool_in_place(compiled, text, pool)
+
+
+def test_latent_prefill_program_compiles_with_the_flash_kernel(
+        latent, no_persistent_cache, monkeypatch):
+    from chainermn_tpu.serving import prefill_program
+    model, state, pool, spec = latent
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    compiled, text = _compile(
+        functools.partial(prefill_program, model), state, pool,
+        spec((1, 4096), jnp.int32), spec((), jnp.int32),
+        spec((288,), jnp.int32), donate_argnums=(1,))
+    assert "_flash_kernel" in text and text.count("tpu_custom_call") == 2
+    _assert_pool_in_place(compiled, text, pool)
