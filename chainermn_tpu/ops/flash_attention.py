@@ -13,14 +13,21 @@ pin kernel↔reference equivalence).
 
 The backward is a FUSED one-pass kernel by default
 (:func:`_flash_bwd_fused_kernel`): each (qi, ki) attention tile is
-recomputed once — s = q·kᵀ, mask, p = exp(s − lse) — and feeds all
-three gradients (dk/dv accumulate in VMEM across the query loop, dq
-leaves as per-key-block partial planes reduced by one XLA sum).  The
-legacy two-kernel lowering (one dq pass + one dkv pass, each
+recomputed once — s = q·kᵀ, p = exp(s − lse) — and feeds all three
+gradients (dk/dv accumulate across the query loop, dq on the chip
+across the key tiles; each leaves the kernel once).
+The legacy two-kernel lowering (one dq pass + one dkv pass, each
 recomputing the tile) stays available bit-for-bit behind
-``CHAINERMN_TPU_FLASH_BWD=split``.  Backward tiles are tuned
-independently of the forward's (``CHAINERMN_TPU_FLASH_BWD_BLOCK_Q/K``,
-sweep-driven per-T table — `make sweep-flash`).
+``CHAINERMN_TPU_FLASH_BWD=split``.
+
+Causal calls walk the lower triangle only: a kernel skips the tiles
+wholly above the diagonal, runs an unmasked body on the tiles wholly at
+or below it and a masked body on the tiles it crosses
+(:func:`_causal_k_tiles` / :func:`_causal_q_tiles`).  So tiles under the
+sequence length are what makes a causal call cheap; the log-sum-exp
+forward and the backward resolve theirs from a chip sweep keyed on what
+the call shows (T, D, causal: :data:`_CAUSAL_BLOCK_TABLE`), each with
+its own env knobs for the next sweep (`make sweep-flash`).
 
 Ring-attention composition: ``parallel.ring_attention`` rotates KV blocks
 between chips; within a chip this kernel computes each block's
@@ -31,6 +38,7 @@ inner.
 from __future__ import annotations
 
 import functools
+import math
 import os
 import warnings
 
@@ -48,6 +56,12 @@ __all__ = ["attention", "flash_attention", "xla_attention"]
 # outputs in VMEM (observed OOM on v5e at T=8192 with the default).
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel"),
+    vmem_limit_bytes=100 * 1024 * 1024)
+# The fused backward keeps its dq accumulator resident across the
+# key-block axis, so that axis runs in order on one core ("arbitrary").
+# v5e has one TensorCore a chip: the head axis carries the parallelism.
+_BWD_FUSED_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "arbitrary"),
     vmem_limit_bytes=100 * 1024 * 1024)
 
 
@@ -73,58 +87,166 @@ def xla_attention(q, k, v, causal=False, scale=None):
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k,
-                      causal, scale):
+def _imin(a, b):
+    """min for tile indices: Python ints in the tile-walk helper, traced
+    int32 scalars inside the kernels."""
+    return min(a, b) if isinstance(a, int) else jnp.minimum(a, b)
+
+
+def _causal_k_tiles(qi, block_q, block_k, n_kblocks):
+    """Key tiles of query tile ``qi`` at a causal call (row i sees keys
+    0..i): ``[0, full)`` lie wholly at or below the diagonal and need no
+    mask, ``[full, last)`` are crossed by it, ``[last, n_kblocks)`` lie
+    wholly above it and are never computed."""
+    full = _imin((qi * block_q + 1) // block_k, n_kblocks)
+    last = _imin((qi * block_q + block_q + block_k - 1) // block_k,
+                 n_kblocks)
+    return full, last
+
+
+def _causal_q_tiles(ki, block_q, block_k, n_qblocks):
+    """The same walk seen from key tile ``ki`` (the backward's loop):
+    query tiles ``[0, first)`` lie wholly above the diagonal and are
+    never computed, ``[first, full)`` are crossed by it,
+    ``[full, n_qblocks)`` lie wholly at or below it and need no mask."""
+    first = _imin((ki * block_k) // block_q, n_qblocks)
+    full = _imin(((ki + 1) * block_k + block_q - 2) // block_q, n_qblocks)
+    return first, full
+
+
+def _causal_tile_walk(tq, tk, block_q, block_k):
+    """``[(qi, ki, masked)]``: the tiles a causal call computes with
+    these blocks, from the forward's bounds.  Pure Python: the tests
+    hold it against a brute-force mask and against the backward's
+    bounds, and count the share of the square the committed tiles cost."""
+    walk = []
+    for qi in range(tq // block_q):
+        full, last = _causal_k_tiles(qi, block_q, block_k, tk // block_k)
+        walk += [(qi, ki, ki >= full) for ki in range(last)]
+    return walk
+
+
+def _scale_folds(scale):
+    """Whether ``scale`` is a power of two (1/sqrt(D) at D = 16, 64,
+    256): multiplying a bfloat16 or float32 operand by it is then exact,
+    so it goes into a ``[block, D]`` operand once instead of into every
+    ``[block_q, block_k]`` score tile."""
+    return math.frexp(scale)[0] == 0.5
+
+
+def _fold_scale(x, scale):
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+#: A head whose whole tile walk covers at most this many scores (the
+#: square of T = 1024) is walked by ONE program, its loops unrolled at
+#: trace time: straight-line code lets the scheduler run one tile's MXU
+#: work under another's softmax, each query tile's first key tile needs
+#: no rescaling, and there is one grid step a head.  At the cell's shape
+#: that alone took the forward from 0.32 to 0.20 ms a call before any
+#: tile was skipped (chip sweep, PR 28: tools/flash_budgets.json).  A
+#: longer walk keeps one tile row a program and `fori_loop`s: unrolled
+#: it would be megabytes of code.
+_STATIC_WALK_ELEMS = 1024 * 1024
+
+
+def _static_walk(tq, tk, block_q, block_k, causal):
+    tiles = len(_causal_tile_walk(tq, tk, block_q, block_k)) if causal \
+        else (tq // block_q) * (tk // block_k)
+    return tiles * block_q * block_k <= _STATIC_WALK_ELEMS
+
+
+def _loop(lo, hi, body, carry, unroll):
+    """``fori_loop``, or (a static walk, whose bounds are Python ints)
+    the same loop unrolled at trace time."""
+    if unroll:
+        for i in range(lo, hi):
+            carry = body(i, carry)
+        return carry
+    return jax.lax.fori_loop(lo, hi, body, carry)
+
+
+def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
+                      block_k, causal, scale, static_walk):
     """Forward kernel variant that also writes the log-sum-exp row
-    statistics (softmax normalizer) needed by the backward kernels."""
-    bq, d = q_ref.shape
-    tk = k_ref.shape[0]
-    qi = pl.program_id(1)
+    statistics (softmax normalizer) needed by the backward kernels.
 
-    # dtype discipline: blocks go into the dots in their STORAGE dtype
-    # (bf16 rides the MXU's native path; an f32 upcast would force the
-    # 3-pass f32 matmul emulation) with fp32 accumulators via
-    # preferred_element_type; the online-softmax state stays fp32.
-    q = q_ref[:]
-    m = jnp.full((bq, 1), -jnp.inf, jnp.float32)
-    l = jnp.zeros((bq, 1), jnp.float32)
-    acc = jnp.zeros((bq, d), jnp.float32)
-    n_kblocks = tk // block_k
-    q_pos = (qi * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0))
+    A program holds one query tile, or every query tile of its head
+    where the walk is static (:data:`_STATIC_WALK_ELEMS`).  Two loop
+    bodies over a query tile's key tiles (:func:`_causal_k_tiles`):
+    unmasked for the tiles wholly at or below the diagonal (every tile
+    of a non-causal call), masked for those it crosses.  Neither guards
+    against an empty row with ``isfinite``: this kernel numbers queries
+    and keys from 0, so every row sees key 0, key tile 0 is the first
+    one walked (in whichever body), and the running max is finite from
+    there on; exp(-inf) = 0 does the rest.  A non-causal call (ring
+    blocks, ``Tq != Tk``) masks nothing at all.  `_flash_kernel` and the
+    split backward keep their guards."""
+    d = q_ref.shape[-1]
+    n_kblocks = k_ref.shape[0] // block_k
+    fold = _scale_folds(scale)
 
-    def body(ki, carry):
-        m, l, acc = carry
-        k_blk = k_ref[pl.ds(ki * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+    def q_tile(qi, rows):
+        # dtype discipline: blocks go into the dots in their STORAGE
+        # dtype (bf16 rides the MXU's native path; an f32 upcast would
+        # force the 3-pass f32 matmul emulation) with fp32 accumulators
+        # via preferred_element_type; the online-softmax state stays fp32
+        q = _fold_scale(q_ref[rows, :], scale) if fold else q_ref[rows, :]
+        q_pos = (qi * block_q
+                 + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
+
+        def tile(ki, carry, masked):
+            cols = pl.ds(ki * block_k, block_k)
+            s = jax.lax.dot_general(q, k_ref[cols, :],
+                                    (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if not fold:
+                s = s * scale
+            if masked:
+                k_pos = (ki * block_k
+                         + lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
+                s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
+            m_new = jnp.max(s, axis=-1, keepdims=True)
+            if carry is not None:
+                m, l, acc = carry
+                m_new = jnp.maximum(m, m_new)
+            p = jnp.exp(s - m_new)
+            l_new = jnp.sum(p, axis=-1, keepdims=True)
+            acc_new = jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[cols, :],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            if carry is not None:
+                corr = jnp.exp(m - m_new)
+                l_new = l * corr + l_new
+                acc_new = acc * corr + acc_new
+            return m_new, l_new, acc_new
+
         if causal:
-            k_pos = (ki * block_k
-                     + lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        m_blk = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, m_blk)
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - m_safe), 0.0)
-        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
-        l_new = l * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc * corr + jax.lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+            full, last = _causal_k_tiles(qi, block_q, block_k, n_kblocks)
+        else:
+            full = last = n_kblocks
+        # a static walk knows its first tile and starts from it; a loop
+        # starts from the empty state, which exp(-inf) = 0 rescales away
+        carry = None if static_walk else (
+            jnp.full((block_q, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((block_q, 1), jnp.float32),
+            jnp.zeros((block_q, d), jnp.float32))
+        carry = _loop(0, full, functools.partial(tile, masked=False), carry,
+                      static_walk)
+        if causal:
+            carry = _loop(full, last, functools.partial(tile, masked=True),
+                          carry, static_walk)
+        m, l, acc = carry
+        o_ref[rows, :] = (acc / l).astype(o_ref.dtype)
+        # lse is [rows, 1]: Mosaic requires the block's trailing dims to
+        # divide (8, 128) or equal the array dims — a trailing singleton
+        # qualifies, a squeezed 1-D block does not
+        lse_ref[rows, :] = m + jnp.log(l)
 
-    if causal:
-        last = jnp.minimum((qi * bq + bq + block_k - 1) // block_k,
-                           n_kblocks)
-    else:
-        last = n_kblocks
-    m, l, acc = jax.lax.fori_loop(0, last, body, (m, l, acc))
-    o_ref[:] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    m_safe = jnp.where(jnp.isfinite(m), m, 0.0)
-    # lse is [bq, 1]: Mosaic requires the block's trailing dims to divide
-    # (8, 128) or equal the array dims — a trailing singleton qualifies,
-    # a squeezed 1-D block does not
-    lse_ref[:] = m_safe + jnp.log(jnp.maximum(l, 1e-30))
+    for j in range(q_ref.shape[0] // block_q):
+        q_tile(j if static_walk else pl.program_id(1),
+               pl.ds(j * block_q, block_q))
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
@@ -221,9 +343,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                            dq_part_ref, dk_ref, dv_ref, *, block_q,
-                            causal, scale):
-    """Fused backward: ONE pass over the (qi, ki) tiles per key block.
+                            dq_ref, dk_ref, dv_ref, *dq_acc_ref,
+                            block_q, block_k, causal, scale, static_walk):
+    """Fused backward: ONE pass over the (qi, ki) tiles per key tile.
 
     The split lowering (`_flash_bwd_dq_kernel` + `_flash_bwd_dkv_kernel`)
     recomputes the attention block twice: each kernel re-runs the
@@ -231,76 +353,122 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     it touches.  Here each (qi, ki) tile is recomputed ONCE and all three
     gradient contributions leave together:
 
-        dv  += pᵀ g                      (accumulated in VMEM over qi)
-        dk  += dsᵀ q                     (accumulated in VMEM over qi)
-        dq_part[qi] = ds·k               (per-key-block partial plane)
+        dv  += pᵀ g           (carried over this key tile's query loop)
+        dk  += dsᵀ q          (carried over this key tile's query loop)
+        dq[qi] += ds·k        (float32, on the chip, across the key tiles)
 
-    dq cannot be accumulated in-place across key blocks — the grid is
-    parallel over ki and Mosaic offers no cross-program accumulation —
-    so each program writes its [Tq, D] dq contribution to its own slot
-    of a [n_kblocks, Tq, D] partial array; the caller reduces it with
-    one XLA sum (the splash-attention fused-backward shape; the reduce
-    is HBM-bound but a rounding error next to the recomputed dots it
-    replaces).  Per tile pair the split lowering runs 8 MXU dots + 2
-    exp's; this runs 5 dots + 1 exp — the recompute-once argument in
-    docs/performance.md quantifies it.
+    A program holds one key tile, or every key tile of its head where
+    the walk is static (:data:`_STATIC_WALK_ELEMS`); dq then adds up in
+    values, one a query tile (through a VMEM scratch the same walk read
+    0.03 ms a call slower at the cell's shape: my chip runs, PR 28).
+    Otherwise the grid is
+    ``(batch·heads, key tiles)`` with the key-tile axis ``arbitrary``:
+    the programs of one head run in order on one core, so a ``[Tq, D]``
+    float32 VMEM scratch (``dq_acc_ref``) is zeroed at the head's first
+    key tile, every later one adds to it, and the last writes dq out
+    (``dq_ref``'s block ignores the key-tile index, so it goes to HBM
+    when the head changes).  Either way dq leaves the kernel once,
+    scaled and in the gradient's dtype: no per-key-tile float32 dq plane
+    in HBM, no zero-fill of one, no XLA sum over it.
+
+    Two loop bodies over the query tiles (:func:`_causal_q_tiles`), as in
+    the forward: the tiles the diagonal crosses pay two iotas, a compare
+    and a select on p; the others pay nothing.  ``lse`` is finite for
+    every row (see `_flash_kernel_lse`), so no ``isfinite`` either.  Per
+    tile the split lowering runs 8 MXU dots + 2 exp's; this runs 5 dots
+    + 1 exp — the recompute-once argument in docs/performance.md
+    quantifies it.
     """
-    bk, d = k_ref.shape
-    tq = q_ref.shape[0]
-    ki = pl.program_id(1)
-    k = k_ref[:]          # storage dtype into the dots (see fwd kernel)
-    v = v_ref[:]
+    tq, d = q_ref.shape
     n_qblocks = tq // block_q
-    k_pos = (ki * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1))
-    dk = jnp.zeros((bk, d), jnp.float32)
-    dv = jnp.zeros((bk, d), jnp.float32)
-    # causally-skipped query tiles must still leave a defined partial:
-    # zero the whole plane once, the live tiles overwrite below
-    dq_part_ref[:] = jnp.zeros((tq, d), jnp.float32)
+    n_here = k_ref.shape[0] // block_k
+    fold = _scale_folds(scale)
+    dq_tiles = [None] * n_qblocks     # a static walk's dq, by query tile
 
-    def body(qi, carry):
-        dk, dv = carry
-        q_blk = q_ref[pl.ds(qi * block_q, block_q), :]
-        g_blk = g_ref[pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[pl.ds(qi * block_q, block_q), :] \
-            .reshape(block_q, 1)
-        delta = delta_ref[pl.ds(qi * block_q, block_q), :] \
-            .reshape(block_q, 1)
-        s = jax.lax.dot_general(q_blk, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+    def dq_out(dq):
+        return (dq if fold else dq * scale).astype(dq_ref.dtype)
+
+    def k_tile(ki, cols):
+        v = v_ref[cols, :]    # storage dtype into the dots (see fwd kernel)
+        # with scale folded into k both s and this tile's dq come out
+        # scaled
+        k = _fold_scale(k_ref[cols, :], scale) if fold else k_ref[cols, :]
+        k_pos = (ki * block_k
+                 + lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
+
+        if not static_walk:
+            @pl.when(ki == 0)
+            def _():
+                dq_acc_ref[0][:] = jnp.zeros((tq, d), jnp.float32)
+
+        def tile(qi, carry, masked):
+            rows = pl.ds(qi * block_q, block_q)
+            q_blk = q_ref[rows, :]
+            g_blk = g_ref[rows, :]
+            s = jax.lax.dot_general(q_blk, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            if not fold:
+                s = s * scale
+            p = jnp.exp(s - lse_ref[rows, :])  # ONCE; lse arrives [bq, 1]
+            if masked:
+                q_pos = (qi * block_q
+                         + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
+                p = jnp.where(q_pos >= k_pos, p, 0.0)
+            dv = jax.lax.dot_general(
+                p.astype(g_blk.dtype), g_blk, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            gv = jax.lax.dot_general(g_blk, v, (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (gv - delta_ref[rows, :])).astype(q_blk.dtype)
+            dk = jax.lax.dot_general(
+                ds, q_blk, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dq = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            if not static_walk:
+                dq_acc_ref[0][rows, :] += dq
+            elif dq_tiles[qi] is None:
+                dq_tiles[qi] = dq
+            else:
+                dq_tiles[qi] += dq
+            if carry is not None:
+                dk, dv = carry[0] + dk, carry[1] + dv
+            return dk, dv
+
+        # as in the forward: a static walk starts from its first tile
+        carry = None if static_walk else (
+            jnp.zeros((block_k, d), jnp.float32),
+            jnp.zeros((block_k, d), jnp.float32))
         if causal:
-            q_pos = (qi * block_q
-                     + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse), 0.0)  # ONCE
-        dv = dv + jax.lax.dot_general(
-            p.astype(g_blk.dtype), g_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        gv = jax.lax.dot_general(g_blk, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (gv - delta)
-        dk = dk + jax.lax.dot_general(
-            ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # dq contribution of this (qi, ki) tile; scale is applied after
-        # the cross-block sum (mirrors the split dq kernel's `dq * scale`
-        # after its fori accumulation)
-        dq_part_ref[pl.ds(qi * block_q, block_q), :] = \
-            jax.lax.dot_general(ds.astype(k.dtype), k,
-                                (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        return dk, dv
+            first, full = _causal_q_tiles(ki, block_q, block_k, n_qblocks)
+            carry = _loop(first, full, functools.partial(tile, masked=True),
+                          carry, static_walk)
+        else:
+            full = 0
+        carry = _loop(full, n_qblocks,
+                      functools.partial(tile, masked=False), carry,
+                      static_walk)
+        # a causal key tile past the last query row is seen by nobody
+        dk, dv = carry if carry is not None else (
+            jnp.zeros((block_k, d), jnp.float32),) * 2
+        # ds came from s = scale · q·kᵀ, so dk = scale · Σ dsᵀq; dq
+        # likewise, unless the folded k already carried the scale into
+        # ds·k
+        dk_ref[cols, :] = (dk * scale).astype(dk_ref.dtype)
+        dv_ref[cols, :] = dv.astype(dv_ref.dtype)
 
-    if causal:
-        # query blocks at or after this key block participate
-        first = (ki * bk) // block_q
-    else:
-        first = 0
-    dk, dv = jax.lax.fori_loop(first, n_qblocks, body, (dk, dv))
-    # ds was computed from UNSCALED q·k products with scale folded into s,
-    # so dk = scale · Σ ds·q (the fwd scale that s carries)
-    dk_ref[:] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+        if not static_walk:
+            @pl.when(ki == pl.num_programs(1) - 1)
+            def _():
+                dq_ref[:] = dq_out(dq_acc_ref[0][:])
+
+    for j in range(n_here):
+        k_tile(j if static_walk else pl.program_id(1),
+               pl.ds(j * block_k, block_k))
+    if static_walk:
+        # every query tile sees key tile 0, so none is left None
+        for qi, dq in enumerate(dq_tiles):
+            dq_ref[pl.ds(qi * block_q, block_q), :] = dq_out(dq)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, scale,
@@ -351,15 +519,16 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, scale,
     o_ref[:] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-# Adaptive-default tile candidates, largest first.  The round-5 on-chip
-# sweep (tools/flash_block_sweep.py, BENCH_NOTES r5): 128×128 tiles
-# serialize the online-softmax loop into too-small MXU dots — 1024-wide
-# tiles ran the same fwd+bwd 2.85× faster at T=8192 (11.2 → 31.8
-# TFLOP/s) and lifted the end-to-end seq-1024 transformer step 1.40×
-# (74.1k → 103.4k tokens/sec/chip, MFU 29.1% → 40.6%).  VMEM cost at
-# 1024: the f32 score/probability tiles are 4 MB each — comfortably
-# inside the kernel's 100 MB scoped-VMEM cap with whole-T K/V staging
-# up to T≈64k.
+# Adaptive-default tile candidates, largest first: a tile is the largest
+# candidate that divides T.  This is what the serving forward
+# (`_flash_kernel`: one dynamic loop a query tile) and the split pair
+# resolve, and what a length no sweep has visited keeps.  Every loop
+# iteration there costs about 0.4 us of latency that nothing hides
+# (128 x 128 tiles at T = 1024, D = 64: 0.98 ms a forward call against
+# 0.35 with one 1024 x 1024 tile; chip sweep, PR 28), so under dynamic
+# loops the largest tile wins although a causal head then computes its
+# whole square.  VMEM cost at 1024: the f32 score/probability tiles are
+# 4 MB each, inside the kernel's 100 MB scoped-VMEM cap.
 _BLOCK_CANDIDATES = (1024, 512, 256, 128)
 
 
@@ -375,21 +544,19 @@ def _adaptive_block(t):
     return 128
 
 
-def _flash_blocks(block_q=None, block_k=None, tq=None, tk=None):
-    """Resolve kernel tile sizes: explicit arguments win, else the
-    CHAINERMN_TPU_FLASH_BLOCK_Q/K env knobs (so an on-chip session can
-    A/B block shapes without code edits), else the shape-adaptive
-    default (:func:`_adaptive_block` over the given Tq/Tk).  Env changes
+def _resolve_blocks(axes):
+    """One tile size for each ``(env knob, explicit argument, default)``:
+    the argument wins, else the knob (so an on-chip session can A/B
+    block shapes without code edits), else the default.  Env changes
     only affect programs traced AFTERWARDS — jit caches are not keyed on
     them, so run each configuration in a fresh process (the probe does).
     Values must be positive multiples of 8 (Mosaic sublane tiling)."""
     out = []
-    for name, given, t in (("CHAINERMN_TPU_FLASH_BLOCK_Q", block_q, tq),
-                           ("CHAINERMN_TPU_FLASH_BLOCK_K", block_k, tk)):
+    for name, given, default in axes:
         if given is None:
             raw = os.environ.get(name)
             if raw is None:
-                given = _adaptive_block(t)
+                given = default
             else:
                 try:
                     given = int(raw)
@@ -403,6 +570,47 @@ def _flash_blocks(block_q=None, block_k=None, tq=None, tk=None):
     return tuple(out)
 
 
+def _flash_blocks(block_q=None, block_k=None, tq=None, tk=None):
+    """Tiles of the serving forward (`flash_attention`) and of the split
+    backward, and the shape-validated pair every custom VJP carries in
+    its residuals: arguments, else CHAINERMN_TPU_FLASH_BLOCK_Q/K, else
+    :func:`_adaptive_block` over the given Tq/Tk."""
+    return _resolve_blocks((
+        ("CHAINERMN_TPU_FLASH_BLOCK_Q", block_q, _adaptive_block(tq)),
+        ("CHAINERMN_TPU_FLASH_BLOCK_K", block_k, _adaptive_block(tk))))
+
+
+#: What the chip sweep chose for a CAUSAL call with Tq == Tk == T, keyed
+#: by what the call shows, (T, D): ``{"fwd": (block_q, block_k)}`` for
+#: the log-sum-exp forward, ``"bwd"`` for the fused backward.  At
+#: (1024, 64), GPT-2-medium's training shape ([4, 16, 1024, 64]
+#: bfloat16), 256 x 256 tiles walk 10 of 16 tiles, 0.625 of the square,
+#: and ran the forward in 0.16 ms a call and the backward in 0.35
+#: (1024 x 1024: 0.20 and 0.45; the parent's kernels 0.37 and 0.53);
+#: every pair tried is in tools/flash_budgets.json.  Only walks that
+#: :func:`_static_walk` unrolls gain from tiles under T.
+_CAUSAL_BLOCK_TABLE = {
+    (1024, 64): {"fwd": (256, 256), "bwd": (256, 256)},
+}
+
+
+def _swept_causal_blocks(leg, tq, tk, d, causal):
+    entry = _CAUSAL_BLOCK_TABLE.get((tq, d)) if causal and tq == tk \
+        else None
+    return entry[leg] if entry else (None, None)
+
+
+def _flash_lse_blocks(block_q=None, block_k=None, tq=None, tk=None,
+                      d=None, causal=False):
+    """Tiles of the log-sum-exp forward: arguments, else the same env
+    knobs as :func:`_flash_blocks`, else the swept table
+    (:data:`_CAUSAL_BLOCK_TABLE`), else the adaptive default."""
+    sq, sk = _swept_causal_blocks("fwd", tq, tk, d, causal)
+    return _resolve_blocks((
+        ("CHAINERMN_TPU_FLASH_BLOCK_Q", block_q, sq or _adaptive_block(tq)),
+        ("CHAINERMN_TPU_FLASH_BLOCK_K", block_k, sk or _adaptive_block(tk))))
+
+
 # -- backward lowering selection ---------------------------------------------
 
 #: CHAINERMN_TPU_FLASH_BWD: "fused" (default) = the one-pass dq/dkv
@@ -413,16 +621,14 @@ def _flash_blocks(block_q=None, block_k=None, tq=None, tk=None):
 #: untouched so `split` restores the old lowering bit-for-bit.
 _FLASH_BWD = os.environ.get("CHAINERMN_TPU_FLASH_BWD", "fused")
 
-#: Backward-specific tile table, keyed by sequence length — the bwd
-#: kernels have a different VMEM/recompute balance than the forward
-#: (whole-T q/g staging + an f32 [Tq, D] partial plane vs the forward's
-#: K/V streaming), so their best tiles need not match.  Regenerate with
-#: `make sweep-flash` (tools/flash_sweep.py sweeps fwd/bwd/fwd+bwd per
-#: (block_q, block_k) and rewrites tools/flash_budgets.json; paste the
-#: winners here).  Committed values are the best KNOWN config — the r5
-#: on-chip sweep's 1024-tile winner for the split backward (BENCH_NOTES
-#: r5: 128-tiles 11.2 → 1024-tiles 31.8 TFLOP/s at T=8192); the fused
-#: kernel's own sweep refines them on the next chip session.
+#: Backward tiles by sequence length, for the calls
+#: :data:`_CAUSAL_BLOCK_TABLE` does not cover.  Never swept on this
+#: chip: the rows say what `_adaptive_block` would, and stand for the
+#: sweep to fill (`make sweep-flash` times fwd/bwd/fwd+bwd per
+#: (block_q, block_k); paste the winners here and into
+#: tools/flash_budgets.json).  At T = 1024 the dynamic walk read 0.52 ms
+#: a call with one 1024 x 1024 tile and 0.53-0.57 with smaller ones
+#: (chip sweep, PR 28).
 _BWD_BLOCK_TABLE = {
     1024: (1024, 1024),
     2048: (1024, 1024),
@@ -439,32 +645,21 @@ def _flash_bwd_mode():
     return mode
 
 
-def _flash_bwd_blocks(block_q=None, block_k=None, tq=None, tk=None):
-    """Backward tile resolution: explicit arguments win, else the
-    CHAINERMN_TPU_FLASH_BWD_BLOCK_Q/K env knobs, else the sweep-driven
-    per-T table (:data:`_BWD_BLOCK_TABLE`), else the forward's
-    shape-adaptive default.  Same env-retrace caveat and multiple-of-8
-    validation as :func:`_flash_blocks`."""
-    out = []
-    for i, (name, given, t) in enumerate(
-            (("CHAINERMN_TPU_FLASH_BWD_BLOCK_Q", block_q, tq),
-             ("CHAINERMN_TPU_FLASH_BWD_BLOCK_K", block_k, tk))):
-        if given is None:
-            raw = os.environ.get(name)
-            if raw is None:
-                entry = _BWD_BLOCK_TABLE.get(t)
-                given = entry[i] if entry else _adaptive_block(t)
-            else:
-                try:
-                    given = int(raw)
-                except ValueError:
-                    raise ValueError(f"{name}={raw!r} is not an integer")
-                if given <= 0 or given % 8:
-                    raise ValueError(
-                        f"{name}={given} invalid: flash block sizes must "
-                        "be positive multiples of 8")
-        out.append(given)
-    return tuple(out)
+def _flash_bwd_blocks(block_q=None, block_k=None, tq=None, tk=None,
+                      d=None, causal=False):
+    """Fused-backward tile resolution: arguments, else the
+    CHAINERMN_TPU_FLASH_BWD_BLOCK_Q/K env knobs, else the swept table
+    (:data:`_CAUSAL_BLOCK_TABLE`), else the per-T table
+    (:data:`_BWD_BLOCK_TABLE`), else the forward's shape-adaptive
+    default."""
+    swept = _swept_causal_blocks("bwd", tq, tk, d, causal)
+    defaults = [
+        swept[i] or (_BWD_BLOCK_TABLE[t][i] if t in _BWD_BLOCK_TABLE
+                     else _adaptive_block(t))
+        for i, t in enumerate((tq, tk))]
+    return _resolve_blocks((
+        ("CHAINERMN_TPU_FLASH_BWD_BLOCK_Q", block_q, defaults[0]),
+        ("CHAINERMN_TPU_FLASH_BWD_BLOCK_K", block_k, defaults[1])))
 
 
 def _on_tpu():
@@ -543,41 +738,101 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     return out.reshape(B, H, Tq, D)
 
 
-def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=None,
-                        block_k=None, interpret=False):
-    """Forward kernel returning (out, lse [B, H, Tq])."""
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    scale = scale if scale is not None else 1.0 / (D ** 0.5)
-    block_q, block_k = _flash_blocks(block_q, block_k, tq=Tq, tk=Tk)
-    block_q = min(block_q, Tq)
-    block_k = min(block_k, Tk)
-    qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * H, Tk, D)
-    vr = v.reshape(B * H, Tk, D)
-    kernel = functools.partial(_flash_kernel_lse, block_k=block_k,
-                               causal=causal, scale=scale)
-    out, lse = pl.pallas_call(
-        kernel,
+@functools.partial(jax.jit, static_argnames=(
+    "block_q", "block_k", "causal", "scale", "static_walk", "interpret"))
+def _lse_forward_call(qr, kr, vr, *, block_q, block_k, causal, scale,
+                      static_walk, interpret):
+    """The forward's one ``pallas_call``.  A jit of its own, everything
+    but the operands static: the 24 layers of a step then share ONE
+    trace of the kernel and one lowering, where each call site used to
+    trace its own (an unrolled walk of ten tiles is ten times the
+    equations; the step's set-up rose by 7 s until this: my chip runs,
+    PR 28)."""
+    BH, Tq, D = qr.shape
+    Tk = kr.shape[1]
+    q_rows = Tq if static_walk else block_q   # of q, in one program
+    return pl.pallas_call(
+        functools.partial(_flash_kernel_lse, block_q=block_q,
+                          block_k=block_k, causal=causal, scale=scale,
+                          static_walk=static_walk),
         name="_flash_kernel_lse",
-        grid=(B * H, Tq // block_q),
+        grid=(BH, Tq // q_rows),
         in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, q_rows, D), lambda b, i: (b, i, 0)),
             pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, q_rows, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, q_rows, 1), lambda b, i: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Tq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((BH, Tq, D), qr.dtype),
+            jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
     )(qr, kr, vr)
+
+
+def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=None,
+                        block_k=None, interpret=False):
+    """Forward kernel returning (out, lse [B, H, Tq]).  Tiles come from
+    :func:`_flash_lse_blocks`; the callers have validated that the
+    shape tiles."""
+    B, H, Tq, D = q.shape
+    Tk = k.shape[2]
+    scale = scale if scale is not None else 1.0 / (D ** 0.5)
+    block_q, block_k = _flash_lse_blocks(block_q, block_k, tq=Tq, tk=Tk,
+                                         d=D, causal=causal)
+    block_q = min(block_q, Tq)
+    block_k = min(block_k, Tk)
+    out, lse = _lse_forward_call(
+        q.reshape(B * H, Tq, D), k.reshape(B * H, Tk, D),
+        v.reshape(B * H, Tk, D), block_q=block_q, block_k=block_k,
+        causal=causal, scale=scale, interpret=interpret,
+        static_walk=_static_walk(Tq, Tk, block_q, block_k, causal))
     return out.reshape(B, H, Tq, D), lse.reshape(B, H, Tq)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_q", "block_k", "causal", "scale", "static_walk", "interpret"))
+def _fused_backward_call(qr, kr, vr, gr, lser, delta, *, block_q, block_k,
+                         causal, scale, static_walk, interpret):
+    """The fused backward's one ``pallas_call``, a jit of its own as
+    :func:`_lse_forward_call` is."""
+    BH, Tq, D = qr.shape
+    Tk = kr.shape[1]
+    k_rows = Tk if static_walk else block_k   # of k and v, in one program
+    return pl.pallas_call(
+        functools.partial(_flash_bwd_fused_kernel, block_q=block_q,
+                          block_k=block_k, causal=causal, scale=scale,
+                          static_walk=static_walk),
+        name="_flash_bwd_fused_kernel",
+        grid=(BH, Tk // k_rows),
+        in_specs=[
+            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, k_rows, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, k_rows, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, Tq, 1), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, Tq, 1), lambda b, i: (b, 0, 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, k_rows, D), lambda b, i: (b, i, 0)),
+            pl.BlockSpec((None, k_rows, D), lambda b, i: (b, i, 0)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((BH, Tq, D), qr.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, D), kr.dtype),
+            jax.ShapeDtypeStruct((BH, Tk, D), vr.dtype),
+        ],
+        scratch_shapes=([] if static_walk
+                        else [pltpu.VMEM((Tq, D), jnp.float32)]),
+        interpret=interpret,
+        compiler_params=_BWD_FUSED_COMPILER_PARAMS,
+    )(qr, kr, vr, gr, lser, delta)
 
 
 def flash_attention_bwd(q, k, v, out, lse, g, causal=False, scale=None,
@@ -624,43 +879,15 @@ def flash_attention_bwd(q, k, v, out, lse, g, causal=False, scale=None,
         # tiles are the fallback when the table/env tiles don't divide
         # this T — e.g. ragged lengths reached with explicit fwd blocks
         bq, bk = _flash_bwd_blocks(bwd_block_q, bwd_block_k,
-                                   tq=Tq, tk=Tk)
+                                   tq=Tq, tk=Tk, d=D, causal=causal)
         bq = min(bq, Tq)
         bk = min(bk, Tk)
         if Tq % bq or Tk % bk:
             bq, bk = block_q, block_k
-        n_kblocks = Tk // bk
-        dq_part, dk, dv = pl.pallas_call(
-            functools.partial(_flash_bwd_fused_kernel, block_q=bq,
-                              causal=causal, scale=scale),
-            name="_flash_bwd_fused_kernel",
-            grid=(B * H, n_kblocks),
-            in_specs=[
-                pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((None, Tq, 1), lambda b, i: (b, 0, 0)),
-                pl.BlockSpec((None, Tq, 1), lambda b, i: (b, 0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((None, None, Tq, D),
-                             lambda b, i: (b, i, 0, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i: (b, i, 0)),
-                pl.BlockSpec((None, bk, D), lambda b, i: (b, i, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((B * H, n_kblocks, Tq, D),
-                                     jnp.float32),
-                jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
-                jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype),
-            ],
-            interpret=interpret,
-            compiler_params=_COMPILER_PARAMS,
-        )(qr, kr, vr, gr, lser, delta)
-        # the cross-key-block dq reduction the grid cannot express:
-        # one XLA sum over the partial planes, then the fwd scale
-        dq = (jnp.sum(dq_part, axis=1) * scale).astype(q.dtype)
+        dq, dk, dv = _fused_backward_call(
+            qr, kr, vr, gr, lser, delta, block_q=bq, block_k=bk,
+            causal=causal, scale=scale, interpret=interpret,
+            static_walk=_static_walk(Tq, Tk, bq, bk, causal))
         return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
                 dv.reshape(B, H, Tk, D))
 
@@ -725,11 +952,12 @@ def _flash_diff_fwd(q, k, v, causal, scale, interpret):
         _warn_fallback(q, k, "XLA attention (forward and backward)")
         out = xla_attention(q, k, v, causal=causal, scale=scale)
         return out, (q, k, v, None, None, None)
+    # the log-sum-exp forward resolves its own tiles (swept where the
+    # call's shape was, else these)
     out, lse = flash_attention_fwd(q, k, v, causal=causal, scale=scale,
-                                   block_q=bq, block_k=bk,
                                    interpret=interpret)
-    # carry the block config in the residuals: the backward's SHAPE
-    # validation must use the exact tiles the forward was validated with
+    # carry the validated pair in the residuals: the backward's SHAPE
+    # validation must use the exact tiles this shape was validated with
     # (they are the fused path's divisibility fallback and the split
     # path's tiles; re-reading the fwd env there would silently corrupt
     # gradients if it changed mid-process)
@@ -833,13 +1061,12 @@ def _flash_lse_diff(q, k, v, causal, scale, interpret):
 
 
 def _flash_lse_fwd(q, k, v, causal, scale, interpret):
-    bq, bk = _flash_blocks(tq=q.shape[2], tk=k.shape[2])
     out, lse = flash_attention_fwd(q, k, v, causal=causal, scale=scale,
-                                   block_q=bq, block_k=bk,
                                    interpret=interpret)
-    # same residual-carried block config as _flash_diff: the fwd tiles
-    # are the backward's validated divisibility fallback
-    return (out, lse), (q, k, v, out, lse, (bq, bk))
+    # same residual-carried block config as _flash_diff: the validated
+    # pair is the backward's divisibility fallback and the split tiles
+    return (out, lse), (q, k, v, out, lse,
+                        _flash_blocks(tq=q.shape[2], tk=k.shape[2]))
 
 
 def _flash_lse_bwd(causal, scale, interpret, res, cots):

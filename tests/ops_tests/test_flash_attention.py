@@ -1,5 +1,7 @@
 """Flash-attention kernel vs XLA reference (interpreter mode on CPU)."""
 
+import importlib
+
 import numpy as np
 import pytest
 
@@ -194,6 +196,98 @@ def test_flash_bf16_matches_fp32_reference():
                                    np.asarray(r), rtol=0.1, atol=0.05)
 
 
+def _ref_out_lse(q, k, v, causal):
+    """(out, lse) in float32 with the [Tq, Tk] scores spelled out."""
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    if causal:
+        t = s.shape[-1]
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", jnp.exp(s - lse[..., None]), v), lse
+
+
+@pytest.mark.parametrize("walk", ["unrolled", "looped"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blocks", [(64, 128), (128, 64), (64, 64)])
+def test_lse_forward_with_tiles_under_t(monkeypatch, blocks, causal, dtype,
+                                        walk):
+    """The log-sum-exp forward with tiles under T, block_q != block_k in
+    both orders: out and lse against the plain softmax, with the walk
+    unrolled in one program a head and looped over one query tile a
+    program (what a long sequence gets)."""
+    fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
+    if walk == "looped":
+        monkeypatch.setattr(fa, "_STATIC_WALK_ELEMS", 0)
+    q, k, v = (x.astype(dtype) for x in _data(B=1, T=256, D=16, seed=21))
+    assert fa._static_walk(256, 256, *blocks, causal) == (walk == "unrolled")
+    out, lse = fa.flash_attention_fwd(q, k, v, causal=causal,
+                                      block_q=blocks[0], block_k=blocks[1],
+                                      interpret=True)
+    ref, ref_lse = _ref_out_lse(q, k, v, causal)
+    tol = dict(rtol=2e-4, atol=2e-5) if dtype == jnp.float32 \
+        else dict(rtol=0.05, atol=0.02)
+    np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
+                               np.asarray(ref), **tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               rtol=1e-4, atol=1e-4 if dtype == jnp.float32
+                               else 0.02)
+
+
+@pytest.mark.parametrize("t,bq,bk", [
+    (1024, 256, 256), (1024, 128, 512), (1024, 512, 128), (1024, 1024, 1024),
+    (256, 64, 128), (256, 128, 64), (384, 128, 128), (512, 512, 128)])
+def test_causal_tile_walk_is_the_lower_triangle(t, bq, bk):
+    """The walk against a brute-force mask: no tile wholly above the
+    diagonal is computed, every other tile is, and a tile is masked
+    exactly where the diagonal crosses it; the backward's bounds list
+    the same tiles."""
+    fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
+    visible = np.tril(np.ones((t, t), bool))
+    want = {}
+    for qi in range(t // bq):
+        for ki in range(t // bk):
+            tile = visible[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            if tile.any():
+                want[(qi, ki)] = not tile.all()
+    walk = fa._causal_tile_walk(t, t, bq, bk)
+    assert len(walk) == len(set(walk))
+    assert {(qi, ki): masked for qi, ki, masked in walk} == want
+    seen_from_keys = {}
+    for ki in range(t // bk):
+        first, full = fa._causal_q_tiles(ki, bq, bk, t // bq)
+        for qi in range(first, t // bq):
+            seen_from_keys[(qi, ki)] = qi < full
+    assert seen_from_keys == want
+
+
+def test_committed_tiles_skip_the_masked_half():
+    """GPT-2-medium's training shape: the committed tiles compute at most
+    0.65 of the square (1024 x 1024 tiles computed all of it), and
+    the head's walk is unrolled."""
+    fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
+    for leg, blocks in fa._CAUSAL_BLOCK_TABLE[(1024, 64)].items():
+        walk = fa._causal_tile_walk(1024, 1024, *blocks)
+        share = len(walk) * blocks[0] * blocks[1] / 1024 ** 2
+        assert share <= 0.65, (leg, blocks, share)
+        assert fa._static_walk(1024, 1024, *blocks, True), leg
+    assert len(fa._causal_tile_walk(1024, 1024, 1024, 1024)) == 1
+
+
+@pytest.mark.parametrize("t,want", [(64, 128), (128, 128), (256, 256),
+                                    (512, 512), (1024, 1024), (4096, 1024)])
+def test_serving_forward_resolves_the_parents_tiles(monkeypatch, t, want):
+    """`flash_attention` (serving prefills: GPT-2 buckets of 64-512 at
+    D = 64, Kimi at 4096) resolves what it did before the training
+    kernels got a swept table: the largest candidate that divides T,
+    whatever D or causal (it is shown neither)."""
+    from chainermn_tpu.ops.flash_attention import _flash_blocks
+    monkeypatch.delenv("CHAINERMN_TPU_FLASH_BLOCK_Q", raising=False)
+    monkeypatch.delenv("CHAINERMN_TPU_FLASH_BLOCK_K", raising=False)
+    assert _flash_blocks(tq=t, tk=t) == (want, want)
+
+
 def test_adaptive_block_defaults(monkeypatch):
     """Round-5 on-chip sweep: tile defaults are shape-adaptive (largest
     candidate dividing T), env still pins, explicit args still win."""
@@ -210,8 +304,26 @@ def test_adaptive_block_defaults(monkeypatch):
     assert _adaptive_block(None) == 128   # no shape info: legacy default
     assert _flash_blocks(tq=2048, tk=8192) == (1024, 1024)
     assert _flash_blocks(256, None, tq=2048, tk=1536) == (256, 512)
+    # the log-sum-exp forward: the swept table where the call's shape was
+    # swept (causal, Tq == Tk == 1024, D = 64), else the same default
+    from chainermn_tpu.ops.flash_attention import _CAUSAL_BLOCK_TABLE, \
+        _flash_lse_blocks
+    assert _CAUSAL_BLOCK_TABLE == {
+        (1024, 64): {"fwd": (256, 256), "bwd": (256, 256)}}
+    assert _flash_lse_blocks(tq=1024, tk=1024, d=64, causal=True) \
+        == (256, 256)
+    for tq, tk, d, causal in ((1024, 1024, 64, False),
+                              (1024, 1024, 128, True),
+                              (1024, 2048, 64, True),
+                              (2048, 2048, 64, True)):
+        assert _flash_lse_blocks(tq=tq, tk=tk, d=d, causal=causal) \
+            == (1024, 1024)
+    assert _flash_lse_blocks(512, None, tq=1024, tk=1024, d=64,
+                             causal=True) == (512, 256)
     monkeypatch.setenv("CHAINERMN_TPU_FLASH_BLOCK_Q", "64")
     assert _flash_blocks(tq=2048, tk=2048) == (64, 1024)
+    assert _flash_lse_blocks(tq=1024, tk=1024, d=64, causal=True) \
+        == (64, 256)
 
 def test_adaptive_block_invalid_env(monkeypatch):
     monkeypatch.setenv("CHAINERMN_TPU_FLASH_BLOCK_K", "70")

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .test_flash_attention import _ref_out_lse
+
 fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
 
 
@@ -93,6 +95,77 @@ def test_fused_bwd_grad_parity_vs_blockwise(monkeypatch, T, blocks,
             np.asarray(b, dtype=np.float32), rtol=rtol, atol=atol,
             err_msg=f"d{name} T={T} blocks={blocks} causal={causal} "
                     f"dtype={dtype.__name__}")
+
+
+@pytest.mark.parametrize("walk", ["unrolled", "looped"])
+@pytest.mark.parametrize("with_g_lse", [False, True])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("blocks", [(64, 128), (128, 64)])
+def test_fused_bwd_with_tiles_under_t(monkeypatch, blocks, causal, dtype,
+                                      with_g_lse, walk):
+    """All three gradients against autodiff of the plain softmax, with
+    backward tiles under T and block_q != block_k in both orders, with
+    and without a cotangent on lse, the walk unrolled (dq in values) and
+    looped (dq in the VMEM scratch, across the key-tile grid axis)."""
+    if walk == "looped":
+        monkeypatch.setattr(fa, "_STATIC_WALK_ELEMS", 0)
+    assert fa._flash_bwd_mode() == "fused"
+    q, k, v = _data(T=256, seed=31 + causal, dtype=dtype)
+    g = _data(T=256, seed=33, dtype=dtype)[0]
+    g_lse = jnp.cos(jnp.arange(2 * 256, dtype=jnp.float32)
+                    .reshape(1, 2, 256)) if with_g_lse else None
+    out, lse = fa.flash_attention_fwd(q, k, v, causal=causal,
+                                      block_q=128, block_k=128,
+                                      interpret=True)
+    got = fa.flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
+                                 block_q=128, block_k=128, interpret=True,
+                                 g_lse=g_lse, bwd_block_q=blocks[0],
+                                 bwd_block_k=blocks[1])
+    _, vjp = jax.vjp(lambda q, k, v: _ref_out_lse(q, k, v, causal),
+                     *(x.astype(jnp.float32) for x in (q, k, v)))
+    want = vjp((g.astype(jnp.float32),
+                g_lse if with_g_lse else jnp.zeros_like(lse)))
+    tol = dict(rtol=2e-4, atol=2e-5) if dtype == jnp.float32 \
+        else dict(rtol=0.1, atol=0.05)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        assert a.dtype == dtype
+        np.testing.assert_allclose(np.asarray(a, dtype=np.float32),
+                                   np.asarray(b), err_msg=name, **tol)
+
+
+@pytest.mark.parametrize("walk", ["unrolled", "looped"])
+def test_fused_bwd_writes_no_partial_dq_plane(monkeypatch, walk):
+    """dq leaves the kernel once, in the gradient's dtype: the backward
+    is ONE pallas_call whose outputs are dq, dk, dv as ``[B·H, T, D]`` in
+    the operands' dtype, no ``[B·H, n_k, Tq, D]`` float32 plane, and
+    nothing after the kernel sums anything."""
+    if walk == "looped":
+        monkeypatch.setattr(fa, "_STATIC_WALK_ELEMS", 0)
+    q, k, v = _data(T=256, seed=41, dtype=jnp.bfloat16)
+    g = _data(T=256, seed=42, dtype=jnp.bfloat16)[0]
+    out, lse = fa.flash_attention_fwd(q, k, v, causal=True, interpret=True)
+    jaxpr = jax.make_jaxpr(lambda *a: fa.flash_attention_bwd(
+        *a, causal=True, interpret=True, bwd_block_q=64,
+        bwd_block_k=64))(q, k, v, out, lse, g).jaxpr
+
+    def flat(jx):
+        # the kernel's call is a jit of its own: look through it
+        for e in jx.eqns:
+            if e.primitive.name == "jit":
+                yield from flat(e.params["jaxpr"].jaxpr)
+            else:
+                yield e
+
+    eqns = list(flat(jaxpr))
+    names = [e.primitive.name for e in eqns]
+    at = names.index("pallas_call")
+    assert names.count("pallas_call") == 1
+    call = eqns[at]
+    assert call.params["name"] == "_flash_bwd_fused_kernel"
+    assert [(o.aval.shape, o.aval.dtype) for o in call.outvars] == \
+        [((2, 256, 16), jnp.bfloat16)] * 3
+    assert "reduce_sum" not in names[at:]
 
 
 def _legacy_two_kernel_bwd(q, k, v, out, lse, g, causal, scale,
@@ -204,12 +277,24 @@ def test_bwd_mode_validation(monkeypatch):
 
 
 def test_bwd_block_resolution(monkeypatch):
-    """Explicit args > env knobs > sweep table > fwd-adaptive default."""
+    """Explicit args > env knobs > swept causal table > per-T table >
+    fwd-adaptive default."""
     monkeypatch.delenv("CHAINERMN_TPU_FLASH_BWD_BLOCK_Q", raising=False)
     monkeypatch.delenv("CHAINERMN_TPU_FLASH_BWD_BLOCK_K", raising=False)
-    # table rows exist for the swept lengths
+    # the per-T rows, for the calls no chip sweep has visited
+    assert fa._BWD_BLOCK_TABLE == {t: (1024, 1024)
+                                   for t in (1024, 2048, 8192, 16384)}
     for t in (1024, 2048, 8192, 16384):
         assert fa._flash_bwd_blocks(tq=t, tk=t) == fa._BWD_BLOCK_TABLE[t]
+    # the swept shape: causal, Tq == Tk == 1024, D = 64 (and only it)
+    assert fa._flash_bwd_blocks(tq=1024, tk=1024, d=64, causal=True) \
+        == (256, 256)
+    for tq, tk, d, causal in ((1024, 1024, 64, False),
+                              (1024, 1024, 128, True),
+                              (2048, 2048, 64, True),
+                              (1024, 2048, 64, True)):
+        assert fa._flash_bwd_blocks(tq=tq, tk=tk, d=d, causal=causal) \
+            == (1024, 1024)
     # off-table lengths: fwd-adaptive fallback
     assert fa._flash_bwd_blocks(tq=512, tk=512) == (512, 512)
     assert fa._flash_bwd_blocks(tq=192, tk=192) == (128, 128)
@@ -226,8 +311,9 @@ def test_bwd_block_resolution(monkeypatch):
 
 def test_fused_bwd_kernel_count_and_single_exp():
     """Structural pin of the recompute-once property: the fused backward
-    lowers to exactly ONE pallas_call whose kernel contains exactly ONE
-    exp; split lowers to two kernels with one exp each.  Uses the same
+    lowers to exactly ONE pallas_call that spends exactly ONE exp a tile
+    it walks (its two loop bodies, masked and unmasked, hold one each);
+    split lowers to two kernels with one exp a tile each.  Uses the same
     jaxpr census the tier-1 budget gate runs (tools/flash_sweep.py) —
     here pinned against absolute expectations, there against the
     committed tools/flash_budgets.json structure section."""
@@ -237,10 +323,11 @@ def test_fused_bwd_kernel_count_and_single_exp():
         os.path.dirname(os.path.abspath(__file__)))), "tools"))
     import flash_sweep
 
-    # fused: ONE backward kernel with ONE exp
+    # fused: ONE backward kernel, ONE exp a tile
     assert flash_sweep.bwd_kernel_census(fa, "fused") == \
-        {"_flash_bwd_fused_kernel": 1}
+        {"_flash_bwd_fused_kernel": {"loop_bodies": 2, "exp_per_tile": 1}}
     # split: the legacy pair, each recomputing its own exp(s - lse) —
     # the duplicated recompute the fusion eliminates
+    one = {"loop_bodies": 1, "exp_per_tile": 1}
     assert flash_sweep.bwd_kernel_census(fa, "split") == \
-        {"_flash_bwd_dq_kernel": 1, "_flash_bwd_dkv_kernel": 1}
+        {"_flash_bwd_dq_kernel": one, "_flash_bwd_dkv_kernel": one}

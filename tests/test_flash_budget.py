@@ -5,10 +5,10 @@ flash-attention backward's contract and this gate holds every future PR
 to it.  Two layers:
 
 * STRUCTURE (backend-neutral, checked here on CPU): the fused backward
-  lowers to exactly one Pallas kernel with exactly one exp — the
-  recompute-once property the fusion exists for — and the split escape
-  hatch to the legacy two kernels.  Verified against the traced
-  program, not against documentation.
+  lowers to exactly one Pallas kernel that spends exactly one exp on a
+  tile it walks — the recompute-once property the fusion exists for —
+  and the split escape hatch to the legacy two kernels.  Verified
+  against the traced program, not against documentation.
 * NUMBERS (measured on chip by `make sweep-flash`): when the committed
   sweep section says ``measured``, the T=8192 fused fwd+bwd TFLOP/s
   must meet the committed target (≥2× the r5 split-backward baseline);
@@ -20,6 +20,8 @@ import importlib
 import json
 import os
 import sys
+
+import pytest
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "tools"))
@@ -48,20 +50,49 @@ def test_budget_schema_and_target_relation():
     assert b["sweep"]["status"] in ("pending_on_chip", "measured")
 
 
-def test_bwd_block_table_matches_kernel_literal():
-    """The kernel reads the literal table in ops/flash_attention.py;
-    the budgets file records it — they must not desync (the sweep tool
+def _recorded_bwd_table(b):
+    return {int(t): tuple(v) for t, v in b["bwd_block_table"].items()}
+
+
+def _recorded_causal_table(b):
+    return {tuple(int(x) for x in key.split("x")):
+            {leg: tuple(blocks) for leg, blocks in entry.items()}
+            for key, entry in b["causal_block_table"].items()}
+
+
+@pytest.mark.parametrize("recorded,literal", [
+    (_recorded_bwd_table, "_BWD_BLOCK_TABLE"),
+    (_recorded_causal_table, "_CAUSAL_BLOCK_TABLE")])
+def test_bwd_block_table_matches_kernel_literal(recorded, literal):
+    """The kernels read the literal tables in ops/flash_attention.py;
+    the budgets file records them — they must not desync (the sweep tool
     prints a reminder to paste winners into the literal)."""
+    assert recorded(_budgets()) == getattr(fa, literal)
+
+
+@pytest.mark.parametrize("leg,kernel", [
+    ("fwd", "_flash_kernel_lse"), ("bwd", "_flash_bwd_fused_kernel")])
+def test_committed_causal_tiles_come_from_the_recorded_sweep(leg, kernel):
+    """PR 28's chip sweep at [4, 16, 1024, 64] causal bfloat16 is
+    recorded whole (every block_q, block_k over {128..1024}), and the
+    committed tiles are within 3 % of the fastest row of their leg."""
     b = _budgets()
-    assert {int(t): tuple(v) for t, v in b["bwd_block_table"].items()} \
-        == fa._BWD_BLOCK_TABLE
+    rows = b["cell_sweep_T1024_D64"]["rows"]
+    assert {(r["block_q"], r["block_k"]) for r in rows} == {
+        (bq, bk) for bq in (128, 256, 512, 1024)
+        for bk in (128, 256, 512, 1024)}
+    by_blocks = {(r["block_q"], r["block_k"]): r["kernel_ms"][kernel]
+                 for r in rows}
+    committed = tuple(b["causal_block_table"]["1024x64"][leg])
+    assert by_blocks[committed] <= 1.03 * min(by_blocks.values())
 
 
 def test_fused_structure_gate():
     """Recompute-once, machine-checked: the fused backward is ONE
-    pallas kernel with ONE exp.  A PR that splits the pass again or
-    adds a second exp(s - lse) recompute fails here and must either fix
-    it or consciously re-commit the structure section."""
+    pallas kernel with ONE exp a tile walked (two loop bodies, one exp
+    each).  A PR that splits the pass again or adds a second
+    exp(s - lse) recompute fails here and must either fix it or
+    consciously re-commit the structure section."""
     b = _budgets()
     census = flash_sweep.bwd_kernel_census(fa, "fused")
     assert census == b["structure"]["fused_bwd_kernels"], (

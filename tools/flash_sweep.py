@@ -56,11 +56,52 @@ def _timed(fn, args, reps):
     return (time.perf_counter() - t0) / reps
 
 
+def _kernel_ms(fn, args, reps=10):
+    """{kernel name: ms a call} of the flash kernels in ``fn``, from the
+    device lines of a profiler trace (the benchmark's own reduction):
+    the time the rooflines in PERF.md divide by, with none of the XLA
+    operations around the kernel in it."""
+    import shutil
+    import tempfile
+
+    import jax
+    from benchmark import trace_reduce
+    jax.block_until_ready(fn(*args))
+    tdir = tempfile.mkdtemp(prefix="flash_sweep_trace")
+    try:
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(tdir, profiler_options=options)
+        for _ in range(reps):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        trace = trace_reduce.load(trace_reduce.find_xplane(tdir))
+    finally:
+        shutil.rmtree(tdir, ignore_errors=True)
+    lo, hi = trace_reduce.window(trace)
+    ms = {}
+    for name in ("_flash_kernel_lse", "_flash_bwd_fused_kernel",
+                 "_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"):
+        seconds, calls = trace_reduce.op_seconds(trace, (name,), lo, hi)
+        if calls:
+            ms[name] = round(seconds / calls * 1e3, 4)
+    return ms
+
+
+#: calls timed back to back inside ONE program on the chip, each fed the
+#: one before it (so none is folded away): a 0.2 ms kernel is then timed
+#: by the device and not by the host's dispatch
+CHAIN = 24
+
+
 def measure_point(fa, B, H, D, T, bq, bk, mode, reps, interp):
     """One (T, block_q, block_k, mode) sweep point → dict of leg
     timings/TFLOP/s (fwd is mode-independent but re-timed per point so
-    each row stands alone).  Raises on kernel failure — callers report
-    and continue."""
+    each row stands alone): a call's share of :data:`CHAIN` chained
+    calls, and on the chip each kernel's own device time
+    (``kernel_ms``).  Raises on kernel failure — callers report and
+    continue."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -91,69 +132,111 @@ def measure_point(fa, B, H, D, T, bq, bk, mode, reps, interp):
             o, l = fwd(q, k, v)
             return bwd(q, k, v, o, l, g)
 
+        chain = 1 if interp else CHAIN
+
+        def chained(fn):
+            # the call's first output (out, or dq) has q's shape and
+            # dtype: it is the next call's q
+            if chain == 1:
+                return fn
+
+            def run(q, *rest):
+                return jax.lax.fori_loop(
+                    0, chain, lambda _, q: fn(q, *rest)[0], q)
+            return run
+
         row = {}
         for leg, fn, args in (
-                ("fwd", jax.jit(fwd), (q, k, v)),
-                ("bwd", jax.jit(bwd), (q, k, v, out, lse, g)),
-                ("fwd_bwd", jax.jit(both), (q, k, v, g))):
-            dt = _timed(fn, args, reps)
-            row[f"{leg}_ms"] = round(dt * 1e3, 2)
+                ("fwd", fwd, (q, k, v)),
+                ("bwd", bwd, (q, k, v, out, lse, g)),
+                ("fwd_bwd", both, (q, k, v, g))):
+            dt = _timed(jax.jit(chained(fn)), args, reps) / chain
+            row[f"{leg}_ms"] = round(dt * 1e3, 4)
             row[f"{leg}_tflops"] = round(
                 model_flops(B, H, T, D, leg) / dt / 1e12, 1)
+        if not interp:
+            row["kernel_ms"] = _kernel_ms(jax.jit(both), (q, k, v, g))
         return row
     finally:
         fa._FLASH_BWD = prev
 
 
-def bwd_kernel_census(fa, mode, T=128):
-    """Structural census of the backward lowering: {kernel_name: number
-    of exp ops} for every pallas_call in the traced grad program (tiles
-    resolve through the normal env/adaptive chain — the census counts
-    kernels and exps, which are tile-independent).  The tier-1 budget
-    gate pins this — the recompute-once property as a machine-checkable
-    fact (fused: ONE bwd kernel, ONE exp; split: two kernels, one exp
-    each)."""
+def bwd_kernel_census(fa, mode, T=128, block=64):
+    """Structural census of the backward lowering: for every backward
+    pallas_call of a causal call whose walk is 3 tiles (T = 128 in 64 x
+    64 tiles: two the diagonal crosses, one below it), the ``exp``
+    equations a tile costs.  Counted both ways a kernel walks: with the
+    walk unrolled (``fa._STATIC_WALK_ELEMS`` as committed: the exps in
+    the kernel over the tiles walked) and with it looped (forced: the
+    most exps any ONE loop body holds, and ``loop_bodies``, since a tile
+    is walked by exactly one body, masked where the diagonal crosses it
+    and unmasked elsewhere); ``exp_per_tile`` is the larger.  That is
+    the recompute-once property as a machine-checkable fact: fused = ONE
+    bwd kernel, ONE exp a tile; split = two kernels, one exp a tile
+    each."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    q, k, v = (jnp.asarray(np.random.RandomState(i)
-                           .normal(0, 1, (1, 2, T, 16))
-                           .astype(np.float32)) for i in range(3))
-    prev = fa._FLASH_BWD
-    fa._FLASH_BWD = mode
-    try:
-        jaxpr = jax.make_jaxpr(
-            lambda q, k, v: jax.grad(
-                lambda q, k, v: jnp.sum(
-                    fa._flash_diff(q, k, v, True, None, True) ** 2),
-                argnums=(0, 1, 2))(q, k, v))(q, k, v)
-    finally:
-        fa._FLASH_BWD = prev
-    calls = {}
+    q, k, v, g = (jnp.asarray(np.random.RandomState(i)
+                              .normal(0, 1, (1, 2, T, 16))
+                              .astype(np.float32)) for i in range(4))
+    tiles = len(fa._causal_tile_walk(T, T, block, block))
 
-    def count_exp(sub, n):
-        for e in sub.eqns:
-            if e.primitive.name == "exp":
-                n[0] += 1
-            for p in e.params.values():
-                pj = getattr(p, "jaxpr", None)
-                if pj is not None:
-                    count_exp(getattr(pj, "jaxpr", pj), n)
+    def subjaxprs(eqn):
+        for p in eqn.params.values():
+            for pj in (p if isinstance(p, (tuple, list)) else (p,)):
+                pj = getattr(pj, "jaxpr", pj)  # closed or open
+                if hasattr(pj, "eqns"):
+                    yield pj
 
-    def walk(jx):
+    def count_exp(jx):
+        return sum((e.primitive.name == "exp")
+                   + sum(count_exp(sub) for sub in subjaxprs(e))
+                   for e in jx.eqns)
+
+    def loop_bodies(jx):
+        for e in jx.eqns:
+            if e.primitive.name in ("while", "scan"):
+                yield sum(count_exp(sub) for sub in subjaxprs(e))
+            else:
+                for sub in subjaxprs(e):
+                    yield from loop_bodies(sub)
+
+    def kernels(jx):
         for eqn in jx.eqns:
             if eqn.primitive.name == "pallas_call":
-                name = eqn.params["name"]
-                n = [0]
-                inner = eqn.params["jaxpr"]
-                count_exp(getattr(inner, "jaxpr", inner), n)
-                calls[name] = n[0]
-            for p in eqn.params.values():
-                pj = getattr(p, "jaxpr", None)
-                if pj is not None:
-                    walk(getattr(pj, "jaxpr", pj))
-    walk(jaxpr.jaxpr)
-    return {k: v for k, v in calls.items() if "bwd" in k}
+                yield eqn.params["name"], next(subjaxprs(eqn))
+            else:
+                for sub in subjaxprs(eqn):
+                    yield from kernels(sub)
+
+    def trace():
+        out, lse = fa.flash_attention_fwd(q, k, v, causal=True,
+                                          block_q=block, block_k=block,
+                                          interpret=True)
+        return jax.make_jaxpr(lambda *a: fa.flash_attention_bwd(
+            *a, causal=True, block_q=block, block_k=block,
+            bwd_block_q=block, bwd_block_k=block, interpret=True))(
+                q, k, v, out, lse, g).jaxpr
+
+    prev = fa._FLASH_BWD, fa._STATIC_WALK_ELEMS
+    fa._FLASH_BWD = mode
+    try:
+        unrolled = dict(kernels(trace()))
+        fa._STATIC_WALK_ELEMS = 0
+        looped = dict(kernels(trace()))
+    finally:
+        fa._FLASH_BWD, fa._STATIC_WALK_ELEMS = prev
+    census = {}
+    for name, jx in looped.items():
+        bodies = list(loop_bodies(jx))
+        per_tile = max(bodies)
+        if not list(loop_bodies(unrolled[name])):   # this kernel unrolls
+            exps = count_exp(unrolled[name])
+            per_tile = max(per_tile, -(-exps // tiles))
+        census[name] = {"loop_bodies": len(bodies),
+                        "exp_per_tile": per_tile}
+    return census
 
 
 def write_budgets(winners, args):
