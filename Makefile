@@ -1,10 +1,13 @@
 # Common workflows.  The CPU-simulated mesh flags are applied by each
 # entry point itself (tests/conftest.py pins cpu; examples take
-# --platform/--simulate-devices; bench/dryrun self-configure).
+# --platform/--simulate-devices; dryrun self-configures).
 
 PY := PYTHONPATH=$(CURDIR):$$PYTHONPATH python
 
-.PHONY: test chaos chaos-elastic chaos-fleet chaos-convert bench bench-smoke bench-input chip-smoke scaling scaling-gloo probe-input probe-bytes probe-flash probe-comm probe-autotune probe-serving probe-obs sweep-flash audit dryrun examples clean
+.PHONY: test chaos chaos-elastic chaos-fleet chaos-convert benchmark chip-smoke sweep-flash dryrun examples clean
+
+# the cell `make benchmark` runs (one entry of BENCHMARK.json's workloads)
+CELL ?= gpt2m-train-1chip
 
 test:
 	$(PY) -m pytest tests/ -x -q
@@ -47,84 +50,16 @@ chaos-convert:    ## capacity-transfer E2E (2-process gloo)
 	@# (tier-1 runs it too; this target is the focused repro loop).
 	$(PY) -m pytest tests/multiprocess_tests/test_capacity_chaos.py -q -m chaos
 
-bench:            ## real-hardware benchmark (one JSON line)
-	$(PY) bench.py
-
-bench-smoke:      ## CPU rehearsal of the bench mechanics (labelled rows)
-	JAX_PLATFORMS=cpu BENCH_BS=2 BENCH_SIZE=64 BENCH_STEPS=2 $(PY) bench.py
+benchmark:        ## one cell of BENCHMARK.json on the chip (CELL=<workload>)
+	python3 -m benchmark.run --workload $(CELL) --seed 1 --seconds 40 --trace 0
 
 chip-smoke:       ## the trainer and the serving engine, once, on the chip
 	$(PY) chip_smoke.py
 
-scaling:
-	$(PY) bench_scaling.py --platform cpu --simulate-devices 8 --per-chip-bs 4 --size 64 --steps 3
-
-scaling-gloo:     ## real cross-process compiled-DP + ZeRO curves (CPU gloo)
-	$(PY) bench_scaling.py --gloo-procs 1,2,4 --per-chip-bs 64 --steps 200
-	$(PY) bench_scaling.py --gloo-procs 1,2,4 --per-chip-bs 64 --steps 200 --gloo-zero
-
-probe-input:      ## host input-pipeline bandwidth at flagship scale (no chip)
-	PROBE=input_pipeline PROBE_PLATFORM=cpu $(PY) tools/probe_perf.py
-
-probe-bytes:      ## flagship HBM byte bill vs committed budget (no chip)
-	@# per-op-category bytes_accessed table + memory_analysis peaks for
-	@# the flagship ResNet-50 train step, checked against
-	@# tools/hbm_budgets.json (the tier-1 regression gate's data).
-	@# PROBE_COMPILE=0 skips backend codegen (lowered accounting only).
-	PROBE=hbm_bytes PROBE_PLATFORM=cpu $(PY) tools/probe_perf.py
-
-bench-input:      ## GIL-bound transform: MultiprocessIterator vs MultithreadIterator (no chip, no jax)
-	$(PY) tools/bench_input.py
-
-sweep-flash:      ## on-chip flash fwd/bwd/fwd+bwd tile sweep; regenerates tools/flash_budgets.json
-	@# the r5 BENCH_NOTES sweep methodology as one command.  On a
-	@# chip-less box this interpret-smokes clamped T and REFUSES the
-	@# budget rewrite (budgets are measured artifacts).
+sweep-flash:      ## on-chip flash fwd/bwd/fwd+bwd tile sweep; rewrites tools/flash_budgets.json's sweep section
+	@# On a chip-less box this interpret-smokes clamped T and REFUSES
+	@# the budget rewrite (budgets are measured artifacts).
 	$(PY) tools/flash_sweep.py --write-budgets
-
-probe-flash:      ## committed flash budgets joined with live fused-vs-split rows (cpu = smoke)
-	PROBE=flash PROBE_PLATFORM=cpu $(PY) tools/probe_perf.py
-
-probe-serving:    ## committed serving budgets + live decode/prefill census + per-phase + fleet tables (no chip)
-	@# decode: one gather per pool per layer through the block table,
-	@# no [T, T] score dot; prefill: flash forward kernels, zero bwd
-	@# kernels — joined with tools/serving_budgets.json (the tier-1
-	@# gate tests/test_serving_budget.py's data) and the decode
-	@# roofline byte table; plus the ISSUE 15 fleet table (one row per
-	@# replica seat: live, queue depth, routed/reroute counters) from a
-	@# tiny live 2-replica fleet with one replica preempted mid-load.
-	PROBE=serving PROBE_PLATFORM=cpu $(PY) tools/probe_perf.py
-
-probe-obs:        ## runtime observability join: trace schema + merged metrics registry (no chip)
-	@# runs a tiny seeded trainer + one serving request with the span
-	@# tracer on (CHAINERMN_TPU_TRACE=events), validates the exported
-	@# Chrome-trace shard against the committed schema, round-trips it
-	@# through tools/trace_merge.py, and renders the rank-merged
-	@# metrics registry in Prometheus text format (docs/observability.md).
-	PROBE=obs PROBE_PLATFORM=cpu $(PY) tools/probe_perf.py
-
-probe-comm:       ## committed gradient-exchange budgets + live per-bucket/per-hop tables (no chip)
-	@# jaxpr collective census per exchange config (per_leaf / flat /
-	@# bucketed / bucketed_bf16 / reduce_scatter / hierarchical*)
-	@# joined with tools/comm_budgets.json, the live bucket plan at
-	@# PROBE_BUCKET_MB (default 4), and the hierarchical configs'
-	@# per-hop table (hop, collective, bytes, dtype) on the simulated
-	@# 2-host split.  Trace property — chip-free.
-	PROBE=comm PROBE_PLATFORM=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8 $$XLA_FLAGS" $(PY) tools/probe_perf.py
-
-probe-autotune:   ## committed autotune plan artifact + live micro-bench/derivation (no chip)
-	@# the startup fabric micro-bench on the simulated 8-device mesh,
-	@# the plan it derives (fingerprint, bucket_mb, stripe_ratio,
-	@# grad_dtype + derivation notes), the join against
-	@# tools/autotune_plan.json (the tier-1 gate
-	@# tests/test_autotune_plan.py's data), and the per-knob provenance
-	@# table (plan value / hand-set / applied).  CPU-sim numbers are
-	@# labeled mechanics-only — the artifact's numeric half is stamped
-	@# only from a run on the real fabric.
-	PROBE=autotune PROBE_PLATFORM=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8 $$XLA_FLAGS" $(PY) tools/probe_perf.py
-
-audit:            ## StableHLO dtype census, resnet + transformer (no chip)
-	PROBE=precision_audit $(PY) tools/probe_perf.py
 
 dryrun:
 	$(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8)"

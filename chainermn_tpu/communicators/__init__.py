@@ -74,7 +74,6 @@ __all__ = ["create_communicator", "CommunicatorBase", "MeshCommunicator",
            "ChannelError", "ChannelTimeoutError", "PeerLostError",
            "HostChannel", "HeartbeatMonitor",
            "ElasticMembership", "MembershipView", "multicast_tree_plan",
-           "EXCHANGES", "exchange_knobs",
            "agree_exchange_plan", "derive_exchange_plan", "measure_fabric",
            "measurements_from_trace", "plan_fingerprint", "record_plan",
            "reduce_measurements", "retune_communicator",
@@ -83,50 +82,6 @@ __all__ = ["create_communicator", "CommunicatorBase", "MeshCommunicator",
 _NAMES = ("naive", "flat", "hierarchical", "two_dimensional", "single_node",
           "non_cuda_aware", "pure_nccl", "jax_ici", "dummy", "debug",
           "fault")
-
-#: gradient-exchange vocabulary shared by bench rows, the gloo A/B, and
-#: tools/comm_budgets.json configs
-EXCHANGES = ("per_leaf", "flat", "bucketed", "reduce_scatter",
-             "hierarchical", "hierarchical_rs", "striped", "striped_rs")
-
-
-def exchange_knobs(exchange):
-    """``(communicator name, batch_collectives, optimizer exchange=)``
-    triple for a named gradient-exchange structure — the ONE mapping
-    bench.py's on-chip rows and bench_scaling.py's gloo A/B share, so
-    the same name always measures the same collective structure on both
-    surfaces.  ``reduce_scatter`` keeps a flat communicator: the
-    optimizer-level step variant owns its collective structure (the
-    communicator's packing only affects eager-mode collectives there).
-    ``hierarchical`` is the two-level (ici × dcn) allreduce exchange;
-    ``hierarchical_rs`` composes it with the reduce-scatter DP update
-    (both hops reduce-scatter the gradient, both all-gather the
-    params).  ``striped``/``striped_rs`` (ISSUE 11) are the multi-path
-    variants of those two: same communicator name, but the caller must
-    additionally pass a nonzero ``stripe_ratio`` to
-    ``create_communicator`` (bench surfaces default it to
-    ``DEFAULT_STRIPE_RATIO`` / the ``BENCH_STRIPE_RATIO`` /
-    ``CHAINERMN_TPU_STRIPE_RATIO`` knobs) — a zero ratio would silently
-    measure the strict hierarchical schedule under the striped name."""
-    try:
-        name, bc = {
-            "per_leaf": ("jax_ici", False),
-            "flat": ("jax_ici", True),
-            "bucketed": ("jax_ici", "bucketed"),
-            "reduce_scatter": ("jax_ici", True),
-            "hierarchical": ("hierarchical", True),
-            "hierarchical_rs": ("hierarchical", True),
-            "striped": ("hierarchical", True),
-            "striped_rs": ("hierarchical", True),
-        }[exchange]
-    except KeyError:
-        raise ValueError(f"unknown exchange {exchange!r} "
-                         f"({'|'.join(EXCHANGES)})") from None
-    return name, bc, ("reduce_scatter"
-                      if exchange in ("reduce_scatter", "hierarchical_rs",
-                                      "striped_rs")
-                      else "allreduce")
-
 
 def create_communicator(communicator_name="jax_ici", devices=None,
                         axis_name="mn_world", allreduce_grad_dtype=None,
@@ -172,9 +127,8 @@ def create_communicator(communicator_name="jax_ici", devices=None,
     multi-path exchange — that slice runs the transposed slow-hop-major
     exchange concurrently with the fast-hop-major remainder, so both
     fabrics carry bulk traffic at once instead of hierarchically
-    (docs/performance.md §10; 0 = the strict hierarchical schedule;
-    the committed per-topology value comes from the ``bench_scaling``
-    striped ratio sweep).  ``CHAINERMN_TPU_HIERARCHY=flat`` collapses
+    (docs/performance.md §10; 0 = the strict hierarchical schedule).
+    ``CHAINERMN_TPU_HIERARCHY=flat`` collapses
     ``hierarchical``/``two_dimensional`` back to the flat one-axis
     alias (sizes ignored, striping dropped — one fabric has no second
     path) — the no-code-change escape hatch.

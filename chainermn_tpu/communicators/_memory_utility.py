@@ -229,10 +229,9 @@ def stripe_plan(n_elems, ratio):
       ``ratio == 1`` routes the whole payload over the slow-hop-major
       path (the flat-one-fabric shape with DCN as the bulk wire).
 
-    The ratio itself is a committed per-topology constant (like
-    ``bucket_mb``): the ``bench_scaling --gloo-exchange striped`` ratio
-    sweep measures the real bandwidth split on ≥2 hosts and first chip
-    contact commits the winner.
+    The ratio itself is a per-topology constant (like ``bucket_mb``):
+    the split that equalizes the two paths' finish times is the ratio of
+    the fabrics' measured bandwidths (:func:`derived_stripe_ratio`).
     """
     if not 0.0 <= ratio <= 1.0:
         raise ValueError(f"stripe ratio must be in [0, 1], got {ratio}")
@@ -629,9 +628,9 @@ def striped_exchanged_bytes(n_bytes, intra_size, inter_size, ratio,
     chunk crossing is always priced at f32 — the transform upcasts it
     before the fast-hop allreduce (lossless-over-ICI by design).
 
-    This is the ONE per-path pricing surface: bench.py's striped rows
-    route through it, so the committed identities and the bench
-    columns cannot drift apart.
+    This is the ONE per-path pricing of the striped exchange: the
+    committed census identities (tools/comm_budgets.json) are held to
+    it.
     """
     elems = n_bytes // itemsize
     if elems * itemsize != n_bytes:
@@ -684,8 +683,8 @@ def moe_dispatch_exchanged_bytes(n_bytes, intra_size, inter_size,
       (``n·(E−1)/E``), one fabric label, unsplittable and
       uncompressible per hop.  Returns ``{"world": ...}``.
 
-    This is the ONE pricing surface bench.py's MoE rows and the
-    committed MoE census identities share.
+    This is the ONE pricing of the MoE dispatch: the committed MoE
+    census identities (tools/comm_budgets.json) are held to it.
     """
     if two_stage:
         ici = exchanged_bytes(n_bytes, intra_size, "all_to_all")

@@ -203,8 +203,7 @@ class FailureRecovery(Extension):
         """Fold :attr:`stats` into the observability registry (ISSUE
         14): the supervisor's lifetime telemetry — recoveries,
         generation bumps, and the elastic resize/rank-churn counts —
-        become gauges a ``PROBE=obs`` render (or a real scraper) reads
-        next to the subsystem counters.  No-op when observability is
+        become gauges a scraper reads next to the subsystem counters.  No-op when observability is
         off."""
         if not observability.ring_enabled():
             return

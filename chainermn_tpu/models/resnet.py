@@ -17,11 +17,10 @@ Freshly designed for TPU rather than transcribed:
   projection (option B, the ResNet-50 default), all fusible.
 * ``input_norm="imagenet"`` moves input normalization IN-GRAPH: the host
   pipeline ships raw uint8 pixels and the cast + per-channel standardize
-  fuses into the first conv on device.  Measured motivation (BENCH_NOTES
-  r5 input-pipeline probe): host-side float32 casting caps the one-core
-  input pipeline at ~2.6k img/s — below the 25-30% MFU target's ~4.5k
-  img/s demand — while the uint8 gather sustains ~9k img/s; shipping
-  uint8 also cuts host→HBM DMA traffic 4×.
+  fuses into the first conv on device.  Motivation: the host-side
+  float32 cast is what bounds a one-core input pipeline, the uint8
+  gather is several times cheaper, and shipping uint8 also cuts
+  host→HBM DMA traffic 4×.
 """
 
 from __future__ import annotations

@@ -9,8 +9,7 @@ Two host-side pieces every subsystem shares:
   ``tools/trace_merge.py``) under ``CHAINERMN_TPU_TRACE=off|events``;
 * :mod:`~chainermn_tpu.observability.metrics` — a mergeable registry
   of counters/gauges/fixed-bucket histograms, joined across ranks over
-  the object collectives and rendered in Prometheus text format
-  (``PROBE=obs`` / ``make probe-obs``).
+  the object collectives and rendered in Prometheus text format.
 
 Span classification, knob ladder, and the merge workflow:
 ``docs/observability.md``.

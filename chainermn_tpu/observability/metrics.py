@@ -14,8 +14,7 @@ sites (trainer step phases, serving scheduler, elastic supervisor) and:
 * **dumped in Prometheus text exposition format**
   (:meth:`to_prometheus` — ``# HELP``/``# TYPE`` + samples, histograms
   as cumulative ``_bucket{le=...}`` / ``_sum`` / ``_count``), which is
-  what ``PROBE=obs`` renders and what a real deployment's scraper
-  ingests unchanged.
+  what a real deployment's scraper ingests unchanged.
 
 Histograms use FIXED bucket bounds chosen at construction (the
 Prometheus discipline): merging is then bucket-wise addition, exact —
@@ -302,8 +301,7 @@ class MetricsRegistry:
     # -- export --------------------------------------------------------------
 
     def to_prometheus(self):
-        """Text exposition format (the scrape payload / PROBE=obs
-        rendering)."""
+        """Text exposition format (the scrape payload)."""
         lines = []
         for name, m in sorted(self.metrics().items()):
             if m.help:

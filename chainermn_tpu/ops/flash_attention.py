@@ -11,14 +11,11 @@ tiles, softmax runs online (running max/normalizer), and the MXU sees one
 jnp reference elsewhere (CPU tests run the kernel in interpreter mode to
 pin kernel↔reference equivalence).
 
-The backward is a FUSED one-pass kernel by default
+The backward is ONE fused one-pass kernel
 (:func:`_flash_bwd_fused_kernel`): each (qi, ki) attention tile is
 recomputed once — s = q·kᵀ, p = exp(s − lse) — and feeds all three
 gradients (dk/dv accumulate across the query loop, dq on the chip
 across the key tiles; each leaves the kernel once).
-The legacy two-kernel lowering (one dq pass + one dkv pass, each
-recomputing the tile) stays available bit-for-bit behind
-``CHAINERMN_TPU_FLASH_BWD=split``.
 
 Causal calls walk the lower triangle only: a kernel skips the tiles
 wholly above the diagonal, runs an unmasked body on the tiles wholly at
@@ -26,8 +23,9 @@ or below it and a masked body on the tiles it crosses
 (:func:`_causal_k_tiles` / :func:`_causal_q_tiles`).  So tiles under the
 sequence length are what makes a causal call cheap; the log-sum-exp
 forward and the backward resolve theirs from a chip sweep keyed on what
-the call shows (T, D, causal: :data:`_CAUSAL_BLOCK_TABLE`), each with
-its own env knobs for the next sweep (`make sweep-flash`).
+the call shows (T, D, causal: :data:`_CAUSAL_BLOCK_TABLE`).  Every tile
+is decided in :func:`_flash_tiles`; the next sweep passes its tiles as
+arguments (`make sweep-flash`).
 
 Ring-attention composition: ``parallel.ring_attention`` rotates KV blocks
 between chips; within a chip this kernel computes each block's
@@ -180,8 +178,8 @@ def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
     and keys from 0, so every row sees key 0, key tile 0 is the first
     one walked (in whichever body), and the running max is finite from
     there on; exp(-inf) = 0 does the rest.  A non-causal call (ring
-    blocks, ``Tq != Tk``) masks nothing at all.  `_flash_kernel` and the
-    split backward keep their guards."""
+    blocks, ``Tq != Tk``) masks nothing at all.  `_flash_kernel` keeps
+    its guards."""
     d = q_ref.shape[-1]
     n_kblocks = k_ref.shape[0] // block_k
     fold = _scale_folds(scale)
@@ -249,109 +247,14 @@ def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
                pl.ds(j * block_q, block_q))
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                         dq_ref, *, block_k, causal, scale):
-    """dq for one query block: recompute P from (q, k, lse); then
-    dq = scale * sum_j (P_ij (g_i·v_j - delta_i)) k_j."""
-    bq, d = q_ref.shape
-    tk = k_ref.shape[0]
-    qi = pl.program_id(1)
-    q = q_ref[:]          # storage dtype into the dots (see fwd kernel)
-    g = g_ref[:]
-    lse = lse_ref[:].reshape(bq, 1)   # block arrives [bq, 1]
-    delta = delta_ref[:].reshape(bq, 1)
-    n_kblocks = tk // block_k
-    q_pos = (qi * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0))
-    dq = jnp.zeros((bq, d), jnp.float32)
-
-    def body(ki, dq):
-        k_blk = k_ref[pl.ds(ki * block_k, block_k), :]
-        v_blk = v_ref[pl.ds(ki * block_k, block_k), :]
-        s = jax.lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            k_pos = (ki * block_k
-                     + lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse), 0.0)
-        gv = jax.lax.dot_general(g, v_blk, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (gv - delta)
-        return dq + jax.lax.dot_general(
-            ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        last = jnp.minimum((qi * bq + bq + block_k - 1) // block_k,
-                           n_kblocks)
-    else:
-        last = n_kblocks
-    dq = jax.lax.fori_loop(0, last, body, dq)
-    dq_ref[:] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, *, block_q, causal, scale):
-    """dk/dv for one key block: loop over query blocks;
-    dv = P^T g ; dk = scale * sum_i (P_ij (g_i·v_j - delta_i)) q_i."""
-    bk, d = k_ref.shape
-    tq = q_ref.shape[0]
-    ki = pl.program_id(1)
-    k = k_ref[:]          # storage dtype into the dots (see fwd kernel)
-    v = v_ref[:]
-    n_qblocks = tq // block_q
-    k_pos = (ki * bk + lax.broadcasted_iota(jnp.int32, (1, bk), 1))
-    dk = jnp.zeros((bk, d), jnp.float32)
-    dv = jnp.zeros((bk, d), jnp.float32)
-
-    def body(qi, carry):
-        dk, dv = carry
-        q_blk = q_ref[pl.ds(qi * block_q, block_q), :]
-        g_blk = g_ref[pl.ds(qi * block_q, block_q), :]
-        lse = lse_ref[pl.ds(qi * block_q, block_q), :] \
-            .reshape(block_q, 1)
-        delta = delta_ref[pl.ds(qi * block_q, block_q), :] \
-            .reshape(block_q, 1)
-        s = jax.lax.dot_general(q_blk, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal:
-            q_pos = (qi * block_q
-                     + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
-            s = jnp.where(q_pos >= k_pos, s, -jnp.inf)
-        p = jnp.where(jnp.isfinite(s), jnp.exp(s - lse), 0.0)
-        dv = dv + jax.lax.dot_general(
-            p.astype(g_blk.dtype), g_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        gv = jax.lax.dot_general(g_blk, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (gv - delta)
-        dk = dk + jax.lax.dot_general(
-            ds.astype(q_blk.dtype), q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return dk, dv
-
-    if causal:
-        # query blocks at or after this key block participate
-        first = (ki * bk) // block_q
-    else:
-        first = 0
-    dk, dv = jax.lax.fori_loop(first, n_qblocks, body, (dk, dv))
-    # ds was computed from UNSCALED q·k products with scale folded into s,
-    # so dk = scale · Σ ds·q (the fwd scale that s carries)
-    dk_ref[:] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
-
-
 def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
                             dq_ref, dk_ref, dv_ref, *dq_acc_ref,
                             block_q, block_k, causal, scale, static_walk):
     """Fused backward: ONE pass over the (qi, ki) tiles per key tile.
 
-    The split lowering (`_flash_bwd_dq_kernel` + `_flash_bwd_dkv_kernel`)
-    recomputes the attention block twice: each kernel re-runs the
-    s = q·kᵀ dot, the mask, exp(s − lse) and the g·vᵀ dot for every tile
-    it touches.  Here each (qi, ki) tile is recomputed ONCE and all three
-    gradient contributions leave together:
+    Each (qi, ki) tile is recomputed ONCE (the s = q·kᵀ dot, the mask,
+    exp(s − lse), the g·vᵀ dot) and all three gradient contributions
+    leave together:
 
         dv  += pᵀ g           (carried over this key tile's query loop)
         dk  += dsᵀ q          (carried over this key tile's query loop)
@@ -375,9 +278,8 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     the forward: the tiles the diagonal crosses pay two iotas, a compare
     and a select on p; the others pay nothing.  ``lse`` is finite for
     every row (see `_flash_kernel_lse`), so no ``isfinite`` either.  Per
-    tile the split lowering runs 8 MXU dots + 2 exp's; this runs 5 dots
-    + 1 exp — the recompute-once argument in docs/performance.md
-    quantifies it.
+    tile: 5 MXU dots + 1 exp (a dq pass and a dkv pass of their own
+    would run 8 + 2; docs/performance.md section 6).
     """
     tq, d = q_ref.shape
     n_qblocks = tq // block_q
@@ -521,14 +423,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, scale,
 
 # Adaptive-default tile candidates, largest first: a tile is the largest
 # candidate that divides T.  This is what the serving forward
-# (`_flash_kernel`: one dynamic loop a query tile) and the split pair
-# resolve, and what a length no sweep has visited keeps.  Every loop
-# iteration there costs about 0.4 us of latency that nothing hides
-# (128 x 128 tiles at T = 1024, D = 64: 0.98 ms a forward call against
-# 0.35 with one 1024 x 1024 tile; chip sweep, PR 28), so under dynamic
-# loops the largest tile wins although a causal head then computes its
-# whole square.  VMEM cost at 1024: the f32 score/probability tiles are
-# 4 MB each, inside the kernel's 100 MB scoped-VMEM cap.
+# (`_flash_kernel`: one dynamic loop a query tile) resolves, and what a
+# length no sweep has visited keeps.  Every loop iteration there costs
+# about 0.4 us of latency that nothing hides (128 x 128 tiles at
+# T = 1024, D = 64: 0.98 ms a forward call against 0.35 with one
+# 1024 x 1024 tile; chip sweep, PR 28), so under dynamic loops the
+# largest tile wins although a causal head then computes its whole
+# square.  VMEM cost at 1024: the f32 score/probability tiles are 4 MB
+# each, inside the kernel's 100 MB scoped-VMEM cap.
 _BLOCK_CANDIDATES = (1024, 512, 256, 128)
 
 
@@ -544,42 +446,6 @@ def _adaptive_block(t):
     return 128
 
 
-def _resolve_blocks(axes):
-    """One tile size for each ``(env knob, explicit argument, default)``:
-    the argument wins, else the knob (so an on-chip session can A/B
-    block shapes without code edits), else the default.  Env changes
-    only affect programs traced AFTERWARDS — jit caches are not keyed on
-    them, so run each configuration in a fresh process (the probe does).
-    Values must be positive multiples of 8 (Mosaic sublane tiling)."""
-    out = []
-    for name, given, default in axes:
-        if given is None:
-            raw = os.environ.get(name)
-            if raw is None:
-                given = default
-            else:
-                try:
-                    given = int(raw)
-                except ValueError:
-                    raise ValueError(f"{name}={raw!r} is not an integer")
-                if given <= 0 or given % 8:
-                    raise ValueError(
-                        f"{name}={given} invalid: flash block sizes must "
-                        "be positive multiples of 8")
-        out.append(given)
-    return tuple(out)
-
-
-def _flash_blocks(block_q=None, block_k=None, tq=None, tk=None):
-    """Tiles of the serving forward (`flash_attention`) and of the split
-    backward, and the shape-validated pair every custom VJP carries in
-    its residuals: arguments, else CHAINERMN_TPU_FLASH_BLOCK_Q/K, else
-    :func:`_adaptive_block` over the given Tq/Tk."""
-    return _resolve_blocks((
-        ("CHAINERMN_TPU_FLASH_BLOCK_Q", block_q, _adaptive_block(tq)),
-        ("CHAINERMN_TPU_FLASH_BLOCK_K", block_k, _adaptive_block(tk))))
-
-
 #: What the chip sweep chose for a CAUSAL call with Tq == Tk == T, keyed
 #: by what the call shows, (T, D): ``{"fwd": (block_q, block_k)}`` for
 #: the log-sum-exp forward, ``"bwd"`` for the fused backward.  At
@@ -588,78 +454,36 @@ def _flash_blocks(block_q=None, block_k=None, tq=None, tk=None):
 #: and ran the forward in 0.16 ms a call and the backward in 0.35
 #: (1024 x 1024: 0.20 and 0.45; the parent's kernels 0.37 and 0.53);
 #: every pair tried is in tools/flash_budgets.json.  Only walks that
-#: :func:`_static_walk` unrolls gain from tiles under T.
+#: :func:`_static_walk` unrolls gain from tiles under T: at T = 1024
+#: the backward's dynamic walk read 0.52 ms a call with one
+#: 1024 x 1024 tile and 0.53-0.57 with smaller ones (chip sweep, PR 28).
+#: Lengths 2048-16384 are unswept and keep :func:`_adaptive_block`.
 _CAUSAL_BLOCK_TABLE = {
     (1024, 64): {"fwd": (256, 256), "bwd": (256, 256)},
 }
 
 
-def _swept_causal_blocks(leg, tq, tk, d, causal):
-    entry = _CAUSAL_BLOCK_TABLE.get((tq, d)) if causal and tq == tk \
-        else None
-    return entry[leg] if entry else (None, None)
+def _flash_tiles(leg, tq, tk, d=None, causal=False, block_q=None,
+                 block_k=None):
+    """THE tile decision, a function of what the call shows: an explicit
+    argument, else what the chip sweep chose for this ``leg`` (``"fwd"``:
+    the log-sum-exp forward, ``"bwd"``: the fused backward) of a causal
+    ``Tq == Tk`` call (:data:`_CAUSAL_BLOCK_TABLE`), else
+    :func:`_adaptive_block`.  ``leg=None`` (the serving forward, which is
+    shown neither D nor causal) is never in the table."""
+    swept = _CAUSAL_BLOCK_TABLE.get((tq, d), {}).get(leg) \
+        if causal and tq == tk else None
+    default_q, default_k = swept or (_adaptive_block(tq),
+                                     _adaptive_block(tk))
+    return (default_q if block_q is None else block_q,
+            default_k if block_k is None else block_k)
 
 
-def _flash_lse_blocks(block_q=None, block_k=None, tq=None, tk=None,
-                      d=None, causal=False):
-    """Tiles of the log-sum-exp forward: arguments, else the same env
-    knobs as :func:`_flash_blocks`, else the swept table
-    (:data:`_CAUSAL_BLOCK_TABLE`), else the adaptive default."""
-    sq, sk = _swept_causal_blocks("fwd", tq, tk, d, causal)
-    return _resolve_blocks((
-        ("CHAINERMN_TPU_FLASH_BLOCK_Q", block_q, sq or _adaptive_block(tq)),
-        ("CHAINERMN_TPU_FLASH_BLOCK_K", block_k, sk or _adaptive_block(tk))))
-
-
-# -- backward lowering selection ---------------------------------------------
-
-#: CHAINERMN_TPU_FLASH_BWD: "fused" (default) = the one-pass dq/dkv
-#: kernel; "split" = the legacy two-kernel lowering (dq pass + dkv pass,
-#: each recomputing the attention block) — the escape hatch, kept
-#: exactly like nn.functions' CHAINERMN_TPU_MAXPOOL_VJP=xla: read once
-#: at import, monkeypatchable in tests, and the legacy kernels are
-#: untouched so `split` restores the old lowering bit-for-bit.
-_FLASH_BWD = os.environ.get("CHAINERMN_TPU_FLASH_BWD", "fused")
-
-#: Backward tiles by sequence length, for the calls
-#: :data:`_CAUSAL_BLOCK_TABLE` does not cover.  Never swept on this
-#: chip: the rows say what `_adaptive_block` would, and stand for the
-#: sweep to fill (`make sweep-flash` times fwd/bwd/fwd+bwd per
-#: (block_q, block_k); paste the winners here and into
-#: tools/flash_budgets.json).  At T = 1024 the dynamic walk read 0.52 ms
-#: a call with one 1024 x 1024 tile and 0.53-0.57 with smaller ones
-#: (chip sweep, PR 28).
-_BWD_BLOCK_TABLE = {
-    1024: (1024, 1024),
-    2048: (1024, 1024),
-    8192: (1024, 1024),
-    16384: (1024, 1024),
-}
-
-
-def _flash_bwd_mode():
-    mode = _FLASH_BWD
-    if mode not in ("fused", "split"):
-        raise ValueError(
-            f"CHAINERMN_TPU_FLASH_BWD={mode!r} invalid (fused|split)")
-    return mode
-
-
-def _flash_bwd_blocks(block_q=None, block_k=None, tq=None, tk=None,
-                      d=None, causal=False):
-    """Fused-backward tile resolution: arguments, else the
-    CHAINERMN_TPU_FLASH_BWD_BLOCK_Q/K env knobs, else the swept table
-    (:data:`_CAUSAL_BLOCK_TABLE`), else the per-T table
-    (:data:`_BWD_BLOCK_TABLE`), else the forward's shape-adaptive
-    default."""
-    swept = _swept_causal_blocks("bwd", tq, tk, d, causal)
-    defaults = [
-        swept[i] or (_BWD_BLOCK_TABLE[t][i] if t in _BWD_BLOCK_TABLE
-                     else _adaptive_block(t))
-        for i, t in enumerate((tq, tk))]
-    return _resolve_blocks((
-        ("CHAINERMN_TPU_FLASH_BWD_BLOCK_Q", block_q, defaults[0]),
-        ("CHAINERMN_TPU_FLASH_BWD_BLOCK_K", block_k, defaults[1])))
+def _flash_blocks(block_q=None, block_k=None, tq=None, tk=None):
+    """Tiles of the serving forward (`flash_attention`), and the pair a
+    shape is validated with before it is given to the kernels: arguments,
+    else :func:`_adaptive_block` over the given Tq/Tk."""
+    return _flash_tiles(None, tq, tk, block_q=block_q, block_k=block_k)
 
 
 def _on_tpu():
@@ -703,7 +527,7 @@ def _warn_fallback(q, k, path):
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=False):
     """Fused attention via Pallas.  q/k/v: [B, H, T, D].  Default block
-    sizes come from :func:`_flash_blocks` (env-tunable)."""
+    sizes come from :func:`_flash_blocks`."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
@@ -778,13 +602,13 @@ def _lse_forward_call(qr, kr, vr, *, block_q, block_k, causal, scale,
 def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=None,
                         block_k=None, interpret=False):
     """Forward kernel returning (out, lse [B, H, Tq]).  Tiles come from
-    :func:`_flash_lse_blocks`; the callers have validated that the
-    shape tiles."""
+    :func:`_flash_tiles`; the callers have validated that the shape
+    tiles."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
-    block_q, block_k = _flash_lse_blocks(block_q, block_k, tq=Tq, tk=Tk,
-                                         d=D, causal=causal)
+    block_q, block_k = _flash_tiles("fwd", Tq, Tk, D, causal, block_q,
+                                    block_k)
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
     out, lse = _lse_forward_call(
@@ -838,24 +662,22 @@ def _fused_backward_call(qr, kr, vr, gr, lser, delta, *, block_q, block_k,
 def flash_attention_bwd(q, k, v, out, lse, g, causal=False, scale=None,
                         block_q=None, block_k=None, interpret=False,
                         g_lse=None, bwd_block_q=None, bwd_block_k=None):
-    """Backward: (dq, dk, dv) with flash memory behavior.
-
-    Default lowering is the FUSED one-pass kernel
-    (:func:`_flash_bwd_fused_kernel`): one recompute of each (qi, ki)
-    attention tile feeds dq, dk and dv together, with its own
-    sweep-tunable tiles (``bwd_block_q``/``bwd_block_k`` →
-    :func:`_flash_bwd_blocks`).  ``CHAINERMN_TPU_FLASH_BWD=split``
-    restores the legacy two-kernel lowering (a dq pass and a dkv pass,
-    each recomputing exp(q·kᵀ − lse)) bit-for-bit — the escape hatch,
-    same contract as PR 3's ``MAXPOOL_VJP=xla``.
+    """Backward: (dq, dk, dv) with flash memory behavior, through the
+    fused one-pass kernel (:func:`_flash_bwd_fused_kernel`): one
+    recompute of each (qi, ki) attention tile feeds dq, dk and dv
+    together.  Its tiles are ``bwd_block_q``/``bwd_block_k``, else what
+    :func:`_flash_tiles` resolves for the ``"bwd"`` leg;
+    ``block_q``/``block_k`` are the pair the shape was validated with
+    (:func:`_flash_blocks`) and take over where the backward's own tiles
+    do not divide this T (ragged lengths reached with explicit forward
+    blocks).
 
     ``g_lse``: optional cotangent of the lse output.  Since
     ∂lse_i/∂s_ij = p_ij, its whole contribution is ``ds += g_lse_i * p``
     — algebraically identical to replacing ``delta`` with
-    ``delta - g_lse`` in the kernels (``ds = p*(gv - delta)``), so no
-    kernel changes are needed on either path.  Ring attention depends on
-    this: the cross-block merge weights are functions of each block's
-    lse."""
+    ``delta - g_lse`` in the kernel (``ds = p*(gv - delta)``), so the
+    kernel needs no change.  Ring attention depends on this: the
+    cross-block merge weights are functions of each block's lse."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
@@ -874,66 +696,16 @@ def flash_attention_bwd(q, k, v, out, lse, g, causal=False, scale=None,
     if g_lse is not None:
         delta = delta - g_lse.reshape(B * H, Tq, 1).astype(jnp.float32)
 
-    if _flash_bwd_mode() == "fused":
-        # bwd-specific tiles; the (already shape-validated) forward
-        # tiles are the fallback when the table/env tiles don't divide
-        # this T — e.g. ragged lengths reached with explicit fwd blocks
-        bq, bk = _flash_bwd_blocks(bwd_block_q, bwd_block_k,
-                                   tq=Tq, tk=Tk, d=D, causal=causal)
-        bq = min(bq, Tq)
-        bk = min(bk, Tk)
-        if Tq % bq or Tk % bk:
-            bq, bk = block_q, block_k
-        dq, dk, dv = _fused_backward_call(
-            qr, kr, vr, gr, lser, delta, block_q=bq, block_k=bk,
-            causal=causal, scale=scale, interpret=interpret,
-            static_walk=_static_walk(Tq, Tk, bq, bk, causal))
-        return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
-                dv.reshape(B, H, Tk, D))
-
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_k=block_k,
-                          causal=causal, scale=scale),
-        name="_flash_bwd_dq_kernel",
-        grid=(B * H, Tq // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-        interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
-    )(qr, kr, vr, gr, lser, delta)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkv_kernel, block_q=block_q,
-                          causal=causal, scale=scale),
-        name="_flash_bwd_dkv_kernel",
-        grid=(B * H, Tk // block_k),
-        in_specs=[
-            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tq, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tq, 1), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype),
-        ],
-        interpret=interpret,
-        compiler_params=_COMPILER_PARAMS,
-    )(qr, kr, vr, gr, lser, delta)
+    bq, bk = _flash_tiles("bwd", Tq, Tk, D, causal, bwd_block_q,
+                          bwd_block_k)
+    bq = min(bq, Tq)
+    bk = min(bk, Tk)
+    if Tq % bq or Tk % bk:
+        bq, bk = block_q, block_k
+    dq, dk, dv = _fused_backward_call(
+        qr, kr, vr, gr, lser, delta, block_q=bq, block_k=bk,
+        causal=causal, scale=scale, interpret=interpret,
+        static_walk=_static_walk(Tq, Tk, bq, bk, causal))
     return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
             dv.reshape(B, H, Tk, D))
 
@@ -951,30 +723,24 @@ def _flash_diff_fwd(q, k, v, causal, scale, interpret):
         # irregular shapes: XLA fallback for both directions
         _warn_fallback(q, k, "XLA attention (forward and backward)")
         out = xla_attention(q, k, v, causal=causal, scale=scale)
-        return out, (q, k, v, None, None, None)
+        return out, (q, k, v, None, None)
     # the log-sum-exp forward resolves its own tiles (swept where the
-    # call's shape was, else these)
+    # call's shape was, else these); so does the backward, from the same
+    # shapes
     out, lse = flash_attention_fwd(q, k, v, causal=causal, scale=scale,
                                    interpret=interpret)
-    # carry the validated pair in the residuals: the backward's SHAPE
-    # validation must use the exact tiles this shape was validated with
-    # (they are the fused path's divisibility fallback and the split
-    # path's tiles; re-reading the fwd env there would silently corrupt
-    # gradients if it changed mid-process)
-    return out, (q, k, v, out, lse, (bq, bk))
+    return out, (q, k, v, out, lse)
 
 
 def _flash_diff_bwd(causal, scale, interpret, res, g):
-    q, k, v, out, lse, blocks = res
+    q, k, v, out, lse = res
     if lse is None:
         _, vjp = jax.vjp(
             lambda q, k, v: xla_attention(q, k, v, causal=causal,
                                           scale=scale), q, k, v)
         return vjp(g)
-    bq, bk = blocks
     return flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
-                               scale=scale, block_q=bq, block_k=bk,
-                               interpret=interpret)
+                               scale=scale, interpret=interpret)
 
 
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
@@ -1063,18 +829,15 @@ def _flash_lse_diff(q, k, v, causal, scale, interpret):
 def _flash_lse_fwd(q, k, v, causal, scale, interpret):
     out, lse = flash_attention_fwd(q, k, v, causal=causal, scale=scale,
                                    interpret=interpret)
-    # same residual-carried block config as _flash_diff: the validated
-    # pair is the backward's divisibility fallback and the split tiles
-    return (out, lse), (q, k, v, out, lse,
-                        _flash_blocks(tq=q.shape[2], tk=k.shape[2]))
+    return (out, lse), (q, k, v, out, lse)
 
 
 def _flash_lse_bwd(causal, scale, interpret, res, cots):
-    q, k, v, out, lse, (bq, bk) = res
+    q, k, v, out, lse = res
     g, g_lse = cots
     return flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
-                               scale=scale, block_q=bq, block_k=bk,
-                               interpret=interpret, g_lse=g_lse)
+                               scale=scale, interpret=interpret,
+                               g_lse=g_lse)
 
 
 _flash_lse_diff.defvjp(_flash_lse_fwd, _flash_lse_bwd)

@@ -1231,7 +1231,7 @@ class _MultiNodeOptimizer:
         forward/backward/allreduce/update iterations per host dispatch,
         so per-step host and dispatch latency is amortized K-fold (the
         TPU-idiomatic equivalent of the reference's tight C-level update
-        loop; measured in BENCH_NOTES "fused multi-step").
+        loop).
 
         Returns the per-step loss array of shape ``(K,)``.  Reported
         observations are the MEAN over the K steps (what a LogReport

@@ -71,10 +71,9 @@ __all__ = ["switch_moe", "moe_dispatch_combine", "moe_dispatch_combine_topk",
 def moe_capacity(n_tokens, n_experts, capacity_factor, k=1):
     """Per-expert slot count of the dispatch capacity buffer:
     ``max(1, int(capacity_factor · k · n_tokens / n_experts))`` over the
-    RANK-LOCAL token count.  The ONE formula the dispatchers, bench.py's
-    dispatch-byte columns, and the comm census share — a rounding tweak
-    here re-prices every committed row together instead of letting the
-    surfaces drift apart."""
+    RANK-LOCAL token count.  The ONE formula the dispatchers and the
+    comm census share — a rounding tweak here re-prices every committed
+    row together instead of letting the surfaces drift apart."""
     return max(1, int(capacity_factor * k * n_tokens / n_experts))
 
 

@@ -62,9 +62,8 @@ fact: XLA schedules each hop's ppermute concurrently with the two dense
 half-block attentions of that step, so the extra bytes cost wall-clock
 only if ICI time exceeds compute time — at the flop:byte ratio of two
 dense half-blocks per half-unit of traffic (∝ T_local/4 flops per KV
-byte) the rotation is compute-dominated for realistic block sizes; an
-on-chip trace slot records the overlap when chip time exists
-(BENCH_NOTES round-4).
+byte) the rotation is compute-dominated for realistic block sizes (not
+measured on the chip: no cell runs the ring, ROADMAP.md).
 """
 
 from __future__ import annotations

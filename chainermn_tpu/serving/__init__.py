@@ -35,12 +35,11 @@ tensor-parallel paged decode.  Pieces, each its own module:
   weight-synced over a multicast tree in O(log N) rounds
   (``CHAINERMN_TPU_FLEET=off`` = single-engine hatch).
 
-Measurement: ``BENCH_MODEL=serving python bench.py`` (tokens/sec,
-p50/p99 per-token latency, page-pool occupancy, ``prefix_hit_rate`` +
-effective-capacity multiplier, ``transferred_page_bytes``, ``tp`` under
-a seeded chat-shaped open-loop load); structure committed in
-``tools/serving_budgets.json`` and gated tier-1 by
-``tests/test_serving_budget.py``; ``make probe-serving`` joins the two.
+Measurement: ``python3 -m benchmark.run --workload gpt2m-serve-chat``
+(the cells of ``BENCHMARK.json``: completed tokens/sec and the latency
+tails under a seeded open-loop load, per-layer metrics from the trace);
+structure committed in ``tools/serving_budgets.json`` and gated tier-1
+by ``tests/test_serving_budget.py``.
 Design notes: ``docs/serving.md``.
 """
 

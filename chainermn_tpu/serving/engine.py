@@ -1156,8 +1156,8 @@ class ServingEngine:
         transfer page bucket (disagg — padding rows, every scatter
         drops), and one dummy decode per batch bucket (all lanes idle).
         Pool contents are unchanged; afterwards joins/leaves/forks/
-        transfers never retrace (the serving bench asserts
-        ``window_retraces == 0``).  Round 20 grids ride along: one
+        transfers never retrace (the benchmark's serve driver refuses a
+        window that traced).  Round 20 grids ride along: one
         chunk program per prefill bucket (per pool shape on the disagg
         split), one spec verify per batch bucket (all lanes idle,
         every span write dropped), and the draft model's prefill +

@@ -137,8 +137,7 @@ class LocalReplica:
 
     ``kill_at``: seeded preemption — the replica raises
     :class:`RankPreempted` when its engine reaches that decode step
-    (the fleet bench's ``BENCH_FLEET_KILL_AT`` and the chaos tests'
-    kill-under-load injection point)."""
+    (the chaos tests' kill-under-load injection point)."""
 
     remote = False
 

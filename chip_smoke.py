@@ -33,7 +33,7 @@ import warnings
 
 import numpy as np
 
-#: the d768 x 12 GPT-2-small-class LM (bench.py's transformer vertical)
+#: the d768 x 12 GPT-2-small-class LM
 TRANSFORMER = dict(n_vocab=32768, d_model=768, n_heads=12, n_layers=12,
                    seq_len=1024, per_chip_batch=8)
 #: the source paper's flagship (BASELINE.json): ResNet-50, ImageNet shapes

@@ -64,10 +64,9 @@ def main():
                              "trajectory as plain DP, 1/n state memory")
     parser.add_argument("--uint8-input", action="store_true",
                         help="ship raw uint8 pixels and normalize "
-                             "IN-GRAPH on device (any arch) — the "
-                             "measured input-pipeline fix: host f32 "
-                             "casting caps at ~2.6k img/s on one core, "
-                             "uint8 gather sustains ~9k (BENCH_NOTES r5)")
+                             "IN-GRAPH on device (any arch): the host "
+                             "f32 cast is what bounds a one-core input "
+                             "pipeline, the uint8 gather is not")
     parser.add_argument("--native-loader", action="store_true",
                         help="deprecated alias for --loader native")
     parser.add_argument("--loader", default=None,
@@ -135,8 +134,7 @@ def main():
         # C++ gather engine over the materialized local shard: batches
         # arrive pre-stacked (x, t) tuples, so downstream converters are
         # identity.  With --uint8-input the rows stay uint8 end to end
-        # and the cast happens in-graph on device — the full
-        # measured-fast pipeline (BENCH_NOTES r5).
+        # and the cast happens in-graph on device.
         from chainermn_tpu.dataset import NativeBatchIterator
         xs, ys = concat_examples([train[i] for i in range(len(train))])
         train_iter = NativeBatchIterator((xs, ys),

@@ -520,27 +520,6 @@ def test_hierarchical_split_flattens():
         sum(range(subs[0].size)))
 
 
-def test_exchange_knobs_vocabulary():
-    """The one exchange-name mapping bench.py and bench_scaling share:
-    (communicator name, batch_collectives, optimizer exchange)."""
-    from chainermn_tpu.communicators import EXCHANGES, exchange_knobs
-    assert exchange_knobs("flat") == ("jax_ici", True, "allreduce")
-    assert exchange_knobs("bucketed") == \
-        ("jax_ici", "bucketed", "allreduce")
-    assert exchange_knobs("reduce_scatter") == \
-        ("jax_ici", True, "reduce_scatter")
-    assert exchange_knobs("hierarchical") == \
-        ("hierarchical", True, "allreduce")
-    assert exchange_knobs("hierarchical_rs") == \
-        ("hierarchical", True, "reduce_scatter")
-    assert set(EXCHANGES) == {"per_leaf", "flat", "bucketed",
-                              "reduce_scatter", "hierarchical",
-                              "hierarchical_rs", "striped",
-                              "striped_rs"}
-    with pytest.raises(ValueError, match="unknown exchange"):
-        exchange_knobs("chunky")
-
-
 def test_hierarchical_two_level_reduction_matches_global():
     """Reference 'hierarchical' structure as an explicit two-level
     reduction over split() groups: intra-group mean → leader-level mean

@@ -16,7 +16,6 @@ import numpy as np
 import pytest
 
 import chainermn_tpu as ct
-from chainermn_tpu.communicators import EXCHANGES, exchange_knobs
 from chainermn_tpu.communicators._memory_utility import (
     DEFAULT_STRIPE_RATIO, exchanged_bytes, hop_schedule, stripe_plan,
     striped_exchanged_bytes)
@@ -192,12 +191,21 @@ def test_hierarchy_flat_hatch_drops_striping(monkeypatch):
     assert comm.topology == "flat"
 
 
-def test_exchange_vocabulary_and_knobs():
-    assert "striped" in EXCHANGES and "striped_rs" in EXCHANGES
-    assert exchange_knobs("striped") == ("hierarchical", True, "allreduce")
-    assert exchange_knobs("striped_rs") == \
-        ("hierarchical", True, "reduce_scatter")
+def test_striped_pair_is_spelled_directly():
+    """``striped`` / ``striped_rs`` (tools/comm_budgets.json's names)
+    are the hierarchical communicator with a nonzero ratio under the
+    allreduce / reduce-scatter step: (communicator name,
+    batch_collectives, exchange=), with no table between."""
+    from chainermn_tpu.core.optimizer import Adam
     assert DEFAULT_STRIPE_RATIO == 0.25
+    for exchange in ("allreduce", "reduce_scatter"):
+        comm = ct.create_communicator(
+            "hierarchical", inter_size=2, batch_collectives=True,
+            stripe_ratio=DEFAULT_STRIPE_RATIO)
+        opt = ct.create_multi_node_optimizer(Adam(), comm,
+                                             exchange=exchange)
+        assert comm.topology == "striped"
+        assert opt.exchange == exchange
 
 
 def test_grad_dcn_stale_len_matches_plan():

@@ -49,7 +49,7 @@ def test_resnet50_uint8_input_norm_matches_host_normalized():
     """input_norm='imagenet' over raw uint8 pixels must equal the same
     weights fed host-normalized float32 ((x/255 - mean)/std) — the
     in-graph path exists so the pipeline can ship uint8 and cast on
-    device (BENCH_NOTES r5 input-pipeline probe)."""
+    device."""
     from chainermn_tpu.models.resnet import IMAGENET_MEAN, IMAGENET_STD
 
     rng = np.random.RandomState(0)

@@ -345,24 +345,3 @@ def test_elastic_shrink_regrow_timeline(events_mode, tmp_path):
     assert reg.get("chainermn_tpu_recovery_ranks_lost").value() == 1
     assert reg.get("chainermn_tpu_recovery_ranks_joined").value() == 1
     assert reg.get("chainermn_tpu_recovery_recoveries").value() >= 1
-
-
-# -- PROBE=obs ------------------------------------
-
-def test_probe_obs_renders_merged_registry(events_mode, capsys):
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__),
-                                    "..", "..", "tools"))
-    import probe_perf
-    probe_perf.probe_obs()
-    out = capsys.readouterr().out
-    import json
-    rows = [json.loads(l) for l in out.strip().split("\n")]
-    head = [r for r in rows if r.get("probe") == "obs"]
-    assert head and head[0]["schema_valid"]
-    assert "serve/decode_window" in head[0]["span_counts"]
-    assert "train/optimizer_update" in head[0]["span_counts"]
-    prom = [r["line"] for r in rows if r.get("probe") == "obs_prometheus"]
-    assert any(l.startswith("# TYPE chainermn_tpu_input_stall_ms_total")
-               for l in prom)
-    assert any("chainermn_tpu_serving_queue_wait_ms_count" in l
-               for l in prom)

@@ -288,14 +288,12 @@ def test_serving_forward_resolves_the_parents_tiles(monkeypatch, t, want):
     assert _flash_blocks(tq=t, tk=t) == (want, want)
 
 
-def test_adaptive_block_defaults(monkeypatch):
-    """Round-5 on-chip sweep: tile defaults are shape-adaptive (largest
-    candidate dividing T), env still pins, explicit args still win."""
+def test_adaptive_block_defaults():
+    """Tile defaults are shape-adaptive (largest candidate dividing T),
+    explicit args win."""
     from chainermn_tpu.ops.flash_attention import _adaptive_block, \
         _flash_blocks
 
-    monkeypatch.delenv("CHAINERMN_TPU_FLASH_BLOCK_Q", raising=False)
-    monkeypatch.delenv("CHAINERMN_TPU_FLASH_BLOCK_K", raising=False)
     assert _adaptive_block(8192) == 1024
     assert _adaptive_block(1024) == 1024
     assert _adaptive_block(1536) == 512   # 1536 % 1024 != 0
@@ -307,26 +305,78 @@ def test_adaptive_block_defaults(monkeypatch):
     # the log-sum-exp forward: the swept table where the call's shape was
     # swept (causal, Tq == Tk == 1024, D = 64), else the same default
     from chainermn_tpu.ops.flash_attention import _CAUSAL_BLOCK_TABLE, \
-        _flash_lse_blocks
+        _flash_tiles
     assert _CAUSAL_BLOCK_TABLE == {
         (1024, 64): {"fwd": (256, 256), "bwd": (256, 256)}}
-    assert _flash_lse_blocks(tq=1024, tk=1024, d=64, causal=True) \
-        == (256, 256)
+    assert _flash_tiles("fwd", 1024, 1024, 64, True) == (256, 256)
     for tq, tk, d, causal in ((1024, 1024, 64, False),
                               (1024, 1024, 128, True),
                               (1024, 2048, 64, True),
                               (2048, 2048, 64, True)):
-        assert _flash_lse_blocks(tq=tq, tk=tk, d=d, causal=causal) \
-            == (1024, 1024)
-    assert _flash_lse_blocks(512, None, tq=1024, tk=1024, d=64,
-                             causal=True) == (512, 256)
-    monkeypatch.setenv("CHAINERMN_TPU_FLASH_BLOCK_Q", "64")
-    assert _flash_blocks(tq=2048, tk=2048) == (64, 1024)
-    assert _flash_lse_blocks(tq=1024, tk=1024, d=64, causal=True) \
-        == (64, 256)
+        assert _flash_tiles("fwd", tq, tk, d, causal) == (1024, 1024)
+    assert _flash_tiles("fwd", 1024, 1024, 64, True, block_q=512) \
+        == (512, 256)
 
-def test_adaptive_block_invalid_env(monkeypatch):
-    monkeypatch.setenv("CHAINERMN_TPU_FLASH_BLOCK_K", "70")
-    from chainermn_tpu.ops.flash_attention import _flash_blocks
-    with pytest.raises(ValueError):
-        _flash_blocks(tq=2048, tk=2048)
+
+def _traced_kernels(fa):
+    """[(kernel name, grid, dot output shapes)] of the causal
+    [1, 2, 1024, 64] bfloat16 forward + backward through the custom VJP,
+    traced and not run: the tiles show as the score products' shapes."""
+    def loss(q, k, v):
+        return jnp.sum(fa._flash_diff(q, k, v, True, None, True)
+                       .astype(jnp.float32))
+
+    x = jax.ShapeDtypeStruct((1, 2, 1024, 64), jnp.bfloat16)
+    found = []
+
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            subs = [getattr(p, "jaxpr", p) for v in e.params.values()
+                    for p in (v if isinstance(v, (tuple, list)) else (v,))]
+            subs = [j for j in subs if hasattr(j, "eqns")]
+            if e.primitive.name == "pallas_call":
+                dots = sorted({tuple(o.aval.shape) for k in subs
+                               for ke in k.eqns
+                               if ke.primitive.name == "dot_general"
+                               for o in ke.outvars})
+                found.append((e.params["name"],
+                              tuple(e.params["grid_mapping"].grid), dots))
+            else:
+                for j in subs:
+                    walk(j)
+
+    walk(jax.make_jaxpr(jax.value_and_grad(loss, argnums=(0, 1, 2)))(
+        x, x, x).jaxpr)
+    return found
+
+
+_RETIRED_FLASH_NAMES = ("CHAINERMN_TPU_FLASH_BLOCK_Q",
+                        "CHAINERMN_TPU_FLASH_BLOCK_K",
+                        "CHAINERMN_TPU_FLASH_BWD_BLOCK_Q",
+                        "CHAINERMN_TPU_FLASH_BWD_BLOCK_K",
+                        "CHAINERMN_TPU_FLASH_BWD")
+
+
+@pytest.mark.parametrize("name", _RETIRED_FLASH_NAMES)
+def test_tiles_ignore_the_environment(monkeypatch, name):
+    """The tile names and the backward switch PR 29 retired are not
+    read: set (the switch before the module is loaded again, since it
+    used to be read at import), the tiles resolved and the kernels
+    traced are those of a clean environment, with the fused kernel the
+    only backward."""
+    fa = importlib.import_module("chainermn_tpu.ops.flash_attention")
+    for retired in _RETIRED_FLASH_NAMES:
+        monkeypatch.delenv(retired, raising=False)
+    clean = _traced_kernels(fa)
+    assert [k[0] for k in clean] == ["_flash_kernel_lse",
+                                    "_flash_bwd_fused_kernel"]
+    # 256 x 256 tiles, unrolled in one program a head
+    assert all(grid == (2, 1) and (256, 256) in dots
+               for _, grid, dots in clean)
+    monkeypatch.setenv(
+        name, "split" if name == "CHAINERMN_TPU_FLASH_BWD" else "64")
+    fa = importlib.reload(fa)
+    assert fa._flash_tiles("fwd", 1024, 1024, 64, True) == (256, 256)
+    assert fa._flash_tiles("bwd", 1024, 1024, 64, True) == (256, 256)
+    assert fa._flash_blocks(tq=1024, tk=1024) == (1024, 1024)
+    assert _traced_kernels(fa) == clean

@@ -4,19 +4,17 @@ Interpret-mode (CPU tier-1) coverage:
 
 * grad parity of the fused one-pass dq/dkv kernel vs the
   ``_blockwise_attention_lse_jnp`` reference over a (T, causal,
-  tile-shape, dtype) grid — including ragged T where the bwd tile table
-  does not divide and the kernel must fall back to the forward tiles;
-* the ``CHAINERMN_TPU_FLASH_BWD=split`` escape hatch restores the
-  legacy two-kernel lowering bit-for-bit;
-* backward tile resolution (env knobs, sweep table, explicit args);
-* fused↔split numerical agreement.
+  tile-shape, dtype) grid — including ragged T where the backward's
+  own tiles do not divide and the kernel must fall back to the forward
+  tiles;
+* backward tile resolution (swept table, adaptive default, explicit
+  args).
 
 Ring/Ulysses consumer coverage lives in
 tests/parallel_tests/test_long_context.py (the kernels there run under
 shard_map via CHAINERMN_TPU_FLASH_INTERPRET=1).
 """
 
-import functools
 import importlib
 
 import numpy as np
@@ -24,7 +22,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from .test_flash_attention import _ref_out_lse
 
@@ -42,10 +39,10 @@ def _grads(loss, q, k, v):
     return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
 
-# (T, (block_q, block_k)) — 192/160 are the ragged rows: no default
-# candidate (1024/512/256/128) divides them, and the bwd table misses,
-# so the fused kernel exercises its forward-tile fallback branch; the
-# 64/128 rows resolve bwd tiles through _adaptive_block.
+# (T, (block_q, block_k)) forward tiles, passed as arguments — 192/160
+# are the ragged rows: no default candidate (1024/512/256/128) divides
+# them, so the backward exercises its forward-tile fallback branch; the
+# 64/128 rows resolve backward tiles through _adaptive_block.
 _GRID = [
     (64, (32, 32)),
     (128, (64, 64)),
@@ -58,33 +55,29 @@ _GRID = [
 @pytest.mark.parametrize("T,blocks", _GRID)
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_fused_bwd_grad_parity_vs_blockwise(monkeypatch, T, blocks,
-                                            causal, dtype):
+def test_fused_bwd_grad_parity_vs_blockwise(T, blocks, causal, dtype):
     """Full-grid grad parity: fused backward (interpret mode) vs the
     differentiable blockwise jnp reference, for a loss touching BOTH
     outputs (out and lse — the g_lse→delta folding included)."""
     bq, bk = blocks
-    monkeypatch.setenv("CHAINERMN_TPU_FLASH_BLOCK_Q", str(bq))
-    monkeypatch.setenv("CHAINERMN_TPU_FLASH_BLOCK_K", str(bk))
-    monkeypatch.delenv("CHAINERMN_TPU_FLASH_BWD_BLOCK_Q", raising=False)
-    monkeypatch.delenv("CHAINERMN_TPU_FLASH_BWD_BLOCK_K", raising=False)
-    assert fa._flash_bwd_mode() == "fused"
     q, k, v = _data(T=T, seed=T + causal, dtype=dtype)
     scale = 1.0 / np.sqrt(q.shape[-1])
 
-    def loss_flash(q, k, v):
-        out, lse = fa._flash_lse_diff(q, k, v, causal, scale, True)
+    def loss(out, lse):
         return jnp.sum(out.astype(jnp.float32) ** 2) \
             + jnp.sum(jnp.sin(lse))
 
-    def loss_ref(q, k, v):
-        out, lse = fa._blockwise_attention_lse_jnp(q, k, v, causal,
-                                                   scale, block_k=32)
-        return jnp.sum(out.astype(jnp.float32) ** 2) \
-            + jnp.sum(jnp.sin(lse))
-
-    gf = _grads(loss_flash, q, k, v)
-    gr = _grads(loss_ref, q, k, v)
+    # the custom VJPs take no tiles: the same forward, cotangents and
+    # backward they chain, with the grid's tiles as arguments
+    out, lse = fa.flash_attention_fwd(q, k, v, causal=causal, scale=scale,
+                                      block_q=bq, block_k=bk,
+                                      interpret=True)
+    g, g_lse = jax.grad(loss, argnums=(0, 1))(out, lse)
+    gf = fa.flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
+                                scale=scale, block_q=bq, block_k=bk,
+                                interpret=True, g_lse=g_lse)
+    gr = _grads(lambda q, k, v: loss(*fa._blockwise_attention_lse_jnp(
+        q, k, v, causal, scale, block_k=32)), q, k, v)
     if dtype == jnp.float32:
         rtol, atol = 2e-4, 1e-5
     else:
@@ -110,7 +103,6 @@ def test_fused_bwd_with_tiles_under_t(monkeypatch, blocks, causal, dtype,
     looped (dq in the VMEM scratch, across the key-tile grid axis)."""
     if walk == "looped":
         monkeypatch.setattr(fa, "_STATIC_WALK_ELEMS", 0)
-    assert fa._flash_bwd_mode() == "fused"
     q, k, v = _data(T=256, seed=31 + causal, dtype=dtype)
     g = _data(T=256, seed=33, dtype=dtype)[0]
     g_lse = jnp.cos(jnp.arange(2 * 256, dtype=jnp.float32)
@@ -168,166 +160,41 @@ def test_fused_bwd_writes_no_partial_dq_plane(monkeypatch, walk):
     assert "reduce_sum" not in names[at:]
 
 
-def _legacy_two_kernel_bwd(q, k, v, out, lse, g, causal, scale,
-                           block_q, block_k):
-    """The pre-fusion lowering, reconstructed verbatim from the split
-    kernels and their original pallas_call specs — the bit-for-bit
-    reference for the escape hatch."""
-    B, H, Tq, D = q.shape
-    Tk = k.shape[2]
-    qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * H, Tk, D)
-    vr = v.reshape(B * H, Tk, D)
-    gr = g.reshape(B * H, Tq, D)
-    lser = lse.reshape(B * H, Tq, 1)
-    delta = jnp.sum(gr.astype(jnp.float32)
-                    * out.reshape(B * H, Tq, D).astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    dq = pl.pallas_call(
-        functools.partial(fa._flash_bwd_dq_kernel, block_k=block_k,
-                          causal=causal, scale=scale),
-        grid=(B * H, Tq // block_q),
-        in_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda b, i: (b, i, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-        interpret=True,
-    )(qr, kr, vr, gr, lser, delta)
-    dk, dv = pl.pallas_call(
-        functools.partial(fa._flash_bwd_dkv_kernel, block_q=block_q,
-                          causal=causal, scale=scale),
-        grid=(B * H, Tk // block_k),
-        in_specs=[
-            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tq, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tq, 1), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype),
-        ],
-        interpret=True,
-    )(qr, kr, vr, gr, lser, delta)
-    return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
-            dv.reshape(B, H, Tk, D))
+def test_bwd_block_resolution():
+    """Explicit args > swept causal table > adaptive default."""
+    def bwd(tq, tk, d=None, causal=False, block_q=None, block_k=None):
+        return fa._flash_tiles("bwd", tq, tk, d, causal, block_q, block_k)
 
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_split_escape_hatch_restores_legacy_bit_for_bit(monkeypatch,
-                                                        causal):
-    q, k, v = _data(T=128, seed=3, dtype=jnp.float32)
-    g = _data(T=128, seed=4)[0]
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    out, lse = fa.flash_attention_fwd(q, k, v, causal=causal, scale=scale,
-                                      block_q=64, block_k=64,
-                                      interpret=True)
-    monkeypatch.setattr(fa, "_FLASH_BWD", "split")
-    got = fa.flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
-                                 scale=scale, block_q=64, block_k=64,
-                                 interpret=True)
-    want = _legacy_two_kernel_bwd(q, k, v, out, lse, g, causal, scale,
-                                  64, 64)
-    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
-        np.testing.assert_array_equal(
-            np.asarray(a), np.asarray(b),
-            err_msg=f"{name}: split mode no longer the legacy lowering")
-
-
-@pytest.mark.parametrize("causal", [False, True])
-def test_fused_matches_split(monkeypatch, causal):
-    """The two lowerings are the same math: fp32 agreement to float
-    noise (the only difference is dq's cross-block summation order)."""
-    q, k, v = _data(T=128, seed=5)
-    g = _data(T=128, seed=6)[0]
-    scale = 1.0 / np.sqrt(q.shape[-1])
-    out, lse = fa.flash_attention_fwd(q, k, v, causal=causal, scale=scale,
-                                      block_q=64, block_k=64,
-                                      interpret=True)
-    monkeypatch.setattr(fa, "_FLASH_BWD", "fused")
-    fused = fa.flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
-                                   scale=scale, block_q=64, block_k=64,
-                                   interpret=True, bwd_block_q=64,
-                                   bwd_block_k=64)
-    monkeypatch.setattr(fa, "_FLASH_BWD", "split")
-    split = fa.flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
-                                   scale=scale, block_q=64, block_k=64,
-                                   interpret=True)
-    for a, b, name in zip(fused, split, ("dq", "dk", "dv")):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-6, atol=1e-6, err_msg=name)
-
-
-def test_bwd_mode_validation(monkeypatch):
-    monkeypatch.setattr(fa, "_FLASH_BWD", "nonsense")
-    with pytest.raises(ValueError, match="CHAINERMN_TPU_FLASH_BWD"):
-        fa._flash_bwd_mode()
-
-
-def test_bwd_block_resolution(monkeypatch):
-    """Explicit args > env knobs > swept causal table > per-T table >
-    fwd-adaptive default."""
-    monkeypatch.delenv("CHAINERMN_TPU_FLASH_BWD_BLOCK_Q", raising=False)
-    monkeypatch.delenv("CHAINERMN_TPU_FLASH_BWD_BLOCK_K", raising=False)
-    # the per-T rows, for the calls no chip sweep has visited
-    assert fa._BWD_BLOCK_TABLE == {t: (1024, 1024)
-                                   for t in (1024, 2048, 8192, 16384)}
+    # the lengths no chip sweep has visited: the adaptive default
     for t in (1024, 2048, 8192, 16384):
-        assert fa._flash_bwd_blocks(tq=t, tk=t) == fa._BWD_BLOCK_TABLE[t]
+        assert bwd(t, t) == (1024, 1024)
     # the swept shape: causal, Tq == Tk == 1024, D = 64 (and only it)
-    assert fa._flash_bwd_blocks(tq=1024, tk=1024, d=64, causal=True) \
-        == (256, 256)
+    assert bwd(1024, 1024, 64, True) == (256, 256)
     for tq, tk, d, causal in ((1024, 1024, 64, False),
                               (1024, 1024, 128, True),
                               (2048, 2048, 64, True),
                               (1024, 2048, 64, True)):
-        assert fa._flash_bwd_blocks(tq=tq, tk=tk, d=d, causal=causal) \
-            == (1024, 1024)
-    # off-table lengths: fwd-adaptive fallback
-    assert fa._flash_bwd_blocks(tq=512, tk=512) == (512, 512)
-    assert fa._flash_bwd_blocks(tq=192, tk=192) == (128, 128)
-    # env knobs pin, explicit args win
-    monkeypatch.setenv("CHAINERMN_TPU_FLASH_BWD_BLOCK_Q", "256")
-    assert fa._flash_bwd_blocks(tq=8192, tk=8192) == (
-        256, fa._BWD_BLOCK_TABLE[8192][1])
-    assert fa._flash_bwd_blocks(64, None, tq=8192, tk=8192) == (
-        64, fa._BWD_BLOCK_TABLE[8192][1])
-    monkeypatch.setenv("CHAINERMN_TPU_FLASH_BWD_BLOCK_K", "70")
-    with pytest.raises(ValueError, match="multiples of 8"):
-        fa._flash_bwd_blocks(tq=8192, tk=8192)
+        assert bwd(tq, tk, d, causal) == (1024, 1024)
+    assert bwd(512, 512) == (512, 512)
+    assert bwd(192, 192) == (128, 128)
+    # explicit args win, one at a time
+    assert bwd(8192, 8192, block_q=64) == (64, 1024)
+    assert bwd(1024, 1024, 64, True, block_k=512) == (256, 512)
 
 
 def test_fused_bwd_kernel_count_and_single_exp():
-    """Structural pin of the recompute-once property: the fused backward
+    """Structural pin of the recompute-once property: the backward
     lowers to exactly ONE pallas_call that spends exactly ONE exp a tile
-    it walks (its two loop bodies, masked and unmasked, hold one each);
-    split lowers to two kernels with one exp a tile each.  Uses the same
-    jaxpr census the tier-1 budget gate runs (tools/flash_sweep.py) —
-    here pinned against absolute expectations, there against the
-    committed tools/flash_budgets.json structure section."""
+    it walks (its two loop bodies, masked and unmasked, hold one each).
+    Uses the same jaxpr census the tier-1 budget gate runs
+    (tools/flash_sweep.py) — here pinned against absolute expectations,
+    there against the committed tools/flash_budgets.json structure
+    section."""
     import os
     import sys
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))), "tools"))
     import flash_sweep
 
-    # fused: ONE backward kernel, ONE exp a tile
-    assert flash_sweep.bwd_kernel_census(fa, "fused") == \
+    assert flash_sweep.bwd_kernel_census(fa) == \
         {"_flash_bwd_fused_kernel": {"loop_bodies": 2, "exp_per_tile": 1}}
-    # split: the legacy pair, each recomputing its own exp(s - lse) —
-    # the duplicated recompute the fusion eliminates
-    one = {"loop_bodies": 1, "exp_per_tile": 1}
-    assert flash_sweep.bwd_kernel_census(fa, "split") == \
-        {"_flash_bwd_dq_kernel": one, "_flash_bwd_dkv_kernel": one}

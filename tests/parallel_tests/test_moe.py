@@ -360,7 +360,7 @@ def test_hierarchy_flat_hatch_drops_two_stage_with_warning(monkeypatch):
 
 
 def _train_moe_vertical(dispatch_dtype=None, two_stage=None, steps=25):
-    """Train the MoE transformer vertical (the BENCH_MODEL=moe family,
+    """Train the MoE transformer vertical (``MoETransformerLM``,
     scaled tier-1 small) through the multi-node optimizer on the
     simulated 2-host split.  ``dispatch_dtype`` compresses ONLY the
     token dispatch's DCN crossing (a separate ep communicator binding
@@ -386,7 +386,7 @@ def _train_moe_vertical(dispatch_dtype=None, two_stage=None, steps=25):
 
 
 def test_moe_vertical_convergence_parity():
-    """The acceptance gates on the BENCH_MODEL=moe vertical: the
+    """The acceptance gates on the MoE transformer vertical: the
     lossless two-stage dispatch trains the SAME trajectory as the
     explicit flat single-axis dispatch on the same communicator (the
     exchange itself is bit-equal — pinned by the dispatch-level tests

@@ -476,10 +476,9 @@ def test_moe_dcn_crossing_at_wire_dtype(moe_live):
 
 
 def test_moe_pricing_surface_matches_census(moe_live):
-    """`_memory_utility.moe_dispatch_exchanged_bytes` — the pricing
-    surface bench.py's MoE rows use — agrees with the traced census
-    byte-for-byte, so the bench columns and the committed budgets
-    cannot drift apart."""
+    """`_memory_utility.moe_dispatch_exchanged_bytes` — the pricing of
+    the MoE dispatch — agrees with the traced census byte-for-byte, so
+    the formula and the committed budgets cannot drift apart."""
     from chainermn_tpu.communicators._memory_utility import \
         moe_dispatch_exchanged_bytes
     row = moe_live["moe_two_stage"]
@@ -518,4 +517,4 @@ def test_measured_sweep_meets_tolerance_when_present(budgets):
     assert best_bucketed >= tol * best_flat, (
         f"bucketed flagship {best_bucketed} fell more than the "
         f"tolerated margin below flat {best_flat} — record the "
-        "refutation in BENCH_NOTES before re-committing")
+        "refutation in PERF.md before re-committing")

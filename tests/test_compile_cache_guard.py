@@ -22,13 +22,14 @@ print("RETURNED", configure_persistent_cache())
 print("CONFIG", jax.config.jax_compilation_cache_dir)
 """
 
-# bench_scaling's single-process curve at its smallest: the cache
-# directory it ends up with is whatever the shared function decided
-_SCALING_PROBE = """
+# an entry SCRIPT at its smallest (one epoch of the MNIST example, one
+# batch): the cache directory it ends up with is whatever the shared
+# function decided
+_EXAMPLE_PROBE = """
 import sys, runpy
 import jax
-sys.argv = ["bench_scaling.py", "--platform", "cpu", "--per-chip-bs", "1",
-            "--size", "32", "--steps", "1", "--model", "resnet18"]
+sys.argv = ["train_mnist.py", "--platform", "cpu", "--epoch", "1",
+            "--batchsize", "6000", "--unit", "8", "--out", {out!r}]
 try:
     runpy.run_path({path!r}, run_name="__main__")
 finally:
@@ -52,20 +53,24 @@ def _run(code, cwd, env_dir):
                            re.M))
 
 
-_SCALING = _SCALING_PROBE.format(path=os.path.join(ROOT, "bench_scaling.py"))
+_EXAMPLE = "example"
 
 
 @pytest.mark.parametrize("code,from_root,env_set", [
     (_PROBE, True, True),      # the variable wins, code sets nothing
     (_PROBE, True, False),     # unset: <checkout>/.jax_cache
     (_PROBE, False, False),    # another pid, another cwd: the same path
-    (_SCALING, False, False),  # bench_scaling goes through the function
-    (_SCALING, False, True),
-], ids=["env_set", "unset", "unset_other_cwd", "bench_scaling_unset",
-        "bench_scaling_env_set"])
+    (_EXAMPLE, False, False),  # an example goes through the function
+    (_EXAMPLE, False, True),
+], ids=["env_set", "unset", "unset_other_cwd", "example_unset",
+        "example_env_set"])
 def test_cache_directory_rule(code, from_root, env_set, tmp_path):
     placed = str(tmp_path / "placed") if env_set else None
     expected = placed or os.path.join(ROOT, ".jax_cache")
+    if code is _EXAMPLE:
+        code = _EXAMPLE_PROBE.format(
+            path=os.path.join(ROOT, "examples", "train_mnist.py"),
+            out=str(tmp_path / "result"))
     got = _run(code, ROOT if from_root else str(tmp_path), placed)
     # CONFIG is what JAX ends up with: from the environment when the
     # variable is set, from the one setter otherwise
@@ -92,7 +97,8 @@ def test_env_set_makes_no_config_update(monkeypatch):
 
 def test_one_setter_in_the_tree():
     """``grep -rn compilation_cache_dir --include=*.py`` finds one
-    setter, and the retired knobs are gone."""
+    setter, and the retired knobs are gone (so is the last script that
+    named one)."""
     setters, retired = [], []
     for base, dirs, files in os.walk(ROOT):
         dirs[:] = [d for d in dirs if not d.startswith(".")
@@ -111,7 +117,4 @@ def test_one_setter_in_the_tree():
                          src):
                 retired.append(os.path.relpath(path, ROOT))
     assert setters == [os.path.join("chainermn_tpu", "utils", "compat.py")]
-    # test_bench_harness names BENCH_XLA_CACHE_DIR only to assert that
-    # bench.py does not
-    assert [p for p in retired
-            if p != os.path.join("tests", "test_bench_harness.py")] == []
+    assert retired == []

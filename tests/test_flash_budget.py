@@ -4,14 +4,13 @@ Mirrors tests/test_hbm_budget.py: tools/flash_budgets.json commits the
 flash-attention backward's contract and this gate holds every future PR
 to it.  Two layers:
 
-* STRUCTURE (backend-neutral, checked here on CPU): the fused backward
+* STRUCTURE (backend-neutral, checked here on CPU): the backward
   lowers to exactly one Pallas kernel that spends exactly one exp on a
-  tile it walks — the recompute-once property the fusion exists for —
-  and the split escape hatch to the legacy two kernels.  Verified
-  against the traced program, not against documentation.
+  tile it walks — the recompute-once property the fusion exists for.
+  Verified against the traced program, not against documentation.
 * NUMBERS (measured on chip by `make sweep-flash`): when the committed
-  sweep section says ``measured``, the T=8192 fused fwd+bwd TFLOP/s
-  must meet the committed target (≥2× the r5 split-backward baseline);
+  sweep section says ``measured``, the T=8192 fwd+bwd TFLOP/s must
+  meet the committed target (≥2× the r5 two-kernel baseline);
   while it says ``pending_on_chip`` the numeric half is dormant but the
   schema/target relation is still enforced.
 """
@@ -39,19 +38,15 @@ def _budgets():
 def test_budget_schema_and_target_relation():
     b = _budgets()
     assert b["baseline"]["fwd_bwd_tflops_T8192"] == 31.8  # the r5 datum
-    # the acceptance bar this PR committed to: >= 2x the split baseline
+    # the acceptance bar ISSUE 4 committed to: >= 2x that baseline
     assert b["target_fwd_bwd_tflops_T8192"] >= \
         2.0 * b["baseline"]["fwd_bwd_tflops_T8192"]
-    assert b["structure"]["bwd_mode_default"] == "fused"
-    assert set(b["bwd_block_table"]) == {"1024", "2048", "8192", "16384"}
-    for blocks in b["bwd_block_table"].values():
-        assert len(blocks) == 2
-        assert all(x > 0 and x % 8 == 0 for x in blocks)
+    assert set(b["structure"]) == {"bwd_kernels"}
+    for entry in b["causal_block_table"].values():
+        for blocks in entry.values():
+            assert len(blocks) == 2
+            assert all(x > 0 and x % 8 == 0 for x in blocks)
     assert b["sweep"]["status"] in ("pending_on_chip", "measured")
-
-
-def _recorded_bwd_table(b):
-    return {int(t): tuple(v) for t, v in b["bwd_block_table"].items()}
 
 
 def _recorded_causal_table(b):
@@ -61,11 +56,10 @@ def _recorded_causal_table(b):
 
 
 @pytest.mark.parametrize("recorded,literal", [
-    (_recorded_bwd_table, "_BWD_BLOCK_TABLE"),
     (_recorded_causal_table, "_CAUSAL_BLOCK_TABLE")])
 def test_bwd_block_table_matches_kernel_literal(recorded, literal):
-    """The kernels read the literal tables in ops/flash_attention.py;
-    the budgets file records them — they must not desync (the sweep tool
+    """The kernels read the literal table in ops/flash_attention.py;
+    the budgets file records it — they must not desync (the sweep tool
     prints a reminder to paste winners into the literal)."""
     assert recorded(_budgets()) == getattr(fa, literal)
 
@@ -88,24 +82,16 @@ def test_committed_causal_tiles_come_from_the_recorded_sweep(leg, kernel):
 
 
 def test_fused_structure_gate():
-    """Recompute-once, machine-checked: the fused backward is ONE
-    pallas kernel with ONE exp a tile walked (two loop bodies, one exp
-    each).  A PR that splits the pass again or adds a second
-    exp(s - lse) recompute fails here and must either fix it or
-    consciously re-commit the structure section."""
+    """Recompute-once, machine-checked: the backward is ONE pallas
+    kernel with ONE exp a tile walked (two loop bodies, one exp each).
+    A PR that splits the pass again or adds a second exp(s - lse)
+    recompute fails here and must either fix it or consciously
+    re-commit the structure section."""
     b = _budgets()
-    census = flash_sweep.bwd_kernel_census(fa, "fused")
-    assert census == b["structure"]["fused_bwd_kernels"], (
-        f"fused backward structure drifted: traced {census}, committed "
-        f"{b['structure']['fused_bwd_kernels']}")
-
-
-def test_split_structure_gate():
-    b = _budgets()
-    census = flash_sweep.bwd_kernel_census(fa, "split")
-    assert census == b["structure"]["split_bwd_kernels"], (
-        f"split escape hatch no longer the legacy two-kernel lowering: "
-        f"traced {census}")
+    census = flash_sweep.bwd_kernel_census(fa)
+    assert census == b["structure"]["bwd_kernels"], (
+        f"backward structure drifted: traced {census}, committed "
+        f"{b['structure']['bwd_kernels']}")
 
 
 def test_measured_numbers_meet_target_when_present():
@@ -116,10 +102,9 @@ def test_measured_numbers_meet_target_when_present():
     assert "8192" in results, "sweep measured but no T=8192 row"
     got = results["8192"]["fwd_bwd_tflops"]
     assert got >= b["target_fwd_bwd_tflops_T8192"], (
-        f"committed T=8192 fused fwd+bwd {got} TFLOP/s below the "
+        f"committed T=8192 fwd+bwd {got} TFLOP/s below the "
         f"{b['target_fwd_bwd_tflops_T8192']} target — record the "
-        "refutation in BENCH_NOTES (r5 ResNet precedent) before "
-        "re-committing a lower target")
+        "refutation in PERF.md before re-committing a lower target")
 
 
 def test_sweep_tool_cpu_smoke(tmp_path):
@@ -136,7 +121,8 @@ def test_sweep_tool_cpu_smoke(tmp_path):
     assert out.returncode == 0, out.stderr[-2000:]
     rows = [json.loads(l) for l in out.stdout.strip().splitlines()]
     timed = [r for r in rows if "fwd_bwd_ms" in r]
-    assert {r["bwd_mode"] for r in timed} == {"fused", "split"}
+    assert [(r["T"], r["block_q"], r["block_k"]) for r in timed] \
+        == [(64, 32, 32)]
     assert all(r["interpreted"] for r in timed)
     out = subprocess.run(
         [sys.executable, os.path.join(root, "tools", "flash_sweep.py"),

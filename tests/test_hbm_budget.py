@@ -9,7 +9,9 @@ consciously re-commit the budget.  Fast: lowering only, no backend
 codegen, no execution.
 """
 
+import json
 import os
+import subprocess
 import sys
 
 import jax
@@ -19,17 +21,17 @@ import pytest
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "tools"))
 
-import probe_perf  # noqa: E402
+import hbm_census  # noqa: E402
 
 
 def _measure(bs, size):
-    return probe_perf.measure_hbm_bytes(bs, size, "NHWC", donate=True,
+    return hbm_census.measure_hbm_bytes(bs, size, "NHWC", donate=True,
                                         do_compile=False)
 
 
 def test_small_proxy_within_budget():
-    budgets = probe_perf.load_hbm_budgets()
-    key = probe_perf.hbm_budget_key(4, 64, "NHWC")
+    budgets = hbm_census.load_hbm_budgets()
+    key = hbm_census.hbm_budget_key(4, 64, "NHWC")
     assert key in budgets, "commit a budget row for the proxy config"
     row = _measure(4, 64)
     assert row["bytes_accessed"] > 0
@@ -42,8 +44,8 @@ def test_small_proxy_within_budget():
 
 
 def test_flagship_within_budget_and_reduced_vs_pre_pr():
-    budgets = probe_perf.load_hbm_budgets()
-    key = probe_perf.hbm_budget_key(64, 224, "NHWC")
+    budgets = hbm_census.load_hbm_budgets()
+    key = hbm_census.hbm_budget_key(64, 224, "NHWC")
     entry = budgets.get(key)
     assert entry, "commit a budget row for the flagship config"
     row = _measure(64, 224)
@@ -75,7 +77,7 @@ def test_category_parser_on_known_program():
     x = jnp.ones((1, 2, 8, 8), jnp.float32)
     w = jnp.ones((2, 2, 3, 3), jnp.float32)
     text = jax.jit(f).lower(x, w).as_text()
-    cats = probe_perf.stablehlo_bytes_by_category(text)
+    cats = hbm_census.stablehlo_bytes_by_category(text)
     # conv: x + w + y accesses
     conv_expected = (1 * 2 * 8 * 8 + 2 * 2 * 3 * 3 + 1 * 2 * 8 * 8) * 4
     assert cats["conv"] == conv_expected
@@ -92,6 +94,25 @@ def test_grad_program_categorizes_select_and_scatter(monkeypatch):
     monkeypatch.setattr(F, "_MAXPOOL_VJP", "xla")
     grad = jax.grad(lambda a: jnp.sum(F.max_pooling_2d(a, 2, 2, 0)))
     text = jax.jit(grad).lower(jnp.ones((1, 1, 8, 8), jnp.float32)).as_text()
-    cats = probe_perf.stablehlo_bytes_by_category(text)
+    cats = hbm_census.stablehlo_bytes_by_category(text)
     assert cats.get("pooling_bwd", 0) > 0, \
         "select_and_scatter should be attributed to pooling_bwd"
+
+
+def test_census_cli_smoke():
+    """One-command reproducibility: the census CLI prints the proxy
+    configuration's row beside its committed budget, and the row is the
+    one the gate above measures."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, os.path.join(root, "tools", "hbm_census.py"),
+         "--bs", "4", "--size", "64"],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=600, cwd=root)
+    assert out.returncode == 0, out.stderr[-2000:]
+    (row,) = [json.loads(l) for l in out.stdout.strip().splitlines()]
+    assert row["config"] == hbm_census.hbm_budget_key(4, 64, "NHWC")
+    assert row["within_budget"] is True
+    assert row["budget_bytes_accessed"] == hbm_census.load_hbm_budgets()[
+        row["config"]]["budget_bytes_accessed"]
+    assert sum(row["bytes_by_category"].values()) > 0

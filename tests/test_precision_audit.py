@@ -1,5 +1,5 @@
 """Bitrot guard for the StableHLO precision-audit classifier
-(tools/probe_perf.py · classify_contractions): the dtype regexes must
+(tools/hbm_census.py · classify_contractions): the dtype regexes must
 keep parsing the StableHLO text format, and the classification must
 distinguish the correct MXU configuration (bf16 inputs, f32
 accumulator) from genuine f32-input contractions."""
@@ -18,11 +18,10 @@ SNIPPET = """\
 
 def _load():
     spec = importlib.util.spec_from_file_location(
-        "probe_perf_audit", os.path.join(
+        "hbm_census_audit", os.path.join(
             os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-            "tools", "probe_perf.py"))
-    # import would trigger the module's jax config at top level — that is
-    # fine (tests pin cpu), but keep it isolated under its own name
+            "tools", "hbm_census.py"))
+    # keep it isolated under its own name
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod

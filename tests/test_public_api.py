@@ -126,3 +126,13 @@ def test_communicator_names_accepted():
                  "single_node", "non_cuda_aware", "pure_nccl", "jax_ici",
                  "dummy", "debug"):
         assert ct.create_communicator(name) is not None
+
+
+def test_communicators_all_resolves_and_holds_no_harness_table():
+    """Every name ``communicators.__all__`` promises exists, and the
+    package exports no table for a measurement script to share
+    (``EXCHANGES`` / ``exchange_knobs`` went with the scripts, PR 29)."""
+    mod = importlib.import_module("chainermn_tpu.communicators")
+    assert [n for n in mod.__all__ if getattr(mod, n, None) is None] == []
+    assert not {"EXCHANGES", "exchange_knobs"} & set(mod.__all__)
+    assert not hasattr(mod, "exchange_knobs")

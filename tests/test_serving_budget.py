@@ -11,9 +11,8 @@ every future PR to it.  Two layers:
   this exists to catch), zero backward kernels; prefill reuses the
   fused flash FORWARD (one Pallas kernel per layer, zero bwd kernels).
   Verified against the traced programs, not documentation.
-* TARGETS (measured on chip by BENCH_MODEL=serving
-  rows): dormant while ``status`` is ``pending_on_chip``; once measured,
-  the committed tokens/sec + p99 latency arm.
+* TARGETS: dormant while ``status`` is ``pending_on_chip``; once
+  measured on the chip, the committed tokens/sec + p99 latency arm.
 """
 
 import json

@@ -341,7 +341,7 @@ def row_wire_bytes(row, comm):
     decomposition, in the row's own operand dtype — the WIRE dtype of
     the packed buffer (``all_gather`` operands are the per-rank chunk;
     the accounting is over the full gathered buffer) — the ONE pricing
-    rule config_row and the PROBE=comm per-hop table share.
+    rule of config_row and of the per-hop table.
 
     A primitive this pricing does not understand is a HARD error (ISSUE
     8 satellite): a silently mispriced or skipped collective would make
@@ -612,8 +612,8 @@ def moe_config_row(name, traced=None):
     lossless, half of it under bf16, a quarter under int8 (the
     quantized fraction falls out of the trace, never out of
     metadata).  ``traced`` takes a prebuilt ``(jaxpr, comm)`` pair so
-    callers that also want the raw census rows (PROBE=comm's hop
-    table) trace each config once, not twice."""
+    callers that also want the raw census rows trace each config
+    once, not twice."""
     import jax.numpy as jnp
     cfg = MOE_CONFIGS[name]
     jaxpr, comm = traced if traced is not None else trace_moe(name)

@@ -1,26 +1,25 @@
 """Flash-attention tile sweep: fwd / bwd / fwd+bwd TFLOP/s per config.
 
-The round-5 BENCH_NOTES methodology (the sweep that found the 1024-tile
-forward win) as ONE reproducible command, extended to the backward:
+The tile sweep as ONE reproducible command:
 
     make sweep-flash              # = python tools/flash_sweep.py --write-budgets
 
 For every T in ``--T`` and every (block_q, block_k) in ``--blocks``,
 times three legs through the Pallas kernels — forward
-(``flash_attention_fwd``), backward (``flash_attention_bwd``, both the
-FUSED one-pass lowering and the legacy ``split`` two-kernel lowering),
-and fwd+bwd — and prints one JSON row each.  ``--write-budgets``
-regenerates ``tools/flash_budgets.json`` from the winners (per-T best
-fused fwd+bwd config), preserving the committed baseline/target/
-structure sections; the tier-1 gate (tests/test_flash_budget.py) then
-holds future PRs to the committed numbers.
+(``flash_attention_fwd``), backward (``flash_attention_bwd``) and
+fwd+bwd, the tiles passed as arguments — and prints one JSON row each.
+``--write-budgets`` rewrites the ``sweep`` section of
+``tools/flash_budgets.json`` from the winners (per-T best fwd+bwd
+config), preserving the committed baseline/target/structure sections;
+the tier-1 gate (tests/test_flash_budget.py) then holds future PRs to
+the committed numbers.
 
 Chip discipline: on the CPU backend this runs interpret mode at clamped
 T (mechanics smoke only — interpret timings are meaningless as perf)
 and REFUSES ``--write-budgets``: budgets are measured artifacts.
 
-Sync discipline (bench.py ``_timed_steps``): sync by device->host value
-fetch, reps >> 1 to amortize the round-trip.
+Sync discipline: sync by device->host value fetch, reps >> 1 to
+amortize the round-trip.
 """
 
 import argparse
@@ -81,8 +80,7 @@ def _kernel_ms(fn, args, reps=10):
         shutil.rmtree(tdir, ignore_errors=True)
     lo, hi = trace_reduce.window(trace)
     ms = {}
-    for name in ("_flash_kernel_lse", "_flash_bwd_fused_kernel",
-                 "_flash_bwd_dq_kernel", "_flash_bwd_dkv_kernel"):
+    for name in ("_flash_kernel_lse", "_flash_bwd_fused_kernel"):
         seconds, calls = trace_reduce.op_seconds(trace, (name,), lo, hi)
         if calls:
             ms[name] = round(seconds / calls * 1e3, 4)
@@ -95,13 +93,11 @@ def _kernel_ms(fn, args, reps=10):
 CHAIN = 24
 
 
-def measure_point(fa, B, H, D, T, bq, bk, mode, reps, interp):
-    """One (T, block_q, block_k, mode) sweep point → dict of leg
-    timings/TFLOP/s (fwd is mode-independent but re-timed per point so
-    each row stands alone): a call's share of :data:`CHAIN` chained
-    calls, and on the chip each kernel's own device time
-    (``kernel_ms``).  Raises on kernel failure — callers report and
-    continue."""
+def measure_point(fa, B, H, D, T, bq, bk, reps, interp):
+    """One (T, block_q, block_k) sweep point → dict of leg
+    timings/TFLOP/s: a call's share of :data:`CHAIN` chained calls, and
+    on the chip each kernel's own device time (``kernel_ms``).  Raises
+    on kernel failure — callers report and continue."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -119,49 +115,44 @@ def measure_point(fa, B, H, D, T, bq, bk, mode, reps, interp):
 
     out, lse = jax.jit(fwd)(q, k, v)
 
-    prev = fa._FLASH_BWD
-    fa._FLASH_BWD = mode
-    try:
-        def bwd(q, k, v, out, lse, g):
-            return fa.flash_attention_bwd(
-                q, k, v, out, lse, g, causal=True, scale=scale,
-                block_q=bq, block_k=bk, interpret=interp,
-                bwd_block_q=bq, bwd_block_k=bk)
+    def bwd(q, k, v, out, lse, g):
+        return fa.flash_attention_bwd(
+            q, k, v, out, lse, g, causal=True, scale=scale,
+            block_q=bq, block_k=bk, interpret=interp,
+            bwd_block_q=bq, bwd_block_k=bk)
 
-        def both(q, k, v, g):
-            o, l = fwd(q, k, v)
-            return bwd(q, k, v, o, l, g)
+    def both(q, k, v, g):
+        o, l = fwd(q, k, v)
+        return bwd(q, k, v, o, l, g)
 
-        chain = 1 if interp else CHAIN
+    chain = 1 if interp else CHAIN
 
-        def chained(fn):
-            # the call's first output (out, or dq) has q's shape and
-            # dtype: it is the next call's q
-            if chain == 1:
-                return fn
+    def chained(fn):
+        # the call's first output (out, or dq) has q's shape and
+        # dtype: it is the next call's q
+        if chain == 1:
+            return fn
 
-            def run(q, *rest):
-                return jax.lax.fori_loop(
-                    0, chain, lambda _, q: fn(q, *rest)[0], q)
-            return run
+        def run(q, *rest):
+            return jax.lax.fori_loop(
+                0, chain, lambda _, q: fn(q, *rest)[0], q)
+        return run
 
-        row = {}
-        for leg, fn, args in (
-                ("fwd", fwd, (q, k, v)),
-                ("bwd", bwd, (q, k, v, out, lse, g)),
-                ("fwd_bwd", both, (q, k, v, g))):
-            dt = _timed(jax.jit(chained(fn)), args, reps) / chain
-            row[f"{leg}_ms"] = round(dt * 1e3, 4)
-            row[f"{leg}_tflops"] = round(
-                model_flops(B, H, T, D, leg) / dt / 1e12, 1)
-        if not interp:
-            row["kernel_ms"] = _kernel_ms(jax.jit(both), (q, k, v, g))
-        return row
-    finally:
-        fa._FLASH_BWD = prev
+    row = {}
+    for leg, fn, args in (
+            ("fwd", fwd, (q, k, v)),
+            ("bwd", bwd, (q, k, v, out, lse, g)),
+            ("fwd_bwd", both, (q, k, v, g))):
+        dt = _timed(jax.jit(chained(fn)), args, reps) / chain
+        row[f"{leg}_ms"] = round(dt * 1e3, 4)
+        row[f"{leg}_tflops"] = round(
+            model_flops(B, H, T, D, leg) / dt / 1e12, 1)
+    if not interp:
+        row["kernel_ms"] = _kernel_ms(jax.jit(both), (q, k, v, g))
+    return row
 
 
-def bwd_kernel_census(fa, mode, T=128, block=64):
+def bwd_kernel_census(fa, T=128, block=64):
     """Structural census of the backward lowering: for every backward
     pallas_call of a causal call whose walk is 3 tiles (T = 128 in 64 x
     64 tiles: two the diagonal crosses, one below it), the ``exp``
@@ -171,9 +162,8 @@ def bwd_kernel_census(fa, mode, T=128, block=64):
     most exps any ONE loop body holds, and ``loop_bodies``, since a tile
     is walked by exactly one body, masked where the diagonal crosses it
     and unmasked elsewhere); ``exp_per_tile`` is the larger.  That is
-    the recompute-once property as a machine-checkable fact: fused = ONE
-    bwd kernel, ONE exp a tile; split = two kernels, one exp a tile
-    each."""
+    the recompute-once property as a machine-checkable fact: ONE bwd
+    kernel, ONE exp a tile."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -219,14 +209,13 @@ def bwd_kernel_census(fa, mode, T=128, block=64):
             bwd_block_q=block, bwd_block_k=block, interpret=True))(
                 q, k, v, out, lse, g).jaxpr
 
-    prev = fa._FLASH_BWD, fa._STATIC_WALK_ELEMS
-    fa._FLASH_BWD = mode
+    prev = fa._STATIC_WALK_ELEMS
     try:
         unrolled = dict(kernels(trace()))
         fa._STATIC_WALK_ELEMS = 0
         looped = dict(kernels(trace()))
     finally:
-        fa._FLASH_BWD, fa._STATIC_WALK_ELEMS = prev
+        fa._STATIC_WALK_ELEMS = prev
     census = {}
     for name, jx in looped.items():
         bodies = list(loop_bodies(jx))
@@ -248,14 +237,11 @@ def write_budgets(winners, args):
             budgets = json.load(f)
     except Exception:
         budgets = {}
-    budgets["bwd_block_table"] = {
-        str(t): list(w["blocks"]) for t, w in sorted(winners.items())}
     budgets["sweep"] = {
         "status": "measured",
         "geometry": {"B": args.B, "H": args.H, "D": args.D,
                      "causal": True, "dtype": "bfloat16"},
-        "results": {str(t): {k: v for k, v in w.items() if k != "blocks"}
-                    for t, w in sorted(winners.items())},
+        "results": {str(t): w for t, w in sorted(winners.items())},
         "measured_at": time.strftime("%Y-%m-%d"),
     }
     tmp = BUDGETS_PATH + ".tmp"
@@ -264,12 +250,14 @@ def write_budgets(winners, args):
         f.write("\n")
     os.replace(tmp, BUDGETS_PATH)
     print(json.dumps({"probe": "flash_sweep", "wrote": BUDGETS_PATH,
-                      "winners": budgets["bwd_block_table"]}), flush=True)
+                      "winners": {str(t): w["blocks"] for t, w in
+                                  sorted(winners.items())}}), flush=True)
     print(json.dumps({
         "probe": "flash_sweep", "note":
-        "paste the winner table into ops/flash_attention.py "
-        "_BWD_BLOCK_TABLE (the kernel reads the literal, not this file) "
-        "and re-run the tier-1 gate"}), flush=True)
+        "a winner under T goes into ops/flash_attention.py "
+        "_CAUSAL_BLOCK_TABLE and this file's causal_block_table (the "
+        "kernel reads the literal, not this file); re-run the tier-1 "
+        "gate"}), flush=True)
 
 
 def main():
@@ -281,7 +269,6 @@ def main():
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--blocks", default="256:256,512:512,512:1024,"
                     "1024:512,1024:1024,2048:1024")
-    ap.add_argument("--modes", default="fused,split")
     ap.add_argument("--write-budgets", action="store_true")
     ap.add_argument("--platform", default=None)
     args = ap.parse_args()
@@ -314,26 +301,24 @@ def main():
             bq, bk = (int(x) for x in spec.split(":"))
             if bq > T or bk > T or T % bq or T % bk:
                 continue
-            for mode in args.modes.split(","):
-                base = {"probe": "flash_sweep", "T": T, "block_q": bq,
-                        "block_k": bk, "bwd_mode": mode,
-                        "B": args.B, "H": args.H, "D": args.D}
-                if interp:
-                    base["interpreted"] = True
-                try:
-                    row = measure_point(fa, args.B, args.H, args.D, T,
-                                        bq, bk, mode, reps, interp)
-                except Exception as e:  # noqa: BLE001 — keep sweeping
-                    print(json.dumps(dict(
-                        base, error=f"{type(e).__name__}: {e}"[:200])),
-                        flush=True)
-                    continue
-                print(json.dumps(dict(base, **row)), flush=True)
-                if mode == "fused" and not interp:
-                    best = winners.get(T)
-                    if best is None or row["fwd_bwd_tflops"] > \
-                            best["fwd_bwd_tflops"]:
-                        winners[T] = dict(row, blocks=(bq, bk))
+            base = {"probe": "flash_sweep", "T": T, "block_q": bq,
+                    "block_k": bk, "B": args.B, "H": args.H, "D": args.D}
+            if interp:
+                base["interpreted"] = True
+            try:
+                row = measure_point(fa, args.B, args.H, args.D, T, bq, bk,
+                                    reps, interp)
+            except Exception as e:  # noqa: BLE001 — keep sweeping
+                print(json.dumps(dict(
+                    base, error=f"{type(e).__name__}: {e}"[:200])),
+                    flush=True)
+                continue
+            print(json.dumps(dict(base, **row)), flush=True)
+            if not interp:
+                best = winners.get(T)
+                if best is None or row["fwd_bwd_tflops"] > \
+                        best["fwd_bwd_tflops"]:
+                    winners[T] = dict(row, blocks=[bq, bk])
 
     if args.write_budgets and winners:
         write_budgets(winners, args)

@@ -37,8 +37,7 @@ The prefill trace forces ``CHAINERMN_TPU_FLASH_INTERPRET=1`` so the CPU
 census sees the same Pallas lowering a TPU run compiles.  ``--write-
 budgets`` regenerates the structure/geometry halves (trace properties —
 allowed off-chip, like comm_census); the ``targets`` section is the
-measured half and only ``BENCH_MODEL=serving`` on a chip (recovery
-queue) should update it.
+measured half and only a chip run may update it.
 """
 
 import argparse

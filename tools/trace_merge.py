@@ -21,7 +21,7 @@ Usage::
     python tools/trace_merge.py -o merged.json result/trace-rank*.jsonl
 
 Library surface: :func:`merge_events` / :func:`merge_files` (used by
-``PROBE=obs`` and the tier-1 tests).
+the tier-1 tests).
 """
 
 from __future__ import annotations
