@@ -21,6 +21,7 @@ from ..core import reporter
 from ..nn import functions as F
 from ..nn import links as L
 from ..ops import attention as fused_attention
+from ..ops import merge_heads, self_attention, split_heads
 from ..ops.paged_attention import (head_sharding, paged_decode_attention,
                                    paged_prefill_attention,
                                    paged_verify_attention)
@@ -62,10 +63,10 @@ class MultiHeadAttention(Chain):
 
     def forward(self, x, causal=True):
         B, T, D = x.shape
-        qkv = self.qkv(x.reshape(B * T, D)).reshape(B, T, 3, self.n_heads,
-                                                    self.d_head)
-        q, k, v = [jnp.moveaxis(qkv[:, :, i], 1, 2) for i in range(3)]
+        qkv = self.qkv(x.reshape(B * T, D)).reshape(B, T, 3 * D)
         if _axis_bound(self.sp_comm):
+            # ring and Ulysses exchange KV blocks or heads: heads first
+            q, k, v = split_heads(qkv, self.n_heads)
             if self.sp_mode in ("ring", "zigzag"):
                 from ..parallel import ring_self_attention
                 schedule = "zigzag" if self.sp_mode == "zigzag" else "naive"
@@ -75,10 +76,12 @@ class MultiHeadAttention(Chain):
                 from ..parallel import ulysses_attention
                 out = ulysses_attention(self.sp_comm, q, k, v,
                                         causal=causal)
+            out = merge_heads(out)
         else:
-            out = fused_attention(q, k, v, causal=causal)
-        out = jnp.moveaxis(out, 2, 1).reshape(B * T, D)
-        return self.proj(out).reshape(B, T, D)
+            # the kernels read these rows as they lie where they can,
+            # else split the heads as above
+            out = self_attention(qkv, self.n_heads, causal=causal)
+        return self.proj(out.reshape(B * T, D)).reshape(B, T, D)
 
 
 class TransformerBlock(Chain):

@@ -46,7 +46,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["attention", "flash_attention", "xla_attention"]
+__all__ = ["attention", "flash_attention", "self_attention", "xla_attention"]
 
 # Both grid dims are embarrassingly parallel (independent programs per
 # (batch*head, block) pair).  vmem_limit_bytes raises Mosaic's scoped-VMEM
@@ -164,12 +164,50 @@ def _loop(lo, hi, body, carry, unroll):
     return jax.lax.fori_loop(lo, hi, body, carry)
 
 
-def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
-                      block_k, causal, scale, static_walk):
+def _take(ref, idx, h, heads):
+    """Head ``h`` of a block's rows ``idx``.  Both training kernels read
+    and write a head through this and :func:`_put` alone, so a head's
+    products, accumulators and statistics are the same whether it has
+    the block to itself (heads-first form: the block as it is) or shares
+    a 128-lane column block of ``[B, T, H·D]`` rows with its neighbours.
+    There the head is the whole block with the other heads' lanes
+    zeroed: every product is then 128 lanes wide and exactly zero
+    outside the head's own lanes (a NaN or an infinity in one head of a
+    block reaches its neighbours, nothing else does), which costs the
+    MXU what a 64-wide one does and no lane is moved.  Taking the head's
+    64 lanes by a slice instead read 0.261 against 0.154 ms a forward
+    call and 0.380 against 0.359 a backward at GPT-2-medium's shape (my
+    chip run, PR 30: tools/flash_budgets.json)."""
+    x = ref[idx, :]
+    if heads == 1:
+        return x
+    d = x.shape[-1] // heads
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= h * d) & (lane < (h + 1) * d), x,
+                     jnp.zeros_like(x))
+
+
+def _put(ref, idx, h, value):
+    """Head ``h``'s result, zero outside its own lanes, into the rows
+    ``idx`` of a block: the heads of a block add up."""
+    if h == 0:
+        ref[idx, :] = value
+    else:
+        ref[idx, :] += value
+
+
+def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, *, heads,
+                      block_q, block_k, causal, scale, static_walk):
     """Forward kernel variant that also writes the log-sum-exp row
     statistics (softmax normalizer) needed by the backward kernels.
 
-    A program holds one query tile, or every query tile of its head
+    A block holds ``heads`` heads side by side in its lanes
+    (:func:`_take`): one in the heads-first form, ``128 // D`` in
+    the rows form, where the block is a column block of the qkv GEMM's
+    own output.  They are walked one after the other, each exactly as a
+    block of its own would be.
+
+    A program holds one query tile, or every query tile of its heads
     where the walk is static (:data:`_STATIC_WALK_ELEMS`).  Two loop
     bodies over a query tile's key tiles (:func:`_causal_k_tiles`):
     unmasked for the tiles wholly at or below the diagonal (every tile
@@ -180,22 +218,23 @@ def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
     there on; exp(-inf) = 0 does the rest.  A non-causal call (ring
     blocks, ``Tq != Tk``) masks nothing at all.  `_flash_kernel` keeps
     its guards."""
-    d = q_ref.shape[-1]
     n_kblocks = k_ref.shape[0] // block_k
     fold = _scale_folds(scale)
 
-    def q_tile(qi, rows):
+    def q_tile(h, qi, rows):
         # dtype discipline: blocks go into the dots in their STORAGE
         # dtype (bf16 rides the MXU's native path; an f32 upcast would
         # force the 3-pass f32 matmul emulation) with fp32 accumulators
         # via preferred_element_type; the online-softmax state stays fp32
-        q = _fold_scale(q_ref[rows, :], scale) if fold else q_ref[rows, :]
+        q = _take(q_ref, rows, h, heads)
+        if fold:
+            q = _fold_scale(q, scale)
         q_pos = (qi * block_q
                  + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
 
         def tile(ki, carry, masked):
             cols = pl.ds(ki * block_k, block_k)
-            s = jax.lax.dot_general(q, k_ref[cols, :],
+            s = jax.lax.dot_general(q, _take(k_ref, cols, h, heads),
                                     (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             if not fold:
@@ -211,7 +250,7 @@ def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
             p = jnp.exp(s - m_new)
             l_new = jnp.sum(p, axis=-1, keepdims=True)
             acc_new = jax.lax.dot_general(
-                p.astype(v_ref.dtype), v_ref[cols, :],
+                p.astype(v_ref.dtype), _take(v_ref, cols, h, heads),
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             if carry is not None:
@@ -229,27 +268,28 @@ def _flash_kernel_lse(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q,
         carry = None if static_walk else (
             jnp.full((block_q, 1), -jnp.inf, jnp.float32),
             jnp.zeros((block_q, 1), jnp.float32),
-            jnp.zeros((block_q, d), jnp.float32))
+            jnp.zeros((block_q, q.shape[-1]), jnp.float32))
         carry = _loop(0, full, functools.partial(tile, masked=False), carry,
                       static_walk)
         if causal:
             carry = _loop(full, last, functools.partial(tile, masked=True),
                           carry, static_walk)
         m, l, acc = carry
-        o_ref[rows, :] = (acc / l).astype(o_ref.dtype)
-        # lse is [rows, 1]: Mosaic requires the block's trailing dims to
-        # divide (8, 128) or equal the array dims — a trailing singleton
-        # qualifies, a squeezed 1-D block does not
-        lse_ref[rows, :] = m + jnp.log(l)
+        _put(o_ref, rows, h, (acc / l).astype(o_ref.dtype))
+        # lse is [heads, rows, 1]: Mosaic requires the block's trailing
+        # dims to divide (8, 128) or equal the array dims — a trailing
+        # singleton qualifies, a squeezed 1-D block does not
+        lse_ref[h, rows, :] = m + jnp.log(l)
 
-    for j in range(q_ref.shape[0] // block_q):
-        q_tile(j if static_walk else pl.program_id(1),
-               pl.ds(j * block_q, block_q))
+    for h in range(heads):
+        for j in range(q_ref.shape[0] // block_q):
+            q_tile(h, j if static_walk else pl.program_id(1),
+                   pl.ds(j * block_q, block_q))
 
 
-def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                            dq_ref, dk_ref, dv_ref, *dq_acc_ref,
-                            block_q, block_k, causal, scale, static_walk):
+def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, lse_ref,
+                            *rest, heads, block_q, block_k, causal, scale,
+                            static_walk, with_g_lse):
     """Fused backward: ONE pass over the (qi, ki) tiles per key tile.
 
     Each (qi, ki) tile is recomputed ONCE (the s = q·kᵀ dot, the mask,
@@ -260,19 +300,28 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
         dk  += dsᵀ q          (carried over this key tile's query loop)
         dq[qi] += ds·k        (float32, on the chip, across the key tiles)
 
-    A program holds one key tile, or every key tile of its head where
+    ``rest`` is ``[g_lse_ref,] dq_ref, dk_ref, dv_ref[, dq_acc_ref]``.
+    ``delta_i = rowsum(g_i · out_i)`` is made here from the blocks the
+    kernel holds anyway (once a query tile where the walk is static,
+    once a tile otherwise), less the log-sum-exp's own cotangent where
+    the call has one (``with_g_lse``: since ∂lse_i/∂s_ij = p_ij, its
+    whole contribution is ``ds += g_lse_i · p``).  A block holds
+    ``heads`` heads side by side (:func:`_take`), walked one after
+    the other, as in the forward.
+
+    A program holds one key tile, or every key tile of its heads where
     the walk is static (:data:`_STATIC_WALK_ELEMS`); dq then adds up in
     values, one a query tile (through a VMEM scratch the same walk read
     0.03 ms a call slower at the cell's shape: my chip runs, PR 28).
     Otherwise the grid is
-    ``(batch·heads, key tiles)`` with the key-tile axis ``arbitrary``:
-    the programs of one head run in order on one core, so a ``[Tq, D]``
-    float32 VMEM scratch (``dq_acc_ref``) is zeroed at the head's first
-    key tile, every later one adds to it, and the last writes dq out
-    (``dq_ref``'s block ignores the key-tile index, so it goes to HBM
-    when the head changes).  Either way dq leaves the kernel once,
-    scaled and in the gradient's dtype: no per-key-tile float32 dq plane
-    in HBM, no zero-fill of one, no XLA sum over it.
+    ``(batch·blocks, key tiles)`` with the key-tile axis ``arbitrary``:
+    the programs of one block run in order on one core, so a
+    ``[heads, Tq, D]`` float32 VMEM scratch (``dq_acc_ref``) is zeroed
+    at the block's first key tile, every later one adds to it, and the
+    last writes dq out (``dq_ref``'s block ignores the key-tile index,
+    so it goes to HBM when the block changes).  Either way dq leaves the
+    kernel once, scaled and in the gradient's dtype: no per-key-tile
+    float32 dq plane in HBM, no zero-fill of one, no XLA sum over it.
 
     Two loop bodies over the query tiles (:func:`_causal_q_tiles`), as in
     the forward: the tiles the diagonal crosses pay two iotas, a compare
@@ -281,96 +330,124 @@ def _flash_bwd_fused_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     tile: 5 MXU dots + 1 exp (a dq pass and a dkv pass of their own
     would run 8 + 2; docs/performance.md section 6).
     """
-    tq, d = q_ref.shape
+    g_lse_ref = rest[0] if with_g_lse else None
+    dq_ref, dk_ref, dv_ref, *dq_acc_ref = rest[with_g_lse:]
+    tq = q_ref.shape[0]
     n_qblocks = tq // block_q
     n_here = k_ref.shape[0] // block_k
     fold = _scale_folds(scale)
-    dq_tiles = [None] * n_qblocks     # a static walk's dq, by query tile
 
     def dq_out(dq):
         return (dq if fold else dq * scale).astype(dq_ref.dtype)
 
-    def k_tile(ki, cols):
-        v = v_ref[cols, :]    # storage dtype into the dots (see fwd kernel)
-        # with scale folded into k both s and this tile's dq come out
-        # scaled
-        k = _fold_scale(k_ref[cols, :], scale) if fold else k_ref[cols, :]
-        k_pos = (ki * block_k
-                 + lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
+    def head(h):
+        # a static walk's dq and delta, by query tile
+        dq_tiles = [None] * n_qblocks
+        delta_tiles = [None] * n_qblocks
 
-        if not static_walk:
-            @pl.when(ki == 0)
-            def _():
-                dq_acc_ref[0][:] = jnp.zeros((tq, d), jnp.float32)
+        def delta(qi, rows, g_blk):
+            if static_walk and delta_tiles[qi] is not None:
+                return delta_tiles[qi]
+            dl = jnp.sum(g_blk.astype(jnp.float32)
+                         * _take(o_ref, rows, h, heads)
+                         .astype(jnp.float32),
+                         axis=-1, keepdims=True)
+            if with_g_lse:
+                dl = dl - g_lse_ref[h, rows, :]
+            if static_walk:
+                delta_tiles[qi] = dl
+            return dl
 
-        def tile(qi, carry, masked):
-            rows = pl.ds(qi * block_q, block_q)
-            q_blk = q_ref[rows, :]
-            g_blk = g_ref[rows, :]
-            s = jax.lax.dot_general(q_blk, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            if not fold:
-                s = s * scale
-            p = jnp.exp(s - lse_ref[rows, :])  # ONCE; lse arrives [bq, 1]
-            if masked:
-                q_pos = (qi * block_q
-                         + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0))
-                p = jnp.where(q_pos >= k_pos, p, 0.0)
-            dv = jax.lax.dot_general(
-                p.astype(g_blk.dtype), g_blk, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            gv = jax.lax.dot_general(g_blk, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            ds = (p * (gv - delta_ref[rows, :])).astype(q_blk.dtype)
-            dk = jax.lax.dot_general(
-                ds, q_blk, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dq = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
+        def k_tile(ki, cols):
+            # storage dtype into the dots (see fwd kernel)
+            v = _take(v_ref, cols, h, heads)
+            # with scale folded into k both s and this tile's dq come
+            # out scaled
+            k = _take(k_ref, cols, h, heads)
+            if fold:
+                k = _fold_scale(k, scale)
+            k_pos = (ki * block_k
+                     + lax.broadcasted_iota(jnp.int32, (1, block_k), 1))
+
             if not static_walk:
-                dq_acc_ref[0][rows, :] += dq
-            elif dq_tiles[qi] is None:
-                dq_tiles[qi] = dq
+                @pl.when(ki == 0)
+                def _():
+                    dq_acc_ref[0][h] = jnp.zeros(dq_acc_ref[0].shape[1:],
+                                                 jnp.float32)
+
+            def tile(qi, carry, masked):
+                rows = pl.ds(qi * block_q, block_q)
+                q_blk = _take(q_ref, rows, h, heads)
+                g_blk = _take(g_ref, rows, h, heads)
+                s = jax.lax.dot_general(q_blk, k, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
+                if not fold:
+                    s = s * scale
+                # ONCE; lse arrives [bq, 1]
+                p = jnp.exp(s - lse_ref[h, rows, :])
+                if masked:
+                    q_pos = (qi * block_q + lax.broadcasted_iota(
+                        jnp.int32, (block_q, 1), 0))
+                    p = jnp.where(q_pos >= k_pos, p, 0.0)
+                dv = jax.lax.dot_general(
+                    p.astype(g_blk.dtype), g_blk, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                gv = jax.lax.dot_general(g_blk, v, (((1,), (1,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                ds = (p * (gv - delta(qi, rows, g_blk))).astype(q_blk.dtype)
+                dk = jax.lax.dot_general(
+                    ds, q_blk, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                dq = jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+                if not static_walk:
+                    dq_acc_ref[0][h, rows, :] += dq
+                elif dq_tiles[qi] is None:
+                    dq_tiles[qi] = dq
+                else:
+                    dq_tiles[qi] += dq
+                if carry is not None:
+                    dk, dv = carry[0] + dk, carry[1] + dv
+                return dk, dv
+
+            # as in the forward: a static walk starts from its first tile
+            carry = None if static_walk else (
+                jnp.zeros(k.shape, jnp.float32),) * 2
+            if causal:
+                first, full = _causal_q_tiles(ki, block_q, block_k,
+                                              n_qblocks)
+                carry = _loop(first, full,
+                              functools.partial(tile, masked=True), carry,
+                              static_walk)
             else:
-                dq_tiles[qi] += dq
-            if carry is not None:
-                dk, dv = carry[0] + dk, carry[1] + dv
-            return dk, dv
+                full = 0
+            carry = _loop(full, n_qblocks,
+                          functools.partial(tile, masked=False), carry,
+                          static_walk)
+            # a causal key tile past the last query row is seen by nobody
+            dk, dv = carry if carry is not None else (
+                jnp.zeros(k.shape, jnp.float32),) * 2
+            # ds came from s = scale · q·kᵀ, so dk = scale · Σ dsᵀq; dq
+            # likewise, unless the folded k already carried the scale
+            # into ds·k
+            _put(dk_ref, cols, h, (dk * scale).astype(dk_ref.dtype))
+            _put(dv_ref, cols, h, dv.astype(dv_ref.dtype))
 
-        # as in the forward: a static walk starts from its first tile
-        carry = None if static_walk else (
-            jnp.zeros((block_k, d), jnp.float32),
-            jnp.zeros((block_k, d), jnp.float32))
-        if causal:
-            first, full = _causal_q_tiles(ki, block_q, block_k, n_qblocks)
-            carry = _loop(first, full, functools.partial(tile, masked=True),
-                          carry, static_walk)
-        else:
-            full = 0
-        carry = _loop(full, n_qblocks,
-                      functools.partial(tile, masked=False), carry,
-                      static_walk)
-        # a causal key tile past the last query row is seen by nobody
-        dk, dv = carry if carry is not None else (
-            jnp.zeros((block_k, d), jnp.float32),) * 2
-        # ds came from s = scale · q·kᵀ, so dk = scale · Σ dsᵀq; dq
-        # likewise, unless the folded k already carried the scale into
-        # ds·k
-        dk_ref[cols, :] = (dk * scale).astype(dk_ref.dtype)
-        dv_ref[cols, :] = dv.astype(dv_ref.dtype)
+            if not static_walk:
+                @pl.when(ki == pl.num_programs(1) - 1)
+                def _():
+                    _put(dq_ref, slice(None), h, dq_out(dq_acc_ref[0][h]))
 
-        if not static_walk:
-            @pl.when(ki == pl.num_programs(1) - 1)
-            def _():
-                dq_ref[:] = dq_out(dq_acc_ref[0][:])
+        for j in range(n_here):
+            k_tile(j if static_walk else pl.program_id(1),
+                   pl.ds(j * block_k, block_k))
+        if static_walk:
+            # every query tile sees key tile 0, so none is left None
+            for qi, dq in enumerate(dq_tiles):
+                _put(dq_ref, pl.ds(qi * block_q, block_q), h, dq_out(dq))
 
-    for j in range(n_here):
-        k_tile(j if static_walk else pl.program_id(1),
-               pl.ds(j * block_k, block_k))
-    if static_walk:
-        # every query tile sees key tile 0, so none is left None
-        for qi, dq in enumerate(dq_tiles):
-            dq_ref[pl.ds(qi * block_q, block_q), :] = dq_out(dq)
+    for h in range(heads):
+        head(h)
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, scale,
@@ -510,18 +587,22 @@ def _interpret_forced():
 _WARNED_FALLBACK = set()
 
 
-def _warn_fallback(q, k, path):
-    """On the tpu backend a shape the flash kernels cannot take is
-    visible: one warning per (shape, path) at trace time.  Off the chip
-    the non-kernel path is the normal one and stays silent."""
-    key = (tuple(q.shape), tuple(k.shape), path)
+def _warn_once(key, message):
+    """On the tpu backend a shape the flash kernels cannot take as asked
+    is visible: one warning per ``key`` at trace time.  Off the chip the
+    non-kernel path is the normal one and stays silent."""
     if not _on_tpu() or key in _WARNED_FALLBACK:
         return
     _WARNED_FALLBACK.add(key)
-    warnings.warn(
-        f"flash attention: q{list(q.shape)} k{list(k.shape)} does not "
-        f"tile (T must be a multiple of its block); running {path} "
-        "instead of the Pallas kernels", UserWarning, stacklevel=3)
+    warnings.warn("flash attention: " + message, UserWarning, stacklevel=4)
+
+
+def _warn_fallback(q, k, path):
+    _warn_once(
+        (tuple(q.shape), tuple(k.shape), path),
+        f"q{list(q.shape)} k{list(k.shape)} does not tile (T must be a "
+        f"multiple of its block); running {path} instead of the Pallas "
+        "kernels")
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
@@ -562,41 +643,85 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     return out.reshape(B, H, Tq, D)
 
 
+def _column_spec(rows, width, blocks, part, whole):
+    """``(rows, width)`` blocks of an ``[N, T, columns]`` operand: the
+    program ``(b, i)`` takes batch row ``b // blocks`` and column block
+    ``part · blocks + b % blocks`` (``part``: which of q, k, v, where the
+    three are one array), all of T (``whole``) or its ``i``-th rows.
+    Heads-first operands are the case ``blocks = 1``, ``part = 0``."""
+    return pl.BlockSpec(
+        (None, rows, width),
+        lambda b, i: (b // blocks, 0 if whole else i,
+                      part * blocks + b % blocks))
+
+
+def _stat_spec(rows, heads, blocks, whole):
+    """The same walk over an ``[N, heads a row, T, 1]`` row statistic."""
+    return pl.BlockSpec(
+        (None, heads, rows, 1),
+        lambda b, i: (b // blocks, b % blocks, 0 if whole else i, 0))
+
+
+def _call_geometry(q, d, heads, fused):
+    """``(batch rows, columns of one of q, k, v, column blocks a row)``
+    of a call whose operands are ``[N, T, columns]``."""
+    columns = q.shape[-1] // (3 if fused else 1)
+    return q.shape[0], columns, columns // (heads * d)
+
+
 @functools.partial(jax.jit, static_argnames=(
-    "block_q", "block_k", "causal", "scale", "static_walk", "interpret"))
-def _lse_forward_call(qr, kr, vr, *, block_q, block_k, causal, scale,
-                      static_walk, interpret):
+    "d", "heads", "fused", "block_q", "block_k", "causal", "scale",
+    "static_walk", "interpret"))
+def _lse_forward_call(q, k, v, *, d, heads, fused, block_q, block_k,
+                      causal, scale, static_walk, interpret):
     """The forward's one ``pallas_call``.  A jit of its own, everything
     but the operands static: the 24 layers of a step then share ONE
     trace of the kernel and one lowering, where each call site used to
     trace its own (an unrolled walk of ten tiles is ten times the
     equations; the step's set-up rose by 7 s until this: my chip runs,
-    PR 28)."""
-    BH, Tq, D = qr.shape
-    Tk = kr.shape[1]
+    PR 28).
+
+    Two forms, one kernel.  Heads-first: q, k, v are ``[B·H, T, D]``,
+    ``heads = 1``.  Rows (``fused``): q, k and v are ONE array, the qkv
+    GEMM's own ``[B, T, 3·H·D]`` given three times, a block is a
+    ``heads · d``-lane column block of it, and the output is
+    ``[B, T, H·D]``, what the next GEMM takes.  Returns ``(out, lse
+    [N, heads a row, T, 1])``."""
+    n, columns, blocks = _call_geometry(q, d, heads, fused)
+    Tq, Tk = q.shape[1], k.shape[1]
+    width = heads * d
     q_rows = Tq if static_walk else block_q   # of q, in one program
+    parts = (0, 1, 2) if fused else (0, 0, 0)
     return pl.pallas_call(
-        functools.partial(_flash_kernel_lse, block_q=block_q,
+        functools.partial(_flash_kernel_lse, heads=heads, block_q=block_q,
                           block_k=block_k, causal=causal, scale=scale,
                           static_walk=static_walk),
         name="_flash_kernel_lse",
-        grid=(BH, Tq // q_rows),
+        grid=(n * blocks, Tq // q_rows),
         in_specs=[
-            pl.BlockSpec((None, q_rows, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
+            _column_spec(q_rows, width, blocks, parts[0], False),
+            _column_spec(Tk, width, blocks, parts[1], True),
+            _column_spec(Tk, width, blocks, parts[2], True),
         ],
         out_specs=[
-            pl.BlockSpec((None, q_rows, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, q_rows, 1), lambda b, i: (b, i, 0)),
+            _column_spec(q_rows, width, blocks, 0, False),
+            _stat_spec(q_rows, heads, blocks, False),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((BH, Tq, D), qr.dtype),
-            jax.ShapeDtypeStruct((BH, Tq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, Tq, columns), q.dtype),
+            jax.ShapeDtypeStruct((n, blocks * heads, Tq, 1), jnp.float32),
         ],
         interpret=interpret,
         compiler_params=_COMPILER_PARAMS,
-    )(qr, kr, vr)
+    )(q, k, v)
+
+
+def _lse_tiles(leg, tq, tk, d, causal, block_q, block_k):
+    """``(block_q, block_k)`` of a training kernel's call:
+    :func:`_flash_tiles` clamped to the lengths."""
+    block_q, block_k = _flash_tiles(leg, tq, tk, d, causal, block_q,
+                                    block_k)
+    return min(block_q, tq), min(block_k, tk)
 
 
 def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=None,
@@ -607,56 +732,73 @@ def flash_attention_fwd(q, k, v, causal=False, scale=None, block_q=None,
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
-    block_q, block_k = _flash_tiles("fwd", Tq, Tk, D, causal, block_q,
-                                    block_k)
-    block_q = min(block_q, Tq)
-    block_k = min(block_k, Tk)
+    block_q, block_k = _lse_tiles("fwd", Tq, Tk, D, causal, block_q,
+                                  block_k)
     out, lse = _lse_forward_call(
         q.reshape(B * H, Tq, D), k.reshape(B * H, Tk, D),
-        v.reshape(B * H, Tk, D), block_q=block_q, block_k=block_k,
+        v.reshape(B * H, Tk, D), d=D, heads=1, fused=False,
+        block_q=block_q, block_k=block_k,
         causal=causal, scale=scale, interpret=interpret,
         static_walk=_static_walk(Tq, Tk, block_q, block_k, causal))
     return out.reshape(B, H, Tq, D), lse.reshape(B, H, Tq)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_q", "block_k", "causal", "scale", "static_walk", "interpret"))
-def _fused_backward_call(qr, kr, vr, gr, lser, delta, *, block_q, block_k,
-                         causal, scale, static_walk, interpret):
-    """The fused backward's one ``pallas_call``, a jit of its own as
-    :func:`_lse_forward_call` is."""
-    BH, Tq, D = qr.shape
-    Tk = kr.shape[1]
+    "d", "heads", "fused", "block_q", "block_k", "causal", "scale",
+    "static_walk", "interpret"))
+def _fused_backward_call(q, k, v, g, out, lse, g_lse, *, d, heads, fused,
+                         block_q, block_k, causal, scale, static_walk,
+                         interpret):
+    """The fused backward's one ``pallas_call``, a jit of its own and in
+    the two forms :func:`_lse_forward_call` has: ``g``, ``out`` and the
+    three gradients are ``[N, T, columns of one of q, k, v]``, ``lse``
+    and ``g_lse`` (or ``None``) ``[N, heads a row, T, 1]``."""
+    n, columns, blocks = _call_geometry(q, d, heads, fused)
+    Tq, Tk = q.shape[1], k.shape[1]
+    width = heads * d
     k_rows = Tk if static_walk else block_k   # of k and v, in one program
+    parts = (0, 1, 2) if fused else (0, 0, 0)
+    with_g_lse = g_lse is not None
+    whole = _column_spec(Tq, width, blocks, 0, True)
+    stat = _stat_spec(Tq, heads, blocks, True)
+    here = _column_spec(k_rows, width, blocks, 0, False)
+    grad = jax.ShapeDtypeStruct((n, Tq, columns), q.dtype)
     return pl.pallas_call(
-        functools.partial(_flash_bwd_fused_kernel, block_q=block_q,
-                          block_k=block_k, causal=causal, scale=scale,
-                          static_walk=static_walk),
+        functools.partial(_flash_bwd_fused_kernel, heads=heads,
+                          block_q=block_q, block_k=block_k, causal=causal,
+                          scale=scale, static_walk=static_walk,
+                          with_g_lse=with_g_lse),
         name="_flash_bwd_fused_kernel",
-        grid=(BH, Tk // k_rows),
+        grid=(n * blocks, Tk // k_rows),
         in_specs=[
-            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, k_rows, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, k_rows, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tq, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tq, 1), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, Tq, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, k_rows, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, k_rows, D), lambda b, i: (b, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, Tq, D), qr.dtype),
-            jax.ShapeDtypeStruct((BH, Tk, D), kr.dtype),
-            jax.ShapeDtypeStruct((BH, Tk, D), vr.dtype),
-        ],
+            _column_spec(Tq, width, blocks, parts[0], True),
+            _column_spec(k_rows, width, blocks, parts[1], False),
+            _column_spec(k_rows, width, blocks, parts[2], False),
+            whole, whole, stat] + [stat] * with_g_lse,
+        out_specs=[whole, here, here],
+        out_shape=[grad,
+                   jax.ShapeDtypeStruct((n, Tk, columns), k.dtype),
+                   jax.ShapeDtypeStruct((n, Tk, columns), v.dtype)],
         scratch_shapes=([] if static_walk
-                        else [pltpu.VMEM((Tq, D), jnp.float32)]),
+                        else [pltpu.VMEM((heads, Tq, width), jnp.float32)]),
         interpret=interpret,
         compiler_params=_BWD_FUSED_COMPILER_PARAMS,
-    )(qr, kr, vr, gr, lser, delta)
+    )(q, k, v, g, out, lse, *([g_lse] * with_g_lse))
+
+
+def _bwd_tiles(tq, tk, d, causal, block_q, block_k, bwd_block_q,
+               bwd_block_k):
+    """The fused backward's tiles: ``bwd_block_q``/``bwd_block_k``, else
+    what :func:`_flash_tiles` resolves for the ``"bwd"`` leg;
+    ``block_q``/``block_k`` are the pair the shape was validated with
+    (:func:`_flash_blocks`) and take over where the backward's own tiles
+    do not divide this T (ragged lengths reached with explicit forward
+    blocks)."""
+    block_q, block_k = _flash_blocks(block_q, block_k, tq=tq, tk=tk)
+    bq, bk = _lse_tiles("bwd", tq, tk, d, causal, bwd_block_q, bwd_block_k)
+    if tq % bq or tk % bk:
+        bq, bk = min(block_q, tq), min(block_k, tk)
+    return bq, bk
 
 
 def flash_attention_bwd(q, k, v, out, lse, g, causal=False, scale=None,
@@ -665,46 +807,27 @@ def flash_attention_bwd(q, k, v, out, lse, g, causal=False, scale=None,
     """Backward: (dq, dk, dv) with flash memory behavior, through the
     fused one-pass kernel (:func:`_flash_bwd_fused_kernel`): one
     recompute of each (qi, ki) attention tile feeds dq, dk and dv
-    together.  Its tiles are ``bwd_block_q``/``bwd_block_k``, else what
-    :func:`_flash_tiles` resolves for the ``"bwd"`` leg;
-    ``block_q``/``block_k`` are the pair the shape was validated with
-    (:func:`_flash_blocks`) and take over where the backward's own tiles
-    do not divide this T (ragged lengths reached with explicit forward
-    blocks).
+    together.  Its tiles: :func:`_bwd_tiles`.
 
-    ``g_lse``: optional cotangent of the lse output.  Since
-    ∂lse_i/∂s_ij = p_ij, its whole contribution is ``ds += g_lse_i * p``
-    — algebraically identical to replacing ``delta`` with
-    ``delta - g_lse`` in the kernel (``ds = p*(gv - delta)``), so the
-    kernel needs no change.  Ring attention depends on this: the
+    ``g_lse``: optional cotangent of the lse output, which the kernel
+    takes off ``delta``.  Ring attention depends on this: the
     cross-block merge weights are functions of each block's lse."""
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
-    block_q, block_k = _flash_blocks(block_q, block_k, tq=Tq, tk=Tk)
-    block_q = min(block_q, Tq)
-    block_k = min(block_k, Tk)
-    qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * H, Tk, D)
-    vr = v.reshape(B * H, Tk, D)
-    gr = g.reshape(B * H, Tq, D)
-    lser = lse.reshape(B * H, Tq, 1)  # trailing singleton: Mosaic-legal
-    # delta_i = rowsum(g_i * out_i) — one fused elementwise reduce
-    delta = jnp.sum(gr.astype(jnp.float32)
-                    * out.reshape(B * H, Tq, D).astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    if g_lse is not None:
-        delta = delta - g_lse.reshape(B * H, Tq, 1).astype(jnp.float32)
+    bq, bk = _bwd_tiles(Tq, Tk, D, causal, block_q, block_k, bwd_block_q,
+                        bwd_block_k)
 
-    bq, bk = _flash_tiles("bwd", Tq, Tk, D, causal, bwd_block_q,
-                          bwd_block_k)
-    bq = min(bq, Tq)
-    bk = min(bk, Tk)
-    if Tq % bq or Tk % bk:
-        bq, bk = block_q, block_k
+    def stat(x):  # trailing singleton: Mosaic-legal
+        return x.reshape(B * H, 1, Tq, 1).astype(jnp.float32)
+
     dq, dk, dv = _fused_backward_call(
-        qr, kr, vr, gr, lser, delta, block_q=bq, block_k=bk,
-        causal=causal, scale=scale, interpret=interpret,
+        q.reshape(B * H, Tq, D), k.reshape(B * H, Tk, D),
+        v.reshape(B * H, Tk, D), g.reshape(B * H, Tq, D),
+        out.reshape(B * H, Tq, D), stat(lse),
+        None if g_lse is None else stat(g_lse), d=D, heads=1, fused=False,
+        block_q=bq, block_k=bk, causal=causal, scale=scale,
+        interpret=interpret,
         static_walk=_static_walk(Tq, Tk, bq, bk, causal))
     return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
             dv.reshape(B, H, Tk, D))
@@ -755,6 +878,120 @@ def attention(q, k, v, causal=False, scale=None):
     if interpret or _on_tpu():
         return _flash_diff(q, k, v, causal, scale, interpret)
     return xla_attention(q, k, v, causal=causal, scale=scale)
+
+
+# ---------------------------------------------------------------------------
+# self-attention over the qkv GEMM's own rows
+# ---------------------------------------------------------------------------
+
+def split_heads(qkv, n_heads):
+    """``[B, T, 3·H·D]`` (columns ordered ``(3, H, D)``, what one qkv
+    GEMM emits) -> q, k, v as ``[B, H, T, D]``.  On the chip each is a
+    physical rewrite: a minor dimension of 64 is stored in 128-lane
+    tiles."""
+    B, T, C = qkv.shape
+    qkv = qkv.reshape(B, T, 3, n_heads, C // (3 * n_heads))
+    return [jnp.moveaxis(qkv[:, :, i], 1, 2) for i in range(3)]
+
+
+def merge_heads(out):
+    """``[B, H, T, D]`` -> ``[B, T, H·D]``, what the output GEMM takes."""
+    B, H, T, D = out.shape
+    return jnp.moveaxis(out, 2, 1).reshape(B, T, H * D)
+
+
+def _rows_heads(qkv, n_heads):
+    """Heads a 128-lane column block where the training kernels can read
+    ``qkv [B, T, 3·H·D]`` as it lies, else 0: a block is whole heads
+    (``128 % D == 0``), the heads fill whole blocks
+    (``H·D % 128 == 0``), and T tiles."""
+    T, d = qkv.shape[1], qkv.shape[2] // (3 * n_heads)
+    block = min(_flash_blocks(tq=T, tk=T)[0], T)
+    if 128 % d or (n_heads * d) % 128 or T % block:
+        return 0
+    return 128 // d
+
+
+def flash_self_attention_fwd(qkv, n_heads, causal=False, scale=None,
+                             block_q=None, block_k=None, interpret=False):
+    """The log-sum-exp forward in its rows form: ``qkv [B, T, 3·H·D]``
+    -> ``(out [B, T, H·D], lse [B, H, T, 1])``.  The caller has asked
+    :func:`_rows_heads`."""
+    T, d = qkv.shape[1], qkv.shape[2] // (3 * n_heads)
+    scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    block_q, block_k = _lse_tiles("fwd", T, T, d, causal, block_q, block_k)
+    return _lse_forward_call(
+        qkv, qkv, qkv, d=d, heads=_rows_heads(qkv, n_heads), fused=True,
+        block_q=block_q, block_k=block_k, causal=causal, scale=scale,
+        interpret=interpret,
+        static_walk=_static_walk(T, T, block_q, block_k, causal))
+
+
+def flash_self_attention_bwd(qkv, out, lse, g, n_heads, causal=False,
+                             scale=None, interpret=False, bwd_block_q=None,
+                             bwd_block_k=None):
+    """The fused backward in its rows form: ``g [B, T, H·D]``, as the
+    output GEMM's backward leaves it -> the ``[B, T, 3·H·D]`` cotangent
+    the qkv GEMM's backward contracts over.  dq, dk, dv leave the kernel
+    as three ``[B, T, H·D]`` arrays and are joined on the last axis: no
+    gradient is padded to the whole cotangent, nothing is transposed."""
+    T, d = qkv.shape[1], qkv.shape[2] // (3 * n_heads)
+    scale = scale if scale is not None else 1.0 / (d ** 0.5)
+    bq, bk = _bwd_tiles(T, T, d, causal, None, None, bwd_block_q,
+                        bwd_block_k)
+    dq, dk, dv = _fused_backward_call(
+        qkv, qkv, qkv, g, out, lse, None, d=d,
+        heads=_rows_heads(qkv, n_heads), fused=True, block_q=bq,
+        block_k=bk, causal=causal, scale=scale, interpret=interpret,
+        static_walk=_static_walk(T, T, bq, bk, causal))
+    return jnp.concatenate([dq, dk, dv], axis=-1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _flash_rows_diff(qkv, n_heads, causal, scale, interpret):
+    return flash_self_attention_fwd(qkv, n_heads, causal=causal,
+                                    scale=scale, interpret=interpret)[0]
+
+
+def _flash_rows_fwd(qkv, n_heads, causal, scale, interpret):
+    out, lse = flash_self_attention_fwd(qkv, n_heads, causal=causal,
+                                        scale=scale, interpret=interpret)
+    # nothing in [B, H, T, D] is kept for the backward
+    return out, (qkv, out, lse)
+
+
+def _flash_rows_bwd(n_heads, causal, scale, interpret, res, g):
+    qkv, out, lse = res
+    return (flash_self_attention_bwd(qkv, out, lse, g, n_heads,
+                                     causal=causal, scale=scale,
+                                     interpret=interpret),)
+
+
+_flash_rows_diff.defvjp(_flash_rows_fwd, _flash_rows_bwd)
+
+
+def self_attention(qkv, n_heads, causal=False, scale=None):
+    """Self-attention between two GEMMs: ``qkv [B, T, 3·H·D]`` (columns
+    ordered ``(3, H, D)``) -> ``[B, T, H·D]``.
+
+    Where the kernels run (as for :func:`attention`) and the shape
+    allows it (:func:`_rows_heads`), both training kernels read the qkv
+    GEMM's rows as they lie and write the rows the output GEMM reads: no
+    transpose, pad, slice or relayout on either side, forwards or
+    backwards.  Any other call splits the heads and takes
+    :func:`attention`; on the chip it says so once."""
+    interpret = _interpret_forced()  # raises on a tpu backend
+    if interpret or _on_tpu():
+        if _rows_heads(qkv, n_heads):
+            return _flash_rows_diff(qkv, n_heads, causal, scale, interpret)
+        _warn_once(
+            (tuple(qkv.shape), n_heads, "rows"),
+            f"qkv{list(qkv.shape)} with {n_heads} heads cannot be read as "
+            "rows (a 128-lane block must hold whole heads, the heads fill "
+            "whole blocks, and T must tile); q, k, v and the result go "
+            "through [B, H, T, D] instead")
+    return merge_heads(attention(*split_heads(qkv, n_heads), causal=causal,
+                                 scale=scale))
 
 
 # ---------------------------------------------------------------------------

@@ -102,6 +102,46 @@ def test_lse_kernel_pair_compiles_at_sequence_parallel_shapes(
         assert name in text
 
 
+def test_rows_form_compiles_between_its_gemms_and_moves_nothing(
+        one_chip, no_persistent_cache):
+    """GPT-2-medium's attention layer a chip (``[4, 1024, 1024]``
+    bfloat16, 16 heads of 64): qkv GEMM -> the rows form of both
+    training kernels -> output GEMM, forwards and backwards.  The custom
+    calls take the qkv GEMM's ``[4, 1024, 3072]`` and the output GEMM's
+    ``[4, 1024, 1024]`` in the GEMMs' own row-major layout, and nothing
+    of head shape is copied, transposed or padded on the way."""
+    import re
+    B, T, H, D = 4, 1024, 16, 64
+
+    def layer(x, w_qkv, w_proj):
+        qkv = (x.reshape(B * T, H * D) @ w_qkv).reshape(B, T, 3 * H * D)
+        assert fa._rows_heads(qkv, H) == 2
+        out = fa._flash_rows_diff(qkv, H, True, None, False)
+        return x + (out.reshape(B * T, H * D) @ w_proj).reshape(B, T, H * D)
+
+    def loss(x, w_qkv, w_proj):
+        return jnp.sum(layer(x, w_qkv, w_proj).astype(jnp.float32) ** 2)
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    _, text = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                       spec(B, T, H * D), spec(H * D, 3 * H * D),
+                       spec(H * D, H * D))
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert len(calls) == 2, calls
+    fwd = next(c for c in calls if "_flash_kernel_lse" in c)
+    bwd = next(c for c in calls if "_flash_bwd_fused_kernel" in c)
+    rows, heads = "bf16[4,1024,3072]{2,1,0}", "bf16[4,1024,1024]{2,1,0}"
+    assert fwd.count(rows) >= 3 and bwd.count(rows) >= 3
+    assert bwd.count(heads) >= 2          # g and out, beside dq, dk, dv
+    moved = re.compile(
+        r"= \S*\[(4,16,1024,64|4,1024,16,64|4,1024,3,16,64)\]\S* "
+        r"(copy|transpose|pad)\(")
+    assert not [line for line in text.splitlines() if moved.search(line)]
+
+
 # -- the serving programs ----------------------------------------------------
 
 @pytest.fixture(scope="module")

@@ -81,14 +81,41 @@ def test_committed_causal_tiles_come_from_the_recorded_sweep(leg, kernel):
     assert by_blocks[committed] <= 1.03 * min(by_blocks.values())
 
 
-def test_fused_structure_gate():
+@pytest.mark.parametrize("leg,kernel", [
+    ("fwd", "_flash_kernel_lse"), ("bwd", "_flash_bwd_fused_kernel")])
+def test_rows_form_keeps_the_committed_tiles_and_separation(leg, kernel):
+    """PR 30's chip sweep of the rows form (the qkv GEMM's own
+    ``[4, 1024, 3072]``, two heads a 128-lane block) is recorded whole:
+    the tiles the table holds are within 3 % of the fastest row there
+    too, so the table needs no key for the form, and the committed way
+    to part a block's heads (``mask``) was the faster of the two tried
+    at those tiles."""
+    b = _budgets()
+    rows_form = b["rows_form_T1024_D64"]
+    assert {(r["block_q"], r["block_k"]) for r in rows_form["rows"]} == {
+        (bq, bk) for bq in (128, 256, 512, 1024)
+        for bk in (128, 256, 512, 1024)}
+    by_blocks = {(r["block_q"], r["block_k"]): r["kernel_ms"][kernel]
+                 for r in rows_form["rows"]}
+    committed = tuple(b["causal_block_table"]["1024x64"][leg])
+    assert by_blocks[committed] <= 1.03 * min(by_blocks.values())
+    tried = {r["separation"]: r["kernel_ms"][kernel]
+             for r in rows_form["head_separations"]["rows"]
+             if (r["block_q"], r["block_k"]) == committed}
+    assert set(tried) == {"heads_first", "lanes", "mask"}
+    assert tried["mask"] < tried["lanes"]
+
+
+@pytest.mark.parametrize("form", flash_sweep.FORMS)
+def test_fused_structure_gate(form):
     """Recompute-once, machine-checked: the backward is ONE pallas
     kernel with ONE exp a tile walked (two loop bodies, one exp each).
     A PR that splits the pass again or adds a second exp(s - lse)
     recompute fails here and must either fix it or consciously
-    re-commit the structure section."""
+    re-commit the structure section.  In the rows form, where a block
+    holds two heads, the counts a head are the same."""
     b = _budgets()
-    census = flash_sweep.bwd_kernel_census(fa)
+    census = flash_sweep.bwd_kernel_census(fa, form=form)
     assert census == b["structure"]["bwd_kernels"], (
         f"backward structure drifted: traced {census}, committed "
         f"{b['structure']['bwd_kernels']}")

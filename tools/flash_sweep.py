@@ -93,84 +93,122 @@ def _kernel_ms(fn, args, reps=10):
 CHAIN = 24
 
 
-def measure_point(fa, B, H, D, T, bq, bk, reps, interp):
+#: how a point's operands lie: ``heads`` = q, k, v as ``[B, H, T, D]``;
+#: ``rows`` = the qkv GEMM's own ``[B, T, 3·H·D]``, ``128 // D`` heads a
+#: 128-lane column block
+FORMS = ("heads", "rows")
+
+
+def measure_point(fa, B, H, D, T, bq, bk, reps, interp, form="heads"):
     """One (T, block_q, block_k) sweep point → dict of leg
     timings/TFLOP/s: a call's share of :data:`CHAIN` chained calls, and
-    on the chip each kernel's own device time (``kernel_ms``).  Raises
-    on kernel failure — callers report and continue."""
+    on the chip each kernel's own device time (``kernel_ms``), in one of
+    :data:`FORMS`.  Raises on kernel failure — callers report and
+    continue."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     scale = 1.0 / (D ** 0.5)
-    q, k, v = (jnp.asarray(np.random.RandomState(i)
-                           .normal(0, 1, (B, H, T, D))
+
+    def draw(i, shape):
+        return jnp.asarray(np.random.RandomState(i).normal(0, 1, shape)
                            .astype(np.float32)).astype(jnp.bfloat16)
-               for i in range(3))
-    g = jnp.ones((B, H, T, D), jnp.bfloat16)
 
-    def fwd(q, k, v):
-        return fa.flash_attention_fwd(q, k, v, causal=True, scale=scale,
-                                      block_q=bq, block_k=bk,
-                                      interpret=interp)
+    if form == "heads":
+        operands = tuple(draw(i, (B, H, T, D)) for i in range(3))
+        g = jnp.ones((B, H, T, D), jnp.bfloat16)
 
-    out, lse = jax.jit(fwd)(q, k, v)
+        def fwd(q, k, v):
+            return fa.flash_attention_fwd(q, k, v, causal=True, scale=scale,
+                                          block_q=bq, block_k=bk,
+                                          interpret=interp)
 
-    def bwd(q, k, v, out, lse, g):
-        return fa.flash_attention_bwd(
-            q, k, v, out, lse, g, causal=True, scale=scale,
-            block_q=bq, block_k=bk, interpret=interp,
-            bwd_block_q=bq, bwd_block_k=bk)
+        def bwd(q, k, v, out, lse, g):
+            return fa.flash_attention_bwd(
+                q, k, v, out, lse, g, causal=True, scale=scale,
+                block_q=bq, block_k=bk, interpret=interp,
+                bwd_block_q=bq, bwd_block_k=bk)
 
-    def both(q, k, v, g):
-        o, l = fwd(q, k, v)
-        return bwd(q, k, v, o, l, g)
+        def feed(first, q):
+            # the call's first output (out, or dq) has q's shape and
+            # dtype: it is the next call's q
+            return first
+    else:
+        operands = (draw(0, (B, T, 3 * H * D)),)
+        g = jnp.ones((B, T, H * D), jnp.bfloat16)
+
+        def fwd(qkv):
+            return fa.flash_self_attention_fwd(
+                qkv, H, causal=True, scale=scale, block_q=bq, block_k=bk,
+                interpret=interp)
+
+        def bwd(qkv, out, lse, g):
+            return (fa.flash_self_attention_bwd(
+                qkv, out, lse, g, H, causal=True, scale=scale,
+                interpret=interp, bwd_block_q=bq, bwd_block_k=bk),)
+
+        def feed(first, qkv):
+            # the backward's output is the next call's qkv; the
+            # forward's is written over its q columns
+            return first if first.shape == qkv.shape else \
+                jax.lax.dynamic_update_slice(qkv, first, (0, 0, 0))
+
+    n = len(operands)
+    out, lse = jax.jit(fwd)(*operands)
+
+    def both(*args):
+        o, l = fwd(*args[:n])
+        return bwd(*args[:n], o, l, args[n])
 
     chain = 1 if interp else CHAIN
 
     def chained(fn):
-        # the call's first output (out, or dq) has q's shape and
-        # dtype: it is the next call's q
         if chain == 1:
             return fn
 
-        def run(q, *rest):
+        def run(x, *rest):
             return jax.lax.fori_loop(
-                0, chain, lambda _, q: fn(q, *rest)[0], q)
+                0, chain, lambda _, x: feed(fn(x, *rest)[0], x), x)
         return run
 
     row = {}
     for leg, fn, args in (
-            ("fwd", fwd, (q, k, v)),
-            ("bwd", bwd, (q, k, v, out, lse, g)),
-            ("fwd_bwd", both, (q, k, v, g))):
+            ("fwd", fwd, operands),
+            ("bwd", bwd, operands + (out, lse, g)),
+            ("fwd_bwd", both, operands + (g,))):
         dt = _timed(jax.jit(chained(fn)), args, reps) / chain
         row[f"{leg}_ms"] = round(dt * 1e3, 4)
         row[f"{leg}_tflops"] = round(
             model_flops(B, H, T, D, leg) / dt / 1e12, 1)
     if not interp:
-        row["kernel_ms"] = _kernel_ms(jax.jit(both), (q, k, v, g))
+        row["kernel_ms"] = _kernel_ms(jax.jit(both), operands + (g,))
     return row
 
 
-def bwd_kernel_census(fa, T=128, block=64):
+def bwd_kernel_census(fa, T=128, block=64, form="heads"):
     """Structural census of the backward lowering: for every backward
     pallas_call of a causal call whose walk is 3 tiles (T = 128 in 64 x
     64 tiles: two the diagonal crosses, one below it), the ``exp``
-    equations a tile costs.  Counted both ways a kernel walks: with the
-    walk unrolled (``fa._STATIC_WALK_ELEMS`` as committed: the exps in
-    the kernel over the tiles walked) and with it looped (forced: the
-    most exps any ONE loop body holds, and ``loop_bodies``, since a tile
-    is walked by exactly one body, masked where the diagonal crosses it
-    and unmasked elsewhere); ``exp_per_tile`` is the larger.  That is
-    the recompute-once property as a machine-checkable fact: ONE bwd
-    kernel, ONE exp a tile."""
+    equations a tile of a head costs.  Counted both ways a kernel walks:
+    with the walk unrolled (``fa._STATIC_WALK_ELEMS`` as committed: the
+    exps in the kernel over the tiles walked) and with it looped
+    (forced: the most exps any ONE loop body holds, and ``loop_bodies``,
+    since a tile is walked by exactly one body, masked where the
+    diagonal crosses it and unmasked elsewhere); ``exp_per_tile`` is the
+    larger.  That is the recompute-once property as a machine-checkable
+    fact: ONE bwd kernel, ONE exp a tile.  ``form="rows"`` counts the
+    same kernel where a block holds two heads (:data:`FORMS`): the
+    counts a head are the heads-first form's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    q, k, v, g = (jnp.asarray(np.random.RandomState(i)
-                              .normal(0, 1, (1, 2, T, 16))
-                              .astype(np.float32)) for i in range(4))
-    tiles = len(fa._causal_tile_walk(T, T, block, block))
+
+    def draw(i, shape):
+        return jnp.asarray(np.random.RandomState(i).normal(0, 1, shape)
+                           .astype(np.float32))
+
+    heads = 1 if form == "heads" else 2     # a block
+    tiles = len(fa._causal_tile_walk(T, T, block, block)) * heads
 
     def subjaxprs(eqn):
         for p in eqn.params.values():
@@ -201,13 +239,22 @@ def bwd_kernel_census(fa, T=128, block=64):
                     yield from kernels(sub)
 
     def trace():
-        out, lse = fa.flash_attention_fwd(q, k, v, causal=True,
-                                          block_q=block, block_k=block,
-                                          interpret=True)
-        return jax.make_jaxpr(lambda *a: fa.flash_attention_bwd(
-            *a, causal=True, block_q=block, block_k=block,
-            bwd_block_q=block, bwd_block_k=block, interpret=True))(
-                q, k, v, out, lse, g).jaxpr
+        if form == "heads":
+            q, k, v, g = (draw(i, (1, 2, T, 16)) for i in range(4))
+            out, lse = fa.flash_attention_fwd(q, k, v, causal=True,
+                                              block_q=block, block_k=block,
+                                              interpret=True)
+            return jax.make_jaxpr(lambda *a: fa.flash_attention_bwd(
+                *a, causal=True, block_q=block, block_k=block,
+                bwd_block_q=block, bwd_block_k=block, interpret=True))(
+                    q, k, v, out, lse, g).jaxpr
+        qkv, g = draw(0, (1, T, 3 * 2 * 64)), draw(1, (1, T, 2 * 64))
+        out, lse = fa.flash_self_attention_fwd(
+            qkv, 2, causal=True, block_q=block, block_k=block,
+            interpret=True)
+        return jax.make_jaxpr(lambda *a: fa.flash_self_attention_bwd(
+            *a, 2, causal=True, bwd_block_q=block, bwd_block_k=block,
+            interpret=True))(qkv, out, lse, g).jaxpr
 
     prev = fa._STATIC_WALK_ELEMS
     try:
@@ -223,7 +270,7 @@ def bwd_kernel_census(fa, T=128, block=64):
         if not list(loop_bodies(unrolled[name])):   # this kernel unrolls
             exps = count_exp(unrolled[name])
             per_tile = max(per_tile, -(-exps // tiles))
-        census[name] = {"loop_bodies": len(bodies),
+        census[name] = {"loop_bodies": len(bodies) // heads,
                         "exp_per_tile": per_tile}
     return census
 
@@ -269,6 +316,8 @@ def main():
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--blocks", default="256:256,512:512,512:1024,"
                     "1024:512,1024:1024,2048:1024")
+    ap.add_argument("--forms", default="heads",
+                    help="comma list of " + ", ".join(FORMS))
     ap.add_argument("--write-budgets", action="store_true")
     ap.add_argument("--platform", default=None)
     args = ap.parse_args()
@@ -301,24 +350,27 @@ def main():
             bq, bk = (int(x) for x in spec.split(":"))
             if bq > T or bk > T or T % bq or T % bk:
                 continue
-            base = {"probe": "flash_sweep", "T": T, "block_q": bq,
-                    "block_k": bk, "B": args.B, "H": args.H, "D": args.D}
-            if interp:
-                base["interpreted"] = True
-            try:
-                row = measure_point(fa, args.B, args.H, args.D, T, bq, bk,
-                                    reps, interp)
-            except Exception as e:  # noqa: BLE001 — keep sweeping
-                print(json.dumps(dict(
-                    base, error=f"{type(e).__name__}: {e}"[:200])),
-                    flush=True)
-                continue
-            print(json.dumps(dict(base, **row)), flush=True)
-            if not interp:
-                best = winners.get(T)
-                if best is None or row["fwd_bwd_tflops"] > \
-                        best["fwd_bwd_tflops"]:
-                    winners[T] = dict(row, blocks=[bq, bk])
+            for form in args.forms.split(","):
+                base = {"probe": "flash_sweep", "T": T, "block_q": bq,
+                        "block_k": bk, "B": args.B, "H": args.H,
+                        "D": args.D, "form": form}
+                if interp:
+                    base["interpreted"] = True
+                try:
+                    row = measure_point(fa, args.B, args.H, args.D, T, bq,
+                                        bk, reps, interp, form=form)
+                except Exception as e:  # noqa: BLE001 — keep sweeping
+                    print(json.dumps(dict(
+                        base, error=f"{type(e).__name__}: {e}"[:200])),
+                        flush=True)
+                    continue
+                print(json.dumps(dict(base, **row)), flush=True)
+                # the budgets' winners are the heads-first form's
+                if not interp and form == "heads":
+                    best = winners.get(T)
+                    if best is None or row["fwd_bwd_tflops"] > \
+                            best["fwd_bwd_tflops"]:
+                        winners[T] = dict(row, blocks=[bq, bk])
 
     if args.write_budgets and winners:
         write_budgets(winners, args)
