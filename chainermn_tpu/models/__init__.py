@@ -11,6 +11,8 @@ from .transformer import TransformerLM, TransformerBlock, MultiHeadAttention
 from .moe_transformer import (MoETransformerLM, MoETransformerBlock,
                               MoEFeedForward)
 from .latent_moe import LatentMoELM, LatentMoEBlock, LatentAttention
+from .window_moe import (WindowMoELM, WindowMoEBlock,
+                         GatedGroupedAttention)
 from .convnets import AlexNet, NIN, VGG16, GoogLeNet
 
 __all__ = ["MLP", "Classifier", "ResNet", "ResNet18", "ResNet50",
@@ -21,4 +23,5 @@ __all__ = ["MLP", "Classifier", "ResNet", "ResNet18", "ResNet50",
            "DCGANUpdater", "TransformerLM", "TransformerBlock",
            "MultiHeadAttention", "MoETransformerLM", "MoETransformerBlock",
            "MoEFeedForward", "LatentMoELM", "LatentMoEBlock",
-           "LatentAttention", "AlexNet", "NIN", "VGG16", "GoogLeNet"]
+           "LatentAttention", "WindowMoELM", "WindowMoEBlock",
+           "GatedGroupedAttention", "AlexNet", "NIN", "VGG16", "GoogLeNet"]

@@ -35,6 +35,7 @@ inner.
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import os
@@ -46,7 +47,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["attention", "flash_attention", "self_attention", "xla_attention"]
+__all__ = ["attention", "flash_attention", "grouped_attention",
+           "self_attention", "xla_attention", "xla_grouped_attention"]
 
 # Both grid dims are embarrassingly parallel (independent programs per
 # (batch*head, block) pair).  vmem_limit_bytes raises Mosaic's scoped-VMEM
@@ -85,10 +87,38 @@ def xla_attention(q, k, v, causal=False, scale=None):
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
+def xla_grouped_attention(q, k, v, scale=None, window=None):
+    """jnp reference (and non-TPU fallback) of the grouped causal
+    forward: ``q [B, H, T, D]`` over ``k``, ``v`` ``[B, G, T, D]``, query
+    head ``a`` reading K/V head ``a // (H // G)``, and under a ``window``
+    query i seeing keys ``(i - window, i]`` only.  No K/V head is
+    repeated in memory: the query heads of a group are one batched
+    product's rows."""
+    B, H, T, D = q.shape
+    G = k.shape[1]
+    scale = scale if scale is not None else 1.0 / (D ** 0.5)
+    qg = q.reshape(B, G, H // G, T, D)
+    s = jnp.einsum("bgrqd,bgkd->bgrqk", qg, k,
+                   preferred_element_type=jnp.float32) * scale
+    qpos = lax.broadcasted_iota(jnp.int32, (T, T), 0)
+    kpos = lax.broadcasted_iota(jnp.int32, (T, T), 1)
+    seen = qpos >= kpos
+    if window is not None:
+        seen &= qpos - kpos < window
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    out = jnp.einsum("bgrqk,bgkd->bgrqd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, H, T, D).astype(q.dtype)
+
+
 def _imin(a, b):
     """min for tile indices: Python ints in the tile-walk helper, traced
     int32 scalars inside the kernels."""
     return min(a, b) if isinstance(a, int) else jnp.minimum(a, b)
+
+
+def _imax(a, b):
+    return max(a, b) if isinstance(a, int) else jnp.maximum(a, b)
 
 
 def _causal_k_tiles(qi, block_q, block_k, n_kblocks):
@@ -112,15 +142,32 @@ def _causal_q_tiles(ki, block_q, block_k, n_qblocks):
     return first, full
 
 
-def _causal_tile_walk(tq, tk, block_q, block_k):
+def _band_k_tiles(qi, block_q, block_k, window):
+    """A causal call under a ``window`` (row i sees keys ``(i - window,
+    i]``): key tiles ``[0, first)`` of query tile ``qi`` lie wholly below
+    the band and are never computed, ``[first, inside)`` are crossed by
+    its lower edge, the tiles from ``inside`` on lie wholly above that
+    edge (:func:`_causal_k_tiles` says which of them the diagonal
+    crosses)."""
+    first = _imax(qi * block_q - (window - 1), 0) // block_k
+    inside = _imax(qi * block_q + block_q - 1 - window + block_k, 0) \
+        // block_k
+    return first, inside
+
+
+def _causal_tile_walk(tq, tk, block_q, block_k, window=None):
     """``[(qi, ki, masked)]``: the tiles a causal call computes with
-    these blocks, from the forward's bounds.  Pure Python: the tests
-    hold it against a brute-force mask and against the backward's
+    these blocks, from the forward's bounds; with a ``window``, the
+    tiles of the band alone (:func:`_band_k_tiles`).  Pure Python: the
+    tests hold it against a brute-force mask and against the backward's
     bounds, and count the share of the square the committed tiles cost."""
     walk = []
     for qi in range(tq // block_q):
         full, last = _causal_k_tiles(qi, block_q, block_k, tk // block_k)
-        walk += [(qi, ki, ki >= full) for ki in range(last)]
+        first, inside = (0, 0) if window is None else \
+            _band_k_tiles(qi, block_q, block_k, window)
+        walk += [(qi, ki, ki >= full or ki < inside)
+                 for ki in range(first, last)]
     return walk
 
 
@@ -498,6 +545,56 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, causal, scale,
     o_ref[:] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
+def _band_tiles(tq, block_q, block_k, window):
+    """The most key tiles any query tile's band touches (a trace-time
+    number: the window kernel walks this many from its first tile)."""
+    return max(collections.Counter(
+        qi for qi, _, _ in _causal_tile_walk(tq, tq, block_q, block_k,
+                                             window)).values())
+
+
+def _flash_window_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k, scale,
+                         window, n_band):
+    """`_flash_kernel` under a causal band: query i sees keys ``(i -
+    window, i]``, numbered alike from 0 (a whole prompt over itself).
+    One (head, query tile) program walks ``n_band`` key tiles from the
+    first one its band touches (:func:`_band_k_tiles`), straight-line:
+    the tiles wholly below the band are never read, and a step past the
+    diagonal (the first query tiles, whose band is cut short by key 0)
+    reads the last tile again under a mask that hides all of it.  Every
+    tile is masked on both edges, so the guards against an empty row
+    stay."""
+    bq, d = q_ref.shape
+    qi = pl.program_id(1)
+    n_kblocks = k_ref.shape[0] // block_k
+    q = q_ref[:]
+    q_pos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
+    first, _ = _band_k_tiles(qi, bq, block_k, window)
+    m = jnp.full((bq, 1), -jnp.inf, jnp.float32)
+    l = jnp.zeros((bq, 1), jnp.float32)
+    acc = jnp.zeros((bq, d), jnp.float32)
+    for t in range(n_band):
+        ki = first + t
+        cols = pl.ds(jnp.minimum(ki, n_kblocks - 1) * block_k, block_k)
+        s = jax.lax.dot_general(
+            q, k_ref[cols, :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        k_pos = ki * block_k + lax.broadcasted_iota(jnp.int32,
+                                                    (1, block_k), 1)
+        s = jnp.where((q_pos >= k_pos) & (q_pos - k_pos < window), s,
+                      -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.where(jnp.isfinite(s), jnp.exp(s - m_safe), 0.0)
+        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[cols, :], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m = m_new
+    o_ref[:] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+
 # Adaptive-default tile candidates, largest first: a tile is the largest
 # candidate that divides T.  This is what the serving forward
 # (`_flash_kernel`: one dynamic loop a query tile) resolves, and what a
@@ -605,35 +702,68 @@ def _warn_fallback(q, k, path):
         "kernels")
 
 
+#: Tiles of the window kernel, where they divide T: its walk is
+#: straight-line, so tiles under the window cost no loop latency, and at
+#: 256 a band of 512 touches 3 tiles a query tile (2/3 of what it
+#: computes lies inside the band; at 512 x 512 it would be 1/3).
+_WINDOW_BLOCK = 256
+
+
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, interpret=False):
-    """Fused attention via Pallas.  q/k/v: [B, H, T, D].  Default block
-    sizes come from :func:`_flash_blocks`."""
+                    block_k=None, interpret=False, window=None):
+    """Fused attention via Pallas.  ``q``: ``[B, H, T, D]``; ``k``, ``v``:
+    ``[B, G, T, D]`` with ``G`` dividing ``H``: query head ``a`` reads
+    the K/V block of head ``a // (H // G)``, which is never repeated in
+    memory (``G = H`` is plain multi-head attention).  ``window``: a
+    causal ``Tq == Tk`` call in which query i sees keys ``(i - window,
+    i]`` only; it lowers as `_flash_window_kernel`, which never walks a
+    tile wholly below the band.  Default block sizes come from
+    :func:`_flash_blocks`."""
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    G, Tk = k.shape[1], k.shape[2]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
+    if window is not None:
+        if not causal or Tq != Tk:
+            raise ValueError("a window needs a causal Tq == Tk call")
+        if block_q is None and block_k is None \
+                and Tq % _WINDOW_BLOCK == 0:
+            block_q = block_k = _WINDOW_BLOCK
     block_q, block_k = _flash_blocks(block_q, block_k, tq=Tq, tk=Tk)
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
     if Tq % block_q or Tk % block_k:
         _warn_fallback(q, k, "XLA attention")
-        return xla_attention(q, k, v, causal=causal, scale=scale)
+        if window is None and G == H:
+            return xla_attention(q, k, v, causal=causal, scale=scale)
+        return xla_grouped_attention(q, k, v, scale=scale, window=window)
 
     qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * H, Tk, D)
-    vr = v.reshape(B * H, Tk, D)
+    kr = k.reshape(B * G, Tk, D)
+    vr = v.reshape(B * G, Tk, D)
 
-    kernel = functools.partial(_flash_kernel, block_k=block_k,
-                               causal=causal, scale=scale,
-                               q_offset_blocks=0)
+    if G == H:
+        def kv_head(b, i):
+            return (b, 0, 0)
+    else:
+        def kv_head(b, i):
+            return (b // H * G + b % H // (H // G), 0, 0)
+    if window is None:
+        kernel = functools.partial(_flash_kernel, block_k=block_k,
+                                   causal=causal, scale=scale,
+                                   q_offset_blocks=0)
+    else:
+        kernel = functools.partial(
+            _flash_window_kernel, block_k=block_k, scale=scale,
+            window=window,
+            n_band=_band_tiles(Tq, block_q, block_k, window))
     out = pl.pallas_call(
         kernel,
-        name="_flash_kernel",
+        name="_flash_kernel" if window is None else "_flash_window_kernel",
         grid=(B * H, Tq // block_q),
         in_specs=[
             pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((None, Tk, D), kv_head),
+            pl.BlockSpec((None, Tk, D), kv_head),
         ],
         out_specs=pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
@@ -878,6 +1008,19 @@ def attention(q, k, v, causal=False, scale=None):
     if interpret or _on_tpu():
         return _flash_diff(q, k, v, causal, scale, interpret)
     return xla_attention(q, k, v, causal=causal, scale=scale)
+
+
+def grouped_attention(q, k, v, scale=None, window=None):
+    """Causal attention of whole prompts with grouped K/V heads, and a
+    ``window`` where the layer has one: ``q [B, H, T, D]`` over ``k``,
+    ``v`` ``[B, G, T, D]``.  Forward only (the serving prefills; no
+    backward is defined): the Pallas forward on TPU, under a window
+    `_flash_window_kernel`, else :func:`xla_grouped_attention`."""
+    interpret = _interpret_forced()
+    if interpret or _on_tpu():
+        return flash_attention(q, k, v, causal=True, scale=scale,
+                               interpret=interpret, window=window)
+    return xla_grouped_attention(q, k, v, scale=scale, window=window)
 
 
 # ---------------------------------------------------------------------------

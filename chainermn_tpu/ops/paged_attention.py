@@ -36,13 +36,19 @@ and the softmax state are fp32 via ``preferred_element_type``.
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["paged_decode_attention", "paged_prefill_attention",
+from .flash_attention import _on_tpu
+
+__all__ = ["paged_decode_attention", "paged_decode_kernel",
+           "paged_prefill_attention",
            "paged_verify_attention", "paged_latent_attention",
            "paged_attn_mode", "head_sharding"]
 
@@ -112,8 +118,66 @@ def _dense_decode(q, k, v, ctx_len, scale):
     return out.astype(q.dtype)
 
 
+def _gather_pages(pool, layer, block_table):
+    """The pages a block table names: from one layer's ``[P, S, ...]``
+    pool, or (a static ``layer``) from that layer of the whole ``[L, P,
+    S, ...]`` pool with no slice taken out first."""
+    return pool[block_table] if layer is None else pool[layer, block_table]
+
+
+def _window_entries(block_table, first_pos, n):
+    """The ``n`` block-table entries from the one that holds position
+    ``first_pos`` on (``block_table [..., N]``, ``first_pos [...]``):
+    ``(entries [..., n], the position of their first slot [...])``.  An
+    entry past the table's end reads the last one; its positions, taken
+    from where it would lie, are beyond every query and masked."""
+    N = block_table.shape[-1]
+    lo = jnp.maximum(first_pos, 0)
+    idx = lo[..., None] + jnp.arange(n, dtype=jnp.int32)
+    return jnp.take_along_axis(block_table, jnp.minimum(idx, N - 1),
+                               axis=-1), lo
+
+
+#: float32 scores a block of suffix queries may hold at once (256 MB):
+#: 48 heads x 2048 queries x 10752 keys would be 4.2 GB
+_PREFILL_SCORE_ELEMS = 1 << 26
+
+
+def _grouped_attention(q, k, v, qpos, kpos, scale, window):
+    """Masked attention of queries ``q [T, H, D]`` at positions ``qpos
+    [T]`` over keys ``k``, ``v`` ``[K, G, D]`` at ``kpos [K]``, query
+    head ``a`` reading K/V head ``a // (H // G)``: key j is seen where
+    ``kpos[j] <= qpos[i]`` and, under a ``window``, ``qpos[i] - kpos[j] <
+    window``.  The queries go in blocks (one full-width softmax each),
+    so the float32 scores stay under :data:`_PREFILL_SCORE_ELEMS`."""
+    T, H, D = q.shape
+    K, G = k.shape[0], k.shape[1]
+    r = H // G
+    kt, vt = jnp.moveaxis(k, 1, 0), jnp.moveaxis(v, 1, 0)    # [G, K, D]
+    qb = T
+    while qb > 16 and qb % 2 == 0 and qb * H * K > _PREFILL_SCORE_ELEMS:
+        qb //= 2
+
+    def block(operands):
+        q_blk, pos = operands                   # [qb, G, r, D], [qb]
+        s = jnp.einsum("qgrd,gkd->grqk", q_blk, kt,
+                       preferred_element_type=jnp.float32) * scale
+        seen = kpos[None, :] <= pos[:, None]
+        if window is not None:
+            seen &= pos[:, None] - kpos[None, :] < window
+        p, l = _masked_softmax_stats(s, seen[None, None])
+        p = p / jnp.maximum(l, 1e-30)
+        return jnp.einsum("grqk,gkd->qgrd", p.astype(vt.dtype), vt,
+                          preferred_element_type=jnp.float32)
+
+    out = lax.map(block, (q.reshape(T // qb, qb, G, r, D),
+                          qpos.reshape(T // qb, qb)))
+    return out.reshape(T, H, D).astype(q.dtype)
+
+
 def paged_prefill_attention(q, k_pool, v_pool, block_table_row, start,
-                            true_len, scale=None):
+                            true_len, scale=None, window=None, layer=None,
+                            kv_heads=None):
     """Suffix attention for a PREFIX-SHARED prefill (round 14).
 
     ``q``: ``[T, H, D]`` — the suffix's queries, query ``t`` sitting at
@@ -129,11 +193,34 @@ def paged_prefill_attention(q, k_pool, v_pool, block_table_row, start,
     ``[H, T, N·S]`` — suffix-length by context, never ``[T_ctx,
     T_ctx]``: the FLOP saving IS the prefix hit.  Returns ``[T, H, D]``
     in ``q.dtype``.
+
+    ``kv_heads=G`` (dividing ``H``): grouped K/V heads over ONE pool
+    ``[P, S, 2 · G · D]`` given as ``k_pool`` (``v_pool`` is ``None``; as
+    :func:`paged_decode_attention`'s): query head ``a``
+    reads head ``a // (H // G)``, the queries go in blocks, and under a
+    ``window`` (a multiple of the page size) query t sees positions
+    ``(start + t - window, start + t]`` and the gather takes the
+    ``(window + T) / S + 1`` entries from the one holding ``start -
+    window + 1`` on, not the whole row.  ``layer``: as
+    :func:`paged_latent_attention`'s.
     """
     T, H, D = q.shape
-    S = k_pool.shape[1]
+    S = k_pool.shape[1] if kv_heads is None else k_pool.shape[-2]
     N = block_table_row.shape[0]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
+    if kv_heads is not None:
+        G = kv_heads
+        bt, first = block_table_row, jnp.int32(0)
+        if window is not None:
+            bt, first = _window_entries(
+                bt, (start - window + 1) // S, (window + T) // S + 1)
+        K = bt.shape[0] * S
+        kpos = first * S + jnp.arange(K, dtype=jnp.int32)
+        qpos = start + jnp.arange(T, dtype=jnp.int32)
+        kv = _gather_pages(k_pool, layer, bt)
+        return _grouped_attention(q, kv[..., :G * D].reshape(K, G, D),
+                                  kv[..., G * D:].reshape(K, G, D), qpos,
+                                  kpos, scale, window)
     k = k_pool[block_table_row].reshape(N * S, H, D)
     v = v_pool[block_table_row].reshape(N * S, H, D)
     s = jnp.einsum("thd,khd->htk", q, k,
@@ -196,9 +283,175 @@ def paged_verify_attention(q, k_pool, v_pool, block_table, start,
     return _constrain_heads(out.astype(q.dtype), 2, tp_mesh, tp_axis)
 
 
+def _grouped_decode(q, pool, block_table, ctx_len, scale, window, layer,
+                    kv_heads):
+    """One query a lane over grouped K/V heads: ``q [B, H, D]``, the
+    pool ``[(L,) P, S, 2 · G · D]`` (a token's K then its V, each its
+    ``G`` heads side by side in the lanes).  One full-width masked
+    softmax a K/V head over what ONE gather brings: the whole block
+    table, or under a ``window`` the ``window / S + 1`` entries that end
+    at the lane's position, so a window layer's reads do not grow with
+    the context.  Each K/V head's queries contract against its own ``D``
+    lanes of the gathered pages (slices of whole lane tiles, taken from
+    the one gathered array): no head is repeated, nothing is transposed
+    and the K and V halves are never made."""
+    B, H, D = q.shape
+    S, G = pool.shape[-2], kv_heads
+    r = H // G
+    qpos = ctx_len - 1
+    bt, first = block_table, jnp.zeros(B, jnp.int32)
+    if window is not None:
+        bt, first = _window_entries(bt, qpos // S - window // S,
+                                    window // S + 1)
+    K = bt.shape[1] * S
+    kv = _gather_pages(pool, layer, bt).reshape(B, K, 2 * G * D)
+    kpos = first[:, None] * S + jnp.arange(K, dtype=jnp.int32)
+    seen = kpos <= qpos[:, None]
+    if window is not None:
+        seen &= qpos[:, None] - kpos < window
+    out = []
+    for g in range(G):
+        s = jnp.einsum("brd,bkd->brk", q[:, g * r:(g + 1) * r],
+                       kv[..., g * D:(g + 1) * D],
+                       preferred_element_type=jnp.float32) * scale
+        p, l = _masked_softmax_stats(s, seen[:, None, :])
+        p = p / jnp.maximum(l, 1e-30)
+        out.append(jnp.einsum(
+            "brk,bkd->brd", p.astype(kv.dtype),
+            kv[..., (G + g) * D:(G + g + 1) * D],
+            preferred_element_type=jnp.float32))
+    return jnp.concatenate(out, axis=1).astype(q.dtype)
+
+
+#: Pages a step of the decode kernel brings in at once (16 pages of 16
+#: tokens: 1 MB of K and V), two such buffers in flight.
+_DECODE_CHUNK_PAGES = 16
+
+
+def _paged_decode_kernel(bt_ref, ctx_ref, q_ref, pool_ref, o_ref, buf, sem,
+                         *, layer, page, width, chunk, window, scale,
+                         n_entries):
+    """One lane's query heads over its pages, READ IN PLACE: the pages
+    its block table names are copied from the pool in HBM straight into
+    one of two VMEM buffers, ``chunk`` pages at a time, the next chunk
+    in flight while this one is scored (online softmax across chunks).
+    Nothing is gathered into a temporary first, a lane stops at its own
+    context (not at the block table's length), and under a ``window``
+    it starts at the first page the window touches.
+
+    ``q_ref [Hp, width]``: every query head as a row of ALL ``width = G
+    · D`` key lanes, zero outside its own K/V head's ``D``, so the chunk
+    is scored by ONE product against its K half and weighed by one
+    against its V half, whatever the grouping (the caller keeps each
+    head's own ``D`` lanes of ``o_ref [Hp, width]``)."""
+    b = pl.program_id(0)
+    ctx = ctx_ref[b]
+    qpos = ctx - 1
+    last = jnp.maximum(qpos, 0) // page
+    first = 0 if window is None else jnp.maximum(last - window // page, 0)
+    n_chunks = jnp.where(ctx > 0, (last - first) // chunk + 1, 0)
+    q = q_ref[...]
+
+    def copies(c, slot):
+        out = []
+        for j in range(chunk):
+            entry = jnp.minimum(first + c * chunk + j, n_entries - 1)
+            out.append(pltpu.make_async_copy(
+                pool_ref.at[layer, bt_ref[b, entry]],
+                buf.at[slot, pl.ds(j * page, page)], sem.at[slot]))
+        return out
+
+    @pl.when(n_chunks > 0)
+    def _():
+        for dma in copies(0, 0):
+            dma.start()
+
+    def body(c, carry):
+        m, l, acc = carry
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            for dma in copies(c + 1, 1 - slot):
+                dma.start()
+        for dma in copies(c, slot):
+            dma.wait()
+        kv = buf[slot]
+        s = lax.dot_general(q, kv[:, :width], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+        # positions as the entries WOULD lie: one past the table's end
+        # re-reads the last page under a mask that hides all of it
+        kpos = (first + c * chunk) * page + lax.broadcasted_iota(
+            jnp.int32, (1, chunk * page), 1)
+        seen = kpos <= qpos
+        if window is not None:
+            seen &= qpos - kpos < window
+        s = jnp.where(seen, s, -jnp.inf)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
+        p = jnp.where(jnp.isfinite(s), jnp.exp(s - m_safe), 0.0)
+        corr = jnp.where(jnp.isfinite(m), jnp.exp(m - m_safe), 0.0)
+        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * corr + lax.dot_general(
+            p.astype(kv.dtype), kv[:, width:], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    rows = q.shape[0]
+    _, l, acc = lax.fori_loop(
+        0, n_chunks, body,
+        (jnp.full((rows, 1), -jnp.inf, jnp.float32),
+         jnp.zeros((rows, 1), jnp.float32),
+         jnp.zeros((rows, width), jnp.float32)))
+    o_ref[...] = acc / jnp.maximum(l, 1e-30)
+
+
+def paged_decode_kernel(q, pool, block_table, ctx_len, *, kv_heads, layer,
+                        window=None, scale=None, interpret=False):
+    """The grouped decode step as a Pallas kernel over ONE pool ``[L, P,
+    S, 2 · G · D]`` (a token's K then its V): what
+    :func:`paged_decode_attention` runs on a TPU for ``kv_heads=`` and
+    ``v_pool=None``; the arguments are its.  An idle lane (``ctx_len``
+    0) reads nothing and gives zeros."""
+    B, H, D = q.shape
+    G, r = kv_heads, q.shape[1] // kv_heads
+    page, width = pool.shape[-2], kv_heads * D
+    rows = -(-H // 16) * 16                     # whole bfloat16 tiles
+    scale = scale if scale is not None else 1.0 / (D ** 0.5)
+    own = (jnp.arange(H)[:, None] // r == jnp.arange(G)[None, :])
+    qx = (q[:, :, None, :] * own[None, :, :, None].astype(q.dtype)) \
+        .reshape(B, H, width)
+    qx = jnp.pad(qx, ((0, 0), (0, rows - H), (0, 0)))
+    kernel = functools.partial(
+        _paged_decode_kernel, layer=layer, page=page, width=width,
+        chunk=_DECODE_CHUNK_PAGES, window=window, scale=scale,
+        n_entries=block_table.shape[1])
+    out = pl.pallas_call(
+        kernel,
+        name="_paged_decode_kernel",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=[pl.BlockSpec((None, rows, width),
+                                   lambda b, bt, ctx: (b, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, rows, width),
+                                   lambda b, bt, ctx: (b, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, _DECODE_CHUNK_PAGES * page, 2 * width),
+                           pool.dtype),
+                pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=jax.ShapeDtypeStruct((B, rows, width), jnp.float32),
+        interpret=interpret,
+    )(block_table, ctx_len, qx, pool)
+    # each head's own D lanes of its row
+    out = out[:, :H].reshape(B, G, r, G, D)
+    return jnp.einsum("bgrgd->bgrd", out).reshape(B, H, D).astype(q.dtype)
+
+
 def paged_decode_attention(q, k_pool, v_pool, block_table, ctx_len,
                            scale=None, mode=None, tp_mesh=None,
-                           tp_axis="tp"):
+                           tp_axis="tp", window=None, layer=None,
+                           kv_heads=None):
     """One decode step of attention for a batch of cached sequences.
 
     q: ``[B, H, D]`` — ONE query token per sequence (the just-appended
@@ -217,11 +470,33 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, ctx_len,
     each shard reads only its own heads' cache bytes; the head axis is
     elementwise throughout, so no collective fires inside this op (the
     projection that consumes the output pays the one psum).
+
+    ``kv_heads=G`` (dividing ``H``): GROUPED K/V heads over ONE pool
+    ``[P, S, 2 · G · D]`` given as ``k_pool`` (``v_pool`` is ``None``):
+    a token's K then its V, each its heads side by side in the lanes
+    (one pool, so that one read brings both; and with four axes after the layer's, a pool of a minor ``[8, 128]`` is
+    relaid whole by a program that wants the heads elsewhere: a one-lane
+    decode copied the 0.7 GB window pool into 11 GB of padding on the
+    chip, PR 31); ``window`` (a multiple of the page size) then bounds
+    both the mask and the reads; ``layer``: as
+    :func:`paged_latent_attention`'s.  That form has the one lowering
+    a backend, and no head axis is sharded for it: on a TPU, over one
+    pool at a ``layer``, the Pallas kernel that reads the pages in place
+    (:func:`paged_decode_kernel`: an XLA gather of 21504 pages of 32 KB
+    ran at a tenth of the chip's bandwidth, PR 31); elsewhere one gather
+    a pool and a masked softmax (:func:`_grouped_decode`).
     """
     B, H, D = q.shape
+    scale = scale if scale is not None else 1.0 / (D ** 0.5)
+    if kv_heads is not None:
+        if layer is not None and _on_tpu():
+            return paged_decode_kernel(
+                q, k_pool, block_table, ctx_len, kv_heads=kv_heads,
+                layer=layer, window=window, scale=scale)
+        return _grouped_decode(q, k_pool, block_table, ctx_len, scale,
+                               window, layer, kv_heads)
     P, S = k_pool.shape[0], k_pool.shape[1]
     N = block_table.shape[1]
-    scale = scale if scale is not None else 1.0 / (D ** 0.5)
     mode = paged_attn_mode(mode)
     q = _constrain_heads(q, 1, tp_mesh, tp_axis)
 

@@ -326,6 +326,19 @@ class ServingEngine:
     ``serve_pool_sharding`` for ``tp``) is refused at construction with
     :class:`~chainermn_tpu.serving.errors.UnsupportedProgramError`.
 
+    A model whose layers keep their entries for different spans declares
+    its cache by GROUPS of layers (``serve_cache_groups()``: ``(name,
+    layers, entry, window)`` each, the group that keeps every position
+    first; docs/serving.md).  Each group gets pools of its own page
+    count and every sequence a block table a group, which the programs
+    take stacked (``[groups, N]``, ``[groups, Bb, N]``).  ``num_pages``
+    is the first group's; a window group is sized by
+    :meth:`window_group_pages`: ``max_batch · (window / page_size + 1)
+    + 4 · max_context / page_size`` pages, rounded up to 128 (every
+    lane's window, one prompt in flight, and room for what the prefix
+    trie alone still holds).  ``spec_k``, ``tp`` and ``disagg`` are
+    refused for such a model.
+
     Greedy sampling (the serving bench's configuration); the paged/dense
     attention lowering is resolved ONCE at construction
     (``CHAINERMN_TPU_PAGED_ATTN``), as is the disaggregation mode
@@ -367,11 +380,37 @@ class ServingEngine:
             page_dtype = model.serve_page_dtype
         self.model = model
         self.state = self._held_state(model)
-        self.kv = PagedKVCache(model.serve_cache_layers, num_pages,
-                               page_size, model.serve_cache_entry(),
-                               dtype=page_dtype)
+        # a model whose layers keep their entries for different spans
+        # declares its cache by groups of layers (the full group first);
+        # any other has the one group it always had
+        groups = model.serve_cache_groups() \
+            if hasattr(model, "serve_cache_groups") else None
+        self.cache_groups = len(groups) if groups else 0
+        if groups is None:
+            self.kv = PagedKVCache(model.serve_cache_layers, num_pages,
+                                   page_size, model.serve_cache_entry(),
+                                   dtype=page_dtype)
+            self.allocator = BlockAllocator(num_pages, page_size)
+        else:
+            (_, n_layers, entry, window), *rest = groups
+            if window is not None:
+                raise ValueError("the first cache group keeps every "
+                                 "position; it has no window")
+            sized = [(n, self.window_group_pages(w, page_size, max_batch,
+                                                 max_context), e, w)
+                     for _, n, e, w in rest]
+            self.kv = PagedKVCache(
+                n_layers, num_pages, page_size, entry, dtype=page_dtype,
+                more=[(n, pages, e) for n, pages, e, _ in sized])
+            self.allocator = BlockAllocator(
+                num_pages, page_size,
+                windows=[(pages, w) for _, pages, _, w in sized])
+            if len(groups) > 1 and serve_disagg_mode(disagg):
+                # one scratch pool and one ship: not written for a
+                # second group
+                raise UnsupportedProgramError(type(model).__name__,
+                                              "page_ship")
         n_pools = len(self.kv.pools)
-        self.allocator = BlockAllocator(num_pages, page_size)
         self.scheduler = scheduler or RequestScheduler(max_queue=max_queue)
         self.max_batch = int(max_batch)
         self.max_context = int(max_context)
@@ -577,6 +616,21 @@ class ServingEngine:
         self._insert_fn = jax.jit(_insert, donate_argnums=donate0)
 
     @staticmethod
+    def window_group_pages(window, page_size, max_batch, max_context):
+        """The pages a window group gets, sized from what the engine is
+        given: every lane's window (``window / page_size + 1`` pages),
+        one whole prompt in flight, and room for three more prompts'
+        worth of pages that only the prefix trie holds, rounded up to
+        128 pages: ``max_batch · (window / page_size + 1) + 4 ·
+        max_context / page_size``.  A live sequence keeps at most
+        ``window / page_size + 2`` pages outside its prefill, so the
+        lanes can never exhaust the pool, and what the trie holds is
+        given up before a lane is touched."""
+        pages = max_batch * (window // page_size + 1) \
+            + 4 * -(-max_context // page_size)
+        return -(-pages // 128) * 128
+
+    @staticmethod
     def _held_state(model):
         """The model's state as the engine holds it: its parameters in
         the dtype the model declares (``serve_param_dtype``; ``None``
@@ -624,10 +678,31 @@ class ServingEngine:
     # -- internals -----------------------------------------------------------
 
     def _bt_row(self, seq_id):
+        """The sequence's block table as the programs take it: ``[N]``,
+        or for a model that declared cache groups ``[groups, N]``, a row
+        a group (a window group's released entries stay zero: they lie
+        below every position its layers read)."""
         row = np.zeros(self.n_block_entries, dtype=np.int32)
         table = self.allocator.block_table(seq_id)
         row[:len(table)] = table
-        return row
+        if not self.cache_groups:
+            return row
+        rows = np.zeros((self.cache_groups, self.n_block_entries),
+                        dtype=np.int32)
+        rows[0] = row
+        for g in range(1, self.cache_groups):
+            table, low = self.allocator.window_table(seq_id, g - 1)
+            rows[g, low:len(table)] = table[low:]
+        return rows
+
+    def _zero_bt(self, *lead):
+        """An all-zero block table of the programs' shape (warm-up,
+        idle lanes): ``[*lead, N]``, a group axis first where the model
+        declared groups."""
+        shape = lead + (self.n_block_entries,)
+        if self.cache_groups:
+            shape = (self.cache_groups,) + shape
+        return np.zeros(shape, dtype=np.int32)
 
     # -- observability (ISSUE 14) -------------------------------------------
 
@@ -955,6 +1030,9 @@ class ServingEngine:
         self.admissions += 1
         if self.prefix_cache:
             self.allocator.register_prefix(sid, prompt_t)
+        # the prompt is written and registered: from here the sequence
+        # holds its window alone (a no-op without window groups)
+        self.allocator.slide(sid, int(req.prompt.size))
         if self.draft_model is not None:
             self._run_draft_prefill(req)
         tok = int(np.asarray(jnp.argmax(logits)))
@@ -1013,6 +1091,8 @@ class ServingEngine:
             prompt_t = tuple(int(t) for t in req.prompt) \
                 if self.prefix_cache else ()
             self._complete_admission(req, logits, clock, prompt_t)
+        else:
+            self.allocator.slide(sid, startp + size)
 
     def _advance_chunks(self, clock):
         """The chunk pass of one engine step: advance mid-chunk
@@ -1164,11 +1244,11 @@ class ServingEngine:
         decode grids — afterwards ``spec_traces``/``chunk_traces``
         stay frozen across joins, forks, evictions and accept-length
         swings (the round-20 retrace pin)."""
-        zero_row = jnp.zeros(self.n_block_entries, jnp.int32)
+        zero_row = jnp.asarray(self._zero_bt())
 
         def idle(Bb):
             return (jnp.zeros(Bb, jnp.int32), jnp.full(Bb, -1, jnp.int32),
-                    jnp.zeros((Bb, self.n_block_entries), jnp.int32))
+                    jnp.asarray(self._zero_bt(Bb)))
 
         for Tb in self.prefill_buckets:
             empty = (jnp.zeros((1, Tb), jnp.int32), np.int32(0))
@@ -1244,9 +1324,14 @@ class ServingEngine:
         with observability.span("serve/step") as sp:
             stats = self._step(clock)
             if observability.enabled():
-                sp.set(running=stats["running"],
-                       used_pages=self.allocator.used_pages,
-                       num_pages=self.allocator.num_pages)
+                a = self.allocator
+                sp.set(running=stats["running"], used_pages=a.used_pages,
+                       num_pages=a.num_pages,
+                       **({"window_used_pages": a.window_used_pages,
+                           "window_num_pages": a.windows[0].num_pages,
+                           "window_retained_pages":
+                           a.window_retained_pages}
+                          if self.cache_groups > 1 else {}))
         return stats
 
     def _step(self, clock):
@@ -1270,6 +1355,7 @@ class ServingEngine:
                 try:
                     self.allocator.ensure(req.request_id,
                                           req._ctx + need)
+                    self.allocator.slide(req.request_id, req._ctx)
                     i += 1
                 except PagePoolExhaustedError:
                     # refcount-aware victim choice: a victim must FREE
@@ -1321,19 +1407,26 @@ class ServingEngine:
         if self.spec_k:
             return self._spec_step(n, clock, stats)
         Bb = _bucket(n, self.batch_buckets, "batch")
-        with observability.span(
-                "serve/decode_window",
-                tags={"batch": n, "bucket": Bb, "step": self.decode_steps}
-                if obs_on else None) as window:
+        tags = {"batch": n, "bucket": Bb, "step": self.decode_steps} \
+            if obs_on else None
+        if obs_on and self.cache_groups > 1:
+            # what a sound step reads of each cache, in tokens: the
+            # whole context in the full group, a window's worth of it
+            # in a window group
+            w = self.allocator.windows[0].window
+            ctx = [req._ctx + 1 for req in self.running]
+            tags.update(ctx_tokens=sum(ctx),
+                        window_tokens=sum(min(c, w) for c in ctx))
+        with observability.span("serve/decode_window",
+                                tags=tags) as window:
             with observability.span("serve/decode_build"):
                 toks = np.zeros(Bb, dtype=np.int32)
                 pos = np.full(Bb, -1, dtype=np.int32)
-                bts = np.zeros((Bb, self.n_block_entries),
-                               dtype=np.int32)
+                bts = self._zero_bt(Bb)
                 for j, req in enumerate(self.running):
                     toks[j] = req.tokens[-1]
                     pos[j] = req._ctx
-                    bts[j] = self._bt_row(req.request_id)
+                    bts[..., j, :] = self._bt_row(req.request_id)
                 operands = (jnp.asarray(toks), jnp.asarray(pos),
                             jnp.asarray(bts))
             with observability.span("serve/decode_dispatch"):

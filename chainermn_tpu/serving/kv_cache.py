@@ -151,18 +151,29 @@ class PagedKVCache:
     token leaves in a layer (a tuple of per-token shapes); construction
     allocates one ``[L, P, S, *shape]`` array of zeros for each, in
     ``pools``.  The engine threads them through its jit programs and
-    stores back the returned (donated) arrays."""
+    stores back the returned (donated) arrays.
+
+    A model whose layers keep their entries for different spans declares
+    its cache by GROUPS of layers, each with a page count of its own:
+    ``more`` is the further groups' ``(n_layers, num_pages, entry)``,
+    and their arrays follow the first group's in ``pools``.  ``n_layers``,
+    ``num_pages``, ``entry`` and ``page_bytes`` stay the first group's;
+    ``pool_bytes`` is every group's."""
 
     def __init__(self, n_layers, num_pages, page_size, entry,
-                 dtype=jnp.bfloat16):
+                 dtype=jnp.bfloat16, more=()):
         self.n_layers = int(n_layers)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.entry = tuple(tuple(int(d) for d in shape) for shape in entry)
         self.dtype = jnp.dtype(dtype)
+        self.groups = ((self.n_layers, self.num_pages, self.entry),) \
+            + tuple((int(n), int(p), tuple(tuple(int(d) for d in shape)
+                                           for shape in e))
+                    for n, p, e in more)
         self.pools = [
-            jnp.zeros((self.n_layers, self.num_pages, self.page_size)
-                      + shape, self.dtype) for shape in self.entry]
+            jnp.zeros((n, pages, self.page_size) + shape, self.dtype)
+            for n, pages, e in self.groups for shape in e]
 
     # the names of a two-array (K, V) entry's pools
     @property
@@ -191,4 +202,6 @@ class PagedKVCache:
 
     @property
     def pool_bytes(self):
-        return self.n_layers * self.num_pages * self.page_bytes
+        return sum(n * pages * sum(math.prod(shape) for shape in e)
+                   for n, pages, e in self.groups) \
+            * self.page_size * self.dtype.itemsize

@@ -33,6 +33,26 @@ SAME physical pages.  Three new pieces:
   frontier, which every borrower's valid region (its matched token
   count) stops strictly short of.
 
+A model whose cache has GROUPS of layers (ISSUE 31: full-attention
+layers beside window layers) gets a further pool a window group
+(``windows=``), with a block table a sequence of its own:
+
+* a sequence's table is position-major in every group, and ``ensure``
+  grows them together; ``slide`` releases the sequence's hold on the
+  window pages that lie wholly below its window, so outside a prefill it
+  holds ``window / page_size + 2`` pages at most;
+* the trie keeps a reference OF ITS OWN on a registered prompt's window
+  pages (one page a trie node: any holder's carries the same bytes), for
+  as long as the node lives, so that a later prompt can still hit at
+  ``m`` tokens after every holder's window has moved on: a hit takes, in
+  a window group, the pages covering ``(m - window, m)`` only;
+* when a window pool runs short, pages held by the trie alone are given
+  up, least recently matched first, before :class:`PagePoolExhaustedError`
+  (and with it the eviction of a live sequence) is raised, and a match
+  is cut back to the longest ``m`` every group can still serve;
+* such a match is cut to whole pages (no fork is needed, so none is
+  written for a window group).
+
 Discipline (mirrors ``_memory_utility.plan_buckets``): every decision is
 a pure function of the call sequence — the free list is FIFO over page
 ids seeded ``0..P-1``, frees return zero-refcount pages in block-table
@@ -76,16 +96,74 @@ class _TrieNode:
     off this node.
     """
 
-    __slots__ = ("children", "holders", "partials")
+    __slots__ = ("children", "holders", "partials", "window_pages")
 
-    def __init__(self):
+    def __init__(self, n_windows=0):
         self.children = {}
         self.holders = OrderedDict()
         self.partials = OrderedDict()
+        # the page the trie itself holds of this chunk in each window
+        # group (None: never written by a holder, or given up)
+        self.window_pages = [None] * n_windows
 
     @property
     def dead(self):
         return not (self.children or self.holders or self.partials)
+
+
+class PrefixMatch(list):
+    """What ``match_prefix`` found: the full group's shareable pages, as
+    the list it always was, and in ``windows`` each window group's table
+    prefix for the borrower (``(table, low)``: entries below ``low`` lie
+    under the window and are not held)."""
+
+    windows = ()
+
+
+class _WindowPool:
+    """One window group's pages: refcounts (a sequence's table and the
+    trie each count one), the sequences' tables with how far each has
+    slid, and the pages the trie holds, least recently matched first."""
+
+    def __init__(self, num_pages, window, page_size):
+        if num_pages <= 0 or window <= 0 or window % page_size:
+            raise ValueError(
+                f"a window group needs pages and a window that is a "
+                f"multiple of the page size; got {num_pages} pages, "
+                f"window {window}, page size {page_size}")
+        self.num_pages, self.window = int(num_pages), int(window)
+        self.free = deque(range(self.num_pages))
+        self.refs = {}
+        self.tables = {}             # seq_id -> [page ids], position-major
+        self.low = {}                # seq_id -> first entry still held
+        self.retained = OrderedDict()   # page -> (trie node, group index)
+
+    def release(self, page):
+        self.refs[page] -= 1
+        if self.refs[page] == 0:
+            del self.refs[page]
+            self.free.append(page)
+
+    def make_room(self, need):
+        """Whether ``need`` pages are free, after giving up as many
+        pages that the trie alone holds as it takes, least recently
+        matched first (none, where even all of them would not do)."""
+        short = need - len(self.free)
+        if short <= 0:
+            return True
+        alone = [page for page in self.retained if self.refs[page] == 1]
+        if len(alone) < short:
+            return False
+        for page in alone[:short]:
+            node, g = self.retained.pop(page)
+            node.window_pages[g] = None
+            self.release(page)
+        return True
+
+    @property
+    def retained_alone(self):
+        """Pages that the trie holds and no sequence does."""
+        return sum(1 for page in self.retained if self.refs[page] == 1)
 
 
 class BlockAllocator:
@@ -101,17 +179,21 @@ class BlockAllocator:
     table order.
     """
 
-    def __init__(self, num_pages, page_size):
+    def __init__(self, num_pages, page_size, windows=()):
+        """``windows``: ``(num_pages, window)`` of each further group of
+        layers that keeps the last ``window`` positions only."""
         if num_pages <= 0 or page_size <= 0:
             raise ValueError("num_pages and page_size must be positive")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
+        self.windows = [_WindowPool(p, w, self.page_size)
+                        for p, w in windows]
         self._free = deque(range(self.num_pages))
         # OrderedDict: iteration order == admission order (the scheduler's
         # eviction policy reads it newest-first)
         self._tables = OrderedDict()
         self._refs = {}          # page id -> number of tables holding it
-        self._trie = _TrieNode()
+        self._trie = _TrieNode(len(self.windows))
         self._trie_refs = {}     # seq_id -> [(parent, key, node), ...]
 
     # -- queries -------------------------------------------------------------
@@ -136,6 +218,13 @@ class BlockAllocator:
     def block_table(self, seq_id):
         """The sequence's page ids, position-major (a copy)."""
         return list(self._tables[seq_id])
+
+    def window_table(self, seq_id, group=0):
+        """``(table, low)`` of the sequence in a window group: its page
+        ids, position-major (NOT a copy), of which the entries below
+        ``low`` have been released and may name anyone's page."""
+        w = self.windows[group]
+        return w.tables[seq_id], w.low[seq_id]
 
     def capacity(self, seq_id):
         """Token positions the sequence's current pages can hold."""
@@ -176,13 +265,38 @@ class BlockAllocator:
         if need > len(self._free):
             raise PagePoolExhaustedError(need, len(self._free),
                                          self.num_pages)
+        for w in self.windows:
+            w_need = self.pages_for(n_tokens) - len(w.tables.get(seq_id, ()))
+            if not w.make_room(w_need):
+                raise PagePoolExhaustedError(w_need, len(w.free),
+                                             w.num_pages)
         if seq_id not in self._tables:
             self._tables[seq_id] = table
         for _ in range(max(0, need)):
             p = self._free.popleft()
             self._refs[p] = 1
             table.append(p)
+        for w in self.windows:
+            w_table = w.tables.setdefault(seq_id, [])
+            w.low.setdefault(seq_id, 0)
+            while len(w_table) < len(table):
+                p = w.free.popleft()
+                w.refs[p] = 1
+                w_table.append(p)
         return list(table)
+
+    def slide(self, seq_id, position):
+        """The sequence's next query sits at ``position``: release its
+        hold on the window pages that lie wholly below ``position -
+        window + 1`` (what the trie holds of them stays).  Nothing to do
+        for an allocator without window groups."""
+        for w in self.windows:
+            table = w.tables[seq_id]
+            upto = min((position - w.window + 1) // self.page_size,
+                       len(table))
+            for i in range(w.low[seq_id], upto):
+                w.release(table[i])
+            w.low[seq_id] = max(w.low[seq_id], upto)
 
     def share(self, seq_id, pages):
         """Seed a NEW sequence's table with shared pages (refcount++ on
@@ -197,6 +311,11 @@ class BlockAllocator:
         for p in pages:
             self._refs[p] += 1
         self._tables[seq_id] = list(pages)
+        for w, (w_table, low) in zip(self.windows,
+                                     getattr(pages, "windows", ())):
+            for p in w_table[low:]:
+                w.refs[p] += 1
+            w.tables[seq_id], w.low[seq_id] = list(w_table), low
 
     def fork(self, seq_id, index):
         """Copy-on-write: swap the (shared) page at ``index`` of
@@ -225,6 +344,10 @@ class BlockAllocator:
         other holders).  Unregisters the sequence's trie entries.
         Returns the number of pages actually returned to the pool."""
         table = self._tables.pop(seq_id)
+        for w in self.windows:
+            low = w.low.pop(seq_id)
+            for p in w.tables.pop(seq_id)[low:]:
+                w.release(p)
         self.unregister_prefix(seq_id)
         freed = 0
         for p in table:
@@ -256,12 +379,20 @@ class BlockAllocator:
             chunk = tokens[i * S:(i + 1) * S]
             child = node.children.get(chunk)
             if child is None:
-                child = node.children[chunk] = _TrieNode()
+                child = node.children[chunk] = _TrieNode(len(self.windows))
             child.holders[seq_id] = table[i]
+            for g, w in enumerate(self.windows):
+                # the trie's own hold on the chunk's window page, taken
+                # from the first holder that still has one
+                if child.window_pages[g] is None and i >= w.low[seq_id]:
+                    page = w.tables[seq_id][i]
+                    child.window_pages[g] = page
+                    w.refs[page] += 1
+                    w.retained[page] = (child, g)
             refs.append((node, chunk, child))
             node = child
         rem = tokens[n_full * S:]
-        if rem:
+        if rem and not self.windows:     # a window group shares whole pages
             node.partials[seq_id] = (rem, table[n_full])
             refs.append((None, None, node))   # partial ref marker
         self._trie_refs[seq_id] = refs
@@ -280,6 +411,10 @@ class BlockAllocator:
                 node.holders.pop(seq_id, None)
                 if node.dead:
                     parent.children.pop(key, None)
+                    for w, page in zip(self.windows, node.window_pages):
+                        if page is not None:
+                            del w.retained[page]
+                            w.release(page)
 
     def match_prefix(self, tokens, cap):
         """Longest shareable prefix of ``tokens`` against live
@@ -299,7 +434,7 @@ class BlockAllocator:
         tokens = tuple(tokens)
         cap = min(int(cap), len(tokens))
         S = self.page_size
-        pages = []
+        pages, path = [], []
         node = self._trie
         n_full = 0
         while (n_full + 1) * S <= cap:
@@ -308,8 +443,11 @@ class BlockAllocator:
             if child is None or not child.holders:
                 break
             pages.append(next(iter(child.holders.values())))
+            path.append(child)
             node = child
             n_full += 1
+        if self.windows:
+            return self._match_windows(pages, path)
         matched = n_full * S
         best_c, best_page = 0, None
         for ptoks, ppage in node.partials.values():
@@ -321,6 +459,42 @@ class BlockAllocator:
             pages.append(best_page)
             matched += best_c
         return pages, matched, n_full, best_c
+
+    def _match_windows(self, pages, path):
+        """Cut a walk of whole pages (``pages``, through the trie nodes
+        ``path``) back to the longest ``m`` for which the trie still
+        holds, in every window group, the pages covering ``(m - window,
+        m)``, and take those: they become the most recently matched."""
+        S = self.page_size
+        held = [all(p is not None for p in n.window_pages) for n in path]
+        run, d = 0, 0
+        for i, ok in enumerate(held):       # run: held chunks ending at i
+            run = run + 1 if ok else 0
+            lows = [max(0, (i + 1) * S - w.window + 1) // S
+                    for w in self.windows]
+            if run >= i + 1 - min(lows):
+                d = i + 1
+        out = PrefixMatch(pages[:d])
+        out.windows = []
+        for g, w in enumerate(self.windows):
+            low = max(0, d * S - w.window + 1) // S
+            table = [0] * low + [n.window_pages[g] for n in path[low:d]]
+            for page in table[low:]:
+                w.retained.move_to_end(page)
+            out.windows.append((table, low))
+        return out, d * S, d, 0
+
+    @property
+    def window_used_pages(self):
+        """Distinct pages of the (first) window group that a sequence or
+        the trie holds."""
+        w = self.windows[0]
+        return w.num_pages - len(w.free)
+
+    @property
+    def window_retained_pages(self):
+        """Those of them that the trie alone holds."""
+        return self.windows[0].retained_alone
 
     # -- invariant check (the property suite's oracle) -----------------------
 
@@ -348,4 +522,25 @@ class BlockAllocator:
             if seq_id not in self._tables:
                 raise AssertionError(
                     f"trie registration for dead sequence {seq_id!r}")
+        for g, w in enumerate(self.windows):
+            counts = {}
+            for seq_id, t in w.tables.items():
+                if seq_id not in self._tables:
+                    raise AssertionError(
+                        f"window table for dead sequence {seq_id!r}")
+                for p in t[w.low[seq_id]:]:
+                    counts[p] = counts.get(p, 0) + 1
+            for page, (node, group) in w.retained.items():
+                if group != g or node.window_pages[g] != page:
+                    raise AssertionError(
+                        f"window page {page} retained by a node that "
+                        f"does not name it")
+                counts[page] = counts.get(page, 0) + 1
+            if counts != w.refs:
+                raise AssertionError(
+                    f"window refcount drift: holders say {counts}, refs "
+                    f"say {w.refs}")
+            if len(w.free) + len(counts) != w.num_pages \
+                    or set(w.free) & set(counts):
+                raise AssertionError("window page conservation violated")
         return True

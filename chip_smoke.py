@@ -338,7 +338,12 @@ def _paged_logits(model, cfg, prompt, forced):
 def serve(model_cfg=TRANSFORMER, cfg=SERVE, seed=0,
           logit_atol=SERVE_LOGIT_ATOL):
     """A ``ServingEngine`` answering seeded requests of mixed length,
-    then parity of one request against the plain one-shot forward."""
+    then parity of one request against the plain one-shot forward.
+    ``TransformerLM`` is the served model here; the other two,
+    ``LatentMoELM`` and ``WindowMoELM``, are driven on the chip by their
+    benchmark cells (``kimi-k2.6-serve-agent``,
+    ``laguna-s-2.1-serve-repo``) and compiled for a described chip by
+    ``tests/test_chip_compile.py``."""
     import jax
     import jax.numpy as jnp
     from chainermn_tpu.core.link import bind_state, extract_state

@@ -271,3 +271,109 @@ def test_latent_prefill_program_compiles_with_the_flash_kernel(
         spec((288,), jnp.int32), donate_argnums=(1,))
     assert "_flash_kernel" in text and text.count("tpu_custom_call") == 2
     _assert_pool_in_place(compiled, text, pool)
+
+
+# -- window and full attention side by side (laguna-s-2.1-share) ---------------
+
+LAGUNA_PAGES, LAGUNA_CONTEXT, LAGUNA_LANES = 21504, 10752, 32
+
+
+@pytest.fixture(scope="module")
+def windowed(one_chip):
+    """The configuration's builder at the published widths and the
+    cell's depth, its bfloat16 state as shapes on the described chip,
+    and the cell's two pools, a token's K then its V in one row of 2048
+    lanes: the full group's (21504 pages) and the window group's (sized
+    by the engine: 3840)."""
+    from benchmark import harness
+    from chainermn_tpu.serving import ServingEngine
+    config = harness.load_json(os.path.join(
+        harness.HERE, "configs", "laguna-s-2.1-share.json"))
+    model = harness.load_module("models", "window_moe_lm").build(
+        config, max_len=LAGUNA_CONTEXT)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    state = {"params": {path: spec(p.shape, jnp.bfloat16)
+                        for path, p in model.namedparams()}, "state": {}}
+    window_pages = ServingEngine.window_group_pages(
+        512, PAGE, LAGUNA_LANES, LAGUNA_CONTEXT)
+    pools = [spec((n, pages, PAGE) + shape, jnp.bfloat16)
+             for (_, n, entry, _), pages in zip(
+                 model.serve_cache_groups(), (LAGUNA_PAGES, window_pages))
+             for shape in entry]
+    return model, state, pools, spec
+
+
+def _pool_bytes(pools):
+    import math
+    return sum(math.prod(p.shape) * 2 for p in pools)
+
+
+@pytest.mark.parametrize("kernel", [True, False],
+                         ids=["pages_in_place", "gather_form"])
+@pytest.mark.parametrize("lanes", [1, LAGUNA_LANES])
+def test_windowed_decode_program_at_its_first_and_last_bucket(
+        windowed, no_persistent_cache, monkeypatch, lanes, kernel):
+    """Both groups' pools donated and updated in place (1.51 GB of
+    window pages where the same layers kept whole would be 8.46).  As
+    the chip runs it, every layer's attention is `_paged_decode_kernel`,
+    which reads the pages in place: no temporary of a layer's gathered
+    pages.  The gather form (every other backend's) has a window layer
+    gather 33 pages a lane and a full layer all 672, its temporaries the
+    gathered ``[lanes, 672, 16, 2048]`` of one full layer (1.41 GB at 32
+    lanes) and little else.  At ONE lane a pool that ended ``[8, 128]``
+    was copied whole into a layout of 16-fold padding (22.5 GB: the chip
+    refused the program, PR 31), so that bucket is compiled here too."""
+    from chainermn_tpu.ops import paged_attention
+    from chainermn_tpu.serving import decode_program
+    model, state, pools, spec = windowed
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: kernel)
+    assert [p.shape for p in pools] == \
+        [(3, 21504, 16, 2048), (6, 3840, 16, 2048)]
+    assert _pool_bytes(pools[1:]) * 4 <= 6 * 21504 * 16 * 2048 * 2
+    N = LAGUNA_CONTEXT // PAGE
+    compiled, text = _compile(
+        functools.partial(decode_program, model, mode=None), state, *pools,
+        spec((lanes,), jnp.int32), spec((lanes,), jnp.int32),
+        spec((2, lanes, N), jnp.int32), donate_argnums=(1, 2))
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= _pool_bytes(pools)
+    per_lane = N * PAGE * 2048 * 2              # one full layer's K and V
+    if kernel:
+        calls = [line for line in text.splitlines()
+                 if "custom_call_target=\"tpu_custom_call\"" in line]
+        assert sum("_paged_decode_kernel" in c for c in calls) == 9
+        assert ma.temp_size_in_bytes < 0.1 * per_lane * lanes + 5e7
+        assert ",672,16,2048]" not in text
+        return
+    assert per_lane * lanes < ma.temp_size_in_bytes \
+        < 1.2 * per_lane * lanes + 5e7, ma.temp_size_in_bytes
+    if lanes > 1:                  # (one lane's unit axis is folded away)
+        assert "bf16[32,33,16,2048]" in text     # a window layer's pages
+        assert "bf16[32,672,16,2048]" in text    # a full layer's
+
+
+def test_windowed_prefill_program_at_10752_tokens(windowed,
+                                                  no_persistent_cache,
+                                                  monkeypatch):
+    """One call a layer of the serving forward: ``_flash_kernel`` with
+    grouped K/V heads on the 3 full layers, ``_flash_window_kernel`` on
+    the 6 sliding ones; pools in place; temporaries pinned."""
+    from chainermn_tpu.serving import prefill_program
+    model, state, pools, spec = windowed
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    compiled, text = _compile(
+        functools.partial(prefill_program, model), state, *pools,
+        spec((1, LAGUNA_CONTEXT), jnp.int32), spec((), jnp.int32),
+        spec((2, LAGUNA_CONTEXT // PAGE), jnp.int32),
+        donate_argnums=(1, 2))
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert sum("_flash_window_kernel" in c for c in calls) == 6
+    assert sum("_flash_kernel" in c for c in calls) == 3
+    # K and V go in as 8 heads: nothing of 48 or 72 K/V heads is made
+    assert all("bf16[8,10752,128]" in c for c in calls)
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= _pool_bytes(pools)
+    assert 1.0e9 < ma.temp_size_in_bytes < 1.6e9, ma.temp_size_in_bytes
