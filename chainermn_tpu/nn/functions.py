@@ -95,13 +95,17 @@ def softmax_cross_entropy(x, t, ignore_label=-1, reduce="mean",
     are excluded from the normalizer; ``class_weight`` ([n_classes]) scales
     each example's loss by its target class's weight.
     """
-    x = x.astype(jnp.float32)  # fp32 log-softmax even for bf16 logits
-    logp = jax.nn.log_softmax(x, axis=1)
+    # fp32 statistics even for bf16 logits.  The target's logit is a masked
+    # row sum, not a gather: a gather cannot fuse into the producer of its
+    # operand, so XLA would write the whole float32 log-softmax for it; the
+    # comparison against an iota rides in the pass that sums the exponentials
+    x = x.astype(jnp.float32)
     t_safe = jnp.where(t == ignore_label, 0, t)
-    # gather the log-prob of the target class along axis 1
-    nll = -jnp.take_along_axis(
-        logp, t_safe[:, None] if logp.ndim == 2 else jnp.expand_dims(t_safe, 1), axis=1
-    ).squeeze(1)
+    lse = jax.nn.logsumexp(x, axis=1)
+    classes = jax.lax.broadcasted_iota(t_safe.dtype, x.shape, 1)
+    picked = jnp.sum(
+        jnp.where(classes == jnp.expand_dims(t_safe, 1), x, 0.0), axis=1)
+    nll = lse - picked
     if class_weight is not None:
         nll = nll * jnp.asarray(class_weight)[t_safe]
     mask = (t != ignore_label)

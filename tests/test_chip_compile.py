@@ -142,6 +142,51 @@ def test_rows_form_compiles_between_its_gemms_and_moves_nothing(
     assert not [line for line in text.splitlines() if moved.search(line)]
 
 
+def test_the_loss_reads_the_logits_once_and_writes_no_float32_copy(
+        one_chip, no_persistent_cache):
+    """GPT-2-medium's vocabulary a chip: ``ln_f`` -> the head's GEMM in
+    bfloat16 -> ``F.softmax_cross_entropy`` over ``[4096, 50257]`` logits,
+    forwards and backwards.  The loss picks the target's logit inside the
+    row reduction, so the bfloat16 logits are read by ONE loop fusion (sum
+    of exponentials and the target's logit together) and by the two
+    backward GEMMs, which rebuild the softmax in their prologues; no
+    float32 array of the logits' shape is an instruction.  With the target
+    gathered from ``log_softmax`` the same piece kept 1 236 MB of
+    temporaries."""
+    import re
+    from chainermn_tpu.nn import functions as F
+    N, D, V = 4096, 1024, 50257
+
+    def loss(h, gamma, beta, W, t):
+        y = F.layer_normalization(h, gamma, beta)
+        return F.softmax_cross_entropy(y @ W.astype(jnp.bfloat16).T, t)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled, text = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
+        spec((N, D), jnp.bfloat16), spec((D,), jnp.float32),
+        spec((D,), jnp.float32), spec((V, D), jnp.float32),
+        spec((N,), jnp.int32))
+    entry = text[text.index("\nENTRY "):].splitlines()
+    assert not [line for line in entry
+                if re.search(r" = \(?[^=]*f32\[4096,50257\]", line)]
+    logits = [m.group(1) for line in entry for m in [re.match(
+        r"\s*%(\S+) = bf16\[4096,50257\]\S* get-tuple-element\(", line)]
+        if m]
+    assert len(logits) == 1, logits
+    readers = [line for line in entry
+               if re.search(rf"\(.*%{re.escape(logits[0])}[,)]", line)]
+    loops = [line for line in readers if "kind=kLoop" in line]
+    gemms = [line for line in readers if "kind=kOutput" in line]
+    assert len(readers) == 3 and len(loops) == 1 and len(gemms) == 2, readers
+    # the one pass gives both row statistics
+    assert re.search(r" = \(f32\[4096\]\S*, f32\[4096\]\S*\) fusion\(",
+                     loops[0])
+    assert compiled.memory_analysis().temp_size_in_bytes < 500e6
+
+
 # -- the serving programs ----------------------------------------------------
 
 @pytest.fixture(scope="module")
