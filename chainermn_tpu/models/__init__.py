@@ -13,6 +13,8 @@ from .moe_transformer import (MoETransformerLM, MoETransformerBlock,
 from .latent_moe import LatentMoELM, LatentMoEBlock, LatentAttention
 from .window_moe import (WindowMoELM, WindowMoEBlock,
                          GatedGroupedAttention)
+from .hybrid_delta import (HybridDeltaLM, HybridDeltaBlock, DeltaMixer,
+                           FullMixer)
 from .convnets import AlexNet, NIN, VGG16, GoogLeNet
 
 __all__ = ["MLP", "Classifier", "ResNet", "ResNet18", "ResNet50",
@@ -24,4 +26,5 @@ __all__ = ["MLP", "Classifier", "ResNet", "ResNet18", "ResNet50",
            "MultiHeadAttention", "MoETransformerLM", "MoETransformerBlock",
            "MoEFeedForward", "LatentMoELM", "LatentMoEBlock",
            "LatentAttention", "WindowMoELM", "WindowMoEBlock",
-           "GatedGroupedAttention", "AlexNet", "NIN", "VGG16", "GoogLeNet"]
+           "GatedGroupedAttention", "HybridDeltaLM", "HybridDeltaBlock",
+           "DeltaMixer", "FullMixer", "AlexNet", "NIN", "VGG16", "GoogLeNet"]

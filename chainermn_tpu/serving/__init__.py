@@ -51,7 +51,7 @@ from .errors import (EvictionStalledError, PagePoolExhaustedError,
                      UnsupportedProgramError)
 from .fleet import (FleetWorker, LocalReplica, QueueDepthScalePolicy,
                     RemoteReplica, ReplicaFleet, fleet_mode)
-from .kv_cache import (PagedKVCache, copy_page, insert_pages,
+from .kv_cache import (PagedKVCache, PerSequence, copy_page, insert_pages,
                        write_prompt_kv, write_prompt_kv_at, write_span_kv,
                        write_token_kv)
 from .page_allocator import BlockAllocator
@@ -64,7 +64,7 @@ __all__ = [
     # round 20 (ISSUE 20): speculative decoding + chunked prefill
     "spec_verify_program", "ngram_propose", "serve_spec_k",
     "write_span_kv",
-    "PagedKVCache", "write_prompt_kv", "write_prompt_kv_at",
+    "PagedKVCache", "PerSequence", "write_prompt_kv", "write_prompt_kv_at",
     "write_token_kv", "copy_page", "insert_pages",
     "BlockAllocator", "Request", "RequestScheduler",
     "ServingError", "PagePoolExhaustedError", "QueueSaturatedError",

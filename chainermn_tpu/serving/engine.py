@@ -118,7 +118,7 @@ from .. import observability
 from ..core.link import bind_state, cast_params, extract_state
 from ..ops.paged_attention import paged_attn_mode
 from .errors import PagePoolExhaustedError, UnsupportedProgramError
-from .kv_cache import PagedKVCache, copy_page, insert_pages
+from .kv_cache import PagedKVCache, PerSequence, copy_page, insert_pages
 from .page_allocator import BlockAllocator
 from .scheduler import RequestScheduler
 
@@ -336,8 +336,16 @@ class ServingEngine:
     :meth:`window_group_pages`: ``max_batch · (window / page_size + 1)
     + 4 · max_context / page_size`` pages, rounded up to 128 (every
     lane's window, one prompt in flight, and room for what the prefix
-    trie alone still holds).  ``spec_k``, ``tp`` and ``disagg`` are
-    refused for such a model.
+    trie alone still holds).  A group that keeps one entry a SEQUENCE
+    (a recurrent layer's state: its span a
+    :class:`~chainermn_tpu.serving.kv_cache.PerSequence`, declared after
+    the groups that keep pages) gets slots, not pages, sized by
+    :meth:`state_group_slots`: ``max_batch + 4 · ceil(max_context /
+    stride)``, rounded up to 8 (every lane, and four prompts' worth of
+    snapshots); its row of the stacked table carries slots: ``[live,
+    source, snapshot 0, ...]`` for a prefill, ``[live]`` a lane for
+    decode.  ``spec_k``, ``tp`` and ``disagg`` are refused for such a
+    model.
 
     Greedy sampling (the serving bench's configuration); the paged/dense
     attention lowering is resolved ONCE at construction
@@ -396,15 +404,36 @@ class ServingEngine:
             if window is not None:
                 raise ValueError("the first cache group keeps every "
                                  "position; it has no window")
+            kinds = [isinstance(span, PerSequence) for *_, span in rest]
+            if kinds != sorted(kinds):
+                raise ValueError("the groups that keep pages come before "
+                                 "those that keep one entry a sequence")
             sized = [(n, self.window_group_pages(w, page_size, max_batch,
                                                  max_context), e, w)
-                     for _, n, e, w in rest]
+                     for _, n, e, w in rest
+                     if not isinstance(w, PerSequence)]
+            slotted = [(n, self.state_group_slots(span.stride, max_batch,
+                                                  max_context), e,
+                        span.stride)
+                       for _, n, e, span in rest
+                       if isinstance(span, PerSequence)]
             self.kv = PagedKVCache(
                 n_layers, num_pages, page_size, entry, dtype=page_dtype,
-                more=[(n, pages, e) for n, pages, e, _ in sized])
+                more=[(n, pages, e) for n, pages, e, _ in sized],
+                states=[(n, slots, e) for n, slots, e, _ in slotted])
             self.allocator = BlockAllocator(
                 num_pages, page_size,
-                windows=[(pages, w) for _, pages, _, w in sized])
+                windows=[(pages, w) for _, pages, _, w in sized],
+                states=[(slots, stride) for _, slots, _, stride in slotted])
+            for _, _, _, stride in slotted:
+                # [live, source] and a snapshot a stride of the longest
+                # prompt ride in the group's row of the block table
+                if 2 + -(-max_context // stride) > -(-max_context
+                                                     // page_size):
+                    raise ValueError(
+                        f"snapshot stride {stride} leaves no room for a "
+                        f"prompt's slots in a block table of "
+                        f"{-(-max_context // page_size)} entries")
             if len(groups) > 1 and serve_disagg_mode(disagg):
                 # one scratch pool and one ship: not written for a
                 # second group
@@ -631,6 +660,16 @@ class ServingEngine:
         return -(-pages // 128) * 128
 
     @staticmethod
+    def state_group_slots(stride, max_batch, max_context):
+        """The slots a state group gets: every lane's live state, and
+        four prompts' worth of the snapshots a prefill leaves every
+        ``stride`` tokens, rounded up to 8: ``max_batch + 4 ·
+        ceil(max_context / stride)``.  What the trie alone holds is
+        given up before an admission is refused."""
+        slots = max_batch + 4 * -(-max_context // stride)
+        return -(-slots // 8) * 8
+
+    @staticmethod
     def _held_state(model):
         """The model's state as the engine holds it: its parameters in
         the dtype the model declares (``serve_param_dtype``; ``None``
@@ -677,11 +716,14 @@ class ServingEngine:
 
     # -- internals -----------------------------------------------------------
 
-    def _bt_row(self, seq_id):
+    def _bt_row(self, seq_id, snapshots=()):
         """The sequence's block table as the programs take it: ``[N]``,
         or for a model that declared cache groups ``[groups, N]``, a row
         a group (a window group's released entries stay zero: they lie
-        below every position its layers read)."""
+        below every position its layers read).  A state group's row is
+        slots: ``[live, source, snapshot 0, ...]``, the snapshot entries
+        those of ``snapshots`` (a group: a slot each state the program's
+        scan leaves, one beyond the pool where none is kept)."""
         row = np.zeros(self.n_block_entries, dtype=np.int32)
         table = self.allocator.block_table(seq_id)
         row[:len(table)] = table
@@ -690,10 +732,36 @@ class ServingEngine:
         rows = np.zeros((self.cache_groups, self.n_block_entries),
                         dtype=np.int32)
         rows[0] = row
-        for g in range(1, self.cache_groups):
-            table, low = self.allocator.window_table(seq_id, g - 1)
-            rows[g, low:len(table)] = table[low:]
+        n_windows = len(self.allocator.windows)
+        for g in range(n_windows):
+            table, low = self.allocator.window_table(seq_id, g)
+            rows[1 + g, low:len(table)] = table[low:]
+        for g in range(len(self.allocator.states)):
+            slots = list(self.allocator.state_slots(seq_id, g))
+            if snapshots:
+                slots += snapshots[g]
+            rows[1 + n_windows + g, :len(slots)] = slots
         return rows
+
+    def _snapshot_slots(self, seq_id, start, size):
+        """Reserve the snapshots a prefill program of ``size`` tokens at
+        offset ``start`` is to fill: its scan, over the bucket the
+        tokens are padded to, leaves state ``i`` after ``min((i + 1) ·
+        stride, size)`` tokens, and the trie keeps those that fall on a
+        stride of the prompt.  A group: the slot of each of the scan's
+        states (one beyond the pool: not kept).  Raises
+        :class:`PagePoolExhaustedError`."""
+        states = self.allocator.states
+        if not states:
+            return ()
+        bucket = _bucket(size, self.prefill_buckets, "prefill length")
+        at = [[start + min((i + 1) * st.stride, size)
+               for i in range(-(-bucket // st.stride))] for st in states]
+        held = self.allocator.reserve_snapshots(
+            seq_id, [p for ps in at for p in ps])
+        # a position's slot goes to the first state that stands there
+        return [[slots.pop(p, st.num_slots) for p in ps]
+                for st, ps, slots in zip(states, at, held)]
 
     def _zero_bt(self, *lead):
         """An all-zero block table of the programs' shape (warm-up,
@@ -821,19 +889,23 @@ class ServingEngine:
                 "chainermn_tpu_serving_forks_total",
                 help="copy-on-write page forks").inc(1)
 
-    def _run_prefix_prefill(self, req, L, matched):
+    def _run_prefix_prefill(self, req, L, matched, snapshots=()):
         """Prefix HIT: prefill only the unmatched suffix, against the
         decode pool (the shared pages live there — and on the disagg
         split this is exactly the work the hit keeps OFF the prefill
-        slice)."""
+        slice).  A state group's layers start from the snapshot the hit
+        gave (copied inside the program: the sequence's hold on it ends
+        here)."""
         Ts = L - matched
         Tb = _bucket(Ts, self.prefill_buckets, "suffix length")
         tokens = np.zeros((1, Tb), dtype=np.int32)
         tokens[0, :Ts] = req.prompt[matched:]
-        return self._run(
+        out = self._run(
             self._prefix_prefill_fn, self.kv, self.state,
             jnp.asarray(tokens), np.int32(Ts), np.int32(matched),
-            jnp.asarray(self._bt_row(req.request_id)))
+            jnp.asarray(self._bt_row(req.request_id, snapshots)))
+        self.allocator.restored(req.request_id)
+        return out
 
     def _run_disagg_prefill(self, req, L):
         """Prefix MISS on the disagg split: the full flash prefill runs
@@ -935,6 +1007,15 @@ class ServingEngine:
                 raise _AdmitDeferred()
             self.allocator.ensure(
                 sid, self.chunk_tokens if chunked else L + 1)
+        # the slots a state group's prefill leaves its snapshots in:
+        # the last host-side allocation, rolled back with the rest
+        snapshots = ()
+        if not chunked:
+            try:
+                snapshots = self._snapshot_slots(sid, matched, L - matched)
+            except PagePoolExhaustedError:
+                self.allocator.free(sid)
+                raise
         # queue-wait accounting (always — the bench reads it trace-off):
         # this admission's wait is arrival → now, or requeue → now after
         # an eviction (the prior RUNNING period is decode time, not
@@ -980,12 +1061,15 @@ class ServingEngine:
         # the host, so the wait for the prefill's result lies inside it
         tags = {"request": sid, "prompt": L, "matched": matched,
                 "wait_ms": wait_s * 1e3} if obs_on else None
+        if obs_on and matched and self.allocator.states:
+            tags["restored"] = matched    # the snapshot's position
         req.admit_time = t_admit
         req.requeue_time = None   # consumed: next eviction re-stamps
         if matched:
             with observability.span("serve/suffix_prefill", tags=tags,
                                     tid=rtid) as sp:
-                logits, *extras = self._run_prefix_prefill(req, L, matched)
+                logits, *extras = self._run_prefix_prefill(
+                    req, L, matched, snapshots)
                 self.prefix_hits += 1
                 self.prefix_tokens_matched += matched
                 self._complete_admission(req, logits, clock, prompt_t)
@@ -1007,7 +1091,7 @@ class ServingEngine:
                 logits, *extras = self._run(
                     self._prefill_fn, self.kv, self.state,
                     jnp.asarray(tokens), np.int32(L),
-                    jnp.asarray(self._bt_row(sid)))
+                    jnp.asarray(self._bt_row(sid, snapshots)))
                 self._complete_admission(req, logits, clock, prompt_t)
                 self._set_model_stats(sp, extras)
 
@@ -1059,7 +1143,7 @@ class ServingEngine:
                   jnp.asarray(self._bt_row(req.request_id)))
         req._draft_ctx = L
 
-    def _run_chunk(self, req, startp, size, final, clock):
+    def _run_chunk(self, req, startp, size, final, clock, snapshots=()):
         """One chunk of a chunked prefill: ``size`` prompt tokens at
         cursor ``startp`` through the chunk program (the offset-writer
         suffix shape; chunk 0 is ``start=0``).  Prefix-miss chunks on
@@ -1081,7 +1165,8 @@ class ServingEngine:
             logits, *_ = self._run(
                 self._chunk_fn, self.kv, self.state,
                 jnp.asarray(tokens), np.int32(size), np.int32(startp),
-                jnp.asarray(self._bt_row(sid)))
+                jnp.asarray(self._bt_row(sid, snapshots)))
+            self.allocator.restored(sid)    # the next chunk: its own slot
         self.chunk_prefills += 1
         req._chunk_pos = startp + size
         if final:
@@ -1121,6 +1206,8 @@ class ServingEngine:
                     self.allocator.ensure(
                         req.request_id,
                         startp + size + (1 if final else 0))
+                    snapshots = self._snapshot_slots(req.request_id,
+                                                     startp, size)
                 except PagePoolExhaustedError:
                     break   # stall: keep pages, retry next step
                 with observability.span(
@@ -1130,7 +1217,8 @@ class ServingEngine:
                               "final": final} if obs_on else None,
                         tid=self._req_tid(req)
                         if observability.ring_enabled() else None):
-                    self._run_chunk(req, startp, size, final, clock)
+                    self._run_chunk(req, startp, size, final, clock,
+                                    snapshots)
                 budget -= size
                 progressed += size
         if not progressed and not self.running \
@@ -1326,12 +1414,7 @@ class ServingEngine:
             if observability.enabled():
                 a = self.allocator
                 sp.set(running=stats["running"], used_pages=a.used_pages,
-                       num_pages=a.num_pages,
-                       **({"window_used_pages": a.window_used_pages,
-                           "window_num_pages": a.windows[0].num_pages,
-                           "window_retained_pages":
-                           a.window_retained_pages}
-                          if self.cache_groups > 1 else {}))
+                       num_pages=a.num_pages, **a.group_stats())
         return stats
 
     def _step(self, clock):
@@ -1410,13 +1493,17 @@ class ServingEngine:
         tags = {"batch": n, "bucket": Bb, "step": self.decode_steps} \
             if obs_on else None
         if obs_on and self.cache_groups > 1:
-            # what a sound step reads of each cache, in tokens: the
-            # whole context in the full group, a window's worth of it
-            # in a window group
-            w = self.allocator.windows[0].window
+            # what a sound step reads of each kind of cache: the whole
+            # context in the full group (tokens), a window's worth of it
+            # in a window group, one state a lane in a state group
+            a = self.allocator
             ctx = [req._ctx + 1 for req in self.running]
-            tags.update(ctx_tokens=sum(ctx),
-                        window_tokens=sum(min(c, w) for c in ctx))
+            tags["ctx_tokens"] = sum(ctx)
+            if a.windows:
+                tags["window_tokens"] = sum(
+                    min(c, w.window) for w in a.windows for c in ctx)
+            if a.states:
+                tags["state_lanes"] = n
         with observability.span("serve/decode_window",
                                 tags=tags) as window:
             with observability.span("serve/decode_build"):

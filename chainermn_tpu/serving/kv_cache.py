@@ -25,12 +25,25 @@ two leading axes of a layer's pool only, so they serve any entry shape.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax.numpy as jnp
 
-__all__ = ["PagedKVCache", "write_prompt_kv", "write_prompt_kv_at",
-           "write_token_kv", "write_span_kv", "copy_page", "insert_pages"]
+__all__ = ["PagedKVCache", "PerSequence", "write_prompt_kv",
+           "write_prompt_kv_at", "write_token_kv", "write_span_kv",
+           "copy_page", "insert_pages"]
+
+
+@dataclasses.dataclass(frozen=True)
+class PerSequence:
+    """The span of a cache group that keeps ONE entry a sequence, not an
+    entry a token: a recurrent layer's state (where a full group's span
+    is ``None`` and a window group's its window).  ``stride``: the
+    tokens between the snapshots of it that a prefill leaves for the
+    prefix trie, a multiple of the page size."""
+
+    stride: int
 
 
 def _geometry(pool, layer):
@@ -158,22 +171,32 @@ class PagedKVCache:
     ``more`` is the further groups' ``(n_layers, num_pages, entry)``,
     and their arrays follow the first group's in ``pools``.  ``n_layers``,
     ``num_pages``, ``entry`` and ``page_bytes`` stay the first group's;
-    ``pool_bytes`` is every group's."""
+    ``pool_bytes`` is every group's.
+
+    A group that keeps one entry a SEQUENCE (:class:`PerSequence`) gets
+    SLOTS, not pages: ``states`` is such groups' ``(n_layers, num_slots,
+    entry)``, each shape of the entry one float32 array ``[L, slots,
+    *shape]`` after the pages' in ``pools``."""
 
     def __init__(self, n_layers, num_pages, page_size, entry,
-                 dtype=jnp.bfloat16, more=()):
+                 dtype=jnp.bfloat16, more=(), states=()):
         self.n_layers = int(n_layers)
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.entry = tuple(tuple(int(d) for d in shape) for shape in entry)
         self.dtype = jnp.dtype(dtype)
+        def sized(groups):
+            return tuple((int(n), int(count),
+                          tuple(tuple(int(d) for d in shape) for shape in e))
+                         for n, count, e in groups)
         self.groups = ((self.n_layers, self.num_pages, self.entry),) \
-            + tuple((int(n), int(p), tuple(tuple(int(d) for d in shape)
-                                           for shape in e))
-                    for n, p, e in more)
+            + sized(more)
+        self.state_groups = sized(states)
         self.pools = [
             jnp.zeros((n, pages, self.page_size) + shape, self.dtype)
-            for n, pages, e in self.groups for shape in e]
+            for n, pages, e in self.groups for shape in e] + [
+            jnp.zeros((n, slots) + shape, jnp.float32)
+            for n, slots, e in self.state_groups for shape in e]
 
     # the names of a two-array (K, V) entry's pools
     @property
@@ -204,4 +227,6 @@ class PagedKVCache:
     def pool_bytes(self):
         return sum(n * pages * sum(math.prod(shape) for shape in e)
                    for n, pages, e in self.groups) \
-            * self.page_size * self.dtype.itemsize
+            * self.page_size * self.dtype.itemsize \
+            + sum(n * slots * sum(math.prod(shape) for shape in e) * 4
+                  for n, slots, e in self.state_groups)

@@ -53,6 +53,23 @@ layers beside window layers) gets a further pool a window group
 * such a match is cut to whole pages (no fork is needed, so none is
   written for a window group).
 
+A group of layers that keeps one entry a SEQUENCE (ISSUE 33: a
+recurrent layer's state; ``states=``) gets SLOTS, not pages:
+
+* a sequence holds one LIVE slot in each such group, taken with its
+  first ``ensure`` and given back by ``free``;
+* a prefix hit at ``m`` tokens needs the state AS IT WAS AT ``m``, and
+  the holder's live state has moved on, so a prefill leaves SNAPSHOTS at
+  every ``stride`` tokens (``reserve_snapshots``) and ``register_prefix``
+  hands them to the trie node at that depth: the trie's own hold, for as
+  long as the node lives;
+* ``match_prefix`` cuts a walk back to the deepest node that has a
+  snapshot in every state group (and, with window groups, the window's
+  pages): whole pages, no fork.  The borrower never writes the snapshot:
+  it holds it (``share``) until its prefill has copied it (``restored``);
+* snapshots the trie alone holds are given up, least recently matched
+  first, before :class:`PagePoolExhaustedError`.
+
 Discipline (mirrors ``_memory_utility.plan_buckets``): every decision is
 a pure function of the call sequence — the free list is FIFO over page
 ids seeded ``0..P-1``, frees return zero-refcount pages in block-table
@@ -96,15 +113,19 @@ class _TrieNode:
     off this node.
     """
 
-    __slots__ = ("children", "holders", "partials", "window_pages")
+    __slots__ = ("children", "holders", "partials", "window_pages",
+                 "snapshots")
 
-    def __init__(self, n_windows=0):
+    def __init__(self, n_windows=0, n_states=0):
         self.children = {}
         self.holders = OrderedDict()
         self.partials = OrderedDict()
         # the page the trie itself holds of this chunk in each window
         # group (None: never written by a holder, or given up)
         self.window_pages = [None] * n_windows
+        # the slot that holds the state as it stood at this chunk's END,
+        # in each state group (None: not on a stride, or given up)
+        self.snapshots = [None] * n_states
 
     @property
     def dead(self):
@@ -118,9 +139,80 @@ class PrefixMatch(list):
     under the window and are not held)."""
 
     windows = ()
+    snapshots = ()      # each state group's slot to start from
 
 
-class _WindowPool:
+class _Retained:
+    """What the trie holds of a pool, least recently matched first, and
+    the giving up of it: ``refs`` counts a sequence's hold and the
+    trie's alike, ``retained`` maps an id the trie holds to ``(trie
+    node, group index)``, ``_forget`` clears the node's name for it."""
+
+    def release(self, item):
+        self.refs[item] -= 1
+        if self.refs[item] == 0:
+            del self.refs[item]
+            self.free.append(item)
+
+    def make_room(self, need):
+        """Whether ``need`` are free, after giving up as many that the
+        trie alone holds as it takes, least recently matched first
+        (none, where even all of them would not do)."""
+        short = need - len(self.free)
+        if short <= 0:
+            return True
+        alone = [item for item in self.retained if self.refs[item] == 1]
+        if len(alone) < short:
+            return False
+        for item in alone[:short]:
+            self._forget(*self.retained.pop(item))
+            self.release(item)
+        return True
+
+    @property
+    def retained_alone(self):
+        """How many the trie holds and no sequence does."""
+        return sum(1 for item in self.retained if self.refs[item] == 1)
+
+
+class _StatePool(_Retained):
+    """One state group's slots: each sequence's live slot, the snapshots
+    a prefill in flight is writing (``pending``: by position, until the
+    prompt is registered), the snapshot a borrower is about to copy
+    (``restoring``), and the snapshots the trie holds."""
+
+    def __init__(self, num_slots, stride, page_size):
+        if num_slots <= 0 or stride <= 0 or stride % page_size:
+            raise ValueError(
+                f"a state group needs slots and a snapshot stride that is "
+                f"a multiple of the page size; got {num_slots} slots, "
+                f"stride {stride}, page size {page_size}")
+        self.num_slots, self.stride = int(num_slots), int(stride)
+        self.free = deque(range(self.num_slots))
+        self.refs = {}
+        self.live = {}               # seq_id -> slot
+        self.pending = {}            # seq_id -> {position: slot}
+        self.restoring = {}          # seq_id -> snapshot slot
+        self.retained = OrderedDict()   # slot -> (trie node, group index)
+
+    @staticmethod
+    def _forget(node, g):
+        node.snapshots[g] = None
+
+    def take(self):
+        slot = self.free.popleft()
+        self.refs[slot] = 1
+        return slot
+
+    def drop(self, seq_id):
+        """Everything ``seq_id`` holds here but its live slot."""
+        for slot in self.pending.pop(seq_id, {}).values():
+            self.release(slot)
+        if seq_id in self.restoring:
+            self.release(self.restoring.pop(seq_id))
+
+
+class _WindowPool(_Retained):
     """One window group's pages: refcounts (a sequence's table and the
     trie each count one), the sequences' tables with how far each has
     slid, and the pages the trie holds, least recently matched first."""
@@ -138,32 +230,9 @@ class _WindowPool:
         self.low = {}                # seq_id -> first entry still held
         self.retained = OrderedDict()   # page -> (trie node, group index)
 
-    def release(self, page):
-        self.refs[page] -= 1
-        if self.refs[page] == 0:
-            del self.refs[page]
-            self.free.append(page)
-
-    def make_room(self, need):
-        """Whether ``need`` pages are free, after giving up as many
-        pages that the trie alone holds as it takes, least recently
-        matched first (none, where even all of them would not do)."""
-        short = need - len(self.free)
-        if short <= 0:
-            return True
-        alone = [page for page in self.retained if self.refs[page] == 1]
-        if len(alone) < short:
-            return False
-        for page in alone[:short]:
-            node, g = self.retained.pop(page)
-            node.window_pages[g] = None
-            self.release(page)
-        return True
-
-    @property
-    def retained_alone(self):
-        """Pages that the trie holds and no sequence does."""
-        return sum(1 for page in self.retained if self.refs[page] == 1)
+    @staticmethod
+    def _forget(node, g):
+        node.window_pages[g] = None
 
 
 class BlockAllocator:
@@ -179,22 +248,29 @@ class BlockAllocator:
     table order.
     """
 
-    def __init__(self, num_pages, page_size, windows=()):
+    def __init__(self, num_pages, page_size, windows=(), states=()):
         """``windows``: ``(num_pages, window)`` of each further group of
-        layers that keeps the last ``window`` positions only."""
+        layers that keeps the last ``window`` positions only;
+        ``states``: ``(num_slots, stride)`` of each group that keeps one
+        entry a sequence, with a snapshot every ``stride`` tokens."""
         if num_pages <= 0 or page_size <= 0:
             raise ValueError("num_pages and page_size must be positive")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.windows = [_WindowPool(p, w, self.page_size)
                         for p, w in windows]
+        self.states = [_StatePool(n, stride, self.page_size)
+                       for n, stride in states]
         self._free = deque(range(self.num_pages))
         # OrderedDict: iteration order == admission order (the scheduler's
         # eviction policy reads it newest-first)
         self._tables = OrderedDict()
         self._refs = {}          # page id -> number of tables holding it
-        self._trie = _TrieNode(len(self.windows))
+        self._trie = self._node()
         self._trie_refs = {}     # seq_id -> [(parent, key, node), ...]
+
+    def _node(self):
+        return _TrieNode(len(self.windows), len(self.states))
 
     # -- queries -------------------------------------------------------------
 
@@ -225,6 +301,14 @@ class BlockAllocator:
         ``low`` have been released and may name anyone's page."""
         w = self.windows[group]
         return w.tables[seq_id], w.low[seq_id]
+
+    def state_slots(self, seq_id, group=0):
+        """``(live, source)`` of the sequence in a state group: its own
+        slot, and the slot its next prefill starts from: the snapshot a
+        hit gave it, until ``restored``, then its own."""
+        st = self.states[group]
+        live = st.live[seq_id]
+        return live, st.restoring.get(seq_id, live)
 
     def capacity(self, seq_id):
         """Token positions the sequence's current pages can hold."""
@@ -270,6 +354,9 @@ class BlockAllocator:
             if not w.make_room(w_need):
                 raise PagePoolExhaustedError(w_need, len(w.free),
                                              w.num_pages)
+        for st in self.states:
+            if seq_id not in st.live and not st.make_room(1):
+                raise PagePoolExhaustedError(1, 0, st.num_slots)
         if seq_id not in self._tables:
             self._tables[seq_id] = table
         for _ in range(max(0, need)):
@@ -283,7 +370,42 @@ class BlockAllocator:
                 p = w.free.popleft()
                 w.refs[p] = 1
                 w_table.append(p)
+        for st in self.states:
+            if seq_id not in st.live:
+                st.live[seq_id] = st.take()
         return list(table)
+
+    def reserve_snapshots(self, seq_id, positions):
+        """A prefill of ``seq_id`` is about to pass ``positions``: take
+        a slot in every state group for each of them that lies on the
+        group's stride (and has none yet), for the program to write the
+        state into as it stands there.  The sequence holds them until
+        ``register_prefix`` hands them to the trie.  Atomic: raises
+        :class:`PagePoolExhaustedError` (state unchanged) where a group
+        cannot give them even after the trie's alone are given up.
+        Returns, a group, ``{position: slot}`` of all it holds."""
+        wanted = []
+        for st in self.states:
+            held = st.pending.get(seq_id, {})
+            new = sorted({p for p in positions
+                          if p > 0 and p % st.stride == 0 and p not in held})
+            if not st.make_room(len(new)):
+                raise PagePoolExhaustedError(len(new), len(st.free),
+                                             st.num_slots)
+            wanted.append(new)
+        for st, new in zip(self.states, wanted):
+            held = st.pending.setdefault(seq_id, {})
+            for p in new:
+                held[p] = st.take()
+        return [dict(st.pending.get(seq_id, {})) for st in self.states]
+
+    def restored(self, seq_id):
+        """The sequence's prefill has copied the snapshot a hit gave it:
+        its hold on it ends, and what it starts from next is its own
+        slot."""
+        for st in self.states:
+            if seq_id in st.restoring:
+                st.release(st.restoring.pop(seq_id))
 
     def slide(self, seq_id, position):
         """The sequence's next query sits at ``position``: release its
@@ -316,6 +438,9 @@ class BlockAllocator:
             for p in w_table[low:]:
                 w.refs[p] += 1
             w.tables[seq_id], w.low[seq_id] = list(w_table), low
+        for st, slot in zip(self.states, getattr(pages, "snapshots", ())):
+            st.refs[slot] += 1
+            st.restoring[seq_id] = slot
 
     def fork(self, seq_id, index):
         """Copy-on-write: swap the (shared) page at ``index`` of
@@ -348,6 +473,10 @@ class BlockAllocator:
             low = w.low.pop(seq_id)
             for p in w.tables.pop(seq_id)[low:]:
                 w.release(p)
+        for st in self.states:
+            st.drop(seq_id)
+            if seq_id in st.live:
+                st.release(st.live.pop(seq_id))
         self.unregister_prefix(seq_id)
         freed = 0
         for p in table:
@@ -379,7 +508,7 @@ class BlockAllocator:
             chunk = tokens[i * S:(i + 1) * S]
             child = node.children.get(chunk)
             if child is None:
-                child = node.children[chunk] = _TrieNode(len(self.windows))
+                child = node.children[chunk] = self._node()
             child.holders[seq_id] = table[i]
             for g, w in enumerate(self.windows):
                 # the trie's own hold on the chunk's window page, taken
@@ -389,13 +518,25 @@ class BlockAllocator:
                     child.window_pages[g] = page
                     w.refs[page] += 1
                     w.retained[page] = (child, g)
+            for g, st in enumerate(self.states):
+                # the snapshot this sequence's prefill left at the
+                # chunk's end becomes the trie's (the sequence's hold
+                # moves; a node that has one already keeps it)
+                slot = st.pending.get(seq_id, {}).get((i + 1) * S)
+                if slot is not None and child.snapshots[g] is None:
+                    del st.pending[seq_id][(i + 1) * S]
+                    child.snapshots[g] = slot
+                    st.retained[slot] = (child, g)
             refs.append((node, chunk, child))
             node = child
         rem = tokens[n_full * S:]
-        if rem and not self.windows:     # a window group shares whole pages
+        # a window or a state group shares whole pages
+        if rem and not (self.windows or self.states):
             node.partials[seq_id] = (rem, table[n_full])
             refs.append((None, None, node))   # partial ref marker
         self._trie_refs[seq_id] = refs
+        for st in self.states:      # the prompt is written: what no node
+            st.drop(seq_id)         # took is given back
 
     def unregister_prefix(self, seq_id):
         """Remove ``seq_id``'s trie entries, pruning nodes that die
@@ -415,6 +556,10 @@ class BlockAllocator:
                         if page is not None:
                             del w.retained[page]
                             w.release(page)
+                    for st, slot in zip(self.states, node.snapshots):
+                        if slot is not None:
+                            del st.retained[slot]
+                            st.release(slot)
 
     def match_prefix(self, tokens, cap):
         """Longest shareable prefix of ``tokens`` against live
@@ -446,8 +591,8 @@ class BlockAllocator:
             path.append(child)
             node = child
             n_full += 1
-        if self.windows:
-            return self._match_windows(pages, path)
+        if self.windows or self.states:
+            return self._match_groups(pages, path)
         matched = n_full * S
         best_c, best_page = 0, None
         for ptoks, ppage in node.partials.values():
@@ -460,11 +605,13 @@ class BlockAllocator:
             matched += best_c
         return pages, matched, n_full, best_c
 
-    def _match_windows(self, pages, path):
+    def _match_groups(self, pages, path):
         """Cut a walk of whole pages (``pages``, through the trie nodes
-        ``path``) back to the longest ``m`` for which the trie still
-        holds, in every window group, the pages covering ``(m - window,
-        m)``, and take those: they become the most recently matched."""
+        ``path``) back to the longest ``m`` that every group can serve:
+        the trie still holds, in every window group, the pages covering
+        ``(m - window, m)``, and in every state group a snapshot of the
+        state at ``m``.  Take those: they become the most recently
+        matched."""
         S = self.page_size
         held = [all(p is not None for p in n.window_pages) for n in path]
         run, d = 0, 0
@@ -472,29 +619,51 @@ class BlockAllocator:
             run = run + 1 if ok else 0
             lows = [max(0, (i + 1) * S - w.window + 1) // S
                     for w in self.windows]
-            if run >= i + 1 - min(lows):
+            if run >= i + 1 - min(lows, default=0) \
+                    and all(s is not None for s in path[i].snapshots):
                 d = i + 1
         out = PrefixMatch(pages[:d])
-        out.windows = []
+        out.windows, out.snapshots = [], []
         for g, w in enumerate(self.windows):
             low = max(0, d * S - w.window + 1) // S
             table = [0] * low + [n.window_pages[g] for n in path[low:d]]
             for page in table[low:]:
                 w.retained.move_to_end(page)
             out.windows.append((table, low))
+        if d:
+            for st, slot in zip(self.states, path[d - 1].snapshots):
+                st.retained.move_to_end(slot)
+                out.snapshots.append(slot)
         return out, d * S, d, 0
 
     @property
     def window_used_pages(self):
-        """Distinct pages of the (first) window group that a sequence or
-        the trie holds."""
-        w = self.windows[0]
-        return w.num_pages - len(w.free)
+        """Distinct pages of the window groups that a sequence or the
+        trie holds (0 without one)."""
+        return sum(w.num_pages - len(w.free) for w in self.windows)
 
     @property
     def window_retained_pages(self):
         """Those of them that the trie alone holds."""
-        return self.windows[0].retained_alone
+        return sum(w.retained_alone for w in self.windows)
+
+    def group_stats(self):
+        """The pools beside the first, by KIND, as ``serve/step``'s
+        stats: a kind the allocator has no group of gives none."""
+        out = {}
+        if self.windows:
+            out.update(
+                window_used_pages=self.window_used_pages,
+                window_num_pages=sum(w.num_pages for w in self.windows),
+                window_retained_pages=self.window_retained_pages)
+        if self.states:
+            out.update(
+                state_used_slots=sum(st.num_slots - len(st.free)
+                                     for st in self.states),
+                state_num_slots=sum(st.num_slots for st in self.states),
+                state_retained_slots=sum(st.retained_alone
+                                         for st in self.states))
+        return out
 
     # -- invariant check (the property suite's oracle) -----------------------
 
@@ -543,4 +712,31 @@ class BlockAllocator:
             if len(w.free) + len(counts) != w.num_pages \
                     or set(w.free) & set(counts):
                 raise AssertionError("window page conservation violated")
+        for g, st in enumerate(self.states):
+            counts = {}
+            for holds in (st.live, st.restoring):
+                for seq_id, slot in holds.items():
+                    if seq_id not in self._tables:
+                        raise AssertionError(
+                            f"state slot for dead sequence {seq_id!r}")
+                    counts[slot] = counts.get(slot, 0) + 1
+            for seq_id, held in st.pending.items():
+                if seq_id not in self._tables:
+                    raise AssertionError(
+                        f"snapshot pending for dead sequence {seq_id!r}")
+                for slot in held.values():
+                    counts[slot] = counts.get(slot, 0) + 1
+            for slot, (node, group) in st.retained.items():
+                if group != g or node.snapshots[g] != slot:
+                    raise AssertionError(
+                        f"slot {slot} retained by a node that does not "
+                        f"name it")
+                counts[slot] = counts.get(slot, 0) + 1
+            if counts != st.refs:
+                raise AssertionError(
+                    f"slot refcount drift: holders say {counts}, refs say "
+                    f"{st.refs}")
+            if len(st.free) + len(counts) != st.num_slots \
+                    or set(st.free) & set(counts):
+                raise AssertionError("state slot conservation violated")
         return True

@@ -422,3 +422,136 @@ def test_windowed_prefill_program_at_10752_tokens(windowed,
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= _pool_bytes(pools)
     assert 1.0e9 < ma.temp_size_in_bytes < 1.6e9, ma.temp_size_in_bytes
+
+
+# -- recurrent state beside pages (olmo-hybrid-7b-stage), published widths ---
+
+HYBRID_PAGES, HYBRID_CONTEXT, HYBRID_LANES = 7168, 17920, 16
+
+
+@pytest.fixture(scope="module")
+def hybrid(one_chip):
+    """The configuration's builder at the published widths and the
+    cell's depth, its bfloat16 state as shapes on the described chip,
+    and the cell's pools: the full layers' pages (a token's K then its V
+    in one row of 7680 lanes) and the linear layers' slots, sized by the
+    engine (56): every head's state ``[96, 5760]`` and the convolution's
+    three last inputs, float32."""
+    from benchmark import harness
+    from chainermn_tpu.serving import ServingEngine
+    config = harness.load_json(os.path.join(
+        harness.HERE, "configs", "olmo-hybrid-7b-stage.json"))
+    model = harness.load_module("models", "hybrid_delta_lm").build(
+        config, max_len=HYBRID_CONTEXT)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    state = {"params": {path: spec(p.shape, jnp.bfloat16)
+                        for path, p in model.namedparams()}, "state": {}}
+    (_, n_full, (row,), _), (_, n_linear, entry, span) = \
+        model.serve_cache_groups()
+    slots = ServingEngine.state_group_slots(span.stride, HYBRID_LANES,
+                                            HYBRID_CONTEXT)
+    pools = [spec((n_full, HYBRID_PAGES, PAGE) + row, jnp.bfloat16)] \
+        + [spec((n_linear, slots) + shape, jnp.float32) for shape in entry]
+    assert [p.shape for p in pools] == [
+        (2, 7168, 16, 7680), (6, 56, 96, 5760), (6, 56, 34560)]
+    return model, state, pools, spec
+
+
+def _on_the_chip(monkeypatch):
+    """The three dispatchers take their TPU branch, as the chip would."""
+    from chainermn_tpu.ops import gated_delta, paged_attention
+    for module in (fa, paged_attention, gated_delta):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+
+
+def _assert_no_pool_is_copied(compiled, text, pools):
+    """Every pool donated, aliased and never copied whole, relaid or
+    cut into pieces (a slot of state rows and convolution rows in ONE
+    array had the whole 0.8 GB pool relaid between layers at 16 lanes,
+    and copied at one; an XLA gather of the lanes' slots first sliced
+    the whole pool into three ``[6, 56, 96, 1920]``, 13 ms of every
+    decode step on the chip: PR 33)."""
+    import math
+    import re
+    nbytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in pools)
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
+    for p in pools:
+        shape = "%s[%s]" % ({"bfloat16": "bf16", "float32": "f32"}[
+            str(p.dtype)], ",".join(map(str, p.shape)))
+        assert not re.findall(re.escape(shape) + r"\{[^}]*\} copy\(", text)
+        layouts = set(re.findall(re.escape(shape) + r"\{([\d,]+)", text))
+        assert layouts == {",".join(map(str, reversed(range(p.ndim))))}
+        lead = shape[:shape.rindex(",") + 1]        # all but the lanes
+        assert set(re.findall(re.escape(lead) + r"\d+\]", text)) == {shape}
+
+
+@pytest.mark.parametrize("lanes", [1, HYBRID_LANES])
+def test_hybrid_decode_program_at_its_first_and_last_bucket(
+        hybrid, no_persistent_cache, monkeypatch, lanes):
+    """Pages and slots donated and updated in place; the 2 full layers'
+    attention is `_paged_decode_kernel` over 30 K/V heads (3840 key
+    lanes a row), the 6 linear layers read and write each lane's slot
+    alone: the temporaries are a few slots a lane, not a pool."""
+    from chainermn_tpu.serving import decode_program
+    model, state, pools, spec = hybrid
+    _on_the_chip(monkeypatch)
+    compiled, text = _compile(
+        functools.partial(decode_program, model, mode=None), state, *pools,
+        spec((lanes,), jnp.int32), spec((lanes,), jnp.int32),
+        spec((2, lanes, HYBRID_CONTEXT // PAGE), jnp.int32),
+        donate_argnums=(1, 2, 3))
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert sum("_paged_decode_kernel" in c for c in calls) == 2
+    _assert_no_pool_is_copied(compiled, text, pools)
+    slot = 96 * 5760 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 24 * slot * lanes + 2e7
+
+
+def test_hybrid_prefill_program_at_17920_tokens(hybrid,
+                                                no_persistent_cache,
+                                                monkeypatch):
+    """One `_gated_delta_chunk_kernel` a linear layer (the pass over 280
+    chunks, 9 states out) and one `_flash_kernel` a full layer; pools in
+    place; temporaries pinned (weights 4.87 + pools 4.31 + these fit the
+    chip's 16 GB)."""
+    from chainermn_tpu.serving import prefill_program
+    model, state, pools, spec = hybrid
+    _on_the_chip(monkeypatch)
+    compiled, text = _compile(
+        functools.partial(prefill_program, model), state, *pools,
+        spec((1, HYBRID_CONTEXT), jnp.int32), spec((), jnp.int32),
+        spec((2, HYBRID_CONTEXT // PAGE), jnp.int32),
+        donate_argnums=(1, 2, 3))
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert sum("_gated_delta_chunk_kernel" in c for c in calls) == 6
+    assert sum("_flash_kernel" in c for c in calls) == 2
+    assert all("f32[9,30,96,192]" in c for c in calls
+               if "_gated_delta_chunk_kernel" in c)
+    _assert_no_pool_is_copied(compiled, text, pools)
+    assert 2.0e9 < compiled.memory_analysis().temp_size_in_bytes < 4.0e9
+
+
+def test_hybrid_suffix_program_at_1024_tokens(hybrid, no_persistent_cache,
+                                              monkeypatch):
+    """A hit's suffix: the scan starts from the snapshot's slot (one
+    state in, one out), the full layers read the shared pages through
+    the block table."""
+    from chainermn_tpu.serving import prefix_prefill_program
+    model, state, pools, spec = hybrid
+    _on_the_chip(monkeypatch)
+    compiled, text = _compile(
+        functools.partial(prefix_prefill_program, model), state, *pools,
+        spec((1, 1024), jnp.int32), spec((), jnp.int32),
+        spec((), jnp.int32), spec((2, HYBRID_CONTEXT // PAGE), jnp.int32),
+        donate_argnums=(1, 2, 3))
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert sum("_gated_delta_chunk_kernel" in c for c in calls) == 6
+    assert all("f32[1,30,96,192]" in c for c in calls)
+    _assert_no_pool_is_copied(compiled, text, pools)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
