@@ -1,0 +1,16 @@
+"""Device time a step of attention: roles ``attn`` (the two flash kernels,
+the join of dq, dk, dv) and ``attn_proj`` (the qkv and output GEMMs with
+what XLA fused into them), both directions.
+
+Read from each operation's ``tf_op`` (``benchmark/device_scopes.py``): an
+operation counts where it lies inside one of the program's runs that lie
+wholly in the traced window, on the first chip; a fusion is booked
+whole, by the one ``tf_op`` XLA kept for it; the sum is divided by those
+runs.  ``None`` where the program did not run there, or carries no
+role at all (a commit before PR 38, or an executable kept from then)."""
+
+from benchmark import device_scopes
+
+
+def read(view):
+    return device_scopes.role_ms(view, "step", ("attn", "attn_proj"))

@@ -292,6 +292,11 @@ class Link:
 
     # -- call protocol -----------------------------------------------------
     def __call__(self, *args, **kwargs):
+        # the link's name on every operation's path (``blocks/3/attn/qkv``
+        # in the compiled program's ``op_name`` and the profiler's trace)
+        if self.name:
+            with jax.named_scope(self.name):
+                return self.forward(*args, **kwargs)
         return self.forward(*args, **kwargs)
 
     def forward(self, *args, **kwargs):

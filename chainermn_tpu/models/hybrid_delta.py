@@ -47,13 +47,14 @@ import numpy as np
 
 from ..core.link import Chain, ChainList, Parameter
 from ..nn import links as L
+from ..observability import role
 from ..ops import grouped_attention
 from ..ops.gated_delta import CHUNK, gated_delta_chunked, gated_delta_step
 from ..ops.paged_attention import (paged_decode_attention,
                                    paged_prefill_attention)
 from ..serving.kv_cache import (PerSequence, write_prompt_kv,
                                 write_prompt_kv_at, write_token_kv)
-from .latent_moe import SwiGLU
+from .latent_moe import SwiGLU, _last_row
 from .window_moe import _entry
 
 __all__ = ["DeltaMixer", "FullMixer", "HybridDeltaBlock", "HybridDeltaLM"]
@@ -63,6 +64,7 @@ __all__ = ["DeltaMixer", "FullMixer", "HybridDeltaBlock", "HybridDeltaLM"]
 SNAPSHOT_STRIDE = 2048
 
 
+@role("state")
 def _slots_of(pool, layer, slots):
     """``pool[layer, slots]`` for ``slots [B]``, a ``dynamic_slice`` a
     lane: the TPU compiler lowers the gather of 2 MB rows by first
@@ -121,11 +123,13 @@ class DeltaMixer(Chain):
                 np.exp(rng.uniform(np.log(1e-3), np.log(1e-1), n_heads)))
             .astype(np.float32))
 
+    @role("attn_proj")
     def inputs(self, x):
         """``x [..., d]`` -> the convolution's input rows ``[...,
         channels]`` (q~, k~, v~ side by side)."""
         return jnp.concatenate([self.q(x), self.k(x), self.v(x)], axis=-1)
 
+    @role("state")
     def convolve(self, before, rows):
         """The causal convolution and SiLU over ``rows [T, channels]``
         that follow ``before [taps - 1, channels]`` in time: ``[T,
@@ -137,6 +141,7 @@ class DeltaMixer(Chain):
         y = sum(xs[j:j + T] * w[:, j] for j in range(self.taps))
         return jax.nn.silu(y)
 
+    @role("state")
     def heads(self, y, dtype):
         """The convolved rows ``[..., channels]`` as ``(q, k [..., H, dk],
         v [..., H, dv])`` in ``dtype``: q and k normed a head, q scaled."""
@@ -147,6 +152,7 @@ class DeltaMixer(Chain):
         v = y[..., 2 * H * dk:].reshape(lead + (H, dv))
         return q.astype(dtype), k.astype(dtype), v.astype(dtype)
 
+    @role("attn_proj")
     def gates(self, x):
         """``(g, beta) [..., H]`` float32: the log decay and the write
         strength (``linear_allow_neg_eigval``: the factor 2)."""
@@ -155,6 +161,7 @@ class DeltaMixer(Chain):
             * jax.nn.softplus(a + self.dt_bias.array.astype(jnp.float32))
         return g, 2.0 * jax.nn.sigmoid(self.b(x).astype(jnp.float32))
 
+    @role("attn_proj")
     def output(self, o, x):
         """The heads' outputs ``o [..., H, dv]``, normed a head, gated by
         ``silu(x Wg)``, through the output projection."""
@@ -183,6 +190,7 @@ class FullMixer(Chain):
             self.q_norm = L.RMSNorm(n_heads * head_dim, eps)
             self.k_norm = L.RMSNorm(n_kv * head_dim, eps)
 
+    @role("attn_proj")
     def project(self, x):
         """``x [..., d]`` -> ``(q [..., H, D], k, v [..., G, D])``, k and
         v as they are cached."""
@@ -191,6 +199,7 @@ class FullMixer(Chain):
                 self.k_norm(self.k(x)).reshape(lead + (self.n_kv, D)),
                 self.v(x).reshape(lead + (self.n_kv, D)))
 
+    @role("attn_proj")
     def output(self, att):
         return self.o(att.reshape(att.shape[:-2] + (-1,)))
 
@@ -213,8 +222,12 @@ class HybridDeltaBlock(Chain):
             self.ln2 = L.RMSNorm(d_model, eps)
 
     def residual(self, x, mixed):
-        h = x + self.ln1(mixed)
-        return h + self.ln2(self.mlp(h))
+        with role("norm"):
+            h = x + self.ln1(mixed)
+        with role("mlp"):
+            m = self.mlp(h)
+        with role("norm"):
+            return h + self.ln2(m)
 
 
 class HybridDeltaLM(Chain):
@@ -260,18 +273,22 @@ class HybridDeltaLM(Chain):
     def logits(self, x):
         """``x [B, T]`` token ids -> ``[B, T, V]``."""
         def one(tokens):
-            h = self.embed(tokens)
+            with role("embed"):
+                h = self.embed(tokens)
             for block in self.blocks:
-                mix = block.mix
-                if block.linear:
-                    mixed, _, _ = self._scan(mix, h, None, None, None)
-                else:
-                    q, k, v = mix.project(h)
-                    mixed = mix.output(self._prompt_attention(q, k, v))
-                h = block.residual(h, mixed)
-            return self.head(self.ln_f(h))
+                with jax.named_scope(f"blocks/{block.name}"):
+                    mix = block.mix
+                    if block.linear:
+                        mixed, _, _ = self._scan(mix, h, None, None, None)
+                    else:
+                        q, k, v = mix.project(h)
+                        mixed = mix.output(self._prompt_attention(q, k, v))
+                    h = block.residual(h, mixed)
+            with role("head"):
+                return self.head(self.ln_f(h))
         return jnp.stack([one(row) for row in x])
 
+    @role("attn")
     def _prompt_attention(self, q, k, v):
         """A whole prompt over itself: ``q [T, H, D]``, ``k``, ``v`` ``[T,
         G, D]`` -> ``[T, H, D]``, heads first through the flash
@@ -282,6 +299,7 @@ class HybridDeltaLM(Chain):
                                 heads_first(v), scale=self.scale)
         return jnp.moveaxis(out[0], 0, 1)
 
+    @role("state")     # the projections inside open ``attn_proj``
     def _scan(self, mix, x, true_len, state, before):
         """A linear layer over the rows ``x [T, d]`` of one sequence that
         follow the cached ``state [dk, H · dv]`` and convolution inputs
@@ -358,6 +376,7 @@ class HybridDeltaLM(Chain):
                 where[i] = j
         return [(block, where[i]) for i, block in enumerate(self.blocks)]
 
+    @role("head")
     def _finish(self, h_last):
         return self.head(self.ln_f(h_last)).astype(jnp.float32)
 
@@ -370,50 +389,65 @@ class HybridDeltaLM(Chain):
         table)."""
         kv, S, cv = pools
         T = tokens.shape[1]
-        bt, slots = bt_rows[0], bt_rows[1]
-        n_slots = S.shape[1]
-        n = -(-T // self.stride)
-        # [live, source, snapshot 0, ...]: the last of the scan's states
-        # goes to the live slot, state i to snapshot i; nothing is
-        # written for an empty program (warm-up)
-        into = jnp.where(true_len > 0, jnp.concatenate(
-            [slots[2:2 + n], slots[:1]]), n_slots)
-        fresh = None if start is None else start == 0
-        h = self.embed(tokens[0])
+        with role("attn"):
+            bt = bt_rows[0]
+        with role("state"):
+            slots = bt_rows[1]
+            n_slots = S.shape[1]
+            n = -(-T // self.stride)
+            # [live, source, snapshot 0, ...]: the last of the scan's
+            # states goes to the live slot, state i to snapshot i;
+            # nothing is written for an empty program (warm-up)
+            into = jnp.where(true_len > 0, jnp.concatenate(
+                [slots[2:2 + n], slots[:1]]), n_slots)
+            fresh = None if start is None else start == 0
+        with role("embed"):
+            h = self.embed(tokens[0])
         for block, li in self._layers():
-            mix = block.mix
-            if block.linear:
-                state = before = None
-                if start is not None:
-                    state = jnp.where(fresh, 0.0, S[li, slots[1]])
-                    before = jnp.where(fresh, 0.0, cv[li, slots[1]]) \
-                        .reshape(mix.taps - 1, mix.channels)
-                mixed, states, convs = self._scan(mix, h, true_len, state,
-                                                  before)
-                # every stride's state to its snapshot slot (one beyond
-                # the pool where there is none to keep), then the last
-                # to the live slot
-                S = S.at[li, into].set(
-                    jnp.concatenate([states, states[-1:]]), mode="drop")
-                cv = cv.at[li, into].set(
-                    jnp.concatenate([convs, convs[-1:]]), mode="drop")
-            else:
+            with jax.named_scope(f"blocks/{block.name}"):
+                mix = block.mix
+                if block.linear:
+                    h, S, cv = self._prefill_linear(
+                        block, li, h, true_len, start, S, cv, slots, into,
+                        fresh)
+                    continue
                 q, k, v = mix.project(h)
                 if start is None:
-                    kv = write_prompt_kv(kv, _entry(k, v), bt, true_len,
-                                         layer=li)
+                    with role("cache_write"):
+                        kv = write_prompt_kv(kv, _entry(k, v), bt,
+                                             true_len, layer=li)
                     att = self._prompt_attention(q, k, v)
                 else:
-                    kv = write_prompt_kv_at(kv, _entry(k, v), bt, start,
-                                            true_len, layer=li)
+                    with role("cache_write"):
+                        kv = write_prompt_kv_at(kv, _entry(k, v), bt,
+                                                start, true_len, layer=li)
                     att = paged_prefill_attention(
                         q, kv, None, bt, start, true_len, scale=self.scale,
                         layer=li, kv_heads=self.n_kv)
-                mixed = mix.output(att)
-            h = block.residual(h, mixed)
-        h_last = jax.lax.dynamic_slice_in_dim(
-            h, jnp.maximum(true_len - 1, 0), 1, axis=0)
-        return (kv, S, cv), self._finish(h_last)[0], ()
+                h = block.residual(h, mix.output(att))
+        with role("head"):
+            return (kv, S, cv), self._finish(_last_row(h, true_len))[0], ()
+
+    def _prefill_linear(self, block, li, h, true_len, start, S, cv, slots,
+                        into, fresh):
+        """One linear layer of a prefill: the scan on from the SOURCE
+        slot's state, every stride's state to its snapshot slot (one
+        beyond the pool where there is none to keep), then the last to
+        the live slot.  ``(h, S, cv)``."""
+        mix = block.mix
+        with role("state"):
+            state = before = None
+            if start is not None:
+                state = jnp.where(fresh, 0.0, S[li, slots[1]])
+                before = jnp.where(fresh, 0.0, cv[li, slots[1]]) \
+                    .reshape(mix.taps - 1, mix.channels)
+            mixed, states, convs = self._scan(mix, h, true_len, state,
+                                              before)
+            S = S.at[li, into].set(
+                jnp.concatenate([states, states[-1:]]), mode="drop")
+            cv = cv.at[li, into].set(
+                jnp.concatenate([convs, convs[-1:]]), mode="drop")
+        return block.residual(h, mixed), S, cv
 
     def serve_prefill(self, pools, tokens, true_len, bt_rows):
         """Full prefill of one (padded) prompt ``tokens [1, Tb]``;
@@ -437,34 +471,50 @@ class HybridDeltaLM(Chain):
         one lowering, so ``mode`` chooses nothing; ``tp_mesh`` is refused
         by the engine.  Returns ``(pools, logits [Bb, V], ())``."""
         kv, S, cv = pools
-        live = pos >= 0
-        ctx = jnp.where(live, pos + 1, 0)
-        slot = bts[1][:, 0]
-        into = jnp.where(live, slot, S.shape[1])
-        h = self.embed(toks)
+        with role("attn"):
+            live = pos >= 0
+            ctx = jnp.where(live, pos + 1, 0)
+        with role("state"):
+            slot = bts[1][:, 0]
+            into = jnp.where(live, slot, S.shape[1])
+        with role("embed"):
+            h = self.embed(toks)
         for block, li in self._layers():
-            mix = block.mix
-            if block.linear:
-                B = h.shape[0]
-                before = _slots_of(cv, li, slot).reshape(
-                    B, mix.taps - 1, mix.channels)
-                row = mix.inputs(h).astype(jnp.float32)
-                xs = jnp.concatenate([before, row[:, None]], axis=1)
-                y = jax.nn.silu(jnp.einsum(
-                    "btc,ct->bc", xs, mix.conv.array.astype(jnp.float32)))
-                q, k, v = mix.heads(y, h.dtype)
-                g, beta = mix.gates(h)
-                o, state = gated_delta_step(_slots_of(S, li, slot), q, k,
-                                            v, g, beta)
-                S = S.at[li, into].set(state, mode="drop")
-                cv = cv.at[li, into].set(xs[:, 1:].reshape(B, -1),
-                                         mode="drop")
-                mixed = mix.output(o, h)
-            else:
-                q, k, v = mix.project(h)
-                kv = write_token_kv(kv, _entry(k, v), bts[0], pos, layer=li)
-                mixed = mix.output(paged_decode_attention(
-                    q, kv, None, bts[0], ctx, scale=self.scale, layer=li,
-                    kv_heads=self.n_kv))
-            h = block.residual(h, mixed)
+            with jax.named_scope(f"blocks/{block.name}"):
+                mix = block.mix
+                if block.linear:
+                    with role("state"):
+                        mixed, S, cv = self._decode_linear(
+                            mix, li, h, S, cv, slot, into)
+                else:
+                    q, k, v = mix.project(h)
+                    with role("cache_write"):
+                        kv = write_token_kv(kv, _entry(k, v), bts[0], pos,
+                                            layer=li)
+                    with role("attn"):      # the group's block table too
+                        att = paged_decode_attention(
+                            q, kv, None, bts[0], ctx, scale=self.scale,
+                            layer=li, kv_heads=self.n_kv)
+                    mixed = mix.output(att)
+                h = block.residual(h, mixed)
         return (kv, S, cv), self._finish(h), ()
+
+    @staticmethod
+    def _decode_linear(mix, li, h, S, cv, slot, into):
+        """One linear layer of a decode step: each lane's slot read, the
+        convolution's newest row, the delta update, the slot written in
+        place.  ``(mixed, S, cv)``."""
+        B = h.shape[0]
+        before = _slots_of(cv, li, slot).reshape(
+            B, mix.taps - 1, mix.channels)
+        row = mix.inputs(h).astype(jnp.float32)
+        xs = jnp.concatenate([before, row[:, None]], axis=1)
+        y = jax.nn.silu(jnp.einsum(
+            "btc,ct->bc", xs, mix.conv.array.astype(jnp.float32)))
+        q, k, v = mix.heads(y, h.dtype)
+        g, beta = mix.gates(h)
+        o, state = gated_delta_step(_slots_of(S, li, slot), q, k, v, g,
+                                    beta)
+        S = S.at[li, into].set(state, mode="drop")
+        cv = cv.at[li, into].set(xs[:, 1:].reshape(B, -1), mode="drop")
+        return mix.output(o, h), S, cv

@@ -36,6 +36,7 @@ import numpy as np
 
 from ..core.link import Chain, ChainList
 from ..nn import links as L
+from ..observability import role
 from ..ops import attention as flash_attention_op
 from ..ops.paged_attention import paged_latent_attention
 from ..parallel.moe import HeldExperts
@@ -86,6 +87,13 @@ def _rotate(x, pos, inv_freq):
     return out.reshape(x.shape).astype(x.dtype)
 
 
+@role("head")
+def _last_row(h, true_len):
+    """The row of ``h [T, d]`` at position ``true_len - 1``."""
+    return jax.lax.dynamic_slice_in_dim(
+        h, jnp.maximum(true_len - 1, 0), 1, axis=0)
+
+
 class LatentAttention(Chain):
     """Multi-head latent attention's projections.  ``latents`` gives what
     one layer caches and the queries that go with it; the two attention
@@ -110,6 +118,7 @@ class LatentAttention(Chain):
             self.o = L.Linear(n_heads * v_dim, d_model, nobias=True,
                               seed=seed + 4)
 
+    @role("attn_proj")
     def latents(self, x, pos):
         """``x``: ``[T, d]`` normed hidden states at positions ``pos``
         ``[T]``.  Returns ``(q_nope [T, H, nope], q_rope [T, H, rope]
@@ -132,6 +141,7 @@ class LatentAttention(Chain):
             self.n_heads, self.nope_dim + self.v_dim, self.kv_rank)
         return w[:, :self.nope_dim], w[:, self.nope_dim:]
 
+    @role("attn_proj")
     def expanded(self, q_nope, q_rope, latent, scale):
         """Causal attention of one whole sequence over its own latents,
         keys and values expanded per head: ``[T, H · v]``."""
@@ -155,6 +165,7 @@ class LatentAttention(Chain):
                                  heads_first(v), causal=True, scale=scale)
         return jnp.moveaxis(out[0, :, :, :self.v_dim], 0, 1).reshape(T, -1)
 
+    @role("attn_proj")
     def absorb_query(self, q_nope, q_rope):
         """The absorbed query ``[..., H, rank + rope]``: the key half of
         ``W_kvb`` applied to ``q_nope``, then ``q_rope``."""
@@ -162,6 +173,7 @@ class LatentAttention(Chain):
         q_lat = jnp.einsum("...hn,hnc->...hc", q_nope, w_k)
         return jnp.concatenate([q_lat, q_rope], axis=-1)
 
+    @role("attn_proj")
     def unabsorb_output(self, o_lat):
         """``o_lat [..., H, rank]`` through the value half of ``W_kvb``
         and the output projection's input layout: ``[..., H · v]``."""
@@ -211,9 +223,11 @@ class LatentMoEBlock(Chain):
         is the held experts' copy counts ``[held]``, or ``None`` for a
         dense layer."""
         if not self.routed:
-            return self.mlp(x), None
-        y, counts = self.experts(x, valid=valid)
-        return y + self.shared(x), counts
+            with role("mlp"):
+                return self.mlp(x), None
+        y, counts = self.experts(x, valid=valid)   # ``router``, ``experts``
+        with role("experts"):
+            return y + self.shared(x), counts
 
 
 class LatentMoELM(Chain):
@@ -274,14 +288,17 @@ class LatentMoELM(Chain):
         attending causally over itself in the expanded form."""
         def one(tokens):
             T = tokens.shape[0]
-            pos = jnp.arange(T, dtype=jnp.int32)
-            h = self.embed(tokens)
-            for block in self.blocks:
-                q_nope, q_rope, lat = block.attn.latents(block.ln1(h), pos)
-                h = h + block.attn.o(block.attn.expanded(
-                    q_nope, q_rope, lat, self.softmax_scale))
-                h = h + block.ffn(block.ln2(h))[0]
-            return self.head(self.ln_f(h))
+            with role("embed"):
+                pos = jnp.arange(T, dtype=jnp.int32)
+                h = self.embed(tokens)
+            for li, block in enumerate(self.blocks):
+                with jax.named_scope(f"blocks/{li}"):
+                    q_nope, q_rope, lat = self._latents(block, h, pos)
+                    h = self._add_attn(block, h, block.attn.expanded(
+                        q_nope, q_rope, lat, self.softmax_scale))
+                    h = self._add_ffn(block, h, None, [])
+            with role("head"):
+                return self.head(self.ln_f(h))
         return jnp.stack([one(row) for row in x])
 
     # -- the serving interface (docs/serving.md) ------------------------------
@@ -307,6 +324,19 @@ class LatentMoELM(Chain):
         a token, zero-filled up to whole lane tiles (``entry_width``)."""
         return ((self.entry_width,),)
 
+    @staticmethod
+    def _latents(block, h, pos):
+        """``block.attn.latents`` of the normed ``h``."""
+        with role("norm"):
+            x = block.ln1(h)
+        return block.attn.latents(x, pos)
+
+    @staticmethod
+    def _add_attn(block, h, o):
+        """``h`` plus the output projection of the heads' values ``o``."""
+        with role("attn_proj"):
+            return h + block.attn.o(o)
+
     def _entry(self, a):
         """``a [..., kv_rank + rope_dim]`` (a latent, or an absorbed
         query against it) zero-filled to the entry's width: zeros add
@@ -329,14 +359,19 @@ class LatentMoELM(Chain):
     def _add_ffn(block, h, valid, counts):
         """The block's second half, ``h + FFN(norm(h))``; an expert
         layer's held-copy counts (of ``valid`` tokens) join ``counts``."""
-        y, c = block.ffn(block.ln2(h), valid)
+        with role("norm"):
+            x = block.ln2(h)
+        y, c = block.ffn(x, valid)
         if c is not None:
             counts.append(c)
-        return h + y
+        with role("experts" if block.routed else "mlp"):
+            return h + y
 
     def _finish(self, h_last, counts):
-        logits = self.head(self.ln_f(h_last)).astype(jnp.float32)
-        return logits, jnp.stack(counts)
+        with role("head"):
+            logits = self.head(self.ln_f(h_last)).astype(jnp.float32)
+        with role("router"):
+            return logits, jnp.stack(counts)
 
     def serve_prefill(self, pools, tokens, true_len, bt_row):
         """Full prefill of one (padded) prompt ``tokens [1, Tb]``: the
@@ -345,21 +380,25 @@ class LatentMoELM(Chain):
         (held_counts [expert layers, held],))``."""
         (pool,) = pools
         T = tokens.shape[1]
-        pos = jnp.arange(T, dtype=jnp.int32)
-        valid = pos < true_len
-        h = self.embed(tokens[0])
+        with role("embed"):
+            pos = jnp.arange(T, dtype=jnp.int32)
+        with role("router"):
+            valid = pos < true_len
+        with role("embed"):
+            h = self.embed(tokens[0])
         counts = []
         for li, block in enumerate(self.blocks):
-            q_nope, q_rope, lat = block.attn.latents(block.ln1(h), pos)
-            pool = write_prompt_kv(pool, self._entry(lat), bt_row,
-                                   true_len, layer=li)
-            h = h + block.attn.o(block.attn.expanded(
-                q_nope, q_rope, lat, self.softmax_scale))
-            h = self._add_ffn(block, h, valid, counts)
-        h_last = jax.lax.dynamic_slice_in_dim(
-            h, jnp.maximum(true_len - 1, 0), 1, axis=0)
-        logits, counts = self._finish(h_last, counts)
-        return (pool,), logits[0], (counts,)
+            with jax.named_scope(f"blocks/{li}"):
+                q_nope, q_rope, lat = self._latents(block, h, pos)
+                with role("cache_write"):
+                    pool = write_prompt_kv(pool, self._entry(lat), bt_row,
+                                           true_len, layer=li)
+                h = self._add_attn(block, h, block.attn.expanded(
+                    q_nope, q_rope, lat, self.softmax_scale))
+                h = self._add_ffn(block, h, valid, counts)
+        logits, counts = self._finish(_last_row(h, true_len), counts)
+        with role("head"):
+            return (pool,), logits[0], (counts,)
 
     def serve_suffix_prefill(self, pools, tokens, true_len, start, bt_row):
         """Suffix prefill at offset ``start`` against cached context:
@@ -368,26 +407,34 @@ class LatentMoELM(Chain):
         ``bt_row`` (shared prefix pages and fresh suffix pages alike)."""
         (pool,) = pools
         T = tokens.shape[1]
-        t = jnp.arange(T, dtype=jnp.int32)
-        pos = start + t
-        valid = t < true_len
-        h = self.embed(tokens[0])
+        with role("embed"):
+            t = jnp.arange(T, dtype=jnp.int32)
+            pos = start + t
+        with role("router"):
+            valid = t < true_len
+        with role("embed"):
+            h = self.embed(tokens[0])
         counts = []
         for li, block in enumerate(self.blocks):
-            q_nope, q_rope, lat = block.attn.latents(block.ln1(h), pos)
-            pool = write_prompt_kv_at(pool, self._entry(lat), bt_row,
-                                      start, true_len, layer=li)
-            o_lat = paged_latent_attention(
-                self._entry(block.attn.absorb_query(q_nope, q_rope))[None],
-                pool,
-                bt_row[None], pos[None], self.kv_rank,
-                scale=self.softmax_scale, layer=li)[0]
-            h = h + block.attn.o(block.attn.unabsorb_output(o_lat))
-            h = self._add_ffn(block, h, valid, counts)
-        h_last = jax.lax.dynamic_slice_in_dim(
-            h, jnp.maximum(true_len - 1, 0), 1, axis=0)
-        logits, counts = self._finish(h_last, counts)
-        return (pool,), logits[0], (counts,)
+            with jax.named_scope(f"blocks/{li}"):
+                q_nope, q_rope, lat = self._latents(block, h, pos)
+                with role("cache_write"):
+                    pool = write_prompt_kv_at(pool, self._entry(lat),
+                                              bt_row, start, true_len,
+                                              layer=li)
+                with role("attn_proj"):
+                    q_abs = self._entry(block.attn.absorb_query(
+                        q_nope, q_rope))[None]
+                with role("attn"):
+                    o_lat = paged_latent_attention(
+                        q_abs, pool, bt_row[None], pos[None], self.kv_rank,
+                        scale=self.softmax_scale, layer=li)[0]
+                h = self._add_attn(block, h,
+                                   block.attn.unabsorb_output(o_lat))
+                h = self._add_ffn(block, h, valid, counts)
+        logits, counts = self._finish(_last_row(h, true_len), counts)
+        with role("head"):
+            return (pool,), logits[0], (counts,)
 
     def serve_decode(self, pools, toks, pos, bts, mode=None, tp_mesh=None):
         """One token a lane (``pos < 0``: an idle lane, nothing written,
@@ -396,20 +443,28 @@ class LatentMoELM(Chain):
         head axis in the pool).  Returns ``(pools, logits [Bb, V],
         (held_counts,))``."""
         (pool,) = pools
-        safe = jnp.maximum(pos, 0)
-        live = pos >= 0
-        h = self.embed(toks)
+        with role("embed"):
+            safe = jnp.maximum(pos, 0)
+        with role("router"):
+            live = pos >= 0
+        with role("embed"):
+            h = self.embed(toks)
         counts = []
         for li, block in enumerate(self.blocks):
-            q_nope, q_rope, lat = block.attn.latents(block.ln1(h), safe)
-            pool = write_token_kv(pool, self._entry(lat), bts, pos,
-                                  layer=li)
-            o_lat = paged_latent_attention(
-                self._entry(block.attn.absorb_query(q_nope, q_rope))[:, None],
-                pool,
-                bts, pos[:, None], self.kv_rank,
-                scale=self.softmax_scale, layer=li)[:, 0]
-            h = h + block.attn.o(block.attn.unabsorb_output(o_lat))
-            h = self._add_ffn(block, h, live, counts)
+            with jax.named_scope(f"blocks/{li}"):
+                q_nope, q_rope, lat = self._latents(block, h, safe)
+                with role("cache_write"):
+                    pool = write_token_kv(pool, self._entry(lat), bts, pos,
+                                          layer=li)
+                with role("attn_proj"):
+                    q_abs = self._entry(block.attn.absorb_query(
+                        q_nope, q_rope))[:, None]
+                with role("attn"):
+                    o_lat = paged_latent_attention(
+                        q_abs, pool, bts, pos[:, None], self.kv_rank,
+                        scale=self.softmax_scale, layer=li)[:, 0]
+                h = self._add_attn(block, h,
+                                   block.attn.unabsorb_output(o_lat))
+                h = self._add_ffn(block, h, live, counts)
         logits, counts = self._finish(h, counts)
         return (pool,), logits, (counts,)

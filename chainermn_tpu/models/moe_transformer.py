@@ -28,6 +28,7 @@ from ..core import reporter
 from ..nn import functions as F
 from ..nn import links as L
 from .. import functions as mnfn
+from ..observability import role
 from .transformer import MultiHeadAttention, _axis_bound, _remat_policy
 
 __all__ = ["MoEFeedForward", "MoETransformerBlock", "MoETransformerLM"]
@@ -53,6 +54,7 @@ class MoEFeedForward(Chain):
                                    .astype(np.float32))
             self.b_out = Parameter(np.zeros((E, d_model), np.float32))
 
+    @role("router")     # what is not an expert's own product is routing
     def forward(self, x, aux_sink=None):
         B, T, D = x.shape
         tokens = x.reshape(B * T, D)
@@ -63,16 +65,14 @@ class MoEFeedForward(Chain):
             # slice this rank's expert from the (replicated) bank;
             # psum_gradient reassembles the bank's gradient exactly
             idx = jax.lax.axis_index(comm.axis_name)
-            w_in = jax.lax.dynamic_index_in_dim(
-                mnfn.psum_gradient(comm, self.w_in.array), idx, 0, False)
-            b_in = jax.lax.dynamic_index_in_dim(
-                mnfn.psum_gradient(comm, self.b_in.array), idx, 0, False)
-            w_out = jax.lax.dynamic_index_in_dim(
-                mnfn.psum_gradient(comm, self.w_out.array), idx, 0, False)
-            b_out = jax.lax.dynamic_index_in_dim(
-                mnfn.psum_gradient(comm, self.b_out.array), idx, 0, False)
+            with role("experts"):
+                w_in, b_in, w_out, b_out = [
+                    jax.lax.dynamic_index_in_dim(
+                        mnfn.psum_gradient(comm, p.array), idx, 0, False)
+                    for p in (self.w_in, self.b_in, self.w_out, self.b_out)]
             gate_logits = tokens @ self.router.array
 
+            @role("experts")
             def expert_fn(h):
                 return F.gelu(h @ w_in + b_in) @ w_out + b_out
 
@@ -95,10 +95,11 @@ class MoEFeedForward(Chain):
         # routing math, no capacity cut (dense drops nothing)
         probs = jax.nn.softmax(tokens @ self.router.array, axis=-1)
         E = comm.size
-        h = jnp.einsum("td,edh->teh", tokens, self.w_in.array) \
-            + self.b_in.array[None]
-        y = jnp.einsum("teh,ehd->ted", F.gelu(h), self.w_out.array) \
-            + self.b_out.array[None]
+        with role("experts"):
+            h = jnp.einsum("td,edh->teh", tokens, self.w_in.array) \
+                + self.b_in.array[None]
+            y = jnp.einsum("teh,ehd->ted", F.gelu(h), self.w_out.array) \
+                + self.b_out.array[None]
         if self.topk > 1:
             gates, experts = jax.lax.top_k(probs, self.topk)   # [T, k]
             gates = gates / jnp.maximum(
@@ -137,8 +138,14 @@ class MoETransformerBlock(Chain):
                                       topk=topk, two_stage=two_stage)
 
     def forward(self, x, aux_sink=None, causal=True):
-        h = x + self.attn(self.ln1(x), causal=causal)
-        return h + self.moe(self.ln2(h), aux_sink=aux_sink)
+        with role("norm"):
+            a = self.ln1(x)
+        with role("attn_proj"):     # the kernels inside open ``attn``
+            h = x + self.attn(a, causal=causal)
+        with role("norm"):
+            m = self.ln2(h)
+        with role("experts"):       # the layer inside opens ``router``
+            return h + self.moe(m, aux_sink=aux_sink)
 
 
 class MoETransformerLM(Chain):
@@ -181,10 +188,32 @@ class MoETransformerLM(Chain):
 
     def forward(self, x, t):
         B, T = x.shape
-        pos = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-        h = self.embed(x) + self.pos_embed(jnp.broadcast_to(pos, (B, T)))
-        if self.compute_dtype is not None:
-            h = h.astype(self.compute_dtype)
+        with role("embed"):
+            pos = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+            h = self.embed(x) + self.pos_embed(
+                jnp.broadcast_to(pos, (B, T)))
+            if self.compute_dtype is not None:
+                h = h.astype(self.compute_dtype)
+        with jax.named_scope("blocks"):
+            h, aux_sink = self._run_blocks(h)
+        with role("head"):
+            h = self.ln_f(h)
+            # head GEMM stays in the compute dtype (large-vocab GEMMs are
+            # exactly where bf16 MXU rate matters); softmax_cross_entropy
+            # upcasts the logits to fp32 internally — same discipline as
+            # TransformerLM
+            logits = self.head(h.reshape(B * T, -1))
+        with role("loss"):
+            loss = F.softmax_cross_entropy(logits, t.reshape(-1),
+                                           ignore_label=-1)
+            n = max(len(aux_sink), 1)
+            aux = sum(a["aux_loss"] for a in aux_sink) / n
+            dropped = sum(a["dropped_frac"] for a in aux_sink) / n
+            reporter.report({"loss": loss, "moe_aux": aux,
+                             "moe_dropped": dropped}, self)
+            return loss + self.aux_weight * aux
+
+    def _run_blocks(self, h):
         aux_sink = []
         for block in self.blocks:
             if self.remat:
@@ -200,17 +229,4 @@ class MoETransformerLM(Chain):
                 aux_sink.append(aux)
             else:
                 h = block(h, aux_sink=aux_sink)
-        h = self.ln_f(h)
-        # head GEMM stays in the compute dtype (large-vocab GEMMs are
-        # exactly where bf16 MXU rate matters); softmax_cross_entropy
-        # upcasts the logits to fp32 internally — same discipline as
-        # TransformerLM
-        logits = self.head(h.reshape(B * T, -1))
-        loss = F.softmax_cross_entropy(logits, t.reshape(-1),
-                                       ignore_label=-1)
-        n = max(len(aux_sink), 1)
-        aux = sum(a["aux_loss"] for a in aux_sink) / n
-        dropped = sum(a["dropped_frac"] for a in aux_sink) / n
-        reporter.report({"loss": loss, "moe_aux": aux,
-                         "moe_dropped": dropped}, self)
-        return loss + self.aux_weight * aux
+        return h, aux_sink

@@ -20,6 +20,7 @@ from ..core.link import Chain, ChainList
 from ..core import reporter
 from ..nn import functions as F
 from ..nn import links as L
+from ..observability import role
 from ..ops import attention as fused_attention
 from ..ops import merge_heads, self_attention, split_heads
 from ..ops.paged_attention import (head_sharding, paged_decode_attention,
@@ -99,9 +100,15 @@ class TransformerBlock(Chain):
 
     def forward(self, x, causal=True):
         B, T, D = x.shape
-        h = x + self.attn(self.ln1(x), causal=causal)
-        m = self.fc2(F.gelu(self.fc1(self.ln2(h).reshape(B * T, D))))
-        return h + m.reshape(B, T, D)
+        with role("norm"):
+            a = self.ln1(x)
+        with role("attn_proj"):     # the kernels inside open ``attn``
+            h = x + self.attn(a, causal=causal)
+        with role("norm"):
+            m = self.ln2(h)
+        with role("mlp"):
+            m = self.fc2(F.gelu(self.fc1(m.reshape(B * T, D))))
+            return h + m.reshape(B, T, D)
 
 
 def _remat_policy(remat):
@@ -173,10 +180,27 @@ class _ServingMixin:
         """Token + position embeddings cast to the model's compute dtype
         (the ``hidden`` discipline: params fp32, block compute in
         ``compute_dtype``)."""
-        h = self.embed(toks) + self.pos_embed(positions)
-        if self.compute_dtype is not None:
-            h = h.astype(self.compute_dtype)
+        with role("embed"):
+            h = self.embed(toks) + self.pos_embed(positions)
+            if self.compute_dtype is not None:
+                h = h.astype(self.compute_dtype)
         return h
+
+    def _serve_mlp(self, block, h):
+        """The block's second half over ``h [..., D]``: norm, the MLP
+        and the residual add, each under its role."""
+        with role("norm"):
+            m = block.ln2(h).reshape(-1, h.shape[-1])
+        with role("mlp"):
+            return h + block.fc2(F.gelu(block.fc1(m))).reshape(h.shape)
+
+    def _serve_last_logits(self, h, true_len):
+        """The fp32 ``[V]`` logits row of a prefill at position
+        ``true_len - 1`` of ``h [1, T, D]``."""
+        with role("head"):
+            h_last = jax.lax.dynamic_slice_in_dim(
+                h[0], jnp.maximum(true_len - 1, 0), 1, axis=0)
+            return self.head(self.ln_f(h_last))[0].astype(jnp.float32)
 
     def serve_prefill(self, pools, tokens, true_len, bt_row):
         """Full causal forward over the (padded) prompt ``tokens [1,
@@ -186,29 +210,33 @@ class _ServingMixin:
         ``true_len - 1``."""
         k_pool, v_pool = pools
         B, T = tokens.shape
-        pos = jax.lax.broadcasted_iota(jnp.int32, (B, T), 1)
+        with role("embed"):
+            pos = jax.lax.broadcasted_iota(jnp.int32, (B, T), 1)
         h = self._serve_embed(tokens, pos)
         for li, block in enumerate(self.blocks):
-            x = block.ln1(h)
-            qkv = block.attn.qkv(x.reshape(B * T, -1)).reshape(
-                B, T, 3, block.attn.n_heads, block.attn.d_head)
-            q, k, v = [jnp.moveaxis(qkv[:, :, j], 1, 2) for j in range(3)]
-            # the flash dispatcher: Pallas forward on TPU (no backward is
-            # ever traced — inference), XLA/interpret elsewhere
-            att = fused_attention(q, k, v, causal=True)
-            att = jnp.moveaxis(att, 2, 1).reshape(B * T, -1)
-            h = h + block.attn.proj(att).reshape(B, T, -1)
-            m = block.fc2(F.gelu(block.fc1(block.ln2(h).reshape(B * T,
-                                                                -1))))
-            h = h + m.reshape(B, T, -1)
-            k_pool = k_pool.at[li].set(write_prompt_kv(
-                k_pool[li], jnp.moveaxis(k[0], 0, 1), bt_row, true_len))
-            v_pool = v_pool.at[li].set(write_prompt_kv(
-                v_pool[li], jnp.moveaxis(v[0], 0, 1), bt_row, true_len))
-        h_last = jax.lax.dynamic_slice_in_dim(
-            h[0], jnp.maximum(true_len - 1, 0), 1, axis=0)
-        logits = self.head(self.ln_f(h_last))[0]
-        return (k_pool, v_pool), logits.astype(jnp.float32), ()
+            with jax.named_scope(f"blocks/{li}"):
+                with role("norm"):
+                    x = block.ln1(h)
+                with role("attn_proj"):
+                    qkv = block.attn.qkv(x.reshape(B * T, -1)).reshape(
+                        B, T, 3, block.attn.n_heads, block.attn.d_head)
+                    q, k, v = [jnp.moveaxis(qkv[:, :, j], 1, 2)
+                               for j in range(3)]
+                # the flash dispatcher: Pallas forward on TPU (no backward
+                # is ever traced — inference), XLA/interpret elsewhere
+                att = fused_attention(q, k, v, causal=True)
+                with role("attn_proj"):
+                    att = jnp.moveaxis(att, 2, 1).reshape(B * T, -1)
+                    h = h + block.attn.proj(att).reshape(B, T, -1)
+                h = self._serve_mlp(block, h)
+                with role("cache_write"):
+                    k_pool = k_pool.at[li].set(write_prompt_kv(
+                        k_pool[li], jnp.moveaxis(k[0], 0, 1), bt_row,
+                        true_len))
+                    v_pool = v_pool.at[li].set(write_prompt_kv(
+                        v_pool[li], jnp.moveaxis(v[0], 0, 1), bt_row,
+                        true_len))
+        return (k_pool, v_pool), self._serve_last_logits(h, true_len), ()
 
     def serve_suffix_prefill(self, pools, tokens, true_len, start, bt_row):
         """SUFFIX prefill for a prefix-shared request (round 14), and
@@ -225,30 +253,32 @@ class _ServingMixin:
         the FLOP saving the prefix hit buys."""
         k_pool, v_pool = pools
         B, T = tokens.shape
-        pos = start + jax.lax.broadcasted_iota(jnp.int32, (B, T), 1)
+        with role("embed"):
+            pos = start + jax.lax.broadcasted_iota(jnp.int32, (B, T), 1)
         h = self._serve_embed(tokens, pos)
         scale = 1.0 / (self.blocks[0].attn.d_head ** 0.5)
         for li, block in enumerate(self.blocks):
-            x = block.ln1(h)
-            qkv = block.attn.qkv(x.reshape(B * T, -1)).reshape(
-                B, T, 3, block.attn.n_heads, block.attn.d_head)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            k_pool = k_pool.at[li].set(write_prompt_kv_at(
-                k_pool[li], k[0], bt_row, start, true_len))
-            v_pool = v_pool.at[li].set(write_prompt_kv_at(
-                v_pool[li], v[0], bt_row, start, true_len))
-            att = paged_prefill_attention(q[0], k_pool[li], v_pool[li],
-                                          bt_row, start, true_len,
-                                          scale=scale)
-            h = h + block.attn.proj(att.reshape(B * T, -1)) \
-                .reshape(B, T, -1)
-            m = block.fc2(F.gelu(block.fc1(block.ln2(h).reshape(B * T,
-                                                                -1))))
-            h = h + m.reshape(B, T, -1)
-        h_last = jax.lax.dynamic_slice_in_dim(
-            h[0], jnp.maximum(true_len - 1, 0), 1, axis=0)
-        logits = self.head(self.ln_f(h_last))[0]
-        return (k_pool, v_pool), logits.astype(jnp.float32), ()
+            with jax.named_scope(f"blocks/{li}"):
+                with role("norm"):
+                    x = block.ln1(h)
+                with role("attn_proj"):
+                    qkv = block.attn.qkv(x.reshape(B * T, -1)).reshape(
+                        B, T, 3, block.attn.n_heads, block.attn.d_head)
+                    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+                with role("cache_write"):
+                    k_pool = k_pool.at[li].set(write_prompt_kv_at(
+                        k_pool[li], k[0], bt_row, start, true_len))
+                    v_pool = v_pool.at[li].set(write_prompt_kv_at(
+                        v_pool[li], v[0], bt_row, start, true_len))
+                with role("attn"):      # the pools' layer views too
+                    att = paged_prefill_attention(
+                        q[0], k_pool[li], v_pool[li], bt_row, start,
+                        true_len, scale=scale)
+                with role("attn_proj"):
+                    h = h + block.attn.proj(att.reshape(B * T, -1)) \
+                        .reshape(B, T, -1)
+                h = self._serve_mlp(block, h)
+        return (k_pool, v_pool), self._serve_last_logits(h, true_len), ()
 
     def serve_decode(self, pools, toks, pos, bts, mode=None, tp_mesh=None):
         """One token per batch lane (``pos < 0`` marks an idle padding
@@ -259,25 +289,34 @@ class _ServingMixin:
         gathers to stay that way.  ``logits``: ``[Bb, V]`` fp32."""
         k_pool, v_pool = pools
         Bb = toks.shape[0]
-        safe_pos = jnp.maximum(pos, 0)
+        with role("embed"):
+            safe_pos = jnp.maximum(pos, 0)
         h = self._serve_embed(toks, safe_pos)
-        ctx_len = jnp.where(pos >= 0, pos + 1, 0)
+        with role("attn"):
+            ctx_len = jnp.where(pos >= 0, pos + 1, 0)
         scale = 1.0 / (self.blocks[0].attn.d_head ** 0.5)
         for li, block in enumerate(self.blocks):
-            x = block.ln1(h)
-            qkv = block.attn.qkv(x).reshape(
-                Bb, 3, block.attn.n_heads, block.attn.d_head)
-            q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-            k_pool = k_pool.at[li].set(
-                write_token_kv(k_pool[li], k, bts, pos))
-            v_pool = v_pool.at[li].set(
-                write_token_kv(v_pool[li], v, bts, pos))
-            att = paged_decode_attention(q, k_pool[li], v_pool[li], bts,
-                                         ctx_len, scale=scale, mode=mode,
-                                         tp_mesh=tp_mesh)
-            h = h + block.attn.proj(att.reshape(Bb, -1))
-            h = h + block.fc2(F.gelu(block.fc1(block.ln2(h))))
-        logits = self.head(self.ln_f(h)).astype(jnp.float32)
+            with jax.named_scope(f"blocks/{li}"):
+                with role("norm"):
+                    x = block.ln1(h)
+                with role("attn_proj"):
+                    qkv = block.attn.qkv(x).reshape(
+                        Bb, 3, block.attn.n_heads, block.attn.d_head)
+                    q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
+                with role("cache_write"):
+                    k_pool = k_pool.at[li].set(
+                        write_token_kv(k_pool[li], k, bts, pos))
+                    v_pool = v_pool.at[li].set(
+                        write_token_kv(v_pool[li], v, bts, pos))
+                with role("attn"):      # the pools' layer views too
+                    att = paged_decode_attention(
+                        q, k_pool[li], v_pool[li], bts, ctx_len,
+                        scale=scale, mode=mode, tp_mesh=tp_mesh)
+                with role("attn_proj"):
+                    h = h + block.attn.proj(att.reshape(Bb, -1))
+                h = self._serve_mlp(block, h)
+        with role("head"):
+            logits = self.head(self.ln_f(h)).astype(jnp.float32)
         return (k_pool, v_pool), logits, ()
 
     def serve_verify(self, pools, toks, start, n_valid, bts, tp_mesh=None):
@@ -293,29 +332,36 @@ class _ServingMixin:
         that position would see.  ``logits``: ``[Bb, K1, V]`` fp32."""
         k_pool, v_pool = pools
         Bb, K1 = toks.shape
-        safe_start = jnp.maximum(start, 0)
-        pos = safe_start[:, None] + jnp.arange(K1, dtype=jnp.int32)[None]
+        with role("embed"):
+            safe_start = jnp.maximum(start, 0)
+            pos = safe_start[:, None] \
+                + jnp.arange(K1, dtype=jnp.int32)[None]
         h = self._serve_embed(toks, pos)
         scale = 1.0 / (self.blocks[0].attn.d_head ** 0.5)
         for li, block in enumerate(self.blocks):
-            x = block.ln1(h)
-            qkv = block.attn.qkv(x.reshape(Bb * K1, -1)).reshape(
-                Bb, K1, 3, block.attn.n_heads, block.attn.d_head)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            k_pool = k_pool.at[li].set(write_span_kv(
-                k_pool[li], k, bts, start, n_valid))
-            v_pool = v_pool.at[li].set(write_span_kv(
-                v_pool[li], v, bts, start, n_valid))
-            att = paged_verify_attention(q, k_pool[li], v_pool[li], bts,
-                                         start, scale=scale,
-                                         tp_mesh=tp_mesh)
-            h = h + block.attn.proj(att.reshape(Bb * K1, -1)) \
-                .reshape(Bb, K1, -1)
-            m = block.fc2(F.gelu(block.fc1(block.ln2(h)
-                                           .reshape(Bb * K1, -1))))
-            h = h + m.reshape(Bb, K1, -1)
-        logits = self.head(self.ln_f(h.reshape(Bb * K1, -1))) \
-            .reshape(Bb, K1, -1).astype(jnp.float32)
+            with jax.named_scope(f"blocks/{li}"):
+                with role("norm"):
+                    x = block.ln1(h)
+                with role("attn_proj"):
+                    qkv = block.attn.qkv(x.reshape(Bb * K1, -1)).reshape(
+                        Bb, K1, 3, block.attn.n_heads, block.attn.d_head)
+                    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+                with role("cache_write"):
+                    k_pool = k_pool.at[li].set(write_span_kv(
+                        k_pool[li], k, bts, start, n_valid))
+                    v_pool = v_pool.at[li].set(write_span_kv(
+                        v_pool[li], v, bts, start, n_valid))
+                with role("attn"):      # the pools' layer views too
+                    att = paged_verify_attention(
+                        q, k_pool[li], v_pool[li], bts, start,
+                        scale=scale, tp_mesh=tp_mesh)
+                with role("attn_proj"):
+                    h = h + block.attn.proj(att.reshape(Bb * K1, -1)) \
+                        .reshape(Bb, K1, -1)
+                h = self._serve_mlp(block, h)
+        with role("head"):
+            logits = self.head(self.ln_f(h.reshape(Bb * K1, -1))) \
+                .reshape(Bb, K1, -1).astype(jnp.float32)
         return (k_pool, v_pool), logits, ()
 
 
@@ -350,6 +396,14 @@ class TransformerLM(Chain, _ServingMixin):
                                  seed=seed + 999)
 
     def hidden(self, x):
+        with role("embed"):
+            h = self._embed(x)
+        with jax.named_scope("blocks"):
+            h = self._run_blocks(h)
+        with role("head"):
+            return self.ln_f(h)
+
+    def _embed(self, x):
         B, T = x.shape
         if _axis_bound(self.sp_comm) and self.sp_mode == "zigzag":
             # zigzag layout: rank i holds global half-chunks i and
@@ -372,6 +426,9 @@ class TransformerLM(Chain, _ServingMixin):
             # residual stream) runs in the compute dtype — LN/softmax
             # statistics are fp32 internally (nn.functions discipline)
             h = h.astype(self.compute_dtype)
+        return h
+
+    def _run_blocks(self, h):
         for block in self.blocks:
             if self.remat:
                 # per-block rematerialization: backward recomputes the
@@ -385,19 +442,21 @@ class TransformerLM(Chain, _ServingMixin):
                                    policy=_remat_policy(self.remat))(h)
             else:
                 h = block(h)
-        return self.ln_f(h)
+        return h
 
     def logits(self, x):
         B, T = x.shape
         h = self.hidden(x)
-        return self.head(h.reshape(B * T, -1)).reshape(B, T, -1)
+        with role("head"):
+            return self.head(h.reshape(B * T, -1)).reshape(B, T, -1)
 
     def forward(self, x, t):
         """LM loss with ignore_label=-1 padding."""
         logits = self.logits(x)
-        loss = F.softmax_cross_entropy(
-            logits.reshape(-1, logits.shape[-1]), t.reshape(-1),
-            ignore_label=-1)
+        with role("loss"):
+            loss = F.softmax_cross_entropy(
+                logits.reshape(-1, logits.shape[-1]), t.reshape(-1),
+                ignore_label=-1)
         reporter.report({"loss": loss}, self)
         return loss
 

@@ -40,13 +40,14 @@ import numpy as np
 
 from ..core.link import Chain, ChainList
 from ..nn import links as L
+from ..observability import role
 from ..ops import grouped_attention
 from ..ops.paged_attention import (paged_decode_attention,
                                    paged_prefill_attention)
 from ..parallel.moe import HeldExperts
 from ..serving.kv_cache import (write_prompt_kv, write_prompt_kv_at,
                                 write_token_kv)
-from .latent_moe import SwiGLU, _rotate, yarn_inv_freq
+from .latent_moe import SwiGLU, _last_row, _rotate, yarn_inv_freq
 
 __all__ = ["GatedGroupedAttention", "WindowMoEBlock", "WindowMoELM"]
 
@@ -88,6 +89,7 @@ class GatedGroupedAttention(Chain):
             self.q_norm = L.RMSNorm(head_dim, eps)
             self.k_norm = L.RMSNorm(head_dim, eps)
 
+    @role("attn_proj")
     def _rotary(self, x, pos):
         """``x [..., heads, D]`` at ``pos [...]``: the first ``rot_dim``
         of each head rotated in float32, cos and sin times
@@ -97,6 +99,7 @@ class GatedGroupedAttention(Chain):
         return jnp.concatenate(
             [rot.astype(x.dtype), x[..., self.rot_dim:]], axis=-1)
 
+    @role("attn_proj")
     def project(self, x, pos):
         """``x [..., d]`` normed hidden states at ``pos [...]``: ``(q
         [..., H, D]``, ``k``, ``v`` ``[..., G, D]``, ``gate [..., H]``
@@ -111,6 +114,7 @@ class GatedGroupedAttention(Chain):
         gate = jax.nn.softplus(self.gate(x).astype(jnp.float32))
         return self._rotary(q, pos), self._rotary(k, pos), v, gate
 
+    @role("attn_proj")
     def output(self, att, gate):
         """The heads' outputs ``[..., H, D]``, each times its gate,
         through the output projection."""
@@ -145,9 +149,11 @@ class WindowMoEBlock(Chain):
         """``x [T, d]`` after ``ln2``: ``(y, counts)``, ``counts`` the
         held experts' copy counts ``[held]`` or ``None`` (dense)."""
         if not self.routed:
-            return self.mlp(x), None
-        y, counts = self.experts(x, valid=valid)
-        return y + self.shared(x), counts
+            with role("mlp"):
+                return self.mlp(x), None
+        y, counts = self.experts(x, valid=valid)   # ``router``, ``experts``
+        with role("experts"):
+            return y + self.shared(x), counts
 
 
 class WindowMoELM(Chain):
@@ -220,16 +226,19 @@ class WindowMoELM(Chain):
     def logits(self, x):
         """``x [B, T]`` token ids -> ``[B, T, V]``."""
         def one(tokens):
-            pos = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-            h = self.embed(tokens)
+            with role("embed"):
+                pos = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+                h = self.embed(tokens)
             for block in self.blocks:
-                q, k, v, gate = block.attn.project(block.ln1(h), pos)
-                h = h + block.attn.output(self._prompt_attention(
-                    block.attn, q, k, v), gate)
-                h = h + block.ffn(block.ln2(h))[0]
-            return self.head(self.ln_f(h))
+                with jax.named_scope(f"blocks/{block.name}"):
+                    q, k, v, gate = self._project(block, h, pos)
+                    h = self._block(block, h, self._prompt_attention(
+                        block.attn, q, k, v), gate, None, [])
+            with role("head"):
+                return self.head(self.ln_f(h))
         return jnp.stack([one(row) for row in x])
 
+    @role("attn")
     def _prompt_attention(self, attn, q, k, v):
         """A whole prompt over itself: ``q [T, H, D]``, ``k``, ``v``
         ``[T, G, D]`` -> ``[T, H, D]``, heads first through the flash
@@ -281,6 +290,7 @@ class WindowMoELM(Chain):
                 "held_max": float(counts.max(axis=1).mean()),
                 "held_hit": int((counts > 0).sum())}
 
+    @role("attn")       # a group's block table, cut out for each layer
     def _layers(self, pools, bts):
         """Each block with where its cache lies: ``(block, its group's
         pool, its index in the group, its group's block table)``."""
@@ -290,16 +300,29 @@ class WindowMoELM(Chain):
                 where[i] = (g, j, bts[g])
         return [(block,) + where[i] for i, block in enumerate(self.blocks)]
 
+    @staticmethod
+    def _project(block, h, pos):
+        """``block.attn.project`` of the normed ``h``."""
+        with role("norm"):
+            x = block.ln1(h)
+        return block.attn.project(x, pos)
+
     def _block(self, block, h, att, gate, valid, counts):
-        h = h + block.attn.output(att, gate)
-        y, c = block.ffn(block.ln2(h), valid)
+        with role("attn_proj"):
+            h = h + block.attn.output(att, gate)
+        with role("norm"):
+            x = block.ln2(h)
+        y, c = block.ffn(x, valid)
         if c is not None:
             counts.append(c)
-        return h + y
+        with role("experts" if block.routed else "mlp"):
+            return h + y
 
     def _finish(self, h_last, counts):
-        logits = self.head(self.ln_f(h_last)).astype(jnp.float32)
-        return logits, jnp.stack(counts)
+        with role("head"):
+            logits = self.head(self.ln_f(h_last)).astype(jnp.float32)
+        with role("router"):
+            return logits, jnp.stack(counts)
 
     def serve_prefill(self, pools, tokens, true_len, bt_rows):
         """Full prefill of one (padded) prompt ``tokens [1, Tb]``;
@@ -310,20 +333,24 @@ class WindowMoELM(Chain):
         itself.  Returns ``(pools, logits [V], (held_counts,))``."""
         pools = list(pools)
         T = tokens.shape[1]
-        pos = jnp.arange(T, dtype=jnp.int32)
-        valid = pos < true_len
-        h = self.embed(tokens[0])
+        with role("embed"):
+            pos = jnp.arange(T, dtype=jnp.int32)
+        with role("router"):
+            valid = pos < true_len
+        with role("embed"):
+            h = self.embed(tokens[0])
         counts = []
         for block, p, li, bt in self._layers(pools, bt_rows):
-            q, k, v, gate = block.attn.project(block.ln1(h), pos)
-            pools[p] = write_prompt_kv(pools[p], _entry(k, v), bt,
-                                       true_len, layer=li)
-            h = self._block(block, h, self._prompt_attention(
-                block.attn, q, k, v), gate, valid, counts)
-        h_last = jax.lax.dynamic_slice_in_dim(
-            h, jnp.maximum(true_len - 1, 0), 1, axis=0)
-        logits, counts = self._finish(h_last, counts)
-        return tuple(pools), logits[0], (counts,)
+            with jax.named_scope(f"blocks/{block.name}"):
+                q, k, v, gate = self._project(block, h, pos)
+                with role("cache_write"):
+                    pools[p] = write_prompt_kv(pools[p], _entry(k, v), bt,
+                                               true_len, layer=li)
+                h = self._block(block, h, self._prompt_attention(
+                    block.attn, q, k, v), gate, valid, counts)
+        logits, counts = self._finish(_last_row(h, true_len), counts)
+        with role("head"):
+            return tuple(pools), logits[0], (counts,)
 
     def serve_suffix_prefill(self, pools, tokens, true_len, start, bt_rows):
         """Suffix prefill at offset ``start`` against cached context: the
@@ -332,24 +359,29 @@ class WindowMoELM(Chain):
         layer: the pages from ``start - window`` on)."""
         pools = list(pools)
         T = tokens.shape[1]
-        t = jnp.arange(T, dtype=jnp.int32)
-        pos = start + t
-        valid = t < true_len
-        h = self.embed(tokens[0])
+        with role("embed"):
+            t = jnp.arange(T, dtype=jnp.int32)
+            pos = start + t
+        with role("router"):
+            valid = t < true_len
+        with role("embed"):
+            h = self.embed(tokens[0])
         counts = []
         for block, p, li, bt in self._layers(pools, bt_rows):
-            q, k, v, gate = block.attn.project(block.ln1(h), pos)
-            pools[p] = write_prompt_kv_at(pools[p], _entry(k, v), bt,
-                                          start, true_len, layer=li)
-            att = paged_prefill_attention(
-                q, pools[p], None, bt, start, true_len,
-                scale=self.scale, window=block.attn.window, layer=li,
-                kv_heads=self.n_kv)
-            h = self._block(block, h, att, gate, valid, counts)
-        h_last = jax.lax.dynamic_slice_in_dim(
-            h, jnp.maximum(true_len - 1, 0), 1, axis=0)
-        logits, counts = self._finish(h_last, counts)
-        return tuple(pools), logits[0], (counts,)
+            with jax.named_scope(f"blocks/{block.name}"):
+                q, k, v, gate = self._project(block, h, pos)
+                with role("cache_write"):
+                    pools[p] = write_prompt_kv_at(
+                        pools[p], _entry(k, v), bt, start, true_len,
+                        layer=li)
+                att = paged_prefill_attention(
+                    q, pools[p], None, bt, start, true_len,
+                    scale=self.scale, window=block.attn.window, layer=li,
+                    kv_heads=self.n_kv)
+                h = self._block(block, h, att, gate, valid, counts)
+        logits, counts = self._finish(_last_row(h, true_len), counts)
+        with role("head"):
+            return tuple(pools), logits[0], (counts,)
 
     def serve_decode(self, pools, toks, pos, bts, mode=None, tp_mesh=None):
         """One token a lane (``pos < 0``: an idle lane, nothing written,
@@ -358,18 +390,24 @@ class WindowMoELM(Chain):
         by the engine.  Returns ``(pools, logits [Bb, V],
         (held_counts,))``."""
         pools = list(pools)
-        safe = jnp.maximum(pos, 0)
-        live = pos >= 0
-        ctx = jnp.where(live, pos + 1, 0)
-        h = self.embed(toks)
+        with role("embed"):
+            safe = jnp.maximum(pos, 0)
+        with role("router"):
+            live = pos >= 0
+        with role("attn"):
+            ctx = jnp.where(live, pos + 1, 0)
+        with role("embed"):
+            h = self.embed(toks)
         counts = []
         for block, p, li, bt in self._layers(pools, bts):
-            q, k, v, gate = block.attn.project(block.ln1(h), safe)
-            pools[p] = write_token_kv(pools[p], _entry(k, v), bt, pos,
-                                      layer=li)
-            att = paged_decode_attention(
-                q, pools[p], None, bt, ctx, scale=self.scale,
-                window=block.attn.window, layer=li, kv_heads=self.n_kv)
-            h = self._block(block, h, att, gate, live, counts)
+            with jax.named_scope(f"blocks/{block.name}"):
+                q, k, v, gate = self._project(block, h, safe)
+                with role("cache_write"):
+                    pools[p] = write_token_kv(pools[p], _entry(k, v), bt,
+                                              pos, layer=li)
+                att = paged_decode_attention(
+                    q, pools[p], None, bt, ctx, scale=self.scale,
+                    window=block.attn.window, layer=li, kv_heads=self.n_kv)
+                h = self._block(block, h, att, gate, live, counts)
         logits, counts = self._finish(h, counts)
         return tuple(pools), logits, (counts,)

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..observability import role
+
 __all__ = [
     "relu", "leaky_relu", "elu", "sigmoid", "tanh", "softplus", "gelu", "silu",
     "softmax", "log_softmax", "softmax_cross_entropy", "sigmoid_cross_entropy",
@@ -86,6 +88,7 @@ def log_softmax(x, axis=1):
 
 # -- losses ----------------------------------------------------------------
 
+@role("loss")
 def softmax_cross_entropy(x, t, ignore_label=-1, reduce="mean",
                           normalize=True, class_weight=None):
     """Softmax + NLL with ignore-label masking.

@@ -11,7 +11,14 @@ Two host-side pieces every subsystem shares:
   of counters/gauges/fixed-bucket histograms, joined across ranks over
   the object collectives and rendered in Prometheus text format.
 
-Span classification, knob ladder, and the merge workflow:
+And one piece that lands in the compiled programs:
+
+* :mod:`~chainermn_tpu.observability.scopes` — the ROLES, a fixed
+  vocabulary of ``jax.named_scope`` names (``~attn``, ``~mlp``, ...)
+  that the models and ``ops/`` entry points open, so that the profiler's
+  device operations carry the part of the model they belong to.
+
+Span classification, knob ladder, the roles and the merge workflow:
 ``docs/observability.md``.
 """
 
@@ -21,6 +28,7 @@ from .tracing import (MODES, TRACE_ENV, Span, SpanTracer, complete,
                       validate_events)
 from .metrics import (DEFAULT_TIME_BUCKETS_MS, Counter, Gauge, Histogram,
                       MetricsRegistry, registry, reset_registry)
+from .scopes import ROLE_MARK, ROLES, role
 
 __all__ = [
     "Span", "SpanTracer", "tracer", "span", "instant", "complete", "mode",
@@ -29,4 +37,5 @@ __all__ = [
     "read_jsonl", "TRACE_ENV", "MODES",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "registry",
     "reset_registry", "DEFAULT_TIME_BUCKETS_MS",
+    "ROLES", "ROLE_MARK", "role",
 ]

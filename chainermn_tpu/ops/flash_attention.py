@@ -47,6 +47,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability import role
+
 __all__ = ["attention", "flash_attention", "grouped_attention",
            "self_attention", "xla_attention", "xla_grouped_attention"]
 
@@ -709,6 +711,7 @@ def _warn_fallback(q, k, path):
 _WINDOW_BLOCK = 256
 
 
+@role("attn")
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=False, window=None):
     """Fused attention via Pallas.  ``q``: ``[B, H, T, D]``; ``k``, ``v``:
@@ -999,6 +1002,7 @@ def _flash_diff_bwd(causal, scale, interpret, res, g):
 _flash_diff.defvjp(_flash_diff_fwd, _flash_diff_bwd)
 
 
+@role("attn")
 def attention(q, k, v, causal=False, scale=None):
     """Dispatch: Pallas kernels on TPU (flash forward AND fused backward
     via custom VJP), XLA reference elsewhere.
@@ -1010,6 +1014,7 @@ def attention(q, k, v, causal=False, scale=None):
     return xla_attention(q, k, v, causal=causal, scale=scale)
 
 
+@role("attn")
 def grouped_attention(q, k, v, scale=None, window=None):
     """Causal attention of whole prompts with grouped K/V heads, and a
     ``window`` where the layer has one: ``q [B, H, T, D]`` over ``k``,
@@ -1113,6 +1118,7 @@ def _flash_rows_bwd(n_heads, causal, scale, interpret, res, g):
 _flash_rows_diff.defvjp(_flash_rows_fwd, _flash_rows_bwd)
 
 
+@role("attn")
 def self_attention(qkv, n_heads, causal=False, scale=None):
     """Self-attention between two GEMMs: ``qkv [B, T, 3·H·D]`` (columns
     ordered ``(3, H, D)``) -> ``[B, T, H·D]``.
@@ -1223,6 +1229,7 @@ def _flash_lse_bwd(causal, scale, interpret, res, cots):
 _flash_lse_diff.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
+@role("attn")
 def attention_with_lse(q, k, v, causal=False, scale=None):
     """Differentiable blockwise attention returning ``(out, lse)``.
 
@@ -1245,6 +1252,7 @@ def attention_with_lse(q, k, v, causal=False, scale=None):
     return _blockwise_attention_lse_jnp(q, k, v, causal, scale)
 
 
+@role("attn")
 def blockwise_attention(q, k, v, causal=False, scale=None):
     """Memory-bounded attention (no [Tq, Tk] materialization on any
     backend): flash kernel on TPU, blockwise jnp scan elsewhere."""

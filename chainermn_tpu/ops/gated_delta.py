@@ -44,6 +44,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability import role
 from .flash_attention import _on_tpu
 
 __all__ = ["gated_delta_recurrence", "gated_delta_chunked",
@@ -53,6 +54,7 @@ __all__ = ["gated_delta_recurrence", "gated_delta_chunked",
 CHUNK = 64
 
 
+@role("state")
 def gated_delta_recurrence(q, k, v, g, beta, state=None):
     """The token recurrence.  ``q``, ``k`` ``[T, H, dk]``, ``v`` ``[T, H,
     dv]``, ``g``, ``beta`` ``[T, H]``; ``state`` ``[H, dk, dv]`` (zeros
@@ -203,6 +205,7 @@ def _chunk_scan(state, W, Qg, Kd, U, Aqk, gc, period):
     return jnp.moveaxis(O, 0, 1), states[jnp.asarray(ends)]
 
 
+@role("state")
 def gated_delta_chunked(q, k, v, g, beta, state=None, stride=None,
                         interpret=False):
     """The chunked form over one sequence.  ``q``, ``k`` ``[T, H, dk]``,
@@ -241,6 +244,7 @@ def gated_delta_chunked(q, k, v, g, beta, state=None, stride=None,
     return o, snaps
 
 
+@role("state")
 def gated_delta_step(S, q, k, v, g, beta):
     """One token a lane over states in the cache's layout.  ``S [B, dk,
     H · dv]`` float32 (head ``h`` in lanes ``h · dv`` on); ``q``, ``k``

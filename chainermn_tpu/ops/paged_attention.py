@@ -45,6 +45,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability import role
 from .flash_attention import _on_tpu
 
 __all__ = ["paged_decode_attention", "paged_decode_kernel",
@@ -175,6 +176,7 @@ def _grouped_attention(q, k, v, qpos, kpos, scale, window):
     return out.reshape(T, H, D).astype(q.dtype)
 
 
+@role("attn")
 def paged_prefill_attention(q, k_pool, v_pool, block_table_row, start,
                             true_len, scale=None, window=None, layer=None,
                             kv_heads=None):
@@ -234,6 +236,7 @@ def paged_prefill_attention(q, k_pool, v_pool, block_table_row, start,
     return out.astype(q.dtype)
 
 
+@role("attn")
 def paged_verify_attention(q, k_pool, v_pool, block_table, start,
                            scale=None, tp_mesh=None, tp_axis="tp"):
     """Multi-query verify attention for SPECULATIVE decoding (round 20).
@@ -406,6 +409,7 @@ def _paged_decode_kernel(bt_ref, ctx_ref, q_ref, pool_ref, o_ref, buf, sem,
     o_ref[...] = acc / jnp.maximum(l, 1e-30)
 
 
+@role("attn")
 def paged_decode_kernel(q, pool, block_table, ctx_len, *, kv_heads, layer,
                         window=None, scale=None, interpret=False):
     """The grouped decode step as a Pallas kernel over ONE pool ``[L, P,
@@ -448,6 +452,7 @@ def paged_decode_kernel(q, pool, block_table, ctx_len, *, kv_heads, layer,
     return jnp.einsum("bgrgd->bgrd", out).reshape(B, H, D).astype(q.dtype)
 
 
+@role("attn")
 def paged_decode_attention(q, k_pool, v_pool, block_table, ctx_len,
                            scale=None, mode=None, tp_mesh=None,
                            tp_axis="tp", window=None, layer=None,
@@ -544,6 +549,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, ctx_len,
     return _constrain_heads(out.astype(q.dtype), 1, tp_mesh, tp_axis)
 
 
+@role("attn")
 def paged_latent_attention(q, pool, block_table, qpos, rank, scale,
                            layer=None):
     """Attention over a pool of LATENTS, in the absorbed form of

@@ -62,6 +62,7 @@ import numpy as np
 from jax import lax
 
 from ..core.link import Link, Parameter
+from ..observability import role
 
 __all__ = ["switch_moe", "moe_dispatch_combine", "moe_dispatch_combine_topk",
            "moe_capacity", "sigmoid_topk_route", "held_experts_ffn",
@@ -240,6 +241,7 @@ def _one_hot_capacity(expert_idx, n_experts, capacity):
     return dispatch, keep
 
 
+@role("router")
 def moe_dispatch_combine(comm, x, gate_logits, expert_fn,
                          capacity_factor=1.25, two_stage=None):
     """Route rank-local tokens through rank-sharded experts.
@@ -298,8 +300,10 @@ def switch_moe(comm, x, router_w, w_in, b_in, w_out, b_out,
     stacked [E, ...] expert bank with ``P(axis)``).  Returns
     ([T_local, D], aux).
     """
-    gate_logits = x @ router_w
+    with role("router"):
+        gate_logits = x @ router_w
 
+    @role("experts")
     def expert_fn(h):
         return activation(h @ w_in + b_in) @ w_out + b_out
 
@@ -331,6 +335,7 @@ def _topk_dispatch(probs, k, capacity):
     return dispatch, gates, keep
 
 
+@role("router")
 def moe_dispatch_combine_topk(comm, x, gate_logits, expert_fn, k=2,
                               capacity_factor=1.25, normalize_gates=True,
                               two_stage=None):
@@ -381,6 +386,7 @@ def moe_dispatch_combine_topk(comm, x, gate_logits, expert_fn, k=2,
 # buffer, every copy routed to a held expert is computed.  No exchange is
 # emitted here; what the other chips' experts add is not this layer's.
 
+@role("router")
 def sigmoid_topk_route(x, router_w, bias, k, scale):
     """Sigmoid scoring with a selection bias (DeepSeek-V3's ``noaux_tc``
     with one group): ``s = sigmoid(x W_g)`` in float32 over ALL experts,
@@ -398,6 +404,7 @@ def sigmoid_topk_route(x, router_w, bias, k, scale):
     return ids.astype(jnp.int32), w
 
 
+@role("experts")
 def held_experts_ffn(x, ids, weights, w_gate, w_up, w_down, first,
                      valid=None):
     """The held experts' part of a routed SwiGLU layer, nothing dropped.
@@ -422,11 +429,12 @@ def held_experts_ffn(x, ids, weights, w_gate, w_up, w_down, first,
     ``H · E / (k · H)`` times the products a sort would (PERF.md)."""
     T, D = x.shape
     H, Fw = w_gate.shape[0], w_gate.shape[1]
-    local = ids - first
-    onehot = local[..., None] == jnp.arange(H, dtype=ids.dtype)  # [T,k,H]
-    gate_w = jnp.sum(jnp.where(onehot, weights[..., None], 0.0), axis=1)
-    live = onehot if valid is None else onehot & valid[:, None, None]
-    counts = jnp.sum(live, axis=(0, 1), dtype=jnp.int32)
+    with role("router"):        # which held expert each copy landed on
+        local = ids - first
+        onehot = local[..., None] == jnp.arange(H, dtype=ids.dtype)  # [T,k,H]
+        gate_w = jnp.sum(jnp.where(onehot, weights[..., None], 0.0), axis=1)
+        live = onehot if valid is None else onehot & valid[:, None, None]
+        counts = jnp.sum(live, axis=(0, 1), dtype=jnp.int32)
     g = x @ w_gate.reshape(H * Fw, D).T
     u = x @ w_up.reshape(H * Fw, D).T
     h = (jax.nn.silu(g) * u).reshape(T, H, Fw) \

@@ -73,6 +73,7 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 
+from ..observability import role
 from ..ops.flash_attention import attention_with_lse
 
 __all__ = ["ring_self_attention", "ring_attention", "zigzag_shard",
@@ -141,6 +142,7 @@ def _causal_branch(schedule, kv_chunk, my_chunk):
                      jnp.where(kv_chunk < my_chunk, 0, 2))
 
 
+@role("attn")
 def ring_self_attention(comm, q, k, v, causal=False, scale=None,
                         schedule="naive"):
     """Exact self-attention over a sequence sharded on ``comm``'s axis.
@@ -270,6 +272,7 @@ def _ring_causal_zigzag(comm, q, k, v, scale):
     return out.astype(q.dtype)
 
 
+@role("attn")
 def ring_attention(comm, q, k, v, causal=False, scale=None):
     """Cross-attention variant: same rotation; ``q`` and KV may have
     different local lengths (causal=False only — see

@@ -21,6 +21,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
+from ..observability import role
 from ..ops.flash_attention import blockwise_attention
 
 __all__ = ["ulysses_attention", "seq_to_head_shard", "head_to_seq_shard"]
@@ -42,6 +43,7 @@ def head_to_seq_shard(comm, x):
                           tiled=True)
 
 
+@role("attn")
 def ulysses_attention(comm, q, k, v, causal=False, scale=None):
     """Exact attention with Ulysses sequence parallelism.
 

@@ -257,8 +257,9 @@ def decode_program(model, state, *operands, mode, tp_mesh=None):
     with bind_state(model, state):
         pools, logits, extras = _served(model, "decode")(
             tuple(pools), toks, pos, bts, mode=mode, tp_mesh=tp_mesh)
-        return (*pools, logits, jnp.argmax(logits, axis=-1)
-                .astype(jnp.int32), *extras)
+        with observability.role("head"):      # the token pick
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (*pools, logits, nxt, *extras)
 
 
 def spec_verify_program(model, state, *operands, tp_mesh=None):
@@ -285,8 +286,9 @@ def spec_verify_program(model, state, *operands, tp_mesh=None):
     with bind_state(model, state):
         pools, logits, _ = _served(model, "verify")(
             tuple(pools), toks, start, n_valid, bts, tp_mesh=tp_mesh)
-        return (*pools, logits, jnp.argmax(logits, axis=-1)
-                .astype(jnp.int32))
+        with observability.role("head"):
+            g = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return (*pools, logits, g)
 
 
 class _AdmitDeferred(Exception):

@@ -30,6 +30,8 @@ import math
 
 import jax.numpy as jnp
 
+from ..observability import role
+
 __all__ = ["PagedKVCache", "PerSequence", "write_prompt_kv",
            "write_prompt_kv_at", "write_token_kv", "write_span_kv",
            "copy_page", "insert_pages"]
@@ -64,6 +66,7 @@ def _scatter(pool, layer, pages, slots, kv):
     return at.set(kv.astype(pool.dtype), mode="drop")
 
 
+@role("cache_write")
 def write_prompt_kv(pool_l, kv, block_table_row, true_len, layer=None):
     """Write a whole prompt's entries into one layer's pool.
 
@@ -80,6 +83,7 @@ def write_prompt_kv(pool_l, kv, block_table_row, true_len, layer=None):
     return _scatter(pool_l, layer, pages, t % S, kv)
 
 
+@role("cache_write")
 def write_prompt_kv_at(pool_l, kv, block_table_row, start, true_len,
                        layer=None):
     """Offset prompt writer for the prefix-sharing suffix prefill.
@@ -118,6 +122,7 @@ def insert_pages(pool, block, rows):
     return pool.at[:, rows].set(block.astype(pool.dtype), mode="drop")
 
 
+@role("cache_write")
 def write_token_kv(pool_l, kv, block_tables, pos, layer=None):
     """Write one decode token per batch lane into one layer's pool.
 
@@ -132,6 +137,7 @@ def write_token_kv(pool_l, kv, block_tables, pos, layer=None):
     return _scatter(pool_l, layer, pages, safe % S, kv)
 
 
+@role("cache_write")
 def write_span_kv(pool_l, kv, block_tables, start, n_valid):
     """Write a SPAN of speculative tokens per batch lane (round 20).
 

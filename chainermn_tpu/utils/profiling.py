@@ -32,7 +32,12 @@ def trace(log_dir="/tmp/chainermn_tpu_trace"):
 
 
 def annotate(name):
-    """Named scope visible in trace timelines (``jax.named_scope``)."""
+    """Named scope visible in trace timelines (``jax.named_scope``): the
+    name lands in the compiled program's ``op_name`` and on every device
+    operation of a profile.  For the parts of a model use the ROLES
+    (``chainermn_tpu.observability.role``: ``attn``, ``mlp``, ...), which
+    are the names the benchmark's readers know; this alias is for names
+    of your own (docs/observability.md, "Names on the device")."""
     return jax.named_scope(name)
 
 
