@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..observability import role
+from ..ops import softmax_cotangent
 
 __all__ = [
     "relu", "leaky_relu", "elu", "sigmoid", "tanh", "softplus", "gelu", "silu",
@@ -97,29 +98,36 @@ def softmax_cross_entropy(x, t, ignore_label=-1, reduce="mean",
     int class ids; entries equal to ``ignore_label`` contribute zero loss and
     are excluded from the normalizer; ``class_weight`` ([n_classes]) scales
     each example's loss by its target class's weight.
+
+    The rows' work is ``ops.softmax_cotangent.weighted_nll``: plain
+    ``jnp`` and plain autodiff, except on a TPU for ``[N, V]`` logits
+    that ``ops.softmax_cotangent.fits`` (bfloat16, whole blocks of 16
+    rows, at least 4096 classes), which go through a backward rule of the loss's
+    own.  What that guarantees: the cotangent of ``x``, ``(softmax(x) -
+    onehot(t)) * w / count`` in float32 rounded once to ``x``'s dtype,
+    exists as ONE array of ``x``'s shape and dtype, written by the
+    forward pass from its one read of the logits, and whatever consumes
+    it (the two backward GEMMs of a language model's head) takes a plain
+    operand where each used to rebuild the softmax from the logits in
+    its prologue; the logits themselves are laid out by rows.  What it
+    gives up: it is a ``jax.custom_vjp``, so ``jax.jvp`` (forward mode)
+    through the loss raises there; no caller in this tree differentiates
+    the loss forwards.  Its values are plain autodiff's to the last
+    place or two of the dtype (another ``exp``, another order of sums),
+    not to the bit, and a target that is neither ``ignore_label`` nor a
+    class gives its row no loss there.  ``t`` gets no cotangent.
     """
-    # fp32 statistics even for bf16 logits.  The target's logit is a masked
-    # row sum, not a gather: a gather cannot fuse into the producer of its
-    # operand, so XLA would write the whole float32 log-softmax for it; the
-    # comparison against an iota rides in the pass that sums the exponentials
-    x = x.astype(jnp.float32)
-    t_safe = jnp.where(t == ignore_label, 0, t)
-    lse = jax.nn.logsumexp(x, axis=1)
-    classes = jax.lax.broadcasted_iota(t_safe.dtype, x.shape, 1)
-    picked = jnp.sum(
-        jnp.where(classes == jnp.expand_dims(t_safe, 1), x, 0.0), axis=1)
-    nll = lse - picked
+    mask = t != ignore_label
+    t_safe = jnp.where(mask, t, 0)
+    a = mask.astype(jnp.float32)
     if class_weight is not None:
-        nll = nll * jnp.asarray(class_weight)[t_safe]
-    mask = (t != ignore_label)
-    nll = jnp.where(mask, nll, 0.0)
-    if reduce == "no":
-        return nll
-    if normalize:
-        count = jnp.maximum(mask.sum(), 1)
-    else:
-        count = x.shape[0]
-    return nll.sum() / count
+        a = a * jnp.asarray(class_weight, jnp.float32)[t_safe]
+    if reduce != "no":
+        a = a / (jnp.maximum(mask.sum(), 1) if normalize else x.shape[0])
+    # under a mean the rows' cotangent has to reach the rule as plain ones
+    # (it then has nothing to multiply): the mask is in ``a``, not here
+    nll = softmax_cotangent.weighted_nll(x, t_safe, a)
+    return nll if reduce == "no" else nll.sum()
 
 
 def sigmoid_cross_entropy(x, t, reduce="mean"):

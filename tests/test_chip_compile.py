@@ -152,7 +152,9 @@ def test_the_loss_reads_the_logits_once_and_writes_no_float32_copy(
     backward GEMMs, which rebuild the softmax in their prologues; no
     float32 array of the logits' shape is an instruction.  With the target
     gathered from ``log_softmax`` the same piece kept 1 236 MB of
-    temporaries."""
+    temporaries.  (``softmax_cotangent._on_tpu()`` is false here: this is
+    the plain form, which a TPU still runs for N-D logits, rows that are
+    no whole blocks of 16 and other dtypes.)"""
     import re
     from chainermn_tpu.nn import functions as F
     N, D, V = 4096, 1024, 50257
@@ -185,6 +187,91 @@ def test_the_loss_reads_the_logits_once_and_writes_no_float32_copy(
     assert re.search(r" = \(f32\[4096\]\S*, f32\[4096\]\S*\) fusion\(",
                      loops[0])
     assert compiled.memory_analysis().temp_size_in_bytes < 500e6
+
+
+def test_the_loss_reads_the_logits_once_and_hands_both_gemms_one_cotangent(
+        one_chip, no_persistent_cache, monkeypatch):
+    """GPT-2-medium's vocabulary a chip: ``ln_f`` -> the head's GEMM in
+    bfloat16 -> ``F.softmax_cross_entropy`` over ``[4096, 50257]`` logits
+    as a TPU takes it (``ops.softmax_cotangent.weighted_nll``), forwards
+    and backwards.  The GEMM writes the logits by rows with their row
+    maximum in its epilogue; ONE ``_softmax_cotangent_kernel`` reads
+    them (beside the gather of the 4096 targets' logits) and writes
+    their cotangent, ``bf16[4096,50257]``; the two backward GEMM fusions
+    take that as a plain operand where each used to rebuild the softmax
+    from the logits in its prologue.  Nothing of the logits' shape is
+    copied, none is float32.  Logits and cotangent are 412 MB each; with
+    the target gathered from ``log_softmax`` the same piece kept 1 236 MB
+    of temporaries."""
+    import re
+    from chainermn_tpu.nn import functions as F
+    from chainermn_tpu.ops import softmax_cotangent
+    monkeypatch.setattr(softmax_cotangent, "_on_tpu", lambda: True)
+    N, D, V = 4096, 1024, 50257
+
+    def loss(h, gamma, beta, W, t):
+        y = F.layer_normalization(h, gamma, beta)
+        return F.softmax_cross_entropy(y @ W.astype(jnp.bfloat16).T, t)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled, text = _compile(
+        jax.value_and_grad(loss, argnums=(0, 1, 2, 3)),
+        spec((N, D), jnp.bfloat16), spec((D,), jnp.float32),
+        spec((D,), jnp.float32), spec((V, D), jnp.float32),
+        spec((N,), jnp.int32))
+    entry = text[text.index("\nENTRY "):].splitlines()
+    assert not [line for line in entry
+                if re.search(r" = \(?[^=]*f32\[4096,50257\]", line)]
+    assert not [line for line in entry
+                if re.search(r" = \S+\[4096,50257\]\S* (copy|transpose)\(",
+                             line)]
+
+    def named(pattern):
+        return [m.group(1) for line in entry
+                for m in [re.match(r"\s*%(\S+) = " + pattern, line)] if m]
+
+    def readers(name):
+        return [line for line in entry
+                if re.search(rf"\(.*%{re.escape(name)}[,)]", line)]
+
+    kernels = named(r"\(f32\[4096,1\]\S*, bf16\[4096,50257\]\{1,0\S* "
+                    r"custom-call\(.*_softmax_cotangent_kernel")
+    assert len(kernels) == 1, kernels
+    # the logits, by rows, out of the GEMM that also takes their row maximum
+    logits = named(r"bf16\[4096,50257\]\{1,0\S* get-tuple-element\(%fusion")
+    assert len(logits) == 1, logits
+    read = readers(logits[0])
+    assert len(read) == 2 and sum("custom-call(" in l for l in read) == 1 \
+        and sum("gather" in l for l in read) == 1, read
+    cotangent = named(r"bf16\[4096,50257\]\{1,0\S* get-tuple-element\(%"
+                      + re.escape(kernels[0]))
+    assert len(cotangent) == 1, cotangent
+    gemms = readers(cotangent[0])
+    assert len(gemms) == 2 and all("kind=kOutput" in l for l in gemms), gemms
+    assert sorted(re.match(r"\s*%\S+ = \(?(\w+\[[\d,]*\])", l).group(1)
+                  for l in gemms) == ["f32[1024]", "f32[50257,1024]"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 900e6
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((4096, 50257), jnp.bfloat16), ((8192, 32768), jnp.bfloat16),
+    ((16, 4096), jnp.bfloat16), ((16384, 8192), jnp.bfloat16),
+    ((64, 160000), jnp.bfloat16)])
+def test_softmax_cotangent_kernel_compiles(one_chip, no_persistent_cache,
+                                           shape, dtype):
+    """The kernel at GPT-2-medium's logits, at ``chip_smoke.py``'s, at
+    the least shape it takes, at many rows of few classes and at a
+    vocabulary of 160 000."""
+    from chainermn_tpu.ops import softmax_cotangent
+    assert softmax_cotangent.fits(shape, dtype)
+    n = shape[0]
+    _compile(softmax_cotangent.softmax_sums_and_cotangent,
+             jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip),
+             jax.ShapeDtypeStruct((n, 1), jnp.float32, sharding=one_chip),
+             jax.ShapeDtypeStruct((n,), jnp.int32, sharding=one_chip),
+             jax.ShapeDtypeStruct((n, 1), jnp.float32, sharding=one_chip))
 
 
 # -- the serving programs ----------------------------------------------------
