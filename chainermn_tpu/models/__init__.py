@@ -15,6 +15,7 @@ from .window_moe import (WindowMoELM, WindowMoEBlock,
                          GatedGroupedAttention)
 from .hybrid_delta import (HybridDeltaLM, HybridDeltaBlock, DeltaMixer,
                            FullMixer)
+from .looped import LoopedLM, LoopedBlock, LoopedAttention
 from .convnets import AlexNet, NIN, VGG16, GoogLeNet
 
 __all__ = ["MLP", "Classifier", "ResNet", "ResNet18", "ResNet50",
@@ -27,4 +28,5 @@ __all__ = ["MLP", "Classifier", "ResNet", "ResNet18", "ResNet50",
            "MoEFeedForward", "LatentMoELM", "LatentMoEBlock",
            "LatentAttention", "WindowMoELM", "WindowMoEBlock",
            "GatedGroupedAttention", "HybridDeltaLM", "HybridDeltaBlock",
-           "DeltaMixer", "FullMixer", "AlexNet", "NIN", "VGG16", "GoogLeNet"]
+           "DeltaMixer", "FullMixer", "LoopedLM", "LoopedBlock",
+           "LoopedAttention", "AlexNet", "NIN", "VGG16", "GoogLeNet"]

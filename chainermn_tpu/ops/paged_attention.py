@@ -37,6 +37,7 @@ and the softmax state are fp32 via ``preferred_element_type``.
 from __future__ import annotations
 
 import functools
+import numbers
 import os
 
 import jax
@@ -121,8 +122,9 @@ def _dense_decode(q, k, v, ctx_len, scale):
 
 def _gather_pages(pool, layer, block_table):
     """The pages a block table names: from one layer's ``[P, S, ...]``
-    pool, or (a static ``layer``) from that layer of the whole ``[L, P,
-    S, ...]`` pool with no slice taken out first."""
+    pool, or (a ``layer``, a Python number or a traced scalar: the cache
+    layer inside a device loop) from that layer of the whole ``[L, P, S,
+    ...]`` pool with no slice taken out first."""
     return pool[block_table] if layer is None else pool[layer, block_table]
 
 
@@ -331,8 +333,7 @@ def _grouped_decode(q, pool, block_table, ctx_len, scale, window, layer,
 _DECODE_CHUNK_PAGES = 16
 
 
-def _paged_decode_kernel(bt_ref, ctx_ref, q_ref, pool_ref, o_ref, buf, sem,
-                         *, layer, page, width, chunk, window, scale,
+def _paged_decode_kernel(*refs, layer, page, width, chunk, window, scale,
                          n_entries):
     """One lane's query heads over its pages, READ IN PLACE: the pages
     its block table names are copied from the pool in HBM straight into
@@ -342,11 +343,20 @@ def _paged_decode_kernel(bt_ref, ctx_ref, q_ref, pool_ref, o_ref, buf, sem,
     context (not at the block table's length), and under a ``window``
     it starts at the first page the window touches.
 
+    ``refs``: ``bt_ref, ctx_ref, q_ref, pool_ref, o_ref, buf, sem``
+    behind a static ``layer``; with ``layer=None`` the pool's layer is a
+    scalar the caller prefetched (a loop's cache layer, known on the
+    device alone), ``layer_ref [1]`` ahead of the rest.
+
     ``q_ref [Hp, width]``: every query head as a row of ALL ``width = G
     · D`` key lanes, zero outside its own K/V head's ``D``, so the chunk
     is scored by ONE product against its K half and weighed by one
     against its V half, whatever the grouping (the caller keeps each
     head's own ``D`` lanes of ``o_ref [Hp, width]``)."""
+    if layer is None:
+        layer_ref, *refs = refs
+        layer = layer_ref[0]
+    bt_ref, ctx_ref, q_ref, pool_ref, o_ref, buf, sem = refs
     b = pl.program_id(0)
     ctx = ctx_ref[b]
     qpos = ctx - 1
@@ -415,8 +425,11 @@ def paged_decode_kernel(q, pool, block_table, ctx_len, *, kv_heads, layer,
     """The grouped decode step as a Pallas kernel over ONE pool ``[L, P,
     S, 2 · G · D]`` (a token's K then its V): what
     :func:`paged_decode_attention` runs on a TPU for ``kv_heads=`` and
-    ``v_pool=None``; the arguments are its.  An idle lane (``ctx_len``
-    0) reads nothing and gives zeros."""
+    ``v_pool=None``; the arguments are its.  ``layer`` is a Python
+    number baked into the kernel, or a traced scalar (the cache layer
+    inside a device loop over passes) that the kernel takes as one more
+    prefetched scalar.  An idle lane (``ctx_len`` 0) reads nothing and
+    gives zeros."""
     B, H, D = q.shape
     G, r = kv_heads, q.shape[1] // kv_heads
     page, width = pool.shape[-2], kv_heads * D
@@ -426,6 +439,12 @@ def paged_decode_kernel(q, pool, block_table, ctx_len, *, kv_heads, layer,
     qx = (q[:, :, None, :] * own[None, :, :, None].astype(q.dtype)) \
         .reshape(B, H, width)
     qx = jnp.pad(qx, ((0, 0), (0, rows - H), (0, 0)))
+    prefetch = (block_table, ctx_len)
+    if isinstance(layer, numbers.Integral):
+        layer = int(layer)
+    else:
+        prefetch = (jnp.reshape(layer, (1,)).astype(jnp.int32),) + prefetch
+        layer = None
     kernel = functools.partial(
         _paged_decode_kernel, layer=layer, page=page, width=width,
         chunk=_DECODE_CHUNK_PAGES, window=window, scale=scale,
@@ -434,19 +453,19 @@ def paged_decode_kernel(q, pool, block_table, ctx_len, *, kv_heads, layer,
         kernel,
         name="_paged_decode_kernel",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B,),
+            num_scalar_prefetch=len(prefetch), grid=(B,),
             in_specs=[pl.BlockSpec((None, rows, width),
-                                   lambda b, bt, ctx: (b, 0, 0)),
+                                   lambda b, *_: (b, 0, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((None, rows, width),
-                                   lambda b, bt, ctx: (b, 0, 0)),
+                                   lambda b, *_: (b, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, _DECODE_CHUNK_PAGES * page, 2 * width),
                            pool.dtype),
                 pltpu.SemaphoreType.DMA((2,))]),
         out_shape=jax.ShapeDtypeStruct((B, rows, width), jnp.float32),
         interpret=interpret,
-    )(block_table, ctx_len, qx, pool)
+    )(*prefetch, qx, pool)
     # each head's own D lanes of its row
     out = out[:, :H].reshape(B, G, r, G, D)
     return jnp.einsum("bgrgd->bgrd", out).reshape(B, H, D).astype(q.dtype)

@@ -57,10 +57,15 @@ def _geometry(pool, layer):
 
 def _scatter(pool, layer, pages, slots, kv):
     """The one drop-fenced scatter of every writer.  ``layer=None``:
-    ``pool`` is one layer's ``[P, S, ...]``.  With a (static) ``layer``,
-    ``pool`` is the whole ``[L, P, S, ...]`` array and the entries land
-    in that layer of it directly — no layer slice is taken out and put
-    back, so a donated pool is updated in place by the scatter alone."""
+    ``pool`` is one layer's ``[P, S, ...]``.  With a ``layer``, ``pool``
+    is the whole ``[L, P, S, ...]`` array and the entries land in that
+    layer of it directly — no layer slice is taken out and put back, so
+    a donated pool is updated in place by the scatter alone.  The layer
+    is a Python number, or a traced scalar: a model that runs its blocks
+    several times keeps a cache layer a pass and a block, and inside its
+    device loop over passes that index is known on the device alone
+    (``LoopedLM``); the pool is then the loop's carry, and the scatter
+    updates the carry in place."""
     at = pool.at[pages, slots] if layer is None \
         else pool.at[layer, pages, slots]
     return at.set(kv.astype(pool.dtype), mode="drop")

@@ -642,3 +642,133 @@ def test_hybrid_suffix_program_at_1024_tokens(hybrid, no_persistent_cache,
     assert all("f32[1,30,96,192]" in c for c in calls)
     _assert_no_pool_is_copied(compiled, text, pools)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+# -- layers run several times (ouro-2.6b), published widths, nothing cut ---
+
+LOOPED_PAGES, LOOPED_CONTEXT, LOOPED_LANES = 320, 352, 16
+
+
+@pytest.fixture(scope="module")
+def looped(one_chip):
+    """The configuration's builder at the published widths, its bfloat16
+    state as shapes on the described chip, and the cell's one pool: 4
+    passes x 48 blocks = 192 cache layers of 320 pages, a token's K then
+    its V in one row of 4096 lanes, 8.05 GB."""
+    from benchmark import harness
+    config = harness.load_json(os.path.join(
+        harness.HERE, "configs", "ouro-2.6b.json"))
+    model = harness.load_module("models", "looped_lm").build(
+        config, max_len=LOOPED_CONTEXT)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    state = {"params": {path: spec(p.shape, jnp.bfloat16)
+                        for path, p in model.namedparams()}, "state": {}}
+    (_, layers, (row,), _), = model.serve_cache_groups()
+    pool = spec((layers, LOOPED_PAGES, PAGE) + row, jnp.bfloat16)
+    assert pool.shape == (192, 320, 16, 4096)
+    return model, state, pool, spec
+
+
+def _looped_program(looped, program, size):
+    from chainermn_tpu.serving import (decode_program, prefill_program,
+                                       prefix_prefill_program)
+    model, state, pool, spec = looped
+    N = LOOPED_CONTEXT // PAGE
+    scalar = spec((), jnp.int32)
+    if program == "decode":
+        fn = functools.partial(decode_program, model, mode=None)
+        operands = (spec((size,), jnp.int32), spec((size,), jnp.int32),
+                    spec((1, size, N), jnp.int32))
+    elif program == "prefill":
+        fn = functools.partial(prefill_program, model)
+        operands = (spec((1, size), jnp.int32), scalar,
+                    spec((1, N), jnp.int32))
+    else:
+        fn = functools.partial(prefix_prefill_program, model)
+        operands = (spec((1, size), jnp.int32), scalar, scalar,
+                    spec((1, N), jnp.int32))
+    return _compile(fn, state, pool, *operands, donate_argnums=(1,))
+
+
+@pytest.mark.parametrize("program, size, temporaries", [
+    ("decode", 1, 0.06e9), ("decode", LOOPED_LANES, 0.13e9),
+    ("prefill", 256, 0.11e9), ("suffix_prefill", 128, 0.13e9)])
+def test_looped_program_carries_its_pool_in_place(
+        looped, no_persistent_cache, monkeypatch, program, size,
+        temporaries):
+    """The three programs' body is ONE device loop over the 4 passes
+    (the 48 blocks unrolled inside it, not 192 bodies), the 8.05 GB pool
+    its carry, written and read at a cache layer that is a value of the
+    loop: donated, aliased, never copied, relaid or cut (one copy does
+    not fit beside it: weights 5.34 + pool 8.05 of 16 GB), and the
+    temporaries a hundredth of the pool's order (compiled here: 46 MB
+    the one-lane decode, 100 MB the 16-lane decode and the 128 suffix,
+    85 MB the 256 prefill: the largest is 100 MB).  As the chip runs
+    them, a decode step's attention is `_paged_decode_kernel` with the
+    layer a prefetched scalar, a full prefill's `_flash_kernel`."""
+    from chainermn_tpu.ops import paged_attention
+    pool = looped[2]
+    for module in (fa, paged_attention):
+        monkeypatch.setattr(module, "_on_tpu", lambda: True)
+    compiled, text = _looped_program(looped, program, size)
+    _assert_no_pool_is_copied(compiled, text, [pool])
+    ma = compiled.memory_analysis()
+    assert ma.temp_size_in_bytes < temporaries, ma.temp_size_in_bytes
+    # one loop over passes, and the kernels inside it once a block
+    whiles = [line for line in text.splitlines()
+              if " while(" in line and "/loop/" in line]
+    assert len(whiles) == 1
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    kernel = {"decode": "_paged_decode_kernel",
+              "prefill": "_flash_kernel"}.get(program)
+    if kernel is None:
+        assert not calls            # a suffix reads its pages by a gather
+        assert f"bf16[{LOOPED_CONTEXT // PAGE},16,4096]" in text
+    else:
+        assert sum(kernel in c for c in calls) == len(calls) == 48
+
+
+def _pallas_calls(jaxpr, name):
+    """Every ``pallas_call`` equation called ``name`` in ``jaxpr`` and
+    in what it holds (loops, branches, calls)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call" \
+                and eqn.params["name"] == name:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _pallas_calls(sub, name)
+    return found
+
+
+# `_paged_decode_kernel` bakes a STATIC layer in and takes a TRACED one as
+# one more prefetched scalar ahead of the block table and the contexts.
+# The two models that read their pages through it at a static layer must
+# keep the form their cells were accepted with, at every layer; the
+# looped model's loop supplies the third scalar.
+@pytest.mark.parametrize("which, calls, scalars", [
+    ("windowed", None, 2), ("hybrid", None, 2), ("looped", 48, 3)])
+def test_decode_kernel_prefetches_a_layer_only_inside_a_loop(
+        request, monkeypatch, which, calls, scalars):
+    from chainermn_tpu.serving import decode_program
+    model, state, *pools, spec = request.getfixturevalue(which)
+    if which != "looped":
+        (pools,) = pools
+    _on_the_chip(monkeypatch)
+    lanes, context = {"windowed": (LAGUNA_LANES, LAGUNA_CONTEXT),
+                      "hybrid": (HYBRID_LANES, HYBRID_CONTEXT),
+                      "looped": (LOOPED_LANES, LOOPED_CONTEXT)}[which]
+    groups = len(model.serve_cache_groups())
+    jaxpr = jax.make_jaxpr(
+        functools.partial(decode_program, model, mode=None))(
+        state, *pools, spec((lanes,), jnp.int32), spec((lanes,), jnp.int32),
+        spec((groups, lanes, context // PAGE), jnp.int32)).jaxpr
+    found = _pallas_calls(jaxpr, "_paged_decode_kernel")
+    assert found and (calls is None or len(found) == calls)
+    for eqn in found:
+        assert eqn.params["grid_mapping"].num_index_operands == scalars
+        # the scalars, the padded queries and the whole pool: no more
+        assert len(eqn.invars) == scalars + 2
