@@ -4,10 +4,30 @@ open loop of arrivals at a rate fixed in the cell.
 One thread submits each request when it is due and steps the engine, as
 bench.py's serving loop does.  Requests are timed from when they were
 due; the run prints how late the generator ran.  After the window the
-requests in flight are finished (their first-token times and gaps count,
-their tokens do not count towards the window's rate), and a sample of the
-finished requests, drawn from the seed with the longest in it, is held
-against the plain reference.
+requests in flight are finished (their first-token times and gaps count),
+and a sample of the finished requests, drawn from the seed with the
+longest in it, is held against the plain reference.
+
+**Two ways to count a window's tokens**, chosen by the cell's traffic
+file (``drive`` says why there are two):
+
+- no ``count`` key: the tokens of the requests that FINISHED before the
+  close, over the time at which the step that crossed the close
+  returned.  Below a knee that is the offered load; the cells accepted
+  before PR 42 keep it, so their history in the ledger stays comparable.
+- ``"count": "tokens"``: every token whose own stamp lies inside the
+  window, of every request submitted in it, finished or in flight, over
+  the window's length on the clock.  A cell above its knee takes this
+  one: there finishes crowd at every instant, and a count by whole
+  requests swings by requests (3.5 % over six runs of the Laguna peak
+  file where the stamps spread 0.9 %: PERF.md section 6, PR 42).
+
+``"trace_after_s": <seconds>`` moves a TRACED run's window off the ramp:
+the run drives ``trace_after_s + trace_seconds`` of schedule in one go
+and opens the profiler once ``trace_after_s`` have passed, so every
+per-layer reader (device trace, program spans and counters) reads the
+plateau and not an engine filling up from empty.  A plain run ignores
+the key.
 """
 
 from __future__ import annotations
@@ -161,20 +181,58 @@ def pick_sample(finished, seed, k):
     return [(np.asarray(r.prompt), list(r.tokens)) for r in chosen]
 
 
-def drive(prog, arrivals, seconds, tracer=None):
+def tokens_stamped(requests, lo, hi):
+    """The tokens of ``requests`` whose stamp lies in ``[lo, hi]`` (the
+    engine's ``time.monotonic()``, as the window's edges are)."""
+    return sum(1 for r in requests for t in r.token_times if lo <= t <= hi)
+
+
+def drive(prog, arrivals, seconds, tracer=None, count=None,
+          trace_after_s=None):
     """The window: submit each arrival when it is due, step the engine,
     close at ``seconds`` once every arrival is in, then finish what is
-    in flight.  Returns what was measured."""
+    in flight.  Returns what was measured; the rate is
+    ``tokens_in_window / counted_s``.
+
+    ``count`` says what ``tokens_in_window`` is.  ``None``: the tokens of
+    the requests that finished before the close, all or nothing, and
+    ``counted_s`` the time at which the step that crossed the close
+    returned.  ``"tokens"``: the stamps ``t`` of ``token_times`` with
+    ``t <= base + seconds``, over every request submitted, finished or
+    in flight, and ``counted_s`` is ``seconds``: the window closes on
+    the clock, and a step that crosses the close adds nothing.  There
+    are two because below a knee both are the offered load, and the
+    cells that count by requests keep their ledger history comparable
+    (moving them would raise each by the tokens of the 5-13 requests in
+    flight at its close, 2-3 %); above a knee a request finishes at
+    every instant, the close cannot be put on a plateau of the schedule,
+    and only the stamps mean anything: their quantum is one decode step.
+    Either way the result carries both counts (``tokens_of_finished``,
+    ``tokens_stamped``).
+
+    With ``trace_after_s`` the tracer is entered inside the loop, once
+    that many seconds have passed, and the window that is counted and
+    traced opens when the profiler has started (``opened_s``) and closes
+    ``seconds - trace_after_s`` later: the ramp is driven, not read."""
     import jax
     engine = prog.engine
     n_warm = len(engine.completed)
+    steps_warm, hits_warm = engine.decode_steps, engine.prefix_hits
     requests, late_ms, refused = [], [], 0
     queued_steps = steps = 0
-    with tracer or contextlib.nullcontext():
+    late_open = tracer is not None and trace_after_s is not None
+    with contextlib.ExitStack() as stack:
+        if tracer is not None and not late_open:
+            stack.enter_context(tracer)
         base = time.monotonic()
+        opened, close = (None, float("inf")) if late_open else (0.0, seconds)
         i = 0
         while True:
             t = time.monotonic() - base
+            if opened is None and t >= trace_after_s:
+                stack.enter_context(tracer)
+                t = opened = time.monotonic() - base
+                close = opened + seconds - trace_after_s
             while i < len(arrivals) and arrivals[i].due <= t:
                 a = arrivals[i]
                 req = prog.request(a.prompt, a.max_new_tokens, a.tenant,
@@ -188,7 +246,7 @@ def drive(prog, arrivals, seconds, tracer=None):
                     harness.say({"refused": repr(e)})
                 late_ms.append((time.monotonic() - base - a.due) * 1e3)
                 i += 1
-            if t >= seconds and i == len(arrivals):
+            if t >= close and i == len(arrivals):
                 break
             with jax.profiler.TraceAnnotation("bench/engine_step"):
                 st = engine.step()
@@ -199,12 +257,21 @@ def drive(prog, arrivals, seconds, tracer=None):
                     time.sleep(0.0005)
         window_s = time.monotonic() - base
     done = list(engine.completed[n_warm:])
+    of_finished = sum(len(r.token_times) for r in done)
+    stamped = tokens_stamped(requests, base + opened, base + close)
+    by_stamps = count == "tokens"
     out = {"window_s": window_s, "due": len(arrivals),
            "finished_in_window": len(done),
            "in_flight_at_close": len(requests) - len(done),
            "queued_at_close": engine.scheduler.pending(),
            "queued_share_of_steps": queued_steps / max(steps, 1),
-           "tokens_in_window": sum(len(r.token_times) for r in done)}
+           "decode_steps_at_close": engine.decode_steps - steps_warm,
+           "prefix_hits_at_close": engine.prefix_hits - hits_warm,
+           "count": "tokens" if by_stamps else "requests",
+           "opened_s": opened, "tokens_of_finished": of_finished,
+           "tokens_stamped": stamped,
+           "tokens_in_window": stamped if by_stamps else of_finished,
+           "counted_s": close - opened if by_stamps else window_s}
     prog.drain()
     finished = list(engine.completed[n_warm:])
     ttft_ms, gap_ms, missing = [], [], 0
@@ -217,8 +284,24 @@ def drive(prog, arrivals, seconds, tracer=None):
         gap_ms.extend(np.diff(r.token_times) * 1e3)
     out.update(requests=requests, finished=finished, late_ms=late_ms,
                ttft_ms=ttft_ms, gap_ms=gap_ms, missing=missing,
-               failed=refused + len(requests) - len(finished))
+               failed=refused + len(requests) - len(finished),
+               gap_p50_ms_by_slice=gap_medians(requests, base, close))
     return out
+
+
+def gap_medians(requests, base, close, slice_s=5.0):
+    """The median gap between a request's consecutive tokens, for each
+    ``slice_s`` of the window by the later token's stamp: one engine cycle
+    (a decode step and the host between two) where the lanes are full.  A
+    run whose host gap changes level part way shows it here without a
+    profiler (the level is worth 3-8 % of a saturated cell's rate)."""
+    slices = [[] for _ in range(int(np.ceil(close / slice_s)))]
+    for r in requests:
+        t = np.asarray(r.token_times) - base
+        for at, gap in zip(t[1:], np.diff(t)):
+            if at < close:
+                slices[int(at // slice_s)].append(gap * 1e3)
+    return [float(np.median(s)) if s else None for s in slices]
 
 
 def measure(run, prog, watch, checks):
@@ -227,18 +310,23 @@ def measure(run, prog, watch, checks):
     engine = prog.engine
     seconds = min(run.seconds, run.traffic["trace_seconds"]) \
         if run.trace else run.seconds
+    after = run.traffic.get("trace_after_s") if run.trace else None
+    if after is not None:
+        seconds += after
     arrivals = traffic_gen.generate(run.traffic["mix"], prog.vocab,
                                     run.seed, seconds)
     setup_s = time.perf_counter() - run.t0
     mark, traces_warm = watch.snapshot(), prog.trace_counts()
     tracer = tracing.Tracing(tracing.trace_dir(run)) if run.trace else None
-    w = drive(prog, arrivals, seconds, tracer)
+    w = drive(prog, arrivals, seconds, tracer,
+              count=run.traffic.get("count"), trace_after_s=after)
     compiled, traced = watch.since(mark)
     harness.say({**{k: v for k, v in w.items() if not isinstance(v, list)},
                  "decode_steps": engine.decode_steps,
                  "prefix_hits": engine.prefix_hits,
                  "evictions": engine.evictions, "forks": engine.forks,
                  "setup_s": setup_s, **watch.summary()})
+    harness.say({"serve_gap_p50_ms_by_5s_slice": w["gap_p50_ms_by_slice"]})
     harness.say(harness.timing_summary("generator_late_ms", w["late_ms"]))
     harness.say(harness.timing_summary("serve_ttft_ms", w["ttft_ms"]))
     harness.say(harness.timing_summary("serve_gap_ms", w["gap_ms"]))
@@ -258,6 +346,10 @@ def measure(run, prog, watch, checks):
 def run(run):
     import jax
 
+    if run.traffic.get("count") not in (None, "tokens"):
+        raise harness.BenchmarkError(
+            "a traffic file's count is \"tokens\" or absent, not "
+            f"{run.traffic['count']!r}")
     checks = harness.Checks()
     with harness.watch_compiles() as watch:
         prog = Program(run)
@@ -265,7 +357,7 @@ def run(run):
         w, setup_s, tracer = measure(run, prog, watch, checks)
     device = harness.device_block(run.devices, run.rehearsal)
     metrics = {
-        "serve_tokens_per_s": w["tokens_in_window"] / w["window_s"],
+        "serve_tokens_per_s": w["tokens_in_window"] / w["counted_s"],
         "setup_s": setup_s,
     }
 

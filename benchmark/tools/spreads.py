@@ -2,8 +2,10 @@
 benchmark.tools.spreads <file written by full_sets.sh> ...`` prints, for
 each end-to-end metric, each set's median and its spread (the distance
 between the first and third quartile of ``statistics.quantiles(values,
-n=4)`` as a share of the median), the wider of the two, and how far the
-second set's median lies from the first's."""
+n=4)`` as a share of the median), the wider of the two, how far the
+second set's median lies from the first's, and each set's range less its
+farthest run (how the driver's check reckons a cell's noise against a
+bound: PR 41 was refused on it)."""
 
 from __future__ import annotations
 
@@ -30,6 +32,15 @@ def spread(values):
     return (q3 - q1) / statistics.median(values)
 
 
+def range_less_farthest(values):
+    """The runs' range as a share of their median, the run farthest from
+    the median left out."""
+    med = statistics.median(values)
+    kept = sorted(values, key=lambda v: abs(v - med))
+    kept = kept[:-1] if len(kept) > 2 else kept
+    return (max(kept) - min(kept)) / med
+
+
 def main(argv):
     for path in argv:
         rows = read(path)
@@ -45,10 +56,13 @@ def main(argv):
                     for s, v in sets.items()}
             med = {s: statistics.median(v) for s, v in used.items() if v}
             spr = {s: spread(v) for s, v in used.items() if len(v) >= 2}
+            rng = {s: round(range_less_farthest(v), 5)
+                   for s, v in used.items() if len(v) >= 2}
             print(f"  {name}: medians {med}, spreads "
                   f"{ {s: round(v, 5) for s, v in spr.items()} }, wider "
                   f"{max(spr.values()):.5f}, second/first median "
-                  f"{med.get(2, float('nan')) / med[1] - 1:+.5f}; values "
+                  f"{med.get(2, float('nan')) / med[1] - 1:+.5f}; range less "
+                  f"the farthest run {rng}; values "
                   f"{ {s: [round(x, 4) for x in v] for s, v in sets.items()} }")
         print("  memory_peak_bytes", sorted(
             {r[2]["device"]["memory_peak_bytes"] for r in rows}))

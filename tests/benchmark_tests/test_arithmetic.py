@@ -147,3 +147,15 @@ def test_flash_counts_and_roofline_share():
     peaks = {"bf16_tflops": 197.0, "hbm_gbps": 819.0}
     share, bound = flops.roofline_share(fl, by, fl / 197e12 * 2, peaks)
     assert share == pytest.approx(50.0) and bound == "compute"
+
+
+def test_a_sets_range_leaves_out_its_farthest_run():
+    """How the driver reckons a cell's noise against a bound (PR 41's
+    refusal): the range of the runs less the one farthest from their
+    median, as a share of the median."""
+    from benchmark.tools import spreads
+    runs = [100.0, 100.2, 99.9, 100.1, 100.0, 103.0]
+    assert spreads.range_less_farthest(runs) == pytest.approx(0.3 / 100.05)
+    assert spreads.range_less_farthest([100.0, 101.0]) == pytest.approx(
+        1.0 / 100.5)
+    assert spreads.spread(runs) > 0

@@ -275,13 +275,7 @@ def _manifest_with_the_prepared_cells():
     ``workloads`` and its name wherever its sibling is listed."""
     m = harness.load_manifest()
     assert not set(PREPARED) & {w["name"] for w in m["workloads"]}
-    for cell, sibling in PREPARED.items():
-        m["workloads"].append({"name": cell, "config": "gpt2-medium",
-                               "traffic": cell, "chips": 1})
-        for e in m["end_to_end"] + m["per_layer"]:
-            if sibling in e.get("workloads", ()):
-                e["workloads"].append(cell)
-    return m
+    return _tiny.listed_beside(m, PREPARED)
 
 
 @pytest.fixture
