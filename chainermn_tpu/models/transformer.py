@@ -140,7 +140,8 @@ class _ServingMixin:
     """What :class:`~chainermn_tpu.serving.ServingEngine` asks of a model:
     the cache entry a token leaves in a layer, the context limit, and
     the block's prefill / suffix prefill / decode (and, here, the
-    speculative verify) over per-layer views of the page pools.  Each
+    speculative verify) over the whole page pools, written and read in
+    place at each layer.  Each
     runs with the parameters bound (``bind_state``) inside one of the
     engine's compiled programs and returns ``(pools, logits, extras)``,
     ``extras`` a tuple of further device values for the engine's spans
@@ -163,18 +164,30 @@ class _ServingMixin:
         return self.compute_dtype or jnp.float32
 
     def serve_cache_entry(self):
-        """K and V of ``[H, D]`` a token a layer: two pools."""
+        """K and V of ``[H · D]`` a token a layer, the heads side by side
+        in the lanes: two pools, each stored as the programs compute on
+        it (heads of 64 as a minor axis of their own are half a lane
+        tile, and the chip converted each whole pool between two layouts
+        twice a step: PR 43)."""
         attn = self.blocks[0].attn
-        return ((attn.n_heads, attn.d_head),) * 2
+        return ((attn.n_heads * attn.d_head,),) * 2
 
     def serve_pool_sharding(self, mesh):
-        """Tensor-parallel decode: the pools shard over their HEAD axis
-        (the ulysses layout), which the mesh must divide."""
+        """Tensor-parallel decode: the pools shard over their lanes by
+        whole HEADS (the ulysses layout), so the mesh must divide the
+        heads; and where a token's row is whole lane tiles a shard's
+        must be too, or each shard is back to the layout this entry
+        exists to avoid."""
         attn = self.blocks[0].attn
+        row = attn.n_heads * attn.d_head
         if attn.n_heads % mesh.size:
             raise ValueError(f"tp={mesh.size} must divide n_heads="
                              f"{attn.n_heads}")
-        return head_sharding(mesh, 5, 3)
+        if row % 128 == 0 and (row // mesh.size) % 128:
+            raise ValueError(
+                f"tp={mesh.size} leaves a shard {row // mesh.size} of a "
+                f"token's {row} lanes: not whole tiles of 128")
+        return head_sharding(mesh, 4, 3)
 
     def _serve_embed(self, toks, positions):
         """Token + position embeddings cast to the model's compute dtype
@@ -218,10 +231,13 @@ class _ServingMixin:
                 with role("norm"):
                     x = block.ln1(h)
                 with role("attn_proj"):
-                    qkv = block.attn.qkv(x.reshape(B * T, -1)).reshape(
-                        B, T, 3, block.attn.n_heads, block.attn.d_head)
-                    q, k, v = [jnp.moveaxis(qkv[:, :, j], 1, 2)
-                               for j in range(3)]
+                    # a token's K and V rows as the GEMM leaves them are
+                    # what the cache holds
+                    rows = jnp.split(
+                        block.attn.qkv(x.reshape(B * T, -1)), 3, axis=-1)
+                    q, k, v = [jnp.moveaxis(r.reshape(
+                        B, T, block.attn.n_heads, block.attn.d_head), 1, 2)
+                        for r in rows]
                 # the flash dispatcher: Pallas forward on TPU (no backward
                 # is ever traced — inference), XLA/interpret elsewhere
                 att = fused_attention(q, k, v, causal=True)
@@ -229,13 +245,10 @@ class _ServingMixin:
                     att = jnp.moveaxis(att, 2, 1).reshape(B * T, -1)
                     h = h + block.attn.proj(att).reshape(B, T, -1)
                 h = self._serve_mlp(block, h)
-                with role("cache_write"):
-                    k_pool = k_pool.at[li].set(write_prompt_kv(
-                        k_pool[li], jnp.moveaxis(k[0], 0, 1), bt_row,
-                        true_len))
-                    v_pool = v_pool.at[li].set(write_prompt_kv(
-                        v_pool[li], jnp.moveaxis(v[0], 0, 1), bt_row,
-                        true_len))
+                k_pool = write_prompt_kv(k_pool, rows[1], bt_row, true_len,
+                                         layer=li)
+                v_pool = write_prompt_kv(v_pool, rows[2], bt_row, true_len,
+                                         layer=li)
         return (k_pool, v_pool), self._serve_last_logits(h, true_len), ()
 
     def serve_suffix_prefill(self, pools, tokens, true_len, start, bt_row):
@@ -262,18 +275,16 @@ class _ServingMixin:
                 with role("norm"):
                     x = block.ln1(h)
                 with role("attn_proj"):
-                    qkv = block.attn.qkv(x.reshape(B * T, -1)).reshape(
-                        B, T, 3, block.attn.n_heads, block.attn.d_head)
-                    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-                with role("cache_write"):
-                    k_pool = k_pool.at[li].set(write_prompt_kv_at(
-                        k_pool[li], k[0], bt_row, start, true_len))
-                    v_pool = v_pool.at[li].set(write_prompt_kv_at(
-                        v_pool[li], v[0], bt_row, start, true_len))
-                with role("attn"):      # the pools' layer views too
-                    att = paged_prefill_attention(
-                        q[0], k_pool[li], v_pool[li], bt_row, start,
-                        true_len, scale=scale)
+                    q, k, v = jnp.split(
+                        block.attn.qkv(x.reshape(B * T, -1)), 3, axis=-1)
+                    q = q.reshape(T, block.attn.n_heads, block.attn.d_head)
+                k_pool = write_prompt_kv_at(k_pool, k, bt_row, start,
+                                            true_len, layer=li)
+                v_pool = write_prompt_kv_at(v_pool, v, bt_row, start,
+                                            true_len, layer=li)
+                att = paged_prefill_attention(
+                    q, k_pool, v_pool, bt_row, start, true_len,
+                    scale=scale, layer=li)
                 with role("attn_proj"):
                     h = h + block.attn.proj(att.reshape(B * T, -1)) \
                         .reshape(B, T, -1)
@@ -300,18 +311,13 @@ class _ServingMixin:
                 with role("norm"):
                     x = block.ln1(h)
                 with role("attn_proj"):
-                    qkv = block.attn.qkv(x).reshape(
-                        Bb, 3, block.attn.n_heads, block.attn.d_head)
-                    q, k, v = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-                with role("cache_write"):
-                    k_pool = k_pool.at[li].set(
-                        write_token_kv(k_pool[li], k, bts, pos))
-                    v_pool = v_pool.at[li].set(
-                        write_token_kv(v_pool[li], v, bts, pos))
-                with role("attn"):      # the pools' layer views too
-                    att = paged_decode_attention(
-                        q, k_pool[li], v_pool[li], bts, ctx_len,
-                        scale=scale, mode=mode, tp_mesh=tp_mesh)
+                    q, k, v = jnp.split(block.attn.qkv(x), 3, axis=-1)
+                    q = q.reshape(Bb, block.attn.n_heads, block.attn.d_head)
+                k_pool = write_token_kv(k_pool, k, bts, pos, layer=li)
+                v_pool = write_token_kv(v_pool, v, bts, pos, layer=li)
+                att = paged_decode_attention(
+                    q, k_pool, v_pool, bts, ctx_len, scale=scale,
+                    mode=mode, tp_mesh=tp_mesh, layer=li)
                 with role("attn_proj"):
                     h = h + block.attn.proj(att.reshape(Bb, -1))
                 h = self._serve_mlp(block, h)
@@ -343,18 +349,18 @@ class _ServingMixin:
                 with role("norm"):
                     x = block.ln1(h)
                 with role("attn_proj"):
-                    qkv = block.attn.qkv(x.reshape(Bb * K1, -1)).reshape(
-                        Bb, K1, 3, block.attn.n_heads, block.attn.d_head)
-                    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-                with role("cache_write"):
-                    k_pool = k_pool.at[li].set(write_span_kv(
-                        k_pool[li], k, bts, start, n_valid))
-                    v_pool = v_pool.at[li].set(write_span_kv(
-                        v_pool[li], v, bts, start, n_valid))
-                with role("attn"):      # the pools' layer views too
-                    att = paged_verify_attention(
-                        q, k_pool[li], v_pool[li], bts, start,
-                        scale=scale, tp_mesh=tp_mesh)
+                    q, k, v = jnp.split(
+                        block.attn.qkv(x.reshape(Bb * K1, -1)).reshape(
+                            Bb, K1, -1), 3, axis=-1)
+                    q = q.reshape(Bb, K1, block.attn.n_heads,
+                                  block.attn.d_head)
+                k_pool = write_span_kv(k_pool, k, bts, start, n_valid,
+                                       layer=li)
+                v_pool = write_span_kv(v_pool, v, bts, start, n_valid,
+                                       layer=li)
+                att = paged_verify_attention(
+                    q, k_pool, v_pool, bts, start, scale=scale,
+                    tp_mesh=tp_mesh, layer=li)
                 with role("attn_proj"):
                     h = h + block.attn.proj(att.reshape(Bb * K1, -1)) \
                         .reshape(Bb, K1, -1)

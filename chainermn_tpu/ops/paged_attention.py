@@ -120,6 +120,12 @@ def _dense_decode(q, k, v, ctx_len, scale):
     return out.astype(q.dtype)
 
 
+def _page_slots(pool, layer):
+    """``S`` of one layer's ``[P, S, ...]`` pool, or of the whole ``[L,
+    P, S, ...]`` pool addressed at ``layer``."""
+    return pool.shape[1 if layer is None else 2]
+
+
 def _gather_pages(pool, layer, block_table):
     """The pages a block table names: from one layer's ``[P, S, ...]``
     pool, or (a ``layer``, a Python number or a traced scalar: the cache
@@ -209,7 +215,7 @@ def paged_prefill_attention(q, k_pool, v_pool, block_table_row, start,
     :func:`paged_latent_attention`'s.
     """
     T, H, D = q.shape
-    S = k_pool.shape[1] if kv_heads is None else k_pool.shape[-2]
+    S = _page_slots(k_pool, layer)
     N = block_table_row.shape[0]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     if kv_heads is not None:
@@ -225,8 +231,8 @@ def paged_prefill_attention(q, k_pool, v_pool, block_table_row, start,
         return _grouped_attention(q, kv[..., :G * D].reshape(K, G, D),
                                   kv[..., G * D:].reshape(K, G, D), qpos,
                                   kpos, scale, window)
-    k = k_pool[block_table_row].reshape(N * S, H, D)
-    v = v_pool[block_table_row].reshape(N * S, H, D)
+    k = _gather_pages(k_pool, layer, block_table_row).reshape(N * S, H, D)
+    v = _gather_pages(v_pool, layer, block_table_row).reshape(N * S, H, D)
     s = jnp.einsum("thd,khd->htk", q, k,
                    preferred_element_type=jnp.float32) * scale
     kpos = lax.broadcasted_iota(jnp.int32, (1, 1, N * S), 2)
@@ -240,7 +246,8 @@ def paged_prefill_attention(q, k_pool, v_pool, block_table_row, start,
 
 @role("attn")
 def paged_verify_attention(q, k_pool, v_pool, block_table, start,
-                           scale=None, tp_mesh=None, tp_axis="tp"):
+                           scale=None, tp_mesh=None, tp_axis="tp",
+                           layer=None):
     """Multi-query verify attention for SPECULATIVE decoding (round 20).
 
     ``q``: ``[B, K1, H, D]`` — ``K1 = K + 1`` query tokens per sequence
@@ -257,7 +264,8 @@ def paged_verify_attention(q, k_pool, v_pool, block_table, start,
     constant, never the context length, so no ``[T, T]`` score matrix
     ever forms (the committed ``spec_verify`` census config pins this
     and the one-gather-per-pool fact).  Returns ``[B, K1, H, D]`` in
-    ``q.dtype``.
+    ``q.dtype``.  The pools and ``layer``: as
+    :func:`paged_decode_attention`'s.
 
     This is the whole speculative bargain in one shape: the dense-side
     cost of scoring K extra tokens rides the SAME cache-byte reads the
@@ -266,14 +274,13 @@ def paged_verify_attention(q, k_pool, v_pool, block_table, start,
     accepted)``.
     """
     B, K1, H, D = q.shape
-    S = k_pool.shape[1]
+    S = _page_slots(k_pool, layer)
     N = block_table.shape[1]
     scale = scale if scale is not None else 1.0 / (D ** 0.5)
     q = _constrain_heads(q, 2, tp_mesh, tp_axis)
-    k = _constrain_heads(k_pool[block_table], 3, tp_mesh, tp_axis)
-    v = _constrain_heads(v_pool[block_table], 3, tp_mesh, tp_axis)
-    k = k.reshape(B, N * S, H, D)
-    v = v.reshape(B, N * S, H, D)
+    k, v = (_constrain_heads(
+        _gather_pages(pool, layer, block_table).reshape(B, N * S, H, D),
+        2, tp_mesh, tp_axis) for pool in (k_pool, v_pool))
     s = jnp.einsum("bjhd,bkhd->bhjk", q, k,
                    preferred_element_type=jnp.float32) * scale
     kpos = lax.broadcasted_iota(jnp.int32, (1, 1, 1, N * S), 3)
@@ -479,8 +486,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, ctx_len,
     """One decode step of attention for a batch of cached sequences.
 
     q: ``[B, H, D]`` — ONE query token per sequence (the just-appended
-    position).  ``k_pool``/``v_pool``: ``[P, S, H, D]`` page pools
-    (``P`` pages of ``S`` token slots).  ``block_table``: ``[B, N]``
+    position).  ``k_pool``/``v_pool``: ``[P, S, H · D]`` page pools
+    (``P`` pages of ``S`` token slots, a token's heads side by side in
+    the lanes; ``[P, S, H, D]`` reads the same), or with a ``layer`` the
+    whole ``[L, P, S, H · D]`` pools, gathered from at that layer with
+    no slice taken out first.  ``block_table``: ``[B, N]``
     int32 page ids — sequence ``b``'s token ``t`` lives in page
     ``block_table[b, t // S]`` at slot ``t % S``; entries past the live
     prefix may hold any valid page id (their positions are masked by
@@ -519,15 +529,17 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, ctx_len,
                 layer=layer, window=window, scale=scale)
         return _grouped_decode(q, k_pool, block_table, ctx_len, scale,
                                window, layer, kv_heads)
-    P, S = k_pool.shape[0], k_pool.shape[1]
+    S = _page_slots(k_pool, layer)
     N = block_table.shape[1]
     mode = paged_attn_mode(mode)
     q = _constrain_heads(q, 1, tp_mesh, tp_axis)
 
     # the gather: every cached byte of the batch's context, exactly once,
-    # addressed through the block table (pages, not contiguous buffers)
-    k_pages = _constrain_heads(k_pool[block_table], 3, tp_mesh, tp_axis)
-    v_pages = _constrain_heads(v_pool[block_table], 3, tp_mesh, tp_axis)
+    # addressed through the block table (pages, not contiguous buffers);
+    # the heads are split on what it brought, never on the pool
+    k_pages, v_pages = (_constrain_heads(
+        _gather_pages(pool, layer, block_table).reshape(B, N, S, H, D),
+        3, tp_mesh, tp_axis) for pool in (k_pool, v_pool))
 
     if mode == "dense":
         k = k_pages.reshape(B, N * S, H, D)

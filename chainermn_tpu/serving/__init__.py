@@ -9,7 +9,7 @@ tensor-parallel paged decode.  Pieces, each its own module:
 * :mod:`.page_allocator` — refcounted host-side block allocator (page
   ids, per-sequence block tables, prefix-hash trie for copy-on-write
   prompt sharing, typed OOM);
-* :mod:`.kv_cache` — the preallocated ``[L, P, S, H, D]`` device pools
+* :mod:`.kv_cache` — the preallocated ``[L, P, S, *entry]`` device pools
   (bf16 pages by default) + in-graph scatter writers, the fork-on-write
   page copy, and the disaggregation transfer receiver;
 * :mod:`ops.paged_attention <chainermn_tpu.ops.paged_attention>` — the

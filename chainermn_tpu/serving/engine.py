@@ -4,10 +4,10 @@ The reference's marquee trick — keep the device busy by overlapping the
 slow path behind the hot loop — applied to inference.  The engine owns
 the scheduling, the pages, the buckets and the spans; the MODEL owns its
 block (PR 27): it declares what a token leaves in the cache
-(``serve_cache_entry()``: K and V of ``[H, D]`` for a GPT-2-shaped
+(``serve_cache_entry()``: K and V of ``[H · D]`` for a GPT-2-shaped
 model, one latent vector for a latent-attention one) and provides the
 programs' bodies (``serve_prefill`` / ``serve_suffix_prefill`` /
-``serve_decode``) over per-layer views of the page pools.  Two compiled
+``serve_decode``) over the page pools.  Two compiled
 programs share the pools:
 
 * **prefill** (one request at a time): the prompt runs through the
@@ -1494,7 +1494,7 @@ class ServingEngine:
         Bb = _bucket(n, self.batch_buckets, "batch")
         tags = {"batch": n, "bucket": Bb, "step": self.decode_steps} \
             if obs_on else None
-        if obs_on and self.cache_groups > 1:
+        if obs_on:
             # what a sound step reads of each kind of cache: the whole
             # context in the full group (tokens), a window's worth of it
             # in a window group, one state a lane in a state group

@@ -3,8 +3,12 @@
 What a token leaves in the cache, per layer, is the MODEL's to declare
 (``serve_cache_entry()``): a tuple of per-token shapes, one pool array
 ``[L, P, S, *shape]`` (layers × pages × page slots × the entry) for each.
-A GPT-2-shaped model declares K and V of ``[H, D]`` — two pools, named
-``k_pool``/``v_pool`` here; a latent-attention model declares one vector
+A GPT-2-shaped model declares K and V of ``[H · D]`` — two pools, named
+``k_pool``/``v_pool`` here, a token's heads side by side in the lanes
+(an entry whose minor dimension is whole lane tiles is stored as the
+programs compute on it; one of ``[H, D]`` with ``D`` = 64 was converted
+whole, 805 MB a pool, four times a step: PR 43); a latent-attention
+model declares one vector
 (``[kv_rank + rope_dim]``) — one pool.  The pools are allocated ONCE at
 engine construction and only ever updated functionally inside the
 compiled prefill/decode programs (donated on real accelerators, so XLA
@@ -79,7 +83,7 @@ def write_prompt_kv(pool_l, kv, block_table_row, true_len, layer=None):
     possibly padded past ``true_len``).  ``block_table_row``: ``[N]``
     page ids covering at least ``true_len`` positions.  Positions
     ``>= true_len`` scatter to the out-of-range page and are dropped.
-    ``layer``: see :func:`_scatter` (the prompt and token writers take it).
+    ``layer``: see :func:`_scatter` (every writer takes it).
     """
     P, S = _geometry(pool_l, layer)
     T = kv.shape[0]
@@ -93,7 +97,7 @@ def write_prompt_kv_at(pool_l, kv, block_table_row, start, true_len,
                        layer=None):
     """Offset prompt writer for the prefix-sharing suffix prefill.
 
-    ``kv``: ``[T, H, D]`` SUFFIX K/V — position ``t`` of the suffix
+    ``kv``: ``[T, *entry]`` SUFFIX K/V — position ``t`` of the suffix
     lives at absolute position ``start + t``, so the scatter addresses
     ``block_table_row[(start + t) // S]`` slot ``(start + t) % S``.
     Positions ``>= true_len`` (suffix padding) drop.  ``start = 0``
@@ -131,7 +135,7 @@ def insert_pages(pool, block, rows):
 def write_token_kv(pool_l, kv, block_tables, pos, layer=None):
     """Write one decode token per batch lane into one layer's pool.
 
-    ``kv``: ``[B, H, D]``.  ``pos``: ``[B]`` int32 position being
+    ``kv``: ``[B, *entry]``.  ``pos``: ``[B]`` int32 position being
     written; ``pos < 0`` marks an idle lane (dropped).  ``block_tables``:
     ``[B, N]``.
     """
@@ -143,10 +147,10 @@ def write_token_kv(pool_l, kv, block_tables, pos, layer=None):
 
 
 @role("cache_write")
-def write_span_kv(pool_l, kv, block_tables, start, n_valid):
+def write_span_kv(pool_l, kv, block_tables, start, n_valid, layer=None):
     """Write a SPAN of speculative tokens per batch lane (round 20).
 
-    ``kv``: ``[B, K1, H, D]`` — token ``j`` of lane ``b`` lands at
+    ``kv``: ``[B, K1, *entry]`` — token ``j`` of lane ``b`` lands at
     absolute position ``start[b] + j``.  ``start``: ``[B]`` int32;
     ``start < 0`` marks an idle lane (every write dropped).
     ``n_valid``: ``[B]`` int32 — only the first ``n_valid[b]`` span
@@ -159,7 +163,7 @@ def write_span_kv(pool_l, kv, block_tables, start, n_valid):
     and the next step's writes overwrite them before they are ever
     visible.
     """
-    P, S = pool_l.shape[0], pool_l.shape[1]
+    P, S = _geometry(pool_l, layer)
     B, K1 = kv.shape[0], kv.shape[1]
     b = jnp.arange(B, dtype=jnp.int32)[:, None]
     j = jnp.arange(K1, dtype=jnp.int32)[None, :]
@@ -167,7 +171,7 @@ def write_span_kv(pool_l, kv, block_tables, start, n_valid):
     live = (start[:, None] >= 0) & (j < n_valid[:, None])
     safe = jnp.maximum(posn, 0)
     pages = jnp.where(live, block_tables[b, safe // S], P)
-    return _scatter(pool_l, None, pages, safe % S, kv)
+    return _scatter(pool_l, layer, pages, safe % S, kv)
 
 
 class PagedKVCache:

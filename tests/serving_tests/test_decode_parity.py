@@ -41,10 +41,8 @@ class Harness:
                  mode="paged", dtype=jnp.float32):
         self.model = model
         self.state = extract_state(model)
-        blk = model.blocks[0].attn
         self.kv = PagedKVCache(len(list(model.blocks)), num_pages,
-                               page_size,
-                               ((blk.n_heads, blk.d_head),) * 2,
+                               page_size, model.serve_cache_entry(),
                                dtype=dtype)
         self.alloc = BlockAllocator(num_pages, page_size)
         self.N = max_context // page_size

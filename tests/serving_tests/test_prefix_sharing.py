@@ -342,7 +342,6 @@ def test_tp_decode_matches_single_chip():
 
     # program-level logits parity through the sharded pools
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
-    from chainermn_tpu.ops.paged_attention import head_sharding
     state = extract_state(model)
     blk = model.blocks[0].attn
     rng = np.random.RandomState(9)
@@ -359,7 +358,7 @@ def test_tp_decode_matches_single_chip():
     _, _, lg_ref, _ = decode_program(model, state, k, v, *args,
                                      mode="paged")
     mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
-    sh = head_sharding(mesh, 5, 3)
+    sh = model.serve_pool_sharding(mesh)
     repl = NamedSharding(mesh, PartitionSpec())
     k_sh, v_sh = jax.device_put(k, sh), jax.device_put(v, sh)
     state_sh = jax.device_put(state, repl)

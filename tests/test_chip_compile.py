@@ -279,7 +279,7 @@ def test_softmax_cotangent_kernel_compiles(one_chip, no_persistent_cache,
 @pytest.fixture(scope="module")
 def serving(one_chip):
     """The d768 x 12 model, its state as shapes on the described chip,
-    and the pool pair ``[L, P, S, H, D]`` in bf16."""
+    and the pool pair ``[L, P, S, H · D]`` in bf16."""
     from chainermn_tpu.core.link import extract_state
     from chainermn_tpu.models import TransformerLM
     model = TransformerLM(n_vocab=VOCAB, d_model=D_MODEL, n_heads=N_HEADS,
@@ -290,23 +290,27 @@ def serving(one_chip):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     state = jax.tree.map(lambda a: spec(a.shape, a.dtype),
                          extract_state(model))
-    pool = spec((N_LAYERS, PAGES, PAGE, N_HEADS, D_HEAD), jnp.bfloat16)
-    pool_bytes = N_LAYERS * PAGES * PAGE * N_HEADS * D_HEAD * 2
-    return model, state, pool, pool_bytes, spec
+    (entry, _) = model.serve_cache_entry()
+    pool = spec((N_LAYERS, PAGES, PAGE) + entry, jnp.bfloat16)
+    return model, state, pool, spec
 
 
-def _assert_pools_aliased(compiled, pool_bytes):
-    # the programs write pages with a whole-pool .at[li].set — cheap
-    # only when XLA updates the donated pools in place
-    ma = compiled.memory_analysis()
-    assert ma.alias_size_in_bytes >= 2 * pool_bytes, ma
+def _assert_pools_in_place(compiled, text, pool):
+    """Both pools donated and aliased, stored in ONE layout (rows of
+    whole lane tiles, minor-most), never copied and never cut into a
+    layer's slab: with heads of 64 as a minor axis of their own the chip
+    kept each pool in one layout and computed on another, and converted
+    all of it four times a step (PERF.md section 6, PR 43)."""
+    _assert_no_pool_is_copied(compiled, text, [pool, pool])
+    slab = "bf16[%s]" % ",".join(map(str, pool.shape[1:]))
+    assert slab not in text and slab.replace("[", "[1,") not in text
 
 
 @pytest.mark.parametrize("bucket", [16, MAX_CONTEXT])
 def test_prefill_program_compiles_with_pools_donated(
         serving, no_persistent_cache, monkeypatch, bucket):
     from chainermn_tpu.serving import prefill_program
-    model, state, pool, pool_bytes, spec = serving
+    model, state, pool, spec = serving
     # the dispatcher would take its CPU branch here: steer it onto the
     # compiled kernel, as it goes on the chip
     monkeypatch.setattr(fa, "_on_tpu", lambda: True)
@@ -315,34 +319,46 @@ def test_prefill_program_compiles_with_pools_donated(
         spec((1, bucket), jnp.int32), spec((), jnp.int32),
         spec((MAX_CONTEXT // PAGE,), jnp.int32), donate_argnums=(1, 2))
     assert "_flash_kernel" in text and "tpu_custom_call" in text
-    _assert_pools_aliased(compiled, pool_bytes)
+    _assert_pools_in_place(compiled, text, pool)
 
 
-def test_decode_program_compiles_with_pools_donated(serving,
-                                                    no_persistent_cache):
+def test_suffix_prefill_program_compiles_with_pools_donated(
+        serving, no_persistent_cache):
+    from chainermn_tpu.serving import prefix_prefill_program
+    model, state, pool, spec = serving
+    compiled, text = _compile(
+        functools.partial(prefix_prefill_program, model), state, pool, pool,
+        spec((1, 64), jnp.int32), spec((), jnp.int32), spec((), jnp.int32),
+        spec((MAX_CONTEXT // PAGE,), jnp.int32), donate_argnums=(1, 2))
+    _assert_pools_in_place(compiled, text, pool)
+
+
+@pytest.mark.parametrize("lanes", [1, MAX_BATCH])
+def test_decode_program_compiles_with_pools_donated(
+        serving, no_persistent_cache, lanes):
     from chainermn_tpu.serving import decode_program
-    model, state, pool, pool_bytes, spec = serving
-    compiled, _ = _compile(
+    model, state, pool, spec = serving
+    compiled, text = _compile(
         functools.partial(decode_program, model, mode="paged"),
-        state, pool, pool, spec((MAX_BATCH,), jnp.int32),
-        spec((MAX_BATCH,), jnp.int32),
-        spec((MAX_BATCH, MAX_CONTEXT // PAGE), jnp.int32),
+        state, pool, pool, spec((lanes,), jnp.int32),
+        spec((lanes,), jnp.int32),
+        spec((lanes, MAX_CONTEXT // PAGE), jnp.int32),
         donate_argnums=(1, 2))
-    _assert_pools_aliased(compiled, pool_bytes)
+    _assert_pools_in_place(compiled, text, pool)
 
 
 def test_spec_verify_program_compiles_with_pools_donated(
         serving, no_persistent_cache):
     from chainermn_tpu.serving import spec_verify_program
-    model, state, pool, pool_bytes, spec = serving
+    model, state, pool, spec = serving
     spec_k = 4
-    compiled, _ = _compile(
+    compiled, text = _compile(
         functools.partial(spec_verify_program, model),
         state, pool, pool, spec((MAX_BATCH, spec_k + 1), jnp.int32),
         spec((MAX_BATCH,), jnp.int32), spec((MAX_BATCH,), jnp.int32),
         spec((MAX_BATCH, MAX_CONTEXT // PAGE), jnp.int32),
         donate_argnums=(1, 2))
-    _assert_pools_aliased(compiled, pool_bytes)
+    _assert_pools_in_place(compiled, text, pool)
 
 
 # -- the latent-attention MoE share (kimi-k2.6-share), published widths ------

@@ -112,7 +112,7 @@ def test_full_t_detector_is_alive():
         state, k_pool, v_pool, tokens, jnp.int32(g["prefill_T"]),
         jnp.zeros(N, jnp.int32))
     facts = serving_census._census_facts(
-        jaxpr.jaxpr, tuple(k_pool.shape[1:]), g["prefill_T"])
+        jaxpr.jaxpr, tuple(k_pool.shape), g["prefill_T"])
     assert facts["full_t_score_dots"] >= g["n_layers"]
 
 

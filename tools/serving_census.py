@@ -97,9 +97,8 @@ def _vertical():
                           max_len=g["max_len"], seed=0)
     state = extract_state(model)
     L, P, S = g["n_layers"], g["num_pages"], g["page_size"]
-    H, D = g["n_heads"], g["d_model"] // g["n_heads"]
-    pools = (jnp.zeros((L, P, S, H, D), jnp.float32),
-             jnp.zeros((L, P, S, H, D), jnp.float32))
+    pools = tuple(jnp.zeros((L, P, S) + shape, jnp.float32)
+                  for shape in model.serve_cache_entry())
     N = g["max_context"] // S
     rng = np.random.RandomState(0)
     return model, state, pools, N, rng
@@ -129,11 +128,12 @@ def _walk_eqns(jaxpr, *, into_pallas):
     yield from rec(jaxpr, False)
 
 
-def _census_facts(jaxpr, pool_layer_shape, t_full):
+def _census_facts(jaxpr, pool_shape, t_full):
     """Structure facts of one traced serving program.
 
-    ``pool_layer_shape``: the per-layer pool shape ``(P, S, H, D)`` —
-    gathers/scatters are attributed to the KV pool by operand shape
+    ``pool_shape``: the whole pool's shape ``(L, P, S, H · D)``, which
+    the programs gather from and scatter into at a layer — gathers/
+    scatters are attributed to the KV pool by operand shape
     (embedding lookups are gathers too; shape is the discriminator).
     ``t_full``: the full-T threshold — a dot_general output with TWO
     dims ``>= t_full`` is a dense [T, T] score matrix.  Pallas kernel
@@ -152,10 +152,10 @@ def _census_facts(jaxpr, pool_layer_shape, t_full):
             elif "_flash_kernel" in kname:
                 facts["flash_fwd_kernels"] += 1
         elif name == "gather":
-            if tuple(eqn.invars[0].aval.shape) == pool_layer_shape:
+            if tuple(eqn.invars[0].aval.shape) == pool_shape:
                 facts["pool_gathers"] += 1
         elif name == "scatter":
-            if tuple(eqn.invars[0].aval.shape) == pool_layer_shape:
+            if tuple(eqn.invars[0].aval.shape) == pool_shape:
                 facts["pool_scatters"] += 1
         elif name == "dot_general" and not inside:
             big = sum(1 for d in eqn.outvars[0].aval.shape
@@ -195,7 +195,7 @@ def decode_census(mode="paged"):
         lambda s, k, v, t, p, b: decode_program(
             model, s, k, v, t, p, b, mode=mode))(
         state, k_pool, v_pool, toks, pos, bts)
-    pool_shape = tuple(k_pool.shape[1:])
+    pool_shape = tuple(k_pool.shape)
     facts = _census_facts(jaxpr.jaxpr, pool_shape, g["max_context"])
     facts["attn_mode"] = mode
     return facts
@@ -219,7 +219,7 @@ def prefill_census():
             lambda s, k, v, t, tl, b: prefill_program(
                 model, s, k, v, t, tl, b))(
             state, k_pool, v_pool, tokens, jnp.int32(T), bt_row)
-    pool_shape = tuple(k_pool.shape[1:])
+    pool_shape = tuple(k_pool.shape)
     return _census_facts(jaxpr.jaxpr, pool_shape, g["prefill_T"])
 
 
@@ -244,7 +244,7 @@ def prefix_prefill_census():
             model, s, k, v, t, tl, st, b))(
         state, k_pool, v_pool, tokens, jnp.int32(T),
         jnp.int32(g["prefix_start"]), bt_row)
-    pool_shape = tuple(k_pool.shape[1:])
+    pool_shape = tuple(k_pool.shape)
     return _census_facts(jaxpr.jaxpr, pool_shape, g["max_context"])
 
 
@@ -269,10 +269,9 @@ def transfer_insert_census():
 
     g = GEOMETRY
     L, P, S = g["n_layers"], g["num_pages"], g["page_size"]
-    H, D = g["n_heads"], g["d_model"] // g["n_heads"]
     nb = g["transfer_pages"]
-    pool = jnp.zeros((L, P, S, H, D), jnp.float32)
-    block = jnp.zeros((L, nb, S, H, D), jnp.float32)
+    pool = jnp.zeros((L, P, S, g["d_model"]), jnp.float32)
+    block = jnp.zeros((L, nb, S, g["d_model"]), jnp.float32)
     rows = jnp.zeros(nb, jnp.int32)
     jaxpr = jax.make_jaxpr(insert_pages)(pool, block, rows)
     # attribute by the FULL pool shape: the insert scatters all layers
@@ -309,7 +308,7 @@ def spec_verify_census():
         lambda s, k, v, t, st, nv, b: spec_verify_program(
             model, s, k, v, t, st, nv, b))(
         state, k_pool, v_pool, toks, start, n_valid, bts)
-    pool_shape = tuple(k_pool.shape[1:])
+    pool_shape = tuple(k_pool.shape)
     facts = _census_facts(jaxpr.jaxpr, pool_shape, g["max_context"])
     facts["queries_per_dispatch"] = K1
     return facts
@@ -339,7 +338,7 @@ def chunked_prefill_census():
             model, s, k, v, t, tl, st, b))(
         state, k_pool, v_pool, tokens, jnp.int32(T),
         jnp.int32(g["chunk_T"]), bt_row)
-    pool_shape = tuple(k_pool.shape[1:])
+    pool_shape = tuple(k_pool.shape)
     return _census_facts(jaxpr.jaxpr, pool_shape, g["max_context"])
 
 
