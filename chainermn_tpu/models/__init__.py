@@ -13,6 +13,8 @@ from .moe_transformer import (MoETransformerLM, MoETransformerBlock,
 from .latent_moe import LatentMoELM, LatentMoEBlock, LatentAttention
 from .window_moe import (WindowMoELM, WindowMoEBlock,
                          GatedGroupedAttention)
+from .prerouted_moe import (PreroutedMoELM, PreroutedMoEBlock,
+                            GroupedAttention)
 from .hybrid_delta import (HybridDeltaLM, HybridDeltaBlock, DeltaMixer,
                            FullMixer)
 from .looped import LoopedLM, LoopedBlock, LoopedAttention
@@ -27,6 +29,7 @@ __all__ = ["MLP", "Classifier", "ResNet", "ResNet18", "ResNet50",
            "MultiHeadAttention", "MoETransformerLM", "MoETransformerBlock",
            "MoEFeedForward", "LatentMoELM", "LatentMoEBlock",
            "LatentAttention", "WindowMoELM", "WindowMoEBlock",
-           "GatedGroupedAttention", "HybridDeltaLM", "HybridDeltaBlock",
+           "GatedGroupedAttention", "PreroutedMoELM", "PreroutedMoEBlock",
+           "GroupedAttention", "HybridDeltaLM", "HybridDeltaBlock",
            "DeltaMixer", "FullMixer", "LoopedLM", "LoopedBlock",
            "LoopedAttention", "AlexNet", "NIN", "VGG16", "GoogLeNet"]

@@ -26,6 +26,10 @@ context.  A whole prompt attends through the flash dispatcher
 window only the band's tiles walked); anything that reads the cache back
 goes through ``ops.paged_attention``.
 
+The cache's groups, the prompt's attention and the three serving bodies
+live in :class:`WindowCacheLM`, which a model with another block shares
+(``models/prerouted_moe.py``); ``WindowMoELM`` adds the ``laguna`` block.
+
 The class serves through :class:`~chainermn_tpu.serving.ServingEngine`;
 it has no speculative verify and no head-sharded pool, and the engine
 refuses those for it.  It does not train: the grouped and windowed
@@ -49,7 +53,8 @@ from ..serving.kv_cache import (write_prompt_kv, write_prompt_kv_at,
                                 write_token_kv)
 from .latent_moe import SwiGLU, _last_row, _rotate, yarn_inv_freq
 
-__all__ = ["GatedGroupedAttention", "WindowMoEBlock", "WindowMoELM"]
+__all__ = ["GatedGroupedAttention", "WindowMoEBlock", "WindowCacheLM",
+           "WindowMoELM"]
 
 
 def _entry(k, v):
@@ -156,25 +161,21 @@ class WindowMoEBlock(Chain):
             return y + self.shared(x), counts
 
 
-class WindowMoELM(Chain):
-    """Causal LM whose layer ``l`` has ``layer_heads[l]`` query heads and
-    is a window layer where ``layer_windows[l]`` is a number (every
-    window layer the same one), a full layer where it is ``None``;
-    ``layer_dense[l]`` makes its feed-forward the dense SwiGLU.
+class WindowCacheLM(Chain):
+    """What the language models of window and full attention layers side
+    by side share: the cache by two groups of layers, the prompt's
+    attention and the three serving bodies.  A subclass builds ``embed``,
+    ``blocks`` (each with ``attn.window``), ``ln_f`` and ``head``, and
+    gives the block's two halves: ``_project(block, h, pos)`` -> ``(q, k,
+    v, extra)`` ahead of the attention and ``_block(block, h, att, extra,
+    valid, counts)`` after it, which appends the layer's held experts'
+    copy counts to ``counts``.
 
-    ``held = (first, count)``: the routed experts this chip holds of
-    each layer's ``n_experts``.  ``rope_full``: ``dict(theta, factor,
-    original_max, beta_fast, beta_slow, attention_factor, partial)``
-    (YaRN over ``partial`` of a head); ``rope_window``: ``dict(theta,
-    partial)`` (plain).  ``param_dtype``: the dtype a server holds the
-    parameters in; computation follows it, with norm, rotary, router,
-    gate and softmax statistics in float32.
-    """
+    ``layer_windows[l]``: a window layer's window (every window layer the
+    same one) or ``None`` for a full layer; ``n_kv`` K/V heads of
+    ``head_dim`` a token in every layer."""
 
-    def __init__(self, n_vocab, d_model, layer_heads, layer_windows,
-                 layer_dense, n_kv, head_dim, d_ff, d_expert, n_experts,
-                 held, k, routed_scale, rope_full, rope_window, eps=1e-6,
-                 max_len=4096, param_dtype=None, seed=0):
+    def __init__(self, layer_windows, n_kv, head_dim, max_len, param_dtype):
         super().__init__()
         self.max_len = int(max_len)
         self.param_dtype = param_dtype
@@ -190,36 +191,6 @@ class WindowMoELM(Chain):
                             if w is None]
         self.window_layers = [i for i, w in enumerate(layer_windows)
                               if w is not None]
-        rot_full = int(head_dim * rope_full["partial"])
-        rot_window = int(head_dim * rope_window["partial"])
-        kinds = {
-            False: dict(
-                inv_freq=yarn_inv_freq(
-                    rot_full, rope_full["theta"], rope_full["factor"],
-                    rope_full["original_max"], rope_full["beta_fast"],
-                    rope_full["beta_slow"]),
-                rot_dim=rot_full,
-                rot_factor=rope_full["attention_factor"], window=None),
-            True: dict(
-                inv_freq=rope_window["theta"] ** (
-                    -np.arange(0, rot_window, 2) / rot_window),
-                rot_dim=rot_window, rot_factor=1.0, window=self.window)}
-        experts = (d_expert, n_experts, held, k, routed_scale)
-        with self.init_scope():
-            self.embed = L.EmbedID(n_vocab, d_model, seed=seed)
-            self.blocks = ChainList(*[
-                WindowMoEBlock(
-                    d_model,
-                    dict(n_heads=layer_heads[i], n_kv=n_kv,
-                         head_dim=head_dim,
-                         **kinds[layer_windows[i] is not None]),
-                    d_ff=d_ff, eps=eps,
-                    experts=None if layer_dense[i] else experts,
-                    seed=seed + 100 * (i + 1))
-                for i in range(len(layer_heads))])
-            self.ln_f = L.RMSNorm(d_model, eps)
-            self.head = L.Linear(d_model, n_vocab, nobias=True,
-                                 seed=seed + 999)
 
     # -- the whole forward (tests) ------------------------------------------
 
@@ -231,9 +202,9 @@ class WindowMoELM(Chain):
                 h = self.embed(tokens)
             for block in self.blocks:
                 with jax.named_scope(f"blocks/{block.name}"):
-                    q, k, v, gate = self._project(block, h, pos)
+                    q, k, v, extra = self._project(block, h, pos)
                     h = self._block(block, h, self._prompt_attention(
-                        block.attn, q, k, v), gate, None, [])
+                        block.attn, q, k, v), extra, None, [])
             with role("head"):
                 return self.head(self.ln_f(h))
         return jnp.stack([one(row) for row in x])
@@ -300,23 +271,11 @@ class WindowMoELM(Chain):
                 where[i] = (g, j, bts[g])
         return [(block,) + where[i] for i, block in enumerate(self.blocks)]
 
-    @staticmethod
-    def _project(block, h, pos):
-        """``block.attn.project`` of the normed ``h``."""
-        with role("norm"):
-            x = block.ln1(h)
-        return block.attn.project(x, pos)
+    def _project(self, block, h, pos):
+        raise NotImplementedError
 
-    def _block(self, block, h, att, gate, valid, counts):
-        with role("attn_proj"):
-            h = h + block.attn.output(att, gate)
-        with role("norm"):
-            x = block.ln2(h)
-        y, c = block.ffn(x, valid)
-        if c is not None:
-            counts.append(c)
-        with role("experts" if block.routed else "mlp"):
-            return h + y
+    def _block(self, block, h, att, extra, valid, counts):
+        raise NotImplementedError
 
     def _finish(self, h_last, counts):
         with role("head"):
@@ -342,12 +301,12 @@ class WindowMoELM(Chain):
         counts = []
         for block, p, li, bt in self._layers(pools, bt_rows):
             with jax.named_scope(f"blocks/{block.name}"):
-                q, k, v, gate = self._project(block, h, pos)
+                q, k, v, extra = self._project(block, h, pos)
                 with role("cache_write"):
                     pools[p] = write_prompt_kv(pools[p], _entry(k, v), bt,
                                                true_len, layer=li)
                 h = self._block(block, h, self._prompt_attention(
-                    block.attn, q, k, v), gate, valid, counts)
+                    block.attn, q, k, v), extra, valid, counts)
         logits, counts = self._finish(_last_row(h, true_len), counts)
         with role("head"):
             return tuple(pools), logits[0], (counts,)
@@ -369,7 +328,7 @@ class WindowMoELM(Chain):
         counts = []
         for block, p, li, bt in self._layers(pools, bt_rows):
             with jax.named_scope(f"blocks/{block.name}"):
-                q, k, v, gate = self._project(block, h, pos)
+                q, k, v, extra = self._project(block, h, pos)
                 with role("cache_write"):
                     pools[p] = write_prompt_kv_at(
                         pools[p], _entry(k, v), bt, start, true_len,
@@ -378,7 +337,7 @@ class WindowMoELM(Chain):
                     q, pools[p], None, bt, start, true_len,
                     scale=self.scale, window=block.attn.window, layer=li,
                     kv_heads=self.n_kv)
-                h = self._block(block, h, att, gate, valid, counts)
+                h = self._block(block, h, att, extra, valid, counts)
         logits, counts = self._finish(_last_row(h, true_len), counts)
         with role("head"):
             return tuple(pools), logits[0], (counts,)
@@ -401,13 +360,84 @@ class WindowMoELM(Chain):
         counts = []
         for block, p, li, bt in self._layers(pools, bts):
             with jax.named_scope(f"blocks/{block.name}"):
-                q, k, v, gate = self._project(block, h, safe)
+                q, k, v, extra = self._project(block, h, safe)
                 with role("cache_write"):
                     pools[p] = write_token_kv(pools[p], _entry(k, v), bt,
                                               pos, layer=li)
                 att = paged_decode_attention(
                     q, pools[p], None, bt, ctx, scale=self.scale,
                     window=block.attn.window, layer=li, kv_heads=self.n_kv)
-                h = self._block(block, h, att, gate, live, counts)
+                h = self._block(block, h, att, extra, live, counts)
         logits, counts = self._finish(h, counts)
         return tuple(pools), logits, (counts,)
+
+
+class WindowMoELM(WindowCacheLM):
+    """Causal LM whose layer ``l`` has ``layer_heads[l]`` query heads and
+    is a window layer where ``layer_windows[l]`` is a number (every
+    window layer the same one), a full layer where it is ``None``;
+    ``layer_dense[l]`` makes its feed-forward the dense SwiGLU.
+
+    ``held = (first, count)``: the routed experts this chip holds of
+    each layer's ``n_experts``.  ``rope_full``: ``dict(theta, factor,
+    original_max, beta_fast, beta_slow, attention_factor, partial)``
+    (YaRN over ``partial`` of a head); ``rope_window``: ``dict(theta,
+    partial)`` (plain).  ``param_dtype``: the dtype a server holds the
+    parameters in; computation follows it, with norm, rotary, router,
+    gate and softmax statistics in float32.
+    """
+
+    def __init__(self, n_vocab, d_model, layer_heads, layer_windows,
+                 layer_dense, n_kv, head_dim, d_ff, d_expert, n_experts,
+                 held, k, routed_scale, rope_full, rope_window, eps=1e-6,
+                 max_len=4096, param_dtype=None, seed=0):
+        super().__init__(layer_windows, n_kv, head_dim, max_len,
+                         param_dtype)
+        rot_full = int(head_dim * rope_full["partial"])
+        rot_window = int(head_dim * rope_window["partial"])
+        kinds = {
+            False: dict(
+                inv_freq=yarn_inv_freq(
+                    rot_full, rope_full["theta"], rope_full["factor"],
+                    rope_full["original_max"], rope_full["beta_fast"],
+                    rope_full["beta_slow"]),
+                rot_dim=rot_full,
+                rot_factor=rope_full["attention_factor"], window=None),
+            True: dict(
+                inv_freq=rope_window["theta"] ** (
+                    -np.arange(0, rot_window, 2) / rot_window),
+                rot_dim=rot_window, rot_factor=1.0, window=self.window)}
+        experts = (d_expert, n_experts, held, k, routed_scale)
+        with self.init_scope():
+            self.embed = L.EmbedID(n_vocab, d_model, seed=seed)
+            self.blocks = ChainList(*[
+                WindowMoEBlock(
+                    d_model,
+                    dict(n_heads=layer_heads[i], n_kv=n_kv,
+                         head_dim=head_dim,
+                         **kinds[layer_windows[i] is not None]),
+                    d_ff=d_ff, eps=eps,
+                    experts=None if layer_dense[i] else experts,
+                    seed=seed + 100 * (i + 1))
+                for i in range(len(layer_heads))])
+            self.ln_f = L.RMSNorm(d_model, eps)
+            self.head = L.Linear(d_model, n_vocab, nobias=True,
+                                 seed=seed + 999)
+
+    @staticmethod
+    def _project(block, h, pos):
+        """``block.attn.project`` of the normed ``h``."""
+        with role("norm"):
+            x = block.ln1(h)
+        return block.attn.project(x, pos)
+
+    def _block(self, block, h, att, gate, valid, counts):
+        with role("attn_proj"):
+            h = h + block.attn.output(att, gate)
+        with role("norm"):
+            x = block.ln2(h)
+        y, c = block.ffn(x, valid)
+        if c is not None:
+            counts.append(c)
+        with role("experts" if block.routed else "mlp"):
+            return h + y

@@ -66,7 +66,8 @@ from ..observability import role
 
 __all__ = ["switch_moe", "moe_dispatch_combine", "moe_dispatch_combine_topk",
            "moe_capacity", "sigmoid_topk_route", "held_experts_ffn",
-           "HeldExperts"]
+           "HeldExperts", "softmax_topk_route", "sorted_experts_ffn",
+           "SortedExperts"]
 
 
 def moe_capacity(n_tokens, n_experts, capacity_factor, k=1):
@@ -442,6 +443,148 @@ def held_experts_ffn(x, ids, weights, w_gate, w_up, w_down, first,
     return h.reshape(T, H * Fw) @ w_down.reshape(H * Fw, D), counts
 
 
+@role("router")
+def softmax_topk_route(logits, k):
+    """Softmax over the chosen: the ``k`` largest of ``logits [T, E]``
+    (float32), and the softmax of those ``k`` alone, which is the
+    softmax over all ``E`` kept at the chosen and divided by their sum.
+    Returns ``(ids [T, k] int32, weights [T, k] float32)``."""
+    top, ids = lax.top_k(logits.astype(jnp.float32), k)
+    return ids.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
+def _row_tile(rows):
+    """The rows of one tile of a grouped product over ``rows`` sorted
+    copies.  A group is visited once for each tile it reaches into and
+    reads its whole matrix at each visit, so a long prefill wants tiles
+    tall enough that the products, not the matrices' re-reads, bound it
+    (256 rows against 768 a group at 8192 tokens), and a decode step
+    tiles short enough that a visit's padding rows cost less than the
+    matrix it reads (PERF.md section 6, PR 44: one sweep on the chip at
+    49152, 192, 96 and 16 rows)."""
+    if rows >= 4096:
+        return 256
+    return 32 if rows >= 32 else 16
+
+
+# the matrix tile of a grouped product: a group's whole matrix where it
+# is one expert's of 2560 x 768 (7.9 MB twice buffered, beside 256 rows
+# of both sides inside the 16 MB a kernel may use), so that no output
+# tile is visited twice; a larger matrix is cut along its output
+_TILE_K, _TILE_ELEMS = 2560, 2560 * 768
+
+
+def _tiles(rows, K, N):
+    tk = min(K, _TILE_K)
+    return _row_tile(rows), tk, min(N, _TILE_ELEMS // tk // 128 * 128)
+
+
+def _grouped_product(lhs, rhs, sizes, transpose_rhs, interpret=False):
+    """``lhs [M, K]``, its rows sorted by group, times the group's own
+    matrix of ``rhs`` (``[H, K, N]``, or ``[H, N, K]`` with
+    ``transpose_rhs``): rows ``sum(sizes[:g]) .. sum(sizes[:g + 1]) - 1``
+    times ``rhs[g]``; ``[M, N]`` in ``lhs``'s dtype, the rows past
+    ``sum(sizes)`` undefined.  ``M`` is whole tiles of
+    :func:`_row_tile`.  On a TPU the Pallas grouped matmul that ships
+    with JAX (``megablox.gmm``): a group of no rows is not visited, so
+    its matrix is not read.  Elsewhere ``lax.ragged_dot_general``, which
+    says the same in one line and which the TPU's compiler expands to
+    ONE product over every group's matrix for every row (12.4 TFLOP
+    where 0.19 are asked for, at 49152 rows of 2560 into 64 groups of
+    768: compiled for the described chip, PR 44), so it is the plain
+    form and not the path."""
+    from ..ops.flash_attention import _on_tpu
+    M, K = lhs.shape
+    if interpret or _on_tpu():
+        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+        N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+        return gmm(lhs, rhs, sizes, lhs.dtype, _tiles(M, K, N),
+                   transpose_rhs=transpose_rhs, interpret=interpret)
+    dims = lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((1,), (2 if transpose_rhs else 1,)),
+                               ((), ())),
+        lhs_ragged_dimensions=[0], rhs_group_dimensions=[0])
+    return lax.ragged_dot_general(lhs, rhs, sizes, dims,
+                                  preferred_element_type=lhs.dtype)
+
+
+@role("experts")
+def sorted_experts_ffn(x, ids, weights, w_gate, w_up, w_down, first,
+                       activation, valid=None):
+    """The held experts' part of a routed gated layer, grouped by
+    SORTING, nothing dropped: :func:`held_experts_ffn`'s contract
+    (``x [T, D]``; ``ids``/``weights`` ``[T, k]`` over all experts;
+    ``w_gate``/``w_up`` ``[H, F, D]`` (out, in), ``w_down`` ``[H, F, D]``
+    (in, out) for experts ``first .. first + H - 1``; returns ``(y [T,
+    D], counts [H] int32)``) with the gate's ``activation`` a function
+    (``jax.nn.silu`` gives that function's mathematics) and two
+    differences: an expert computes only the copies routed to it, and a
+    token outside ``valid`` is not computed at all (its row of ``y`` is
+    zeros).
+
+    The ``T · k`` copies are ordered by held expert with a stable sort;
+    a copy for an expert not held here, or of a token outside ``valid``,
+    goes behind them into a group that computes nothing.  ``gate``,
+    ``up`` and ``down`` are grouped products over the stacked leaves as
+    they lie (no copy and no gather of weights; an expert without a copy
+    is not read), the routing weight is applied once, in float32, where
+    each token's ``k`` rows are brought back and added.  The cost
+    follows the routing: ``k / E`` of the masked form's products, and a
+    skew onto one expert lengthens that expert's group and nothing
+    else."""
+    T, D = x.shape
+    k, H = ids.shape[1], w_gate.shape[0]
+    M = T * k
+    rows = -(-M // _row_tile(M)) * _row_tile(M)
+    with role("router"):        # the permutation and the group sizes
+        local = ids - first
+        held = (local >= 0) & (local < H)
+        if valid is not None:
+            held &= valid[:, None]
+        group = jnp.where(held, local, H).reshape(M)
+        order = jnp.argsort(group, stable=True)      # sorted row -> copy
+        place = jnp.argsort(order).reshape(T, k)     # copy -> sorted row
+        token = jnp.zeros(rows, order.dtype).at[:M].set(order // k)
+        counts = jnp.sum(group[:, None] == jnp.arange(H, dtype=group.dtype),
+                         axis=0, dtype=jnp.int32)
+    xs = x[token]
+    hidden = activation(_grouped_product(xs, w_gate, counts, True)) \
+        * _grouped_product(xs, w_up, counts, True)
+    out = _grouped_product(hidden, w_down, counts, False)
+    # the way back, one gather of ``[T, D]`` for each of a token's k
+    # copies, weighted and added as it arrives: at 8192 tokens 2.4 ms
+    # where one gather of all ``T · k`` rows and a sum over them took
+    # 4.0 (the rows written and read again) and 4.6 as ``[T, k, D]``
+    # (relaid besides): PERF.md section 6, PR 44
+    y = jnp.zeros((T, D), jnp.float32)
+    for j in range(k):
+        back = out[place[:, j]].astype(jnp.float32) * weights[:, j, None]
+        y += jnp.where(held[:, j, None], back, 0.0)
+    return y.astype(x.dtype), counts
+
+
+def _draw_share(link, d_model, d_expert, n_experts, held):
+    """``held = (first, count)`` checked against the layer's
+    ``n_experts``, and ``link``'s ``router``, ``w_gate``, ``w_up`` and
+    ``w_down`` drawn for that share (LeCun normal, one seeded stream);
+    returns ``(first, count)`` as ints."""
+    first, count = held
+    if not (0 <= first and first + count <= n_experts and count > 0):
+        raise ValueError(f"held={held} is not inside the layer's "
+                         f"{n_experts} experts")
+    rng = np.random.RandomState(0)
+    shapes = {"router": ((n_experts, d_model), d_model),
+              "w_gate": ((count, d_expert, d_model), d_model),
+              "w_up": ((count, d_expert, d_model), d_model),
+              "w_down": ((count, d_expert, d_model), d_expert)}
+    for name, (shape, fan_in) in shapes.items():
+        getattr(link, name).draw(
+            shape, np.float32,
+            lambda shape=shape, fan_in=fan_in: rng.normal(
+                0.0, fan_in ** -0.5, shape).astype(np.float32))
+    return int(first), int(count)
+
+
 class HeldExperts(Link):
     """A chip's share of a routed expert layer: the router over all
     ``n_experts`` and the SwiGLU experts ``held = (first, count)``.
@@ -455,12 +598,7 @@ class HeldExperts(Link):
     def __init__(self, d_model, d_expert, n_experts, held, k,
                  routed_scale=1.0):
         super().__init__()
-        first, count = held
-        if not (0 <= first and first + count <= n_experts and count > 0):
-            raise ValueError(f"held={held} is not inside the layer's "
-                             f"{n_experts} experts")
         self.n_experts, self.k = int(n_experts), int(k)
-        self.first, self.count = int(first), int(count)
         self.routed_scale = float(routed_scale)
         with self.init_scope():
             self.router = Parameter()
@@ -468,16 +606,8 @@ class HeldExperts(Link):
             self.w_gate = Parameter()
             self.w_up = Parameter()
             self.w_down = Parameter()
-        rng = np.random.RandomState(0)
-        shapes = {"router": ((n_experts, d_model), d_model),
-                  "w_gate": ((count, d_expert, d_model), d_model),
-                  "w_up": ((count, d_expert, d_model), d_model),
-                  "w_down": ((count, d_expert, d_model), d_expert)}
-        for name, (shape, fan_in) in shapes.items():
-            getattr(self, name).draw(
-                shape, np.float32,
-                lambda shape=shape, fan_in=fan_in: rng.normal(
-                    0.0, fan_in ** -0.5, shape).astype(np.float32))
+        self.first, self.count = _draw_share(self, d_model, d_expert,
+                                             n_experts, held)
         self.router_bias.draw((n_experts,), np.float32,
                               lambda: np.zeros(n_experts, np.float32))
 
@@ -488,3 +618,40 @@ class HeldExperts(Link):
         return held_experts_ffn(x, ids, w, self.w_gate.array,
                                 self.w_up.array, self.w_down.array,
                                 self.first, valid=valid)
+
+
+class SortedExperts(Link):
+    """A chip's share of a routed expert layer whose products are
+    grouped by sorting (:func:`sorted_experts_ffn`): the router's matrix
+    over all ``n_experts`` (no bias), the gated experts ``held = (first,
+    count)`` and the gate's ``activation``.  The router's logits are a
+    call of their own, :meth:`logits`, so that a block can take them
+    from another tensor than the experts read (ahead of its attention,
+    say); ``forward(x, logits, valid=None)`` chooses ``k`` by
+    :func:`softmax_topk_route` and returns ``(y, counts)``.  With every
+    expert held it is the whole layer on one chip."""
+
+    def __init__(self, d_model, d_expert, n_experts, held, k, activation):
+        super().__init__()
+        self.k = int(k)
+        self.activation = activation
+        with self.init_scope():
+            self.router = Parameter()
+            self.w_gate = Parameter()
+            self.w_up = Parameter()
+            self.w_down = Parameter()
+        self.first, _ = _draw_share(self, d_model, d_expert, n_experts,
+                                    held)
+
+    @role("router")
+    def logits(self, h):
+        """``h [T, D]`` -> the router's ``[T, n_experts]`` in float32."""
+        return jnp.dot(h.astype(jnp.float32),
+                       self.router.array.astype(jnp.float32).T,
+                       precision=lax.Precision.HIGHEST)
+
+    def forward(self, x, logits, valid=None):
+        ids, w = softmax_topk_route(logits, self.k)
+        return sorted_experts_ffn(x, ids, w, self.w_gate.array,
+                                  self.w_up.array, self.w_down.array,
+                                  self.first, self.activation, valid=valid)

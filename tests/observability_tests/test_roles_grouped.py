@@ -1,4 +1,4 @@
-"""Role coverage (ISSUE 38) of the four served models whose cache has
+"""Role coverage (ISSUE 38) of the five served models whose cache has
 groups or a latent entry, at the tiny configurations of their cells'
 rehearsals: in the text of each serving program compiled on the CPU,
 every instruction has a role of the vocabulary."""
@@ -25,6 +25,10 @@ MODELS = {
     # keep their roles inside ``loop/while/body``
     "looped": {"embed", "norm", "attn_proj", "cache_write", "attn", "mlp",
                "head"},
+    # no dense feed-forward anywhere: every layer is routed experts, and
+    # the router's product is a block's first operation
+    "prerouted": {"embed", "norm", "attn_proj", "cache_write", "attn",
+                  "router", "experts", "head"},
 }
 # The engine's token pick (``argmax`` under ``~head``) starts its reduction
 # from a scalar ``-inf`` that XLA names by the scope alone.  In the other

@@ -527,6 +527,106 @@ def test_windowed_prefill_program_at_10752_tokens(windowed,
     assert 1.0e9 < ma.temp_size_in_bytes < 1.6e9, ma.temp_size_in_bytes
 
 
+# -- a whole expert layer grouped by sorting (smallthinker-21b-a3b-stage) ----
+
+PREROUTED_PAGES, PREROUTED_CONTEXT, PREROUTED_LANES = 17440, 8704, 32
+
+
+@pytest.fixture(scope="module")
+def prerouted(one_chip):
+    """The configuration's builder at the published widths and the
+    cell's depth (4 layers, all 64 experts, the whole vocabulary), its
+    bfloat16 state as shapes on the described chip, and the cell's two
+    pools: the one full layer's (17440 pages) and the three window
+    layers' (sized by the engine for a window of 4096: 10496)."""
+    from benchmark import harness
+    from chainermn_tpu.serving import ServingEngine
+    config = harness.load_json(os.path.join(
+        harness.HERE, "configs", "smallthinker-21b-a3b-stage.json"))
+    model = harness.load_module("models", "prerouted_moe_lm").build(
+        config, max_len=PREROUTED_CONTEXT)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    state = {"params": {path: spec(p.shape, jnp.bfloat16)
+                        for path, p in model.namedparams()}, "state": {}}
+    window_pages = ServingEngine.window_group_pages(
+        4096, PAGE, PREROUTED_LANES, PREROUTED_CONTEXT)
+    pools = [spec((n, pages, PAGE) + shape, jnp.bfloat16)
+             for (_, n, entry, _), pages in zip(
+                 model.serve_cache_groups(),
+                 (PREROUTED_PAGES, window_pages))
+             for shape in entry]
+    assert [p.shape for p in pools] == \
+        [(1, 17440, 16, 1024), (3, 10496, 16, 1024)]
+    return model, state, pools, spec
+
+
+def _grouped_products(text):
+    """The compiled program's calls of the grouped matmul kernel."""
+    return [line for line in text.splitlines()
+            if "custom_call_target=\"tpu_custom_call\"" in line
+            and line.lstrip().startswith("%gmm")]
+
+
+@pytest.mark.parametrize("lanes", [1, 16, PREROUTED_LANES])
+def test_prerouted_decode_program_groups_its_experts_by_sorting(
+        prerouted, no_persistent_cache, monkeypatch, lanes):
+    """Every decode bucket's ends and its usual one: both pools donated
+    and updated in place, every layer's attention the paged kernel at a
+    window of 4096 (257 pages a lane) and a group of 7 query heads, and
+    every layer's experts three grouped products over the stacked
+    leaves: no temporary of an expert layer's 755 MB, which a gather or
+    a copy of the weights would be."""
+    from chainermn_tpu.serving import decode_program
+    model, state, pools, spec = prerouted
+    _on_the_chip(monkeypatch)
+    N = PREROUTED_CONTEXT // PAGE
+    compiled, text = _compile(
+        functools.partial(decode_program, model, mode=None), state, *pools,
+        spec((lanes,), jnp.int32), spec((lanes,), jnp.int32),
+        spec((2, lanes, N), jnp.int32), donate_argnums=(1, 2))
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= _pool_bytes(pools)
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert sum("_paged_decode_kernel" in c for c in calls) == 4
+    assert len(_grouped_products(text)) == 3 * 4
+    assert ma.temp_size_in_bytes < 2e8, ma.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("program", ["prefill", "suffix_prefill"])
+def test_prerouted_prefill_programs_at_8192_tokens(
+        prerouted, no_persistent_cache, monkeypatch, program):
+    """The cell's one prefill bucket and its one suffix bucket: the full
+    layer through ``_flash_kernel`` and the three window layers through
+    ``_flash_window_kernel`` at a window of 4096 (the full prefill), 12
+    grouped products over 49152 sorted copies, pools in place, and
+    temporaries far below the 12.9 GB the masked form's hidden
+    activation ``[8192, 64 x 768]`` would need three times over."""
+    from chainermn_tpu import serving
+    model, state, pools, spec = prerouted
+    _on_the_chip(monkeypatch)
+    operands = (spec((1, 8192), jnp.int32), spec((), jnp.int32))
+    if program == "suffix_prefill":
+        operands += (spec((), jnp.int32),)
+    fn = {"prefill": serving.prefill_program,
+          "suffix_prefill": serving.prefix_prefill_program}[program]
+    compiled, text = _compile(
+        functools.partial(fn, model), state, *pools, *operands,
+        spec((2, PREROUTED_CONTEXT // PAGE), jnp.int32),
+        donate_argnums=(1, 2))
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    if program == "prefill":
+        assert sum("_flash_window_kernel" in c for c in calls) == 3
+        assert sum("_flash_kernel" in c for c in calls) == 1
+    assert len(_grouped_products(text)) == 3 * 4
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= _pool_bytes(pools)
+    assert ma.temp_size_in_bytes < 2.5e9, ma.temp_size_in_bytes
+
+
 # -- recurrent state beside pages (olmo-hybrid-7b-stage), published widths ---
 
 HYBRID_PAGES, HYBRID_CONTEXT, HYBRID_LANES = 7168, 17920, 16
