@@ -95,6 +95,21 @@ programs so XLA updates pages in place (PR 3's donation discipline; on
 the CPU test backend donation is skipped — it is a no-op there and only
 generates warnings).
 
+**One decode run in flight** (PR 46).  A decode run's input tokens are
+the previous run's ``nxt``, which the device already holds, and its
+positions and block tables grow by one a run whatever the tokens are.
+So :meth:`ServingEngine.step` dispatches decode run ``n + 1`` BEFORE it
+fetches run ``n``: the tokens go from one run to the next on the device
+(``nxt`` itself where every lane keeps its row, else one small gather,
+:func:`next_tokens_program`, that also takes the first tokens of the
+lanes admitted since), and the host learns run ``n``'s tokens, records
+them and builds the next operands while run ``n + 1`` executes.  The
+order falls back to dispatch-then-fetch, by what the engine can see and
+no switch, for ``spec_k`` (the accepted length decides the next
+positions), while a prompt is mid-chunk, on the disaggregated split, and
+it lands the run in flight first when the pool runs dry (a victim's
+tokens have to be known before they fold into its prompt).
+
 Scheduling (``serving.scheduler``): open-loop admission at decode-step
 granularity with per-tenant round-robin fairness; when the page pool
 runs dry the youngest running sequence OWNING at least one unique page
@@ -117,13 +132,15 @@ import numpy as np
 from .. import observability
 from ..core.link import bind_state, cast_params, extract_state
 from ..ops.paged_attention import paged_attn_mode
+from ..utils.compat import call_with_frame_room
 from .errors import PagePoolExhaustedError, UnsupportedProgramError
 from .kv_cache import PagedKVCache, PerSequence, copy_page, insert_pages
 from .page_allocator import BlockAllocator
 from .scheduler import RequestScheduler
 
 __all__ = ["ServingEngine", "prefill_program", "prefix_prefill_program",
-           "decode_program", "spec_verify_program", "ngram_propose",
+           "decode_program", "spec_verify_program", "next_tokens_program",
+           "ngram_propose",
            "serve_disagg_mode", "serve_spec_k"]
 
 
@@ -289,6 +306,42 @@ def spec_verify_program(model, state, *operands, tp_mesh=None):
         with observability.role("head"):
             g = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (*pools, logits, g)
+
+
+def next_tokens_program(prevs, sel):
+    """The ``toks`` operand of a decode run, made on the device from the
+    run before it (PR 46), for every pair of batch buckets in ONE
+    program.  ``prevs`` holds one int32 vector a batch bucket, in the
+    buckets' order: the run before's ``nxt`` in the place of its
+    bucket, anything in the others.  ``sel`` is ``[B_max]`` int32: lane
+    ``j`` takes element ``sel[j]`` of the vectors laid end to end where
+    ``sel[j] >= 0`` (the token that run gave the lane at its old row),
+    and the host-known token ``-1 - sel[j]`` otherwise (a lane admitted
+    since: the first token its prefill gave; an idle lane: 0).  Returns
+    the tokens cut to each bucket, in the buckets' order; the caller
+    takes its own.  Rows move when a lane retires and buckets change,
+    and a compile a pair of buckets cost the largest cell 29.7 s of
+    set-up (PERF.md section 6, PR 45): this is one compile an engine."""
+    flat = jnp.concatenate(prevs)
+    toks = jnp.where(sel >= 0, flat[jnp.maximum(sel, 0)], -1 - sel)
+    return tuple(toks[:p.shape[0]] for p in prevs)
+
+
+class _Flight:
+    """A decode run dispatched and not yet landed: its tokens (``nxt``)
+    and what the model counted (``extras``) still on the device, and the
+    requests it serves in row order (``rows``: a request's ``id()`` ->
+    its row; request ids are the caller's and may come round again).
+    ``pos`` (the run's position operand), ``step`` (its index) and
+    ``ahead`` (whether it went behind a run in flight) are what the
+    span that lands it says of it."""
+
+    __slots__ = ("nxt", "extras", "lanes", "rows", "pos", "step", "ahead")
+
+    def __init__(self, nxt, extras, lanes, pos, step, ahead):
+        self.nxt, self.extras, self.lanes = nxt, extras, lanes
+        self.pos, self.step, self.ahead = pos, step, ahead
+        self.rows = {id(req): j for j, req in enumerate(lanes)}
 
 
 class _AdmitDeferred(Exception):
@@ -490,7 +543,12 @@ class ServingEngine:
         self.spec_traces = 0
         self.chunk_traces = 0
         self.evictions = 0
-        self.decode_steps = 0
+        self.decode_steps = 0         # decode runs dispatched
+        self.decode_steps_ahead = 0   # of them, behind a run in flight
+        self.ahead_traces = 0
+        self._flight = None           # the decode run not yet landed
+        self._lowered = set()         # (program, pools, shapes) once run
+        self._idle_prevs = None       # the gather's operands, once warm
         self.admissions = 0
         self.prefix_hits = 0
         self.prefix_tokens_matched = 0
@@ -544,8 +602,15 @@ class ServingEngine:
                 self.state, NamedSharding(self._tp_mesh, PartitionSpec()))
             # transferred page blocks land sharded the same way
             self._block_placement = pool_sh
+            # a decode run leaves its tokens whole on every shard, and
+            # the host's tokens are placed the same: a run that takes
+            # them and a run that takes the output of the run before it
+            # are then one executable
+            self._toks_placement = NamedSharding(self._tp_mesh,
+                                                 PartitionSpec())
         else:
             self._tp_mesh = None
+            self._toks_placement = None
             self._block_placement = decode_device or devices[0]
 
         # -- disaggregation: a scratch pool + weight copy on the prefill
@@ -616,6 +681,10 @@ class ServingEngine:
             return decode_program(self.draft_model, state, *operands,
                                   mode=self.mode, tp_mesh=None)
 
+        def _next_tokens(prevs, sel):
+            self.ahead_traces += 1
+            return next_tokens_program(prevs, sel)
+
         def _fork(*pools_src_dst):
             self.fork_traces += 1
             return copy_page(*pools_src_dst)
@@ -642,6 +711,7 @@ class ServingEngine:
                                          donate_argnums=donate)
         self._draft_decode_fn = jax.jit(_draft_decode,
                                         donate_argnums=donate)
+        self._next_tokens_fn = jax.jit(_next_tokens)
         self._fork_fn = jax.jit(_fork, donate_argnums=donate0)
         self._extract_fn = jax.jit(_extract, static_argnums=n_pools)
         self._insert_fn = jax.jit(_insert, donate_argnums=donate0)
@@ -681,12 +751,22 @@ class ServingEngine:
             cast_params(model, model.serve_param_dtype)
         return extract_state(model)
 
-    @staticmethod
-    def _run(fn, kv, state, *operands):
+    def _run(self, fn, kv, state, *operands):
         """One compiled program over ``kv``'s pools: the pools it
         returns (donated, on an accelerator) are stored back, the rest
-        of its outputs returned."""
-        out = fn(state, *kv.pools, *operands)
+        of its outputs returned.  The first call of ``fn`` at operands
+        of these shapes traces and lowers a program, whoever makes it
+        (``warmup()``, or a caller that drives ``step()``): it is made
+        with room on the interpreter's frame stack, without which a
+        large program's lowering takes one second or twenty by where
+        the caller's stack happens to end
+        (``utils.compat.call_with_frame_room``)."""
+        key = (fn, id(kv), *(getattr(o, "shape", ()) for o in operands))
+        if key in self._lowered:
+            out = fn(state, *kv.pools, *operands)
+        else:
+            self._lowered.add(key)
+            out = call_with_frame_room(fn, state, *kv.pools, *operands)
         kv.pools = list(out[:len(kv.pools)])
         return out[len(kv.pools):]
 
@@ -859,7 +939,12 @@ class ServingEngine:
         A MID-CHUNK victim (round 20) frees its already-written chunk
         pages the same way — the scheduler's requeue resets its chunk
         cursor, so re-admission restarts from chunk 0 with no page
-        leaked and no stale cursor (the scheduler-fix satellite)."""
+        leaked and no stale cursor (the scheduler-fix satellite).
+
+        A victim is never in the decode run in flight (its newest token
+        would be unknown to the host): the capacity pass lands that run
+        before it picks one, and a prompt mid-chunk is in none."""
+        assert self._flight is None or id(req) not in self._flight.rows
         self.allocator.free(req.request_id)
         if req in self.running:
             self.running.remove(req)
@@ -1333,7 +1418,10 @@ class ServingEngine:
         every span write dropped), and the draft model's prefill +
         decode grids — afterwards ``spec_traces``/``chunk_traces``
         stay frozen across joins, forks, evictions and accept-length
-        swings (the round-20 retrace pin)."""
+        swings (the round-20 retrace pin).  PR 46: the gather that hands
+        one decode run's tokens to the next, ONE small program for
+        every pair of batch buckets (an engine that is never warmed
+        compiles it at its first decode run)."""
         zero_row = jnp.asarray(self._zero_bt())
 
         def idle(Bb):
@@ -1380,8 +1468,14 @@ class ServingEngine:
                               self._state_prefill, *empty,
                               self._scratch_bt)
         for Bb in self.batch_buckets:
-            _, nxt, *_ = self._run(self._decode_fn, self.kv, self.state,
-                                   *idle(Bb))
+            _, nxt, *_ = self._run(
+                self._decode_fn, self.kv, self.state,
+                self._host_toks(np.zeros(Bb, np.int32)), *idle(Bb)[1:])
+            if self._may_run_ahead():
+                # a run dispatched ahead takes its tokens as the run
+                # before it left them on the device (the same
+                # executable: ``_host_toks``), or through the gather
+                self._warm_next_tokens(nxt)
             if self.spec_k:
                 _, nxt = self._run(
                     self._spec_verify_fn, self.kv, self.state,
@@ -1404,7 +1498,22 @@ class ServingEngine:
     def step(self, now=None):
         """One continuous-batching step: an admission pass (fair
         rotation, open-loop eligibility by ``now``) then ONE decode step
-        over the running batch.  Returns step stats.
+        over the running batch: when call ``k`` returns, the tokens of
+        decode run ``k`` are recorded and stamped.  Returns step stats.
+
+        One decode run is kept in flight (PR 46): a call dispatches the
+        run AFTER the one it lands, its tokens taken on the device, then
+        fetches and records the run the call before it dispatched, so
+        the device has the next run queued while the host works.  A call
+        that finds nothing in flight dispatches two runs and lands the
+        first.  A lane whose last token (by ``max_new_tokens``) is in
+        flight is left out of the run ahead; a finish by ``eos_id`` is
+        found one run late, and the spare run's token for that lane is
+        dropped unrecorded.  A request admitted in this call joins the
+        run this call dispatches.  ``spec_k``, a prompt mid-chunk and
+        the disaggregated split keep the order dispatch, fetch, record;
+        a pool that runs dry lands the run in flight before it picks a
+        victim.
 
         ``now=None`` (the bench's real-time mode) timestamps each token
         at its actual production instant (after the device fetch); a
@@ -1419,10 +1528,53 @@ class ServingEngine:
                        num_pages=a.num_pages, **a.group_stats())
         return stats
 
+    def _may_run_ahead(self):
+        """Whether this engine ever dispatches a decode run behind one
+        in flight: not with ``spec_k`` (the accepted length decides the
+        next positions) and not on the disaggregated split (the ship
+        crosses devices, which no one stream orders)."""
+        return not (self.spec_k or self.disagg)
+
+    def _last_in_flight(self, req):
+        """The run in flight brings ``req``'s last token (it finishes by
+        ``max_new_tokens`` when that run lands): no later run has it."""
+        f = self._flight
+        return f is not None and id(req) in f.rows \
+            and len(req.tokens) + 1 >= req.max_new_tokens
+
+    def _next_lanes(self):
+        """The lanes of the next decode run to dispatch."""
+        return [req for req in self.running
+                if not self._last_in_flight(req)]
+
+    def _secure(self, req, need=1):
+        """Secure the pages of ``req``'s next ``need`` positions and let
+        its windows slide (``PagePoolExhaustedError`` where the pool has
+        not enough)."""
+        self.allocator.ensure(req.request_id, req._ctx + need)
+        self.allocator.slide(req.request_id, req._ctx)
+
+    def _try_secure_all(self, lanes):
+        """Secure every lane's next position, as the capacity pass does,
+        but from what the pool has left: no eviction.  False where it
+        has not enough."""
+        try:
+            for req in lanes:
+                self._secure(req)
+        except PagePoolExhaustedError:
+            return False
+        return True
+
+    def drop_in_flight(self):
+        """Forget the decode run in flight, its tokens unrecorded (the
+        sequences it serves are leaving this engine: a reroute
+        recomputes them from the tokens already recorded)."""
+        self._flight = None
+
     def _step(self, clock):
         stats = {"admitted": 0}
-        obs_on = observability.enabled()
         evicted_before = self.evictions
+        landed = None    # lanes of the run this call landed
         # capacity FIRST: secure this step's token page(s) for every
         # running sequence (evicting youngest-first when the pool runs
         # dry) BEFORE admitting anyone — admission into pages the
@@ -1431,18 +1583,31 @@ class ServingEngine:
         # Speculative decode secures the whole verify SPAN (up to K+1
         # positions); mid-chunk prompts are eviction candidates too —
         # preferred victims, in fact: they hold pages and have produced
-        # zero tokens
+        # zero tokens.  ``_ctx`` counts the run in flight (it advances
+        # at dispatch), so the page secured is the one the run
+        # dispatched in THIS call writes; a lane whose last token is in
+        # flight needs none
         with observability.span("serve/capacity"):
             i = 0
             while i < len(self.running):
                 req = self.running[i]
-                need = self._spec_nv(req) if self.spec_k else 1
+                if self._last_in_flight(req):
+                    i += 1
+                    continue
                 try:
-                    self.allocator.ensure(req.request_id,
-                                          req._ctx + need)
-                    self.allocator.slide(req.request_id, req._ctx)
+                    self._secure(req, self._spec_nv(req) if self.spec_k
+                                 else 1)
                     i += 1
                 except PagePoolExhaustedError:
+                    if self._flight is not None:
+                        # a victim folds its tokens into its prompt:
+                        # land the run in flight (every running lane is
+                        # in it) before one is picked, then go on as
+                        # the synchronous order does; lanes may have
+                        # retired, so the pass starts over
+                        landed = self._land(clock)
+                        i = 0
+                        continue
                     # refcount-aware victim choice: a victim must FREE
                     # something (EvictionStalledError otherwise — the
                     # prefix-sharing livelock guard)
@@ -1457,7 +1622,8 @@ class ServingEngine:
         # over (its growth page is secured by _admit's ensure; a
         # chunk-admitted prompt counts against max_batch from its
         # FIRST chunk — the engine's concurrency bound covers work in
-        # flight, not just work decoding)
+        # flight, not just work decoding).  A prefill queues on the
+        # device behind the run in flight
         with observability.span("serve/admission"):
             while len(self.running) + len(self.prefilling) \
                     < self.max_batch:
@@ -1486,56 +1652,202 @@ class ServingEngine:
         stats["capacity_x"] = self.capacity_multiplier()
         if observability.ring_enabled():
             self._obs_queue_depths()
-        if n == 0:
-            stats["decoded"] = 0
-            return stats
-        if self.spec_k:
+        if self.spec_k and n:
             return self._spec_step(n, clock, stats)
-        Bb = _bucket(n, self.batch_buckets, "batch")
-        tags = {"batch": n, "bucket": Bb, "step": self.decode_steps} \
-            if obs_on else None
-        if obs_on:
-            # what a sound step reads of each kind of cache: the whole
-            # context in the full group (tokens), a window's worth of it
-            # in a window group, one state a lane in a state group
-            a = self.allocator
-            ctx = [req._ctx + 1 for req in self.running]
-            tags["ctx_tokens"] = sum(ctx)
-            if a.windows:
-                tags["window_tokens"] = sum(
-                    min(c, w.window) for w in a.windows for c in ctx)
-            if a.states:
-                tags["state_lanes"] = n
+        # the decode pass, two moves: dispatch the next run if one may
+        # go, then land the oldest run not yet landed.  The synchronous
+        # order is the case where nothing was in flight and nothing may
+        # go ahead: the run dispatched is the run landed.  A prompt
+        # mid-chunk interleaves with single decode steps: no run goes
+        # ahead of one in flight while it streams in
+        ahead = self._may_run_ahead() and not self.prefilling
+        lanes = self._next_lanes()
+        if landed is None and self._flight is None and ahead and any(
+                len(req.tokens) + 2 <= req.max_new_tokens for req in lanes):
+            # the engine was empty: the run this call lands goes first,
+            # and the one behind it needs a position more than the
+            # capacity pass (or the admission) secured
+            self._dispatch_decode(lanes)
+            lanes = self._next_lanes()
+            ahead = self._try_secure_all(lanes)
+        if landed is not None:
+            # the capacity pass landed this call's run: the next call
+            # lands the one dispatched here
+            if ahead and lanes:
+                self._dispatch_decode(lanes)
+        elif self._flight is not None:
+            landed = self._decode_window(lanes if ahead else (), clock)
+        elif lanes:
+            landed = self._decode_window(lanes, clock)
+        stats["decoded"] = len(landed or ())
+        return stats
+
+    def _decode_window(self, lanes, clock):
+        """One ``serve/decode_window``: build and dispatch the decode
+        run of ``lanes`` (it becomes the run in flight, ``_ctx`` moves
+        on; none where ``lanes`` is empty), then, inside the same span,
+        fetch the tokens of the oldest run not yet landed: the run that
+        was in flight, dispatched a call ago, which executed while this
+        one was built; or, where none was, the run dispatched here (the
+        synchronous order).  Returns the lanes landed.  (A run
+        dispatched with nothing to land, the first after the engine was
+        empty, has its build and dispatch spans and no window: every
+        window is a run landed.)
+
+        The span describes the run it LANDS: its host-known tags
+        (``batch``, ``bucket``, ``step``, ``ctx_tokens``,
+        ``window_tokens``, ``state_lanes``, and ``ahead``: 1 where that
+        run was dispatched behind one in flight), made when it was
+        dispatched, and what the model counted in it (``extras``), so a
+        span's counts are one run's.  The program run that starts
+        inside the span is the one it DISPATCHES, a run later where one
+        was in flight (it starts when the run being fetched ends): a
+        reader that pairs the two sees the same lanes a token apart,
+        and a window's sums move by one boundary term."""
+        prev = self._flight
+        tags = None
+        if observability.enabled():
+            tags = self._run_tags(len(lanes), [r._ctx for r in lanes],
+                                  self.decode_steps, False) \
+                if prev is None else \
+                self._run_tags(len(prev.lanes), prev.pos, prev.step,
+                               prev.ahead)
         with observability.span("serve/decode_window",
                                 tags=tags) as window:
-            with observability.span("serve/decode_build"):
-                toks = np.zeros(Bb, dtype=np.int32)
-                pos = np.full(Bb, -1, dtype=np.int32)
-                bts = self._zero_bt(Bb)
-                for j, req in enumerate(self.running):
-                    toks[j] = req.tokens[-1]
-                    pos[j] = req._ctx
-                    bts[..., j, :] = self._bt_row(req.request_id)
-                operands = (jnp.asarray(toks), jnp.asarray(pos),
-                            jnp.asarray(bts))
-            with observability.span("serve/decode_dispatch"):
-                _logits, nxt, *extras = self._run(
-                    self._decode_fn, self.kv, self.state, *operands)
+            if lanes:
+                self._dispatch_decode(lanes)
+            landing = prev or self._flight
             with observability.span("serve/decode_fetch"):
-                nxt = np.asarray(nxt)   # device->host sync: the decode
-                # window span times the real step; what the model
-                # counted comes in the same fetch, while a span records
-                self._set_model_stats(window, extras)
-            self.decode_steps += 1
+                tokens = np.asarray(landing.nxt)   # device->host sync;
+                # what the model counted comes in the same fetch, while
+                # a span records
+                self._set_model_stats(window, landing.extras)
+        return self._record(landing, tokens, clock)
+
+    def _run_tags(self, n, pos, step, ahead):
+        """What the host knows of a decode run of ``n`` lanes at
+        positions ``pos[:n]``, for the span that lands it."""
+        # what a sound step reads of each kind of cache: the whole
+        # context in the full group (tokens), a window's worth of it in
+        # a window group, one state a lane in a state group
+        a = self.allocator
+        ctx = [int(p) + 1 for p in pos[:n]]
+        tags = {"batch": n,
+                "bucket": _bucket(n, self.batch_buckets, "batch"),
+                "step": step, "ahead": int(ahead),
+                "ctx_tokens": sum(ctx)}
+        if a.windows:
+            tags["window_tokens"] = sum(
+                min(c, w.window) for w in a.windows for c in ctx)
+        if a.states:
+            tags["state_lanes"] = n
+        return tags
+
+    def _dispatch_decode(self, lanes):
+        """Build and dispatch the decode run of ``lanes`` behind the
+        run in flight, if any: it becomes the run in flight and every
+        lane's ``_ctx`` moves on."""
+        prev = self._flight
+        Bb = _bucket(len(lanes), self.batch_buckets, "batch")
+        with observability.span("serve/decode_build"):
+            pos = np.full(Bb, -1, dtype=np.int32)
+            bts = self._zero_bt(Bb)
+            # where each lane's token comes from, as the gather takes
+            # it: ``>= 0`` the lane's row in the run in flight (counted
+            # over the buckets laid end to end), ``-1 - token`` a token
+            # the host knows (a lane admitted since, every lane where
+            # nothing is in flight; an idle lane: token 0)
+            rows = prev.rows if prev is not None else {}
+            at = self.batch_buckets.index
+            base = sum(self.batch_buckets[:at(prev.nxt.shape[0])]) \
+                if prev is not None else 0
+            sel = np.full(self.batch_buckets[-1], -1, dtype=np.int32)
+            for j, req in enumerate(lanes):
+                row = rows.get(id(req))
+                sel[j] = -1 - req.tokens[-1] if row is None else base + row
+                pos[j] = req._ctx
+                bts[..., j, :] = self._bt_row(req.request_id)
+            if prev is None:
+                toks = self._host_toks(-1 - sel[:Bb])
+            elif prev.lanes == lanes:
+                # every lane keeps its row: ``nxt`` as it stands (an
+                # idle lane's token is never read), and no program
+                # between the two runs
+                toks = prev.nxt
+            else:
+                toks = None
+                sel = jnp.asarray(sel)
+            operands = (jnp.asarray(pos), jnp.asarray(bts))
+        with observability.span("serve/decode_dispatch"):
+            if toks is None:
+                prevs = list(self._idle_prevs)
+                prevs[at(prev.nxt.shape[0])] = prev.nxt
+                toks = self._next_tokens_fn(tuple(prevs), sel)[at(Bb)]
+            _logits, nxt, *extras = self._run(
+                self._decode_fn, self.kv, self.state, toks, *operands)
+        for req in lanes:
+            req._ctx += 1       # written, or in flight to be
+        self._flight = _Flight(nxt, extras, lanes, pos, self.decode_steps,
+                               prev is not None)
+        self.decode_steps += 1
+        self.decode_steps_ahead += prev is not None
+        if self._idle_prevs is None and self._may_run_ahead():
+            self._warm_next_tokens(nxt)
+
+    def _host_toks(self, toks):
+        """A decode run's tokens from the host, placed as a decode run
+        leaves its own (``nxt``): whichever of the two a run takes, it
+        is the same executable."""
+        if self._toks_placement is None:
+            return jnp.asarray(toks)
+        return jax.device_put(toks, self._toks_placement)
+
+    def _land(self, clock):
+        """Fetch and record the run in flight with nothing dispatched
+        behind it (the batch is ending, a prompt is mid-chunk, or the
+        pool ran dry).  Returns the lanes landed."""
+        return self._decode_window((), clock)
+
+    def _record(self, flight, tokens, clock):
+        """Record and stamp the tokens of ``flight``, retire what
+        finished.  A lane that finished a run ago (by ``eos_id``, found
+        when that run landed) rode this run spare: its token is dropped.
+        Its pages went back to the pool when it retired, which the
+        stream orders: whatever takes them next was dispatched after
+        this run."""
+        if self._flight is flight:
+            self._flight = None
         with observability.span("serve/record"):
             t_tok = clock()
-            for j, req in enumerate(list(self.running)):
-                req._ctx += 1
-                self._record_token(req, nxt[j], t_tok)
+            for j, req in enumerate(flight.lanes):
+                if req.finish_time is not None:
+                    continue
+                self._record_token(req, tokens[j], t_tok)
                 if self._finished(req):
                     self._retire(req, t_tok)
-        stats["decoded"] = n
-        return stats
+        f = self._flight
+        if f is not None and all(req.finish_time is not None
+                                 for req in f.lanes):
+            self._flight = None     # every lane ahead rode spare
+        return flight.lanes
+
+    def _warm_next_tokens(self, nxt):
+        """Compile the gather between two decode runs, once: its idle
+        operands, one vector a batch bucket, are placed as a decode run
+        leaves ``nxt``, so lanes that join and leave never compile in a
+        window."""
+        if self._idle_prevs is not None:
+            return
+        # placed from host zeros: ``jnp.zeros`` would compile a
+        # broadcast a bucket, and the gather is to be the ONE program
+        # more than the synchronous order compiles
+        self._idle_prevs = tuple(
+            jax.device_put(np.zeros(Bb, np.int32), nxt.sharding)
+            if nxt.committed else jnp.asarray(np.zeros(Bb, np.int32))
+            for Bb in self.batch_buckets)
+        self._next_tokens_fn(
+            self._idle_prevs,
+            jnp.asarray(np.zeros(self.batch_buckets[-1], np.int32)))
 
     def _spec_step(self, n, clock, stats):
         """The speculative decode window: draft K tokens per lane,

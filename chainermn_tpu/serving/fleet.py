@@ -210,6 +210,9 @@ class LocalReplica:
         # request's whole prior life (decode time included) as queue
         # wait at re-admission
         t_requeue = now if now is not None else time.monotonic()
+        # a decode run the dead replica had in flight never lands: its
+        # tokens are recomputed with the rest
+        self.engine.drop_in_flight()
         for req in list(self.engine.running):
             self.engine.allocator.free(req.request_id)
             self.engine.running.remove(req)

@@ -92,7 +92,9 @@ class Recorded:
 
         def decode(fn):
             def run(*args):
-                lanes = [r.request_id for r in e.running]
+                # the run's lanes: the running sequences less those whose
+                # last token is still in flight
+                lanes = [r.request_id for r in e._next_lanes()]
                 out = fn(*args)
                 for j, rid in enumerate(lanes):
                     self.rows[rid].append(np.asarray(out[n][j]))

@@ -235,13 +235,20 @@ def test_serving_non_int_request_id_safe():
         obs.reset_registry()
 
 
+@pytest.mark.parametrize("ahead, dwell_ms", [(False, 3000), (True, 4000)])
 def test_readmitted_request_queue_wait_measured_from_last_admission(
-        events_mode):
+        events_mode, ahead, dwell_ms):
     """Eviction + re-admit emits a SECOND queue_wait span measured from
     the previous admission (tagged readmit), never a re-span of the
-    original arrival window overlapping the first (review finding)."""
+    original arrival window overlapping the first (review finding).
+    The dwell is pinned to the step, under both orders of a decode step:
+    dispatch, fetch, record (the order an engine keeps where it cannot
+    run ahead) and one run in flight (PR 46), which costs the victim one
+    step more."""
     from chainermn_tpu.serving import Request
     eng = _engine(num_pages=4)   # 4 pages of 8: forces eviction at 2 seqs
+    if not ahead:
+        eng._may_run_ahead = lambda: False
     a = Request(np.arange(1, 9, dtype=np.int32), max_new_tokens=12,
                 arrival_time=0.0)
     b = Request(np.arange(11, 19, dtype=np.int32), max_new_tokens=12,
@@ -260,9 +267,12 @@ def test_readmitted_request_queue_wait_measured_from_last_admission(
     # measured from the EVICTION's requeue stamp, not the original
     # arrival / prior admission: the step clock ticks 1s per step, so
     # a wait spanning the victim's whole running period would be many
-    # seconds — the true re-queue dwell is a couple of steps
-    for e in readmits:
-        assert e["args"]["duration_ms"] <= 3000, e["args"]
+    # seconds — the true re-queue dwell is three steps, and four with a
+    # run in flight: the page a run needs is secured in the call that
+    # dispatches it, a call before the one that lands it, so the victim
+    # leaves a call earlier and comes back in the same call as before
+    assert eng.evictions == 1 and len(readmits) == 1
+    assert readmits[0]["args"]["duration_ms"] == dwell_ms, readmits[0]["args"]
     # the whole ring still exports schema-valid
     obs.validate_events(sorted(obs.tracer().events(),
                                key=lambda e: e["ts"]))

@@ -114,6 +114,9 @@ class FakeEngine:
         return {"admitted": 0, "decoded": 0, "running": 0, "evicted": 0,
                 "occupancy": 0.0, "capacity_x": 1.0}
 
+    def drop_in_flight(self):
+        pass
+
 
 def _weights(fleet, rid):
     return np.asarray(fleet.replicas[rid].engine.state["w"])

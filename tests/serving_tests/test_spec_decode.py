@@ -141,6 +141,11 @@ def test_spec_parity_across_forced_same_point_eviction():
         for _ in range(3):
             eng.step(now=t)
             t += 1.0
+        if eng._flight is not None:
+            # the vanilla engine keeps a decode run in flight (PR 46): it
+            # lands before a victim is picked, as in the capacity pass,
+            # so the victim's newest token folds with the rest
+            eng._land(lambda: t)
         eng._evict(eng.running[-1], t)
         while eng.running or eng.prefilling or eng.scheduler.pending():
             eng.step(now=t)

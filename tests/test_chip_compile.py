@@ -361,6 +361,26 @@ def test_spec_verify_program_compiles_with_pools_donated(
     _assert_pools_in_place(compiled, text, pool)
 
 
+@pytest.mark.parametrize("lanes", [16, 32, 64])
+def test_the_gather_between_two_decode_runs_is_one_program_a_lane_count(
+        one_chip, no_persistent_cache, lanes):
+    """PR 46: the tokens of a decode run dispatched behind one in flight
+    come through ONE program whatever pair of batch buckets follow each
+    other, at the lane counts of the serving cells (16: GPT-2, Olmo,
+    Ouro; 32: Laguna; 64: Kimi, SmallThinker): one vector a bucket in,
+    the tokens cut to every bucket out, a few KB of temporaries."""
+    from chainermn_tpu.serving.engine import (_pow2_buckets,
+                                              next_tokens_program)
+    buckets = _pow2_buckets(1, lanes)
+    prevs = tuple(jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
+                  for b in buckets)
+    sel = jax.ShapeDtypeStruct((lanes,), jnp.int32, sharding=one_chip)
+    compiled, text = _compile(next_tokens_program, prevs, sel)
+    assert [o.shape for o in compiled.out_info] == [(b,) for b in buckets]
+    assert "tpu_custom_call" not in text       # no kernel: plain XLA
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 16
+
+
 # -- the latent-attention MoE share (kimi-k2.6-share), published widths ------
 
 @pytest.fixture(scope="module")
