@@ -350,10 +350,13 @@ class LatentMoELM(Chain):
         """The held experts' copy counts ``[expert layers, held]`` of one
         program, as a span's stats: ``held_copies``, the token-copies
         that landed on held experts, a layer (the mean over expert
-        layers), and ``held_max``, those on the fullest held expert of a
-        layer (likewise)."""
+        layers), ``held_max``, those on the fullest held expert of a
+        layer (likewise), and ``held_hit``, the held experts that
+        received a copy, summed over expert layers: the matrices a
+        step's grouped products read (``WindowMoELM``'s key)."""
         return {"held_copies": float(counts.sum(axis=1).mean()),
-                "held_max": float(counts.max(axis=1).mean())}
+                "held_max": float(counts.max(axis=1).mean()),
+                "held_hit": int((counts > 0).sum())}
 
     @staticmethod
     def _add_ffn(block, h, valid, counts):

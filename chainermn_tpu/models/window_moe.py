@@ -38,6 +38,8 @@ forward defines no backward.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -62,6 +64,22 @@ def _entry(k, v):
     2 · G · D]``, K then V."""
     return jnp.concatenate([k.reshape(k.shape[:-2] + (-1,)),
                             v.reshape(v.shape[:-2] + (-1,))], axis=-1)
+
+
+# A decode step's read of the cache, a jit of its own with everything
+# but its operands static and the layer's index in its group an operand:
+# the layers of one kind (full, window) then share ONE trace and ONE
+# lowering of the attention and of ``_paged_decode_kernel``'s body, where
+# each layer made its own with its index baked in (``models/looped.py``'s
+# reason; XLA inlines the calls, the device runs the same kernels).
+# Traced a layer, the kernel was 70 % of a Laguna decode program's trace
+# and 64 % of its lowering, nine layers of two kinds: PERF.md section 6,
+# PR 48.
+@functools.partial(jax.jit, static_argnames=("scale", "window", "kv_heads"))
+def _decode_attention(q, pool, bt, ctx, layer, *, scale, window, kv_heads):
+    return paged_decode_attention(q, pool, None, bt, ctx, scale=scale,
+                                  window=window, layer=layer,
+                                  kv_heads=kv_heads)
 
 
 class GatedGroupedAttention(Chain):
@@ -364,9 +382,11 @@ class WindowCacheLM(Chain):
                 with role("cache_write"):
                     pools[p] = write_token_kv(pools[p], _entry(k, v), bt,
                                               pos, layer=li)
-                att = paged_decode_attention(
-                    q, pools[p], None, bt, ctx, scale=self.scale,
-                    window=block.attn.window, layer=li, kv_heads=self.n_kv)
+                with role("attn"):  # the call's own name; its parts' too
+                    att = _decode_attention(
+                        q, pools[p], bt, ctx, jnp.int32(li),
+                        scale=self.scale, window=block.attn.window,
+                        kv_heads=self.n_kv)
                 h = self._block(block, h, att, extra, live, counts)
         logits, counts = self._finish(h, counts)
         return tuple(pools), logits, (counts,)

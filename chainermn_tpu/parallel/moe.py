@@ -56,6 +56,8 @@ assert capacity is sized honestly instead of silently zeroing overflow.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -65,9 +67,8 @@ from ..core.link import Link, Parameter
 from ..observability import role
 
 __all__ = ["switch_moe", "moe_dispatch_combine", "moe_dispatch_combine_topk",
-           "moe_capacity", "sigmoid_topk_route", "held_experts_ffn",
-           "HeldExperts", "softmax_topk_route", "sorted_experts_ffn",
-           "SortedExperts"]
+           "moe_capacity", "sigmoid_topk_route", "HeldExperts",
+           "softmax_topk_route", "sorted_experts_ffn", "SortedExperts"]
 
 
 def moe_capacity(n_tokens, n_experts, capacity_factor, k=1):
@@ -386,6 +387,16 @@ def moe_dispatch_combine_topk(comm, x, gate_logits, expert_fn, k=2,
 # only their terms of the sum.  Nothing is dropped: there is no capacity
 # buffer, every copy routed to a held expert is computed.  No exchange is
 # emitted here; what the other chips' experts add is not this layer's.
+# The copies are grouped by SORTING (:func:`sorted_experts_ffn`), so an
+# expert computes the copies routed to it and one that received none is
+# not read.  (Until PR 47 the two share cells grouped them by masking:
+# the held experts read as ONE SwiGLU of width ``H · F`` for every token,
+# the routing weight zero where a token was not routed.  That read all
+# 12 held experts of a Kimi layer at a decode step where 2.4 copies
+# landed, 3.4 GB a step of matrices multiplied by zero, and computed 48
+# times a prefill's routed products; PERF.md section 6, PR 47, has the
+# two forms side by side at every decode bucket.)
+
 
 @role("router")
 def sigmoid_topk_route(x, router_w, bias, k, scale):
@@ -403,44 +414,6 @@ def sigmoid_topk_route(x, router_w, bias, k, scale):
     w = jnp.take_along_axis(s, ids, axis=-1)
     w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * scale
     return ids.astype(jnp.int32), w
-
-
-@role("experts")
-def held_experts_ffn(x, ids, weights, w_gate, w_up, w_down, first,
-                     valid=None):
-    """The held experts' part of a routed SwiGLU layer, nothing dropped.
-
-    ``x``: ``[T, D]``.  ``ids``/``weights``: ``[T, k]`` from the router,
-    over all experts.  ``w_gate``/``w_up``: ``[H, F, D]`` (out, in) and
-    ``w_down``: ``[H, F, D]`` (in, out) for the ``H`` experts held,
-    which are experts ``first .. first + H - 1``.  Returns ``(y [T, D],
-    counts [H] int32)``: ``y = sum over held e of w_e · down_e(silu(
-    gate_e x) * up_e x)`` with ``w_e`` zero where the token was not
-    routed to ``e``, and the token-copies that landed on each held
-    expert (those of ``valid`` tokens, a ``[T]`` mask, where given).
-
-    Grouped by masking, not by sorting: the held experts' matrices are
-    read as ONE SwiGLU of width ``H · F`` (two plain products over the
-    stacked leaves, no copy and no gather of weights), and the routing
-    weight scales each expert's slice of the hidden activation between
-    them.  Every held expert is computed for every token, so the cost
-    does not depend on the routing, a skew onto one expert overflows
-    nothing, and a decode step, which reads every held weight once
-    whatever the routing, pays nothing for it; a long prefill computes
-    ``H · E / (k · H)`` times the products a sort would (PERF.md)."""
-    T, D = x.shape
-    H, Fw = w_gate.shape[0], w_gate.shape[1]
-    with role("router"):        # which held expert each copy landed on
-        local = ids - first
-        onehot = local[..., None] == jnp.arange(H, dtype=ids.dtype)  # [T,k,H]
-        gate_w = jnp.sum(jnp.where(onehot, weights[..., None], 0.0), axis=1)
-        live = onehot if valid is None else onehot & valid[:, None, None]
-        counts = jnp.sum(live, axis=(0, 1), dtype=jnp.int32)
-    g = x @ w_gate.reshape(H * Fw, D).T
-    u = x @ w_up.reshape(H * Fw, D).T
-    h = (jax.nn.silu(g) * u).reshape(T, H, Fw) \
-        * gate_w[..., None].astype(x.dtype)
-    return h.reshape(T, H * Fw) @ w_down.reshape(H * Fw, D), counts
 
 
 @role("router")
@@ -468,26 +441,58 @@ def _row_tile(rows):
 
 
 # the matrix tile of a grouped product: a group's whole matrix where it
-# is one expert's of 2560 x 768 (7.9 MB twice buffered, beside 256 rows
-# of both sides inside the 16 MB a kernel may use), so that no output
-# tile is visited twice; a larger matrix is cut along its output
-_TILE_K, _TILE_ELEMS = 2560, 2560 * 768
+# is one expert's of 2560 x 768 (7.9 MB twice buffered), so that no
+# output tile is visited twice; a larger matrix is cut into a tile of
+# no more elements, whole lane tiles that divide it both ways: first
+# one that spans the matrix one way (``tk = K``, or ``tn = N`` where the
+# rows' tile leaves no room for that: a tile short both ways walks the
+# rows' tiles once for every tile of the output AND every tile of K,
+# 0.75 ms where 0.59 at Kimi's 7168 -> 2048 under 256 rows), then the
+# largest, then the taller in K (PERF.md section 6, PR 47: 13 tilings a
+# product at the two share cells' widths).  What a kernel may use
+# (16 MB) also holds the rows' and the output's tiles twice buffered and
+# the float32 accumulator: their sum stays inside what 256 rows of
+# 768 -> 2560 take, the largest that is known to compile (256 rows of
+# 7168 beside a 7168 x 256 tile, 14.5 MB by this count, do not).
+_TILE_ELEMS = 2560 * 768
+
+
+def _tile_bytes(tm, tk, tn):
+    """The rows', the matrix's and the output's tiles in bfloat16, twice
+    buffered, and the float32 accumulator."""
+    return 2 * 2 * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def _whole_lane_tiles(n):
+    """The tiles of whole 128 lanes that divide ``n``, and ``n``."""
+    return [t for t in range(128, n, 128) if n % t == 0] + [n]
 
 
 def _tiles(rows, K, N):
-    tk = min(K, _TILE_K)
-    return _row_tile(rows), tk, min(N, _TILE_ELEMS // tk // 128 * 128)
+    tm = _row_tile(rows)
+    *_, tk, tn = max(
+        (tk == K or tn == N, tk * tn, tk, tn)
+        for tk in _whole_lane_tiles(K) for tn in _whole_lane_tiles(N)
+        if tk * tn <= _TILE_ELEMS
+        and _tile_bytes(tm, tk, tn) <= _tile_bytes(256, 768, 2560))
+    return tm, tk, tn
 
 
-def _grouped_product(lhs, rhs, sizes, transpose_rhs, interpret=False):
+def _grouped_product(lhs, rhs, sizes, transpose_rhs, schedule=None,
+                     interpret=False):
     """``lhs [M, K]``, its rows sorted by group, times the group's own
     matrix of ``rhs`` (``[H, K, N]``, or ``[H, N, K]`` with
     ``transpose_rhs``): rows ``sum(sizes[:g]) .. sum(sizes[:g + 1]) - 1``
     times ``rhs[g]``; ``[M, N]`` in ``lhs``'s dtype, the rows past
     ``sum(sizes)`` undefined.  ``M`` is whole tiles of
-    :func:`_row_tile`.  On a TPU the Pallas grouped matmul that ships
-    with JAX (``megablox.gmm``): a group of no rows is not visited, so
-    its matrix is not read.  Elsewhere ``lax.ragged_dot_general``, which
+    :func:`_row_tile`.  On a TPU the repo's Pallas grouped matmul
+    (:func:`chainermn_tpu.ops.grouped_matmul.gmm`, the kernel that ships
+    with JAX as ``megablox.gmm``): a group of no rows is not visited, so
+    its matrix is not read.  Its order of visits is ``schedule``
+    (:func:`~chainermn_tpu.ops.grouped_matmul.group_metadata` of
+    ``sizes``, made here where a caller has none): products over the
+    same ``sizes`` and ``M`` share one.  Elsewhere
+    ``lax.ragged_dot_general``, which
     says the same in one line and which the TPU's compiler expands to
     ONE product over every group's matrix for every row (12.4 TFLOP
     where 0.19 are asked for, at 49152 rows of 2560 into 64 groups of
@@ -496,9 +501,11 @@ def _grouped_product(lhs, rhs, sizes, transpose_rhs, interpret=False):
     from ..ops.flash_attention import _on_tpu
     M, K = lhs.shape
     if interpret or _on_tpu():
-        from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+        from ..ops.grouped_matmul import gmm, group_metadata
         N = rhs.shape[1] if transpose_rhs else rhs.shape[2]
-        return gmm(lhs, rhs, sizes, lhs.dtype, _tiles(M, K, N),
+        if schedule is None:
+            schedule = group_metadata(sizes, M, _row_tile(M))
+        return gmm(lhs, rhs, schedule, _tiles(M, K, N),
                    transpose_rhs=transpose_rhs, interpret=interpret)
     dims = lax.RaggedDotDimensionNumbers(
         dot_dimension_numbers=(((1,), (2 if transpose_rhs else 1,)),
@@ -508,19 +515,32 @@ def _grouped_product(lhs, rhs, sizes, transpose_rhs, interpret=False):
                                   preferred_element_type=lhs.dtype)
 
 
+def _schedule(sizes, rows):
+    """The order of visits that the grouped products over ``rows`` sorted
+    copies in groups of ``sizes`` share on a TPU; ``None`` elsewhere."""
+    from ..ops.flash_attention import _on_tpu
+    if not _on_tpu():
+        return None
+    from ..ops.grouped_matmul import group_metadata
+    return group_metadata(sizes, rows, _row_tile(rows))
+
+
 @role("experts")
 def sorted_experts_ffn(x, ids, weights, w_gate, w_up, w_down, first,
                        activation, valid=None):
-    """The held experts' part of a routed gated layer, grouped by
-    SORTING, nothing dropped: :func:`held_experts_ffn`'s contract
-    (``x [T, D]``; ``ids``/``weights`` ``[T, k]`` over all experts;
-    ``w_gate``/``w_up`` ``[H, F, D]`` (out, in), ``w_down`` ``[H, F, D]``
-    (in, out) for experts ``first .. first + H - 1``; returns ``(y [T,
-    D], counts [H] int32)``) with the gate's ``activation`` a function
-    (``jax.nn.silu`` gives that function's mathematics) and two
-    differences: an expert computes only the copies routed to it, and a
-    token outside ``valid`` is not computed at all (its row of ``y`` is
-    zeros).
+    """The held experts' part of a routed gated layer, nothing dropped,
+    its products grouped by SORTING.
+
+    ``x``: ``[T, D]``.  ``ids``/``weights``: ``[T, k]`` from the router,
+    over all experts.  ``w_gate``/``w_up``: ``[H, F, D]`` (out, in) and
+    ``w_down``: ``[H, F, D]`` (in, out) for the ``H`` experts held,
+    which are experts ``first .. first + H - 1``.  ``activation``: the
+    gate's, a function (``jax.nn.silu`` makes the layer a SwiGLU).
+    Returns ``(y [T, D], counts [H] int32)``: ``y = sum over held e of
+    w_e · down_e(activation(gate_e x) * up_e x)`` over the experts the
+    token was routed to, and the token-copies that landed on each held
+    expert.  A token outside ``valid`` (a ``[T]`` mask, where given) is
+    neither computed nor counted: its row of ``y`` is zeros.
 
     The ``T · k`` copies are ordered by held expert with a stable sort;
     a copy for an expert not held here, or of a token outside ``valid``,
@@ -529,9 +549,11 @@ def sorted_experts_ffn(x, ids, weights, w_gate, w_up, w_down, first,
     they lie (no copy and no gather of weights; an expert without a copy
     is not read), the routing weight is applied once, in float32, where
     each token's ``k`` rows are brought back and added.  The cost
-    follows the routing: ``k / E`` of the masked form's products, and a
-    skew onto one expert lengthens that expert's group and nothing
-    else."""
+    follows the routing: an expert computes only the copies routed to
+    it, and a skew onto one expert lengthens that expert's group and
+    nothing else.  A share (``H`` of ``E`` experts) still orders and
+    pads all ``T · k`` copies, since every one of them may land here;
+    the rows behind the held groups are gathered and never read."""
     T, D = x.shape
     k, H = ids.shape[1], w_gate.shape[0]
     M = T * k
@@ -547,10 +569,13 @@ def sorted_experts_ffn(x, ids, weights, w_gate, w_up, w_down, first,
         token = jnp.zeros(rows, order.dtype).at[:M].set(order // k)
         counts = jnp.sum(group[:, None] == jnp.arange(H, dtype=group.dtype),
                          axis=0, dtype=jnp.int32)
+    # the three products' order of visits, once: the same ``counts``,
+    # rows and row tile (off the TPU there is none to make)
+    schedule = _schedule(counts, rows)
     xs = x[token]
-    hidden = activation(_grouped_product(xs, w_gate, counts, True)) \
-        * _grouped_product(xs, w_up, counts, True)
-    out = _grouped_product(hidden, w_down, counts, False)
+    hidden = activation(_grouped_product(xs, w_gate, counts, True, schedule)) \
+        * _grouped_product(xs, w_up, counts, True, schedule)
+    out = _grouped_product(hidden, w_down, counts, False, schedule)
     # the way back, one gather of ``[T, D]`` for each of a token's k
     # copies, weighted and added as it arrives: at 8192 tokens 2.4 ms
     # where one gather of all ``T · k`` rows and a sum over them took
@@ -585,15 +610,33 @@ def _draw_share(link, d_model, d_expert, n_experts, held):
     return int(first), int(count)
 
 
+# one body a program: a model's expert layers are alike, and jitted here
+# the second and every later one of a program is a call of the first's
+# lowering.  Traced layer by layer the sorts, the gathers and the two
+# kernels of 4 (Kimi) or 8 (Laguna) layers added 0.2-0.4 s to each of
+# the 25-29 programs an engine warms up, 3.3 s of Kimi's ``setup_s``
+# (PERF.md section 6, PR 47); what the one body still cost a program
+# was the grouped products' order of visits, made inside each product
+# (twice a body) until ``sorted_experts_ffn`` made it once (PR 48).
+@functools.partial(jax.jit, static_argnames=("k", "scale", "first"))
+def _held_share(x, router, bias, w_gate, w_up, w_down, valid, *, k, scale,
+                first):
+    ids, w = sigmoid_topk_route(x, router, bias, k, scale)
+    return sorted_experts_ffn(x, ids, w, w_gate, w_up, w_down, first,
+                              jax.nn.silu, valid=valid)
+
+
 class HeldExperts(Link):
     """A chip's share of a routed expert layer: the router over all
     ``n_experts`` and the SwiGLU experts ``held = (first, count)``.
 
-    ``forward(x, valid=None)`` → ``(y, counts)`` as
-    :func:`held_experts_ffn`.  Under an expert-parallel axis of
-    ``n_experts // count`` chips this is each chip's layer (the sum
-    over chips of ``y`` is the whole layer's routed part); on one chip
-    it runs as it stands, with no exchange."""
+    ``forward(x, valid=None)`` → ``(y, counts)``: sigmoid scores with a
+    selection bias choose ``k`` of all the experts
+    (:func:`sigmoid_topk_route`), and the held ones' products are grouped
+    by sorting (:func:`sorted_experts_ffn`).  Under an expert-parallel
+    axis of ``n_experts // count`` chips this is each chip's layer (the
+    sum over chips of ``y`` is the whole layer's routed part); on one
+    chip it runs as it stands, with no exchange."""
 
     def __init__(self, d_model, d_expert, n_experts, held, k,
                  routed_scale=1.0):
@@ -611,13 +654,12 @@ class HeldExperts(Link):
         self.router_bias.draw((n_experts,), np.float32,
                               lambda: np.zeros(n_experts, np.float32))
 
+    @role("experts")        # the call's own name; the parts keep theirs
     def forward(self, x, valid=None):
-        ids, w = sigmoid_topk_route(x, self.router.array,
-                                    self.router_bias.array, self.k,
-                                    self.routed_scale)
-        return held_experts_ffn(x, ids, w, self.w_gate.array,
-                                self.w_up.array, self.w_down.array,
-                                self.first, valid=valid)
+        return _held_share(x, self.router.array, self.router_bias.array,
+                           self.w_gate.array, self.w_up.array,
+                           self.w_down.array, valid, k=self.k,
+                           scale=self.routed_scale, first=self.first)
 
 
 class SortedExperts(Link):

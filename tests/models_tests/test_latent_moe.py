@@ -34,7 +34,7 @@ import jax.numpy as jnp
 from benchmark import harness, weights
 from benchmark.models import _init
 from chainermn_tpu.core.link import bind_state, extract_state
-from chainermn_tpu.parallel.moe import (HeldExperts, held_experts_ffn,
+from chainermn_tpu.parallel.moe import (HeldExperts, sorted_experts_ffn,
                                         sigmoid_topk_route)
 from chainermn_tpu.serving import (Request, ServingEngine,
                                    UnsupportedProgramError)
@@ -209,10 +209,10 @@ def test_all_shares_with_the_shared_expert_once_make_the_uncut_layer(built):
     parts, copies = 0.0, 0
     for share in range(N_EXPERTS // held):
         sl = slice(share * held, (share + 1) * held)
-        y, counts = held_experts_ffn(
+        y, counts = sorted_experts_ffn(
             x, ids, w, layer["experts/w_gate"][sl],
             layer["experts/w_up"][sl], layer["experts/w_down"][sl],
-            share * held)
+            share * held, jax.nn.silu)
         parts, copies = parts + y, copies + int(counts.sum())
     assert copies == 40 * cfg["num_experts_per_tok"]   # none dropped
     np.testing.assert_allclose(np.asarray(alike + parts),
@@ -293,3 +293,7 @@ def test_decode_counts_the_held_copies_of_live_lanes_only(built):
     stats = model.serve_span_stats(np.asarray(live))
     assert stats["held_copies"] == live.sum() / 2
     assert stats["held_max"] <= stats["held_copies"]
+    # the held experts that received a copy, summed over expert layers
+    assert stats["held_hit"] == int((np.asarray(live) > 0).sum()) > 0
+    assert model.serve_span_stats(np.array([[2, 0, 1, 0], [0, 0, 0, 4]])) \
+        == {"held_copies": 3.5, "held_max": 3.0, "held_hit": 3}

@@ -12,11 +12,12 @@ import os
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from benchmark import harness, weights
 from benchmark.models import _init
-from chainermn_tpu.parallel.moe import held_experts_ffn, sigmoid_topk_route
+from chainermn_tpu.parallel.moe import sigmoid_topk_route, sorted_experts_ffn
 from chainermn_tpu.serving import Request, ServingEngine
 from chainermn_tpu.serving.errors import UnsupportedProgramError
 
@@ -171,10 +172,10 @@ def test_all_shares_with_the_shared_expert_once_make_the_uncut_layer(built):
     parts, copies = 0.0, 0
     for share in range(N_EXPERTS // held):
         sl = slice(share * held, (share + 1) * held)
-        y, counts = held_experts_ffn(
+        y, counts = sorted_experts_ffn(
             x, ids, w, layer["experts/w_gate"][sl],
             layer["experts/w_up"][sl], layer["experts/w_down"][sl],
-            share * held)
+            share * held, jax.nn.silu)
         parts, copies = parts + y, copies + int(counts.sum())
     assert copies == 40 * cfg["num_experts_per_tok"]   # none dropped
     np.testing.assert_allclose(np.asarray(alike + parts),
@@ -213,7 +214,9 @@ def test_a_window_layers_decode_gather_does_not_grow_with_the_context(
         built, max_context):
     """From the lowered decode program's shapes: each window layer
     gathers ``window / page + 1`` pages a lane from its K and from its V
-    pool, each full layer the whole block table."""
+    pool, each full layer the whole block table.  The layers of a kind
+    share ONE lowering of their read (``window_moe._decode_attention``,
+    PR 48): the program holds one gather a kind and calls it a layer."""
     import re
     cfg = tiny_config()
     model = harness.load_module("models", "window_moe_lm").build(
@@ -230,8 +233,9 @@ def test_a_window_layers_decode_gather_does_not_grow_with_the_context(
     gathered = re.findall(r"-> tensor<4x(\d+)x8x64xf32>",
                           "\n".join(line for line in text.splitlines()
                                     if "stablehlo.gather" in line))
-    assert sorted(gathered) == sorted(
-        [str(WINDOW // PAGE + 1)] * 3 + [str(N)] * 2)
+    assert sorted(gathered) == sorted([str(WINDOW // PAGE + 1), str(N)])
+    calls = re.findall(r"call @(_decode_attention\w*)\(", text)
+    assert len(calls) == 3 + 2 and len(set(calls)) == 2
 
 
 def test_span_stats_count_the_experts_hit(built):

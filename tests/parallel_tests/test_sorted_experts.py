@@ -1,12 +1,16 @@
 """``sorted_experts_ffn`` (the routed products grouped by sorting) held to
-``held_experts_ffn`` (the same contract, grouped by masking) on the same
-inputs, over the routings that break a sort: even, every copy on one
-expert, an expert with none, a share that starts past expert 0 and a
-``valid`` mask; the shares' sum is the whole layer; the router is the
-softmax over all kept at the chosen; and the Pallas grouped product the
-TPU takes, interpreted, is the plain ragged product the CPU takes.
-float32 throughout: the two forms add the same terms in another order,
-so they agree to rounding (1e-5 on sums of 16-48 terms of size 1)."""
+a plain loop over tokens and their chosen experts on the same inputs,
+over the routings that break a sort: even, every copy on one expert, an
+expert with none, a share that starts past expert 0 and a ``valid``
+mask; ``HeldExperts`` (sigmoid routing with a selection bias, then that
+function) at the two share cells' ``(held, k, experts)``; the shares'
+sum is the whole layer; the router is the softmax over all kept at the
+chosen; the Pallas grouped product the TPU takes, interpreted, is the
+plain ragged product the CPU takes; and the tiles it is given divide the
+cells' matrices.  float32 throughout: the forms add the same terms in
+another order, so they agree to rounding (1e-5 on sums of 16-48 terms of
+size 1).  (Until PR 47 the reference was ``held_experts_ffn``, the same
+contract grouped by masking, which that PR deleted.)"""
 
 import functools
 
@@ -17,7 +21,8 @@ import jax
 import jax.numpy as jnp
 
 from chainermn_tpu.parallel import moe
-from chainermn_tpu.parallel.moe import (SortedExperts, held_experts_ffn,
+from chainermn_tpu.parallel.moe import (HeldExperts, SortedExperts,
+                                        sigmoid_topk_route,
                                         softmax_topk_route,
                                         sorted_experts_ffn)
 
@@ -31,6 +36,28 @@ def _leaves(rng, held):
                            .astype(np.float32))
     return (draw(held, F, D, fan_in=D), draw(held, F, D, fan_in=D),
             draw(held, F, D, fan_in=F))
+
+
+def plain_experts_ffn(x, ids, weights, w_gate, w_up, w_down, first,
+                      activation=jax.nn.silu, valid=None):
+    """The contract, token by token and copy by copy in float32: ``(y,
+    counts)``, a token outside ``valid`` neither computed nor counted."""
+    x, weights = np.asarray(x, np.float32), np.asarray(weights, np.float32)
+    w_gate, w_up, w_down = (np.asarray(a, np.float32)
+                            for a in (w_gate, w_up, w_down))
+    ids, held = np.asarray(ids), w_gate.shape[0]
+    y = np.zeros_like(x)
+    counts = np.zeros(held, np.int32)
+    for t in range(x.shape[0]):
+        if valid is not None and not bool(valid[t]):
+            continue
+        for j, e in enumerate(ids[t] - first):
+            if 0 <= e < held:
+                counts[e] += 1
+                hidden = np.asarray(activation(jnp.asarray(
+                    w_gate[e] @ x[t]))) * (w_up[e] @ x[t])
+                y[t] += weights[t, j] * (hidden @ w_down[e])
+    return y, counts
 
 
 def _routing(name, rng):
@@ -57,32 +84,84 @@ CASES = ["random", "even", "all_on_one", "one_expert_idle",
 
 
 @pytest.mark.parametrize("case", CASES)
-def test_sorted_with_silu_is_the_masked_form(case):
+def test_sorted_with_silu_is_the_plain_loop(case):
     rng = np.random.RandomState(CASES.index(case))
     logits, first, held, valid = _routing(case, rng)
     x = jnp.asarray(rng.normal(size=(T, D)).astype(np.float32))
     w_gate, w_up, w_down = _leaves(rng, held)
     ids, w = softmax_topk_route(logits, K)
-    want, want_counts = held_experts_ffn(x, ids, w, w_gate, w_up, w_down,
-                                         first, valid=valid)
+    want, want_counts = plain_experts_ffn(x, ids, w, w_gate, w_up, w_down,
+                                          first, valid=valid)
     got, counts = jax.jit(functools.partial(
         sorted_experts_ffn, first=first, activation=jax.nn.silu))(
             x, ids, w, w_gate, w_up, w_down, valid=valid)
     assert counts.dtype == jnp.int32
-    np.testing.assert_array_equal(np.asarray(counts),
-                                  np.asarray(want_counts))
-    keep = np.ones(T, bool) if valid is None else np.asarray(valid)
-    np.testing.assert_allclose(np.asarray(got)[keep],
-                               np.asarray(want)[keep], atol=ATOL, rtol=0)
+    np.testing.assert_array_equal(np.asarray(counts), want_counts)
+    np.testing.assert_allclose(np.asarray(got), want, atol=ATOL, rtol=0)
     # a token outside ``valid`` is not computed at all
+    keep = np.ones(T, bool) if valid is None else np.asarray(valid)
     assert not np.asarray(got)[~keep].any()
-    assert np.abs(np.asarray(want)[keep]).max() > 0.1
+    assert np.abs(want[keep]).max() > 0.1
     if case == "all_on_one":
         assert int(counts[5]) == T
     if case == "one_expert_idle":
         assert int(counts[7]) == 0
     if case in ("random", "even", "all_on_one", "one_expert_idle"):
         assert int(counts.sum()) == T * K       # nothing dropped
+
+
+# (held, k, experts) of kimi-k2.6-share and laguna-s-2.1-share, at narrow
+# widths; a decode step's tokens, a suffix prefill's, and a padded prompt
+SHARES = {"kimi": (12, 8, 384, 2.827), "laguna": (16, 10, 256, 2.5)}
+
+
+def _share(name, first):
+    held, k, n_experts, scale = SHARES[name]
+    link = HeldExperts(D, F, n_experts, (first, held), k, routed_scale=scale)
+    rng = np.random.RandomState(held)
+    # a selection bias that draws tokens onto the share, as a trained
+    # one moves them between experts: a tenth of the copies land here
+    bias = rng.normal(0, 0.05, n_experts).astype(np.float32)
+    bias[first:first + held] += 0.15
+    link.router_bias.array = jnp.asarray(bias)
+    return link
+
+
+@pytest.mark.parametrize("tokens, masked", [(16, False), (96, False),
+                                            (96, True)],
+                         ids=["decode", "suffix", "valid_mask"])
+@pytest.mark.parametrize("name, first", [("kimi", 0), ("laguna", 32)])
+def test_held_experts_is_the_plain_loop_at_the_share_cells_routing(
+        name, first, tokens, masked):
+    link = _share(name, first)
+    rng = np.random.RandomState(tokens)
+    x = jnp.asarray(rng.normal(size=(tokens, D)).astype(np.float32))
+    valid = jnp.arange(tokens) < 70 if masked else None
+    got, counts = jax.jit(lambda x: link(x, valid=valid))(x)
+    ids, w = sigmoid_topk_route(x, link.router.array,
+                                link.router_bias.array, link.k,
+                                link.routed_scale)
+    want, want_counts = plain_experts_ffn(
+        x, ids, w, link.w_gate.array, link.w_up.array, link.w_down.array,
+        first, valid=valid)
+    np.testing.assert_array_equal(np.asarray(counts), want_counts)
+    np.testing.assert_allclose(np.asarray(got), want, atol=ATOL, rtol=0)
+    # most copies go to experts held elsewhere, and some land here
+    assert 0 < int(counts.sum()) < tokens * link.k // 2
+    assert np.abs(want).max() > 0.1
+
+
+@pytest.mark.parametrize("name", sorted(SHARES))
+def test_a_share_that_receives_no_copy_returns_zeros(name):
+    link = _share(name, 0)
+    bias = np.zeros(link.n_experts, np.float32)
+    bias[:link.count] = -10.0           # nobody chooses a held expert
+    link.router_bias.array = jnp.asarray(bias)
+    x = jnp.asarray(np.random.RandomState(3).normal(size=(16, D))
+                    .astype(np.float32))
+    y, counts = jax.jit(link)(x)
+    assert not np.asarray(counts).any() and not np.asarray(y).any()
+    assert y.shape == x.shape and y.dtype == x.dtype
 
 
 def test_relu_is_another_layer_than_silu():
@@ -147,10 +226,10 @@ def test_the_router_is_the_softmax_over_all_kept_at_the_chosen():
 @pytest.mark.parametrize("transpose_rhs", [True, False])
 def test_the_tpus_grouped_product_interpreted_is_the_plain_one(
         transpose_rhs):
-    """``megablox.gmm`` in interpret mode against ``lax.ragged_dot_general``
-    on the rows of the groups (the rows past them are undefined in the
-    kernel, zeros in the plain form), with an empty group in the middle
-    and groups that end inside a tile."""
+    """``ops.grouped_matmul.gmm`` interpreted against
+    ``lax.ragged_dot_general`` on the rows of the groups (the rows past
+    them are undefined in the kernel, zeros in the plain form), with an
+    empty group in the middle and groups that end inside a tile."""
     rng = np.random.RandomState(6)
     sizes = jnp.asarray([10, 0, 21, 7, 3], jnp.int32)
     lhs = jnp.asarray(rng.normal(size=(64, D)).astype(np.float32))
@@ -194,13 +273,32 @@ def test_row_tiles_divide_what_the_model_pads_to(rows, tile):
 
 
 def test_a_groups_matrix_is_one_tile_at_the_published_widths():
-    """2560 x 768 either way round: an output tile is visited once.  A
-    larger matrix is cut along its output, in whole lane tiles."""
+    """2560 x 768 either way round: an output tile is visited once
+    (SmallThinker's tiles, as PR 44 swept them; PR 47 made the rule one
+    over ``K`` and ``N`` and these are what it must still give)."""
     assert moe._tiles(49152, 2560, 768) == (256, 2560, 768)
     assert moe._tiles(49152, 768, 2560) == (256, 768, 2560)
     assert moe._tiles(192, 2560, 768) == (32, 2560, 768)
-    assert moe._tiles(49152, 7168, 2048) == (256, 2560, 768)
+    assert moe._tiles(192, 768, 2560) == (32, 768, 2560)
+    assert moe._tiles(12, 2560, 768) == (16, 2560, 768)
     assert moe._tiles(120, 32, 16) == (32, 32, 16)
+
+
+@pytest.mark.parametrize("rows", [8, 128, 512, 4096, 32768, 107520])
+@pytest.mark.parametrize("K, N", [(7168, 2048), (2048, 7168),
+                                  (3072, 1024), (1024, 3072)])
+def test_a_larger_matrix_is_cut_into_tiles_that_divide_it(rows, K, N):
+    """Kimi's and Laguna's experts, at a decode step's and a prefill's
+    rows: whole lane tiles that divide ``K`` and ``N``, no more elements
+    than SmallThinker's whole matrix, and with the rows' and the
+    output's tiles inside what its largest call takes."""
+    tm, tk, tn = moe._tiles(rows, K, N)
+    assert tm == moe._row_tile(rows)
+    assert K % tk == 0 and N % tn == 0
+    assert tk % 128 == 0 and tn % 128 == 0
+    assert 2560 * 768 // 2 < tk * tn <= 2560 * 768
+    assert moe._tile_bytes(tm, tk, tn) <= moe._tile_bytes(256, 768, 2560) \
+        < 14 * 2 ** 20
 
 
 def test_the_link_holds_a_router_without_bias_and_refuses_a_bad_share():

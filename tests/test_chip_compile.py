@@ -13,6 +13,7 @@ would refuse (tiling, VMEM, HBM, donation that does not alias).
 import functools
 import importlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +42,18 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_trace_from_another_backend():
+    """A jitted body inside the models (``moe._held_share``,
+    ``window_moe._decode_attention``, ``looped._decode_attention``)
+    keeps its trace by its shapes, not by what ``_on_tpu()`` answered
+    when it was made; the tests here answer it both ways at the same
+    shapes."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
 
 
 @pytest.fixture
@@ -73,6 +86,11 @@ def _lse_fwd_bwd():
         out, lse = fa._flash_lse_diff(q, k, v, True, 0.125, False)
         return jnp.sum(out.astype(jnp.float32)) + jnp.sum(lse)
     return jax.value_and_grad(loss, argnums=(0, 1, 2))
+
+
+def _has_axis(text, n):
+    """Whether any array of the compiled program has an axis of ``n``."""
+    return re.search(rf"[\[,]{n}[,\]]", text) is not None
 
 
 def _compile(fn, *specs, **jit_kw):
@@ -404,7 +422,7 @@ def latent(one_chip):
     return model, state, pool, spec
 
 
-def _assert_pool_in_place(compiled, text, pool):
+def _assert_pool_in_place(compiled, text, pool, temp=None):
     """Donated, aliased, and never copied whole: the pool's STORED layout
     (the runtime's choice for its shape) is the one the program computes
     in.  A latent of 576 values, 4.5 lane tiles, is stored page-axis
@@ -414,18 +432,25 @@ def _assert_pool_in_place(compiled, text, pool):
     assert compiled.memory_analysis().alias_size_in_bytes >= nbytes
     assert "bf16[2,18432,16,640]{3,2,1,0" in text
     assert "bf16[2,18432,16,640]{1,3,2,0" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < nbytes
+    assert compiled.memory_analysis().temp_size_in_bytes < (temp or nbytes)
 
 
+@pytest.mark.parametrize("lanes", [1, 16, 64])
 def test_latent_decode_program_updates_its_pool_in_place(
-        latent, no_persistent_cache):
+        latent, no_persistent_cache, monkeypatch, lanes):
+    """The decode bucket's ends and its usual one.  The expert layer's
+    held share is three grouped products over the stacked leaves (PR 47):
+    no product over all 12 as one matrix of width 12 x 2048."""
     from chainermn_tpu.serving import decode_program
     model, state, pool, spec = latent
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
     compiled, text = _compile(
         functools.partial(decode_program, model, mode=None), state, pool,
-        spec((64,), jnp.int32), spec((64,), jnp.int32),
-        spec((64, 288), jnp.int32), donate_argnums=(1,))
+        spec((lanes,), jnp.int32), spec((lanes,), jnp.int32),
+        spec((lanes, 288), jnp.int32), donate_argnums=(1,))
     _assert_pool_in_place(compiled, text, pool)
+    assert len(_grouped_products(text)) == 3
+    assert not _has_axis(text, 12 * 2048)
 
 
 def test_latent_prefill_program_compiles_with_the_flash_kernel(
@@ -437,8 +462,33 @@ def test_latent_prefill_program_compiles_with_the_flash_kernel(
         functools.partial(prefill_program, model), state, pool,
         spec((1, 4096), jnp.int32), spec((), jnp.int32),
         spec((288,), jnp.int32), donate_argnums=(1,))
-    assert "_flash_kernel" in text and text.count("tpu_custom_call") == 2
-    _assert_pool_in_place(compiled, text, pool)
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert sum("_flash_kernel" in c for c in calls) == 2
+    assert len(_grouped_products(text)) == 3 and len(calls) == 5
+    assert not _has_axis(text, 12 * 2048)   # no product over the 12 as one
+    # the 32768 sorted copies' rows in and out are 0.47 GB each; a copy
+    # of the pool would be 0.75 GB more
+    _assert_pool_in_place(compiled, text, pool, temp=1.2e9)
+
+
+@pytest.mark.parametrize("suffix", [64, 512])
+def test_latent_suffix_program_groups_its_experts_by_sorting(
+        latent, no_persistent_cache, monkeypatch, suffix):
+    """The cell's first and last suffix bucket (512 and 4096 sorted
+    copies: row tiles of 32 and 256, ``up`` tiles of 7168 x 256 and
+    896 x 2048)."""
+    from chainermn_tpu.serving import prefix_prefill_program
+    model, state, pool, spec = latent
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    compiled, text = _compile(
+        functools.partial(prefix_prefill_program, model), state, pool,
+        spec((1, suffix), jnp.int32), spec((), jnp.int32),
+        spec((), jnp.int32), spec((288,), jnp.int32), donate_argnums=(1,))
+    assert len(_grouped_products(text)) == 3
+    assert not _has_axis(text, 12 * 2048)
+    # (the temporaries are the gathered context's, a pool's size and more)
+    _assert_pool_in_place(compiled, text, pool, temp=1.2e9)
 
 
 # -- window and full attention side by side (laguna-s-2.1-share) ---------------
@@ -496,7 +546,8 @@ def test_windowed_decode_program_at_its_first_and_last_bucket(
     from chainermn_tpu.ops import paged_attention
     from chainermn_tpu.serving import decode_program
     model, state, pools, spec = windowed
-    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: kernel)
+    for module in (paged_attention, fa):
+        monkeypatch.setattr(module, "_on_tpu", lambda: kernel)
     assert [p.shape for p in pools] == \
         [(3, 21504, 16, 2048), (6, 3840, 16, 2048)]
     assert _pool_bytes(pools[1:]) * 4 <= 6 * 21504 * 16 * 2048 * 2
@@ -512,11 +563,17 @@ def test_windowed_decode_program_at_its_first_and_last_bucket(
         calls = [line for line in text.splitlines()
                  if "custom_call_target=\"tpu_custom_call\"" in line]
         assert sum("_paged_decode_kernel" in c for c in calls) == 9
+        # the 8 expert layers' held shares, three grouped products each
+        assert len(_grouped_products(text)) == 3 * 8
+        assert not _has_axis(text, 16 * 1024)   # none over the 16 as one
         assert ma.temp_size_in_bytes < 0.1 * per_lane * lanes + 5e7
         assert ",672,16,2048]" not in text
         return
-    assert per_lane * lanes < ma.temp_size_in_bytes \
-        < 1.2 * per_lane * lanes + 5e7, ma.temp_size_in_bytes
+    # (since PR 48 a layer's index in its group is an operand of the one
+    # read a kind of layer shares, and the gathered pages of a full layer
+    # are no longer laid down whole: 21 MB at one lane where 44 were)
+    assert ma.temp_size_in_bytes < 1.2 * per_lane * lanes + 5e7, \
+        ma.temp_size_in_bytes
     if lanes > 1:                  # (one lane's unit axis is folded away)
         assert "bf16[32,33,16,2048]" in text     # a window layer's pages
         assert "bf16[32,672,16,2048]" in text    # a full layer's
@@ -541,10 +598,32 @@ def test_windowed_prefill_program_at_10752_tokens(windowed,
     assert sum("_flash_window_kernel" in c for c in calls) == 6
     assert sum("_flash_kernel" in c for c in calls) == 3
     # K and V go in as 8 heads: nothing of 48 or 72 K/V heads is made
-    assert all("bf16[8,10752,128]" in c for c in calls)
+    assert all("bf16[8,10752,128]" in c for c in calls if "_flash" in c)
+    assert len(_grouped_products(text)) == 3 * 8
+    assert not _has_axis(text, 16 * 1024)
     ma = compiled.memory_analysis()
     assert ma.alias_size_in_bytes >= _pool_bytes(pools)
-    assert 1.0e9 < ma.temp_size_in_bytes < 1.6e9, ma.temp_size_in_bytes
+    # of which 0.66 GB a time are the 107520 sorted copies' rows
+    assert 1.0e9 < ma.temp_size_in_bytes < 2.4e9, ma.temp_size_in_bytes
+
+
+@pytest.mark.parametrize("suffix", [256, 2048])
+def test_windowed_suffix_program_groups_its_experts_by_sorting(
+        windowed, no_persistent_cache, monkeypatch, suffix):
+    """The cell's first and last suffix bucket (2560 and 20480 sorted
+    copies): pools in place, 24 grouped products."""
+    from chainermn_tpu.serving import prefix_prefill_program
+    model, state, pools, spec = windowed
+    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
+    compiled, text = _compile(
+        functools.partial(prefix_prefill_program, model), state, *pools,
+        spec((1, suffix), jnp.int32), spec((), jnp.int32),
+        spec((), jnp.int32), spec((2, LAGUNA_CONTEXT // PAGE), jnp.int32),
+        donate_argnums=(1, 2))
+    assert len(_grouped_products(text)) == 3 * 8
+    assert not _has_axis(text, 16 * 1024)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= _pool_bytes(pools)
 
 
 # -- a whole expert layer grouped by sorting (smallthinker-21b-a3b-stage) ----
@@ -882,11 +961,14 @@ def _pallas_calls(jaxpr, name):
 
 # `_paged_decode_kernel` bakes a STATIC layer in and takes a TRACED one as
 # one more prefetched scalar ahead of the block table and the contexts.
-# The two models that read their pages through it at a static layer must
-# keep the form their cells were accepted with, at every layer; the
-# looped model's loop supplies the third scalar.
+# The hybrid model reads its pages through it at a static layer and keeps
+# the form its cell was accepted with, at every layer; the looped model's
+# loop supplies the third scalar, and since PR 48 so does the windowed
+# model (and the prerouted one, its subclass): the layers of a kind share
+# one trace and one lowering of the kernel, the layer's index an operand
+# (`models/window_moe._decode_attention`).
 @pytest.mark.parametrize("which, calls, scalars", [
-    ("windowed", None, 2), ("hybrid", None, 2), ("looped", 48, 3)])
+    ("windowed", None, 3), ("hybrid", None, 2), ("looped", 48, 3)])
 def test_decode_kernel_prefetches_a_layer_only_inside_a_loop(
         request, monkeypatch, which, calls, scalars):
     from chainermn_tpu.serving import decode_program
